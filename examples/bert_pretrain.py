@@ -15,11 +15,6 @@ import argparse
 import time
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    # Some environments force a hardware platform through jax.config at
-    # startup; make the env var authoritative for the example.
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 import jax.numpy as jnp
 import optax
 

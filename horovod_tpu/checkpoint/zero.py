@@ -115,9 +115,9 @@ def _owned_ranks(mesh, axis_name):
     present' would make every process write (a replicated-only copy of)
     every rank's shard, racing the true owner's complete file."""
     import jax
-    pidx = jax.process_index() if hasattr(jax, "process_index") else 0
+    pidx = jax.process_index()
     return {r for d, r in _rank_of_device(mesh, axis_name).items()
-            if getattr(d, "process_index", 0) == pidx}
+            if d.process_index == pidx}
 
 
 # ---------------------------------------------------------------------------

@@ -1,45 +1,4 @@
-"""Version-compat shims for the installed JAX.
+"""One import site for ``shard_map`` and ``axis_size`` (jax 0.9)."""
 
-``shard_map`` has moved twice upstream: ``jax.experimental.shard_map``
-(<= 0.4.x, kwarg ``check_rep``) -> ``jax.shard_map`` (>= 0.5, kwarg
-renamed to ``check_vma``).  Code in this repo is written against the
-new spelling; this module exposes a ``shard_map`` that accepts the new
-signature on every supported JAX and translates for old ones, so the
-models, tests, and examples share one import site instead of each
-guessing the installed version.
-"""
-
-from __future__ import annotations
-
-try:
-    from jax import shard_map as _shard_map  # JAX >= 0.5: check_vma kwarg
-    _NATIVE_CHECK_VMA = True
-except ImportError:  # JAX <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NATIVE_CHECK_VMA = False
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis, inside ``shard_map``/``pmap``.
-
-    ``jax.lax.axis_size`` only exists on newer JAX; older versions get
-    the same static int from the constant-folding path of ``psum(1)``
-    (a non-tracer operand is multiplied by the axis size eagerly).
-    """
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-    """``jax.shard_map`` with the modern keyword surface on any JAX.
-
-    On pre-0.5 JAX the replication-check kwarg was named ``check_rep``;
-    a ``check_vma`` argument is translated so call sites never branch on
-    the installed version.
-    """
-    if not _NATIVE_CHECK_VMA and "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+from jax import shard_map  # noqa: F401
+from jax.lax import axis_size  # noqa: F401

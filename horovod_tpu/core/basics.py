@@ -15,6 +15,7 @@ differences:
 
 from __future__ import annotations
 
+import os as _os
 from typing import Optional, Sequence
 
 from . import state as _state
@@ -22,6 +23,12 @@ from .config import Config, get_env as _cfg_get
 from .exceptions import NotInitializedError
 from .state import global_state, _env_int
 from ..utils import logging as log
+
+# <checkout>/.jax_cache: derived from the package's own location, never from
+# tempfile, a pid or a time.
+COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__)))), ".jax_cache")
 
 
 def init(mesh=None,
@@ -47,15 +54,15 @@ def init(mesh=None,
     global_state.config = Config.from_env()
 
     # --- persistent compilation cache -------------------------------------
-    # HVD_TPU_COMPILE_CACHE_DIR points XLA's persistent cache at a durable
-    # directory so re-runs (and elastic respawns) skip recompilation —
-    # silicon spends its live minutes executing instead of compiling.
-    # Setting the config does NOT initialize the accelerator backend, so
-    # it is safe before the launcher-worker topology resolution below.
-    if global_state.config.compile_cache_dir:
+    # The directory is part of the cache key, so it must not move between
+    # runs: JAX_COMPILATION_CACHE_DIR when the environment places it (JAX
+    # reads that itself; nothing to do here), else one fixed directory in
+    # the checkout.  Launched workers inherit the variable or resolve the
+    # same path.  Setting the config does NOT initialize the accelerator
+    # backend, so it is safe before the topology resolution below.
+    if "JAX_COMPILATION_CACHE_DIR" not in _os.environ:
         import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          global_state.config.compile_cache_dir)
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
 
     # --- topology ---------------------------------------------------------
     # Launcher-spawned workers MUST NOT touch the JAX backend here: N
@@ -68,7 +75,6 @@ def init(mesh=None,
     # Elastic workers fetch their (re-)assignment from the rendezvous KV
     # each init — the world may have changed since the last round.
     elastic_assignment = None
-    import os as _os
     if _os.environ.get("HVD_TPU_ELASTIC_SLOT"):
         from ..runner.worker import fetch_assignment
         elastic_assignment = fetch_assignment(
@@ -304,7 +310,6 @@ def init(mesh=None,
 
 def _build_default_mesh(axes: Optional[Sequence[str]] = None):
     import jax
-    import numpy as np
     from jax.experimental import mesh_utils
 
     spec = global_state.config.mesh_axes
@@ -317,11 +322,8 @@ def _build_default_mesh(axes: Optional[Sequence[str]] = None):
             dims.append(int(dim))
         devices = mesh_utils.create_device_mesh(tuple(dims))
         return jax.sharding.Mesh(devices, tuple(names))
-    n = jax.device_count()
-    try:
-        devices = mesh_utils.create_device_mesh((n,))
-    except Exception:
-        devices = np.array(jax.devices())
+    from ..parallel.mesh import ici_device_array
+    devices = ici_device_array((jax.device_count(),), jax.devices())
     return jax.sharding.Mesh(devices, (_state.DATA_AXIS,))
 
 

@@ -55,7 +55,6 @@ SCHEDULE_PROBE_REPS = "SCHEDULE_PROBE_REPS"    # timed reps per arm
 BATCH_D2D_MEMCOPIES = "BATCH_D2D_MEMCOPIES"
 ELASTIC = "ELASTIC"
 MESH_AXES = "MESH_AXES"                        # TPU-only: mesh axis spec
-COMPILE_CACHE_DIR = "COMPILE_CACHE_DIR"        # TPU-only: persistent XLA cache
 # Input pipeline (horovod_tpu/data/).
 DATA_PREFETCH = "DATA_PREFETCH"                # background prefetch on/off
 DATA_QUEUE_DEPTH = "DATA_QUEUE_DEPTH"          # prefetch queue depth
@@ -292,7 +291,6 @@ class Config:
     schedule_probe_reps: int = 2
     elastic: bool = False
     mesh_axes: str = ""
-    compile_cache_dir: str = ""
     # Input pipeline: prefetch on, double buffering, no hard stall
     # ceiling (the warning still fires at stall_warning_time_seconds).
     data_prefetch: bool = True
@@ -340,9 +338,8 @@ class Config:
     # host gap) and feeds the EWMA/CUSUM drift detector; both default on
     # (the per-step cost is a handful of cached metric reads — bench.py
     # --bench attribution pins it under the 1% bar).  peak_tflops grades
-    # hvd_mfu_ratio: 0 = the chip's spec-sheet peak by device kind; set
-    # it to a CALIBRATED ceiling instead (round-5 silicon measured 171
-    # TFLOP/s steady matmul on the 197-peak v5e — docs/mfu_readiness.md).
+    # hvd_mfu_ratio: 0 = the chip's spec-sheet peak by exact device kind
+    # (metrics/attribution.PEAK_FLOPS_BY_KIND).
     attribution: bool = True
     attribution_jsonl: str = ""
     peak_tflops: float = 0.0
@@ -494,7 +491,6 @@ class Config:
             1, get_int(SCHEDULE_PROBE_REPS, cfg.schedule_probe_reps))
         cfg.elastic = get_bool(ELASTIC)
         cfg.mesh_axes = get_env(MESH_AXES, "") or ""
-        cfg.compile_cache_dir = get_env(COMPILE_CACHE_DIR, "") or ""
         cfg.data_prefetch = get_bool(DATA_PREFETCH, cfg.data_prefetch)
         cfg.data_queue_depth = max(
             1, get_int(DATA_QUEUE_DEPTH, cfg.data_queue_depth))

@@ -42,10 +42,9 @@ executes per chip (helpers: ``models/resnet.train_flops_per_image``,
 ``models/transformer.train_flops_per_seq`` — the bench's audited
 accounting, now importable); every ``step_end`` then grades
 ``hvd_mfu_ratio = flops / (step_time * peak)`` against
-:func:`peak_flops` — ``HVD_TPU_PEAK_TFLOPS`` when set (seed it with a
-*calibrated* ceiling: round-5 silicon measured 171 TFLOP/s steady
-matmul on the 197-peak v5e, docs/mfu_readiness.md), else the detected
-chip's spec-sheet peak.
+:func:`peak_flops` — ``HVD_TPU_PEAK_TFLOPS`` when set, else the detected
+chip's spec-sheet peak (``PEAK_FLOPS_BY_KIND``, keyed by exact
+``device_kind``).
 
 Budget: one ``close_step`` is ~a dozen cached-child reads and float
 arithmetic — ``bench.py --bench attribution`` pins the whole
@@ -97,19 +96,23 @@ def set_enabled(flag: Optional[bool]) -> None:
 
 
 # ---------------------------------------------------------------------------
-# chip peak resolution (HVD_TPU_PEAK_TFLOPS -> detected spec -> None)
+# chip peak resolution (HVD_TPU_PEAK_TFLOPS -> table by device_kind -> None)
 # ---------------------------------------------------------------------------
 
-# Per-chip peak bf16 FLOP/s by device-kind substring (public spec
-# sheets) — the single home of the table bench.py grades MFU against.
-PEAK_FLOPS_BY_KIND = (
-    ("v6 lite", 918e12), ("v6e", 918e12),
-    ("v5 lite", 197e12), ("v5e", 197e12),
-    ("v5p", 459e12), ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 46e12),
-)
+# Per-chip peak bf16 FLOP/s keyed by the exact ``device_kind`` jax reports
+# (the spellings of jax._src.mesh_utils) — the single home of the table
+# bench.py grades MFU against.  Source: Google Cloud TPU documentation,
+# the "System architecture" page of each generation.  A kind that is not
+# here has no peak: no substring guesses a neighbouring generation's.
+PEAK_FLOPS_BY_KIND = {
+    "TPU v2": 46e12,
+    "TPU v3": 123e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+}
 
 _peak: Optional[float] = None
 _peak_known = False
@@ -118,11 +121,10 @@ _peak_known = False
 def peak_flops() -> Optional[float]:
     """The FLOP/s ceiling ``hvd_mfu_ratio`` grades against.
 
-    ``HVD_TPU_PEAK_TFLOPS`` (TFLOP/s) wins when set — the calibration
-    knob: a measured steady-matmul ceiling (round 5: 171 on v5e) makes
-    MFU read "fraction of what this chip demonstrably sustains" instead
-    of the marketing peak.  Otherwise the detected TPU's spec peak;
-    None off-TPU (MFU is then not computed).  Cached after the first
+    ``HVD_TPU_PEAK_TFLOPS`` (TFLOP/s) wins when set.  Otherwise the
+    detected chip's entry in ``PEAK_FLOPS_BY_KIND``; None off-TPU and
+    for a TPU kind the table does not list (MFU is then not computed,
+    and bench.py's chip modes refuse to run).  Cached after the first
     resolution — this runs on every ``close_step``, and an env read per
     step is measurable at the <1% budget; :func:`reset_peak_cache`
     re-reads the knob."""
@@ -133,18 +135,10 @@ def peak_flops() -> Optional[float]:
     if tf > 0:
         _peak = tf * 1e12
     else:
-        _peak = None
-        try:
-            import jax
-            d = jax.devices()[0]
-            if d.platform == "tpu":
-                kind = d.device_kind.lower()
-                for key, peak in PEAK_FLOPS_BY_KIND:
-                    if key in kind:
-                        _peak = peak
-                        break
-        except Exception:  # noqa: BLE001 — observability never breaks
-            _peak = None
+        import jax
+        d = jax.devices()[0]
+        _peak = (PEAK_FLOPS_BY_KIND.get(d.device_kind)
+                 if d.platform == "tpu" else None)
     _peak_known = True
     return _peak
 

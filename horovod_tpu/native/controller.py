@@ -10,14 +10,17 @@ from __future__ import annotations
 
 import contextlib
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
+import time
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..core import config as _config
+from ..utils import logging as log
 
 _DTYPE_CODES = {
     np.dtype(np.uint8): 0,
@@ -63,11 +66,26 @@ def _lib_path() -> str:
 
 
 def _ensure_built() -> str:
+    """Bring the library up to date with its sources and return its path.
+
+    ``make`` always runs — a no-op when the library is current, a rebuild
+    when a stale binary was left on disk — under an flock, so workers that
+    start together on a clean tree build once instead of racing g++ onto
+    one output.  An installed wheel ships the binary without ``src/``;
+    there is nothing to rebuild from and the binary is used as shipped."""
     path = _lib_path()
-    if not os.path.exists(path):
-        src = os.path.join(os.path.dirname(path), "src")
-        subprocess.run(["make", "-C", src], check=True,
-                       capture_output=True)
+    src = os.path.join(os.path.dirname(path), "src")
+    if os.path.exists(os.path.join(src, "Makefile")):
+        with open(path + ".lock", "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            before = os.path.getmtime(path) if os.path.exists(path) else None
+            t0 = time.monotonic()
+            subprocess.run(
+                ["make", "-C", src, f"-j{os.cpu_count() or 1}"],
+                check=True, capture_output=True)
+            if os.path.getmtime(path) != before:
+                log.info("native runtime built in %.1fs: %s",
+                         time.monotonic() - t0, path)
     return path
 
 

@@ -27,8 +27,10 @@ the framework. ``q_offset``/``kv_offset`` globalize the causal mask when
 q/k are shards of a longer sequence (they are traced values under
 shard_map — ring attention passes ``kv_offset = ring_rank * block``).
 
-Falls back to a pure-XLA implementation when not on TPU (tests run the
-kernels in Pallas interpret mode to validate numerics on CPU).
+The kernels are compiled by Mosaic unless a caller passes
+``interpret=True`` (the CPU tests do, to validate numerics); nothing here
+looks at the backend.  Off-TPU dispatch to the pure-XLA path is the
+callers' decision (parallel/ring_attention.py ``_flash_enabled``).
 """
 
 from __future__ import annotations
@@ -74,20 +76,9 @@ def _pick_block(size: int, env: str = "") -> Optional[int]:
     return size if size <= 512 else None
 
 
-def _use_interpret() -> bool:
-    if os.environ.get("HVD_TPU_FLASH_INTERPRET", "") == "1":
-        return True
-    return jax.default_backend() != "tpu"
-
-
 def _compiler_params(n_parallel: int):
-    # Renamed upstream: TPUCompilerParams (<= 0.4.x) -> CompilerParams.
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    try:
-        return cls(
-            dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
-    except TypeError:  # older/newer field sets
-        return cls()
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
 
 
 # ---------------------------------------------------------------------------
@@ -447,8 +438,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     q_offset=0, kv_offset=0,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Differentiable fused attention; (B, S, H, D) in and out."""
+                    interpret: bool = False) -> jax.Array:
+    """Differentiable fused attention; (B, S, H, D) in and out.
+
+    A shape the kernels cannot tile (``_supported`` is None) takes the
+    XLA path with the same semantics."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     blocks = _supported(q, k)
@@ -467,8 +461,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             raise ValueError(
                 f"block_k={block_k} must divide seq_k={k.shape[1]}")
         bk = block_k
-    if interpret is None:
-        interpret = _use_interpret()
     offsets = jnp.stack(
         [jnp.asarray(q_offset, jnp.int32),
          jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)
@@ -479,7 +471,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              scale: Optional[float] = None,
                              q_offset=0, kv_offset=0,
-                             interpret: Optional[bool] = None):
+                             interpret: bool = False):
     """Non-differentiable primitive returning (out, lse).
 
     ``lse`` is (B, H, Sq) fp32 — the softmax log-normalizer per query row,
@@ -492,8 +484,6 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     if blocks is None:
         return _xla_attention_with_lse(q, k, v, causal, scale,
                                        q_offset, kv_offset)
-    if interpret is None:
-        interpret = _use_interpret()
     offsets = jnp.stack(
         [jnp.asarray(q_offset, jnp.int32),
          jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)

@@ -14,6 +14,8 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..utils import logging as log
+
 # Canonical axis names used across the framework.
 DATA = "data"       # data parallel (allreduce axis)
 FSDP = "fsdp"       # sharded data parallel (zero-style weight sharding)
@@ -21,6 +23,24 @@ TENSOR = "model"    # tensor/model parallel (megatron-style)
 SEQUENCE = "seq"    # sequence/context parallel (ring attention / ulysses)
 PIPELINE = "pipe"   # pipeline parallel
 EXPERT = "expert"   # expert parallel (MoE alltoall)
+
+
+def ici_device_array(dims: Sequence[int], devices,
+                     allow_split_physical_axes: bool = True) -> np.ndarray:
+    """``devices`` arranged to ``dims`` in ICI-topology order.  When
+    ``mesh_utils`` cannot place them the order is plain ``devices`` order
+    and neighbours on a mesh axis need not be ICI neighbours — logged with
+    the reason, never silent."""
+    from jax.experimental import mesh_utils
+    try:
+        return mesh_utils.create_device_mesh(
+            tuple(dims), devices=devices,
+            allow_split_physical_axes=allow_split_physical_axes)
+    except (ValueError, NotImplementedError, AssertionError) as e:
+        log.warning("mesh %s: create_device_mesh failed (%r); falling back "
+                    "to device-list order, ICI adjacency is not guaranteed",
+                    tuple(dims), e)
+        return np.array(devices).reshape(tuple(dims))
 
 
 def create_mesh(shape: Dict[str, int], devices=None, allow_split_physical_axes: bool = True):
@@ -32,7 +52,6 @@ def create_mesh(shape: Dict[str, int], devices=None, allow_split_physical_axes: 
     they land on adjacent ICI neighbors.
     """
     import jax
-    from jax.experimental import mesh_utils
 
     names = tuple(shape.keys())
     dims = tuple(int(v) for v in shape.values())
@@ -42,12 +61,7 @@ def create_mesh(shape: Dict[str, int], devices=None, allow_split_physical_axes: 
         raise ValueError(f"mesh shape {shape} has {total} slots but there are "
                          f"only {len(pool)} devices")
     pool = pool[:total]
-    try:
-        dev_array = mesh_utils.create_device_mesh(
-            dims, devices=pool,
-            allow_split_physical_axes=allow_split_physical_axes)
-    except Exception:
-        dev_array = np.array(pool).reshape(dims)
+    dev_array = ici_device_array(dims, pool, allow_split_physical_axes)
     return jax.sharding.Mesh(dev_array, names)
 
 

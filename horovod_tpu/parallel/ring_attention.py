@@ -18,7 +18,7 @@ Two compute paths per ring step:
   ``combine_blocks``.  The custom VJP re-walks the ring, accumulating dK/dV
   *onto the rotating shards* so each gradient lands back on its owner after
   a full revolution.
-* **XLA fallback** (CPU tests, unsupported shapes): the original blockwise
+* **XLA path** (off-TPU, unsupported shapes): the original blockwise
   einsum recurrence, differentiated by JAX AD.
 
 Layout: q, k, v are (batch, seq_local, heads, head_dim) shards of the global
@@ -137,7 +137,7 @@ def _ring_attention_xla(q, k, v, axis_name, causal, scale):
 # Flash-kernel ring path (custom VJP; dK/dV ride the ring home)
 # ---------------------------------------------------------------------------
 
-def _ring_flash_forward(q, k, v, axis_name, causal, scale):
+def _ring_flash_forward(q, k, v, axis_name, causal, scale, interpret):
     from ..ops import flash_attention as fa
     sp = axis_size(axis_name)
     idx = lax.axis_index(axis_name)
@@ -151,7 +151,7 @@ def _ring_flash_forward(q, k, v, axis_name, causal, scale):
         kv_rank = lax.rem(idx + t, sp)
         o_t, lse_t = fa.flash_attention_with_lse(
             q, k_t, v_t, causal=causal, scale=scale,
-            q_offset=q_offset, kv_offset=kv_rank * sq)
+            q_offset=q_offset, kv_offset=kv_rank * sq, interpret=interpret)
         o_t = o_t.astype(jnp.float32)
         if o is None:
             o, lse = o_t, lse_t
@@ -163,18 +163,20 @@ def _ring_flash_forward(q, k, v, axis_name, causal, scale):
     return o.astype(q.dtype), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _ring_flash(q, k, v, axis_name, causal, scale):
-    out, _ = _ring_flash_forward(q, k, v, axis_name, causal, scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _ring_flash(q, k, v, axis_name, causal, scale, interpret):
+    out, _ = _ring_flash_forward(q, k, v, axis_name, causal, scale,
+                                 interpret)
     return out
 
 
-def _ring_flash_fwd(q, k, v, axis_name, causal, scale):
-    out, lse = _ring_flash_forward(q, k, v, axis_name, causal, scale)
+def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret):
+    out, lse = _ring_flash_forward(q, k, v, axis_name, causal, scale,
+                                   interpret)
     return out, (q, k, v, out, lse)
 
 
-def _ring_flash_bwd(axis_name, causal, scale, res, g):
+def _ring_flash_bwd(axis_name, causal, scale, interpret, res, g):
     from ..ops import flash_attention as fa
     q, k, v, out, lse = res
     sp = axis_size(axis_name)
@@ -182,9 +184,7 @@ def _ring_flash_bwd(axis_name, causal, scale, res, g):
     sq = q.shape[1]
     perm = _ring_perm(sp)
 
-    interpret = fa._use_interpret()
-    blocks = fa._supported(q, k)
-    bq, bk = blocks
+    bq, bk = fa._supported(q, k)
 
     # (B, S, H, D) → (B, H, S, D) once for the whole walk.
     qt = q.transpose(0, 2, 1, 3)
@@ -227,10 +227,13 @@ _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    axis_name: str, causal: bool = True,
                    scale: Optional[float] = None,
-                   use_flash: Optional[bool] = None) -> jax.Array:
+                   use_flash: Optional[bool] = None,
+                   interpret: bool = False) -> jax.Array:
     """Exact attention over a sequence-sharded axis via K/V ring rotation.
 
     Call inside ``shard_map``; returns the local (B, Sq, H, D) output shard.
+    ``interpret`` runs the flash kernels in the Pallas interpreter (CPU
+    tests); it is never chosen here.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -241,7 +244,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # backward walk has no per-step XLA fallback.
     use_flash = use_flash and fa._supported(q, k) is not None
     if use_flash:
-        return _ring_flash(q, k, v, axis_name, causal, float(scale))
+        return _ring_flash(q, k, v, axis_name, causal, float(scale),
+                           bool(interpret))
     return _ring_attention_xla(q, k, v, axis_name, causal, scale)
 
 

@@ -79,10 +79,17 @@ def launch_workers(slots: List[SlotInfo], command: List[str],
 
     ``platform_policy`` decides how each host's workers share its TPU chips
     (chips.plan_host_platform): exclusive inherit, per-slot chip partition
-    env, or CPU-pinned eager workers.  Workers needing an in-process
-    platform override are routed through the bootstrap module.
+    env, or CPU-pinned eager workers — or raises ``ChipPartitionError`` when
+    the host's chips cannot be split.  The plan chosen for each host is
+    printed to stderr.  Workers needing an in-process platform override are
+    routed through the bootstrap module.
     """
     from . import chips as chips_mod
+    if any(_is_local(s.hostname) for s in slots):
+        # Build the native runtime here, once, before this host's workers
+        # start: the parent never touches an accelerator, so it may.
+        from ..native.controller import _ensure_built
+        _ensure_built()
     plans = {}
     for slot in slots:
         if slot.hostname not in plans:
@@ -92,6 +99,9 @@ def launch_workers(slots: List[SlotInfo], command: List[str],
                 slot.local_size, platform_policy,
                 chips=chips, partitionable=part,
                 cpu_jax_world=cpu_jax_world)
+            print(f"[hvdrun] host {slot.hostname}: "
+                  f"{plans[slot.hostname].mode} ({chips} chips, "
+                  f"{slot.local_size} workers)", file=sys.stderr, flush=True)
     want_cpu_world = (os.environ.get("HVD_TPU_CPU_JAX_WORLD") == "1"
                       if cpu_jax_world is None else cpu_jax_world)
     if len(plans) > 1 and (want_cpu_world or

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional
 
 from . import exec as exec_mod
 from . import tpu_discovery
+from .chips import ChipPartitionError
 from .hosts import HostInfo, get_host_assignments, parse_hostfile, parse_hosts
 from .rendezvous import RendezvousServer
 
@@ -68,9 +69,10 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     p.add_argument("--worker-platform", choices=("auto", "cpu", "tpu"),
                    default="auto",
                    help="how workers share each host's TPU chips: auto = "
-                        "exclusive/partition/fall-back-to-cpu, cpu = force "
-                        "CPU eager workers, tpu = inherit (externally "
-                        "partitioned)")
+                        "exclusive, or an even partition, or CPU workers "
+                        "on a host with no chips (chips that cannot be "
+                        "split evenly are refused), cpu = force CPU eager "
+                        "workers, tpu = inherit (externally partitioned)")
     p.add_argument("--config-file", default=None)
     # Fleet service mode (docs/fleet.md): submit through a running job
     # gateway instead of owning the device fleet for the process
@@ -313,16 +315,19 @@ def run_static(args: argparse.Namespace) -> int:
         for s in slots:
             print(f"rank {s.rank} -> {s.hostname} (local {s.local_rank}/"
                   f"{s.local_size}, cross {s.cross_rank}/{s.cross_size})")
-    workers = exec_mod.launch_workers(
-        slots, args.command, controller_addr,
-        extra_env=extra_env,
-        platform_policy=args.worker_platform,
-        ssh_port=args.ssh_port,
-        ssh_identity_file=args.ssh_identity_file,
-        output_dir=args.output_filename,
-        prefix_timestamp=args.prefix_output_with_timestamp)
     try:
+        workers = exec_mod.launch_workers(
+            slots, args.command, controller_addr,
+            extra_env=extra_env,
+            platform_policy=args.worker_platform,
+            ssh_port=args.ssh_port,
+            ssh_identity_file=args.ssh_identity_file,
+            output_dir=args.output_filename,
+            prefix_timestamp=args.prefix_output_with_timestamp)
         return exec_mod.wait_all(workers)
+    except ChipPartitionError as e:
+        # Raised while planning, before any worker started.
+        raise SystemExit(f"hvdrun: {e}") from None
     finally:
         rendezvous.stop()
 

@@ -88,3 +88,39 @@ def test_custom_mesh_axes(monkeypatch):
     mesh = hvd.mesh()
     assert mesh.axis_names == ("data", "model")
     assert mesh.devices.shape == (4, 2)
+
+
+def _cache_dir_after_init(tmp_path, **env):
+    """jax's compilation-cache directory after hvd.init(), in a fresh
+    process (the config is process-global and pytest's own is set)."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    full = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    full.update(env, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, %r); import jax; "
+         "import horovod_tpu as hvd; hvd.init(); "
+         "print(jax.config.jax_compilation_cache_dir)" % repo],
+        capture_output=True, text=True, timeout=120, env=full,
+        cwd=str(tmp_path))
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout.strip().splitlines()[-1]
+
+
+@pytest.mark.timeout(300)
+def test_compile_cache_is_placed_from_outside_or_fixed(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it every process
+    resolves the same directory inside the checkout (the path is part of
+    the cache key, so one that moves never hits)."""
+    import os
+    outside = str(tmp_path / "cache")
+    assert _cache_dir_after_init(
+        tmp_path, JAX_COMPILATION_CACHE_DIR=outside) == outside
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    first = _cache_dir_after_init(tmp_path)
+    second = _cache_dir_after_init(tmp_path)
+    assert first == second == os.path.join(repo, ".jax_cache")

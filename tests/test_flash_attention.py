@@ -89,7 +89,7 @@ def test_ring_flash_matches_oracle(sp_mesh):
 
     f = shard_map(
         lambda q, k, v: ra.ring_attention(q, k, v, "sp", causal=True,
-                                          use_flash=True),
+                                          use_flash=True, interpret=True),
         mesh=sp_mesh, in_specs=(P(None, "sp"),) * 3,
         out_specs=P(None, "sp"), check_vma=False)
     out = f(q, k, v)
@@ -103,7 +103,7 @@ def test_ring_flash_gradients_ride_the_ring(sp_mesh):
 
     f = shard_map(
         lambda q, k, v: ra.ring_attention(q, k, v, "sp", causal=True,
-                                          use_flash=True),
+                                          use_flash=True, interpret=True),
         mesh=sp_mesh, in_specs=(P(None, "sp"),) * 3,
         out_specs=P(None, "sp"), check_vma=False)
 
@@ -143,3 +143,36 @@ def test_block_size_env_override(monkeypatch):
     q2, k2, _ = _qkv(s=1024)
     monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "1024")
     assert fa._supported(q2, k2)[0] == 512
+
+
+def test_kernel_is_never_chosen_or_interpreted_behind_the_callers_back(
+        monkeypatch):
+    """Off-TPU, auto mode takes the XLA path (no pallas_call traced), and
+    the kernel, once asked for, is compiled unless ``interpret=True`` is
+    passed: nothing in the dispatch looks at the backend to pick
+    interpret mode."""
+    monkeypatch.delenv("HVD_TPU_FLASH", raising=False)
+    q, k, v = _qkv(s=512)
+    auto = jax.make_jaxpr(
+        lambda q, k, v: ra.full_attention(q, k, v, causal=True))(q, k, v)
+    assert "pallas_call" not in str(auto)
+
+    seen = []
+    real = fa.pl.pallas_call
+
+    def spy(*args, **kwargs):
+        seen.append(kwargs["interpret"])
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, interpret=True))(q, k, v)
+    assert seen == [True]
+    del seen[:]
+    # The default is the compiled kernel, on any backend (tracing only:
+    # Mosaic lowering needs the chip).
+    jax.make_jaxpr(lambda q, k, v: ra.full_attention(
+        q, k, v, causal=True, use_flash=True))(q, k, v)
+    jax.make_jaxpr(lambda q, k, v: fa.flash_attention_with_lse(
+        q, k, v, causal=True))(q, k, v)
+    assert seen == [False, False]

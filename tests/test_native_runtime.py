@@ -189,3 +189,24 @@ def test_native_4proc(body):
 
 def test_native_2proc_allreduce():
     _run("body_allreduce", size=2)
+
+
+def test_ensure_built_always_runs_make(monkeypatch):
+    """A library already on disk is not trusted: make runs every time (a
+    no-op when it is current), so a stale binary is rebuilt from the
+    tracked sources."""
+    import subprocess
+
+    from horovod_tpu.native import controller
+    controller._ensure_built()
+    assert os.path.exists(controller._lib_path())
+    calls = []
+    real_run = subprocess.run
+
+    def spy(cmd, *args, **kwargs):
+        calls.append(list(cmd))
+        return real_run(cmd, *args, **kwargs)
+
+    monkeypatch.setattr(controller.subprocess, "run", spy)
+    assert controller._ensure_built() == controller._lib_path()
+    assert [c[:2] for c in calls] == [["make", "-C"]]

@@ -241,6 +241,29 @@ def test_mfu_absent_without_peak_or_flops():
     assert eng.close_step(1, 0.1)["mfu"] is None
 
 
+def test_peak_table_is_keyed_by_exact_device_kind(monkeypatch):
+    """The chip reports ``TPU v5 lite``; a kind the table does not list has
+    no peak — no substring grades it at a neighbouring generation's."""
+    import types
+
+    import jax
+    from horovod_tpu.metrics.attribution import PEAK_FLOPS_BY_KIND
+
+    def peak_on(kind, platform="tpu"):
+        monkeypatch.setattr(jax, "devices", lambda: [types.SimpleNamespace(
+            platform=platform, device_kind=kind)])
+        reset_peak_cache()
+        return peak_flops()
+
+    assert peak_on("TPU v5 lite") == PEAK_FLOPS_BY_KIND["TPU v5 lite"] \
+        == pytest.approx(197e12)
+    assert peak_on("TPU v5p") == pytest.approx(459e12)
+    assert peak_on("TPU v5") is None          # was graded as a v5p
+    assert peak_on("TPU v7x") is None
+    assert peak_on("TPU v5 lite", platform="cpu") is None
+    reset_peak_cache()
+
+
 def test_models_flops_helpers_feed_set_step_flops():
     from horovod_tpu.models import bert, resnet, transformer
     r = resnet.train_flops_per_image(resnet.ResNetConfig(depth=50))
