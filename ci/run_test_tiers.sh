@@ -82,6 +82,10 @@ TIER_FAST=(
   # goldens, and the KV-page migration codec + token-for-token handoff
   # (`bench.py --bench serving` grows the four matching arms).
   test_serving_scale.py
+  # Names inside the compiled training step (ISSUE 24): the five hvd_*
+  # scopes and three flash-kernel names in both models' lowered step,
+  # and bit-identical results with and without them.
+  test_step_scopes.py
   # Request-scoped tracing + SLO error budgets (ISSUE 19): sampling
   # determinism, burn-rate goldens, burn-aware policy/autoscaler,
   # span coverage with tracing-on/off bit-identity, the migrated

@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
+from ..utils.profiler import scope
 
 IGNORE_INDEX = -100
 
@@ -103,42 +104,47 @@ def _layernorm(x, scale):
 
 
 def _encoder_layer(cfg: BertConfig, lp, x, *, sharded: bool):
-    """Post-LN BERT block. x: (B, S, d). With ``sharded``, wqkv/wo/w1/w2
-    are mp-shards and activations cross tp.column/row_parallel."""
+    """Pre-LN block at BERT's widths (each half normalises its input and
+    adds its output to the residual stream; the published BERT is post-LN).
+    x: (B, S, d). With ``sharded``, wqkv/wo/w1/w2 are mp-shards and
+    activations cross tp.column/row_parallel."""
     hd = cfg.head_dim
-    h = _layernorm(x, lp["ln1"])
-    if sharded:
-        qkv = tp.column_parallel(h, lp["wqkv"].astype(x.dtype))
-    else:
-        qkv = jnp.einsum("bsd,de->bse", h, lp["wqkv"].astype(x.dtype))
-    b, s = qkv.shape[:2]
-    local_heads = qkv.shape[-1] // (3 * hd)
-    qkv = qkv.reshape(b, s, local_heads, 3, hd)
-    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-    o = ra.full_attention(q, k, v, causal=False)
-    o = o.reshape(b, s, local_heads * hd)
-    if sharded:
-        attn = tp.row_parallel(o, lp["wo"].astype(x.dtype), "mp",
-                               scatter_sequence=False)
-    else:
-        attn = jnp.einsum("bse,ed->bsd", o, lp["wo"].astype(x.dtype))
-    x = x + attn
+    with scope("attn"):
+        h = _layernorm(x, lp["ln1"])
+        if sharded:
+            qkv = tp.column_parallel(h, lp["wqkv"].astype(x.dtype))
+        else:
+            qkv = jnp.einsum("bsd,de->bse", h, lp["wqkv"].astype(x.dtype))
+        b, s = qkv.shape[:2]
+        local_heads = qkv.shape[-1] // (3 * hd)
+        qkv = qkv.reshape(b, s, local_heads, 3, hd)
+        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        o = ra.full_attention(q, k, v, causal=False)
+        o = o.reshape(b, s, local_heads * hd)
+        if sharded:
+            attn = tp.row_parallel(o, lp["wo"].astype(x.dtype), "mp",
+                                   scatter_sequence=False)
+        else:
+            attn = jnp.einsum("bse,ed->bsd", o, lp["wo"].astype(x.dtype))
+        x = x + attn
 
-    h = _layernorm(x, lp["ln2"])
-    if sharded:
-        u = jax.nn.gelu(tp.column_parallel(h, lp["w1"].astype(x.dtype)))
-        mlp = tp.row_parallel(u, lp["w2"].astype(x.dtype), "mp",
-                              scatter_sequence=False)
-    else:
-        u = jax.nn.gelu(jnp.einsum("bsd,df->bsf", h,
-                                   lp["w1"].astype(x.dtype)))
-        mlp = jnp.einsum("bsf,fd->bsd", u, lp["w2"].astype(x.dtype))
-    return x + mlp
+    with scope("mlp"):
+        h = _layernorm(x, lp["ln2"])
+        if sharded:
+            u = jax.nn.gelu(tp.column_parallel(h, lp["w1"].astype(x.dtype)))
+            mlp = tp.row_parallel(u, lp["w2"].astype(x.dtype), "mp",
+                                  scatter_sequence=False)
+        else:
+            u = jax.nn.gelu(jnp.einsum("bsd,df->bsf", h,
+                                       lp["w1"].astype(x.dtype)))
+            mlp = jnp.einsum("bsf,fd->bsd", u, lp["w2"].astype(x.dtype))
+        return x + mlp
 
 
 def _encode(cfg: BertConfig, params, tokens, *, sharded: bool):
-    emb = params["embed"][tokens] + params["pos"][None]
-    x = _layernorm(emb.astype(cfg.dtype), params["emb_norm"])
+    with scope("embed"):
+        emb = params["embed"][tokens] + params["pos"][None]
+        x = _layernorm(emb.astype(cfg.dtype), params["emb_norm"])
 
     def body(act, lp):
         return _encoder_layer(cfg, lp, act, sharded=sharded), None
@@ -234,11 +240,12 @@ def forward_loss(cfg: BertConfig, params, tokens, labels,
     and the head projects only those positions (gathered path).
     Returns the replicated global mean loss."""
     hidden = _encode(cfg, params, tokens, sharded=True)
-    if positions is None:
-        loss_sum, n = _mlm_loss(cfg, params, hidden, labels)
-    else:
-        loss_sum, n = _mlm_loss_gathered(cfg, params, hidden, positions,
-                                         labels)
+    with scope("head"):
+        if positions is None:
+            loss_sum, n = _mlm_loss(cfg, params, hidden, labels)
+        else:
+            loss_sum, n = _mlm_loss_gathered(cfg, params, hidden, positions,
+                                             labels)
     loss_sum = lax.psum(loss_sum, "dp")
     n = lax.psum(n, "dp")
     return loss_sum / jnp.maximum(n, 1.0)
@@ -292,9 +299,10 @@ def make_train_step(cfg: BertConfig, mesh, optimizer,
         # batch = (tokens, positions, labels) when gathered else
         # (tokens, labels); value_and_grad differentiates argnum 0 only.
         loss, grads = jax.value_and_grad(loss_of)(params, *batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params,
-                                        updates)
+        with scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                            updates)
         return params, opt_state, loss
 
     def shard_params(params):

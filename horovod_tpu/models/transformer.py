@@ -39,6 +39,7 @@ from ..parallel import moe as moe_lib
 from ..parallel import pipeline as pp_lib
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
+from ..utils.profiler import scope
 
 
 class TransformerConfig(NamedTuple):
@@ -214,8 +215,10 @@ def _make_stage_fn(cfg: TransformerConfig):
     """stage_fn(stage_params, act) scanning this stage's layers."""
 
     def layer_fn(act, lp):
-        act = act + _attention_block(cfg, lp, act)
-        act = act + _mlp_block(cfg, lp, act)
+        with scope("attn"):
+            act = act + _attention_block(cfg, lp, act)
+        with scope("mlp"):
+            act = act + _mlp_block(cfg, lp, act)
         return act, None
 
     def stage_fn(stage_params, act):
@@ -243,9 +246,10 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
 
     # Embedding (replicated weights; computed once per device, then the
     # sequence chunk for this mp member is sliced off → sp-sharded stream).
-    emb = params["embed"][tokens] + params["pos"][None]
-    x = lax.dynamic_slice_in_dim(emb, mp_idx * s_local, s_local, axis=1)
-    x = x.astype(cfg.dtype)
+    with scope("embed"):
+        emb = params["embed"][tokens] + params["pos"][None]
+        x = lax.dynamic_slice_in_dim(emb, mp_idx * s_local, s_local, axis=1)
+        x = x.astype(cfg.dtype)
 
     # Pipeline over pp with GPipe microbatching.
     xs = pp_lib.stack_microbatches(x, par.n_microbatches)
@@ -266,14 +270,16 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     hidden = pp_lib.unstack_microbatches(out)            # (B_local, s_local, d)
 
     # Final norm + tied logits + CE on the local sequence chunk.
-    hidden = _rmsnorm(hidden, params["final_norm"])
-    logits = jnp.einsum("bsd,vd->bsv", hidden.astype(jnp.float32),
-                        params["embed"].astype(jnp.float32))
-    labels_local = lax.dynamic_slice_in_dim(labels, mp_idx * s_local,
-                                            s_local, axis=1)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels_local[..., None], axis=-1)[..., 0]
-    loss_local = -jnp.mean(ll)
+    with scope("head"):
+        hidden = _rmsnorm(hidden, params["final_norm"])
+        logits = jnp.einsum("bsd,vd->bsv", hidden.astype(jnp.float32),
+                            params["embed"].astype(jnp.float32))
+        labels_local = lax.dynamic_slice_in_dim(labels, mp_idx * s_local,
+                                                s_local, axis=1)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, labels_local[..., None],
+                                 axis=-1)[..., 0]
+        loss_local = -jnp.mean(ll)
 
     # Average over sequence chunks (mp) and batch shards (dp); the loss is
     # only valid on the last pipeline stage → masked psum over pp.
@@ -353,8 +359,10 @@ def make_train_step(cfg: TransformerConfig, par: ParallelConfig, mesh,
 
     def train_step(params, opt_state, tokens, labels):
         loss, grads = jax.value_and_grad(loss_of)(params, tokens, labels)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        with scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                            updates)
         return params, opt_state, loss
 
     from jax.sharding import NamedSharding

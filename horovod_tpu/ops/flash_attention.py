@@ -185,6 +185,7 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
         ],
         compiler_params=_compiler_params(3),
         interpret=interpret,
+        name="hvd_flash_fwd",
     )(offsets, q_bhsd, k_bhsd, v_bhsd)
     return out, lse[:, :, 0, :]
 
@@ -339,6 +340,7 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(3),
         interpret=interpret,
+        name="hvd_flash_bwd_dq",
     )(offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
 
     # dK/dV: grid over (k tiles, q tiles), q innermost.
@@ -369,6 +371,7 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
         ],
         compiler_params=_compiler_params(3),
         interpret=interpret,
+        name="hvd_flash_bwd_dkv",
     )(offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
     return dq, dk, dv
 

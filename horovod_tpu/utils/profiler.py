@@ -12,8 +12,13 @@ on the host timeline of a captured trace alongside the device steps.
   (the Chrome-trace Timeline of the native runtime is separate and remains
   the coordinator-side view).
 
+* ``scope(name)`` — names a block of the *compiled* training step
+  (``STEP_SCOPES``): a ``jax.named_scope``, so the name lands in every HLO
+  operation's ``op_name`` and from there in a device profile.
+
 Disable knob: ``HVD_TPU_DISABLE_TRACE_RANGES=1`` (reference knob:
-``HOROVOD_DISABLE_NVTX_RANGES``, common.h:96).
+``HOROVOD_DISABLE_NVTX_RANGES``, common.h:96).  It governs the host-side
+ranges only; ``scope`` has no knob, because it has no run-time cost.
 """
 
 from __future__ import annotations
@@ -52,6 +57,23 @@ def op_range(name: str, payload_bytes: Optional[int] = None):
     else:
         with ann:
             yield
+
+
+# The blocks of a compiled training step that ``models/transformer.py`` and
+# ``models/bert.py`` name, each as ``scope(<name>)``.
+STEP_SCOPES = ("embed", "attn", "mlp", "head", "optimizer")
+
+
+def scope(name: str):
+    """``jax.named_scope("hvd_" + name)`` around a block of a traced
+    function: ``hvd_<name>`` becomes a component of the ``op_name`` of every
+    HLO operation the block lowers to (under AD it can also sit inside
+    ``jvp(...)`` / ``transpose(...)``), which a device profile shows as the
+    operation's ``tf_op``.  It exists only while tracing, costs nothing when
+    the compiled step runs, and so is not subject to
+    ``HVD_TPU_DISABLE_TRACE_RANGES``."""
+    import jax
+    return jax.named_scope("hvd_" + name)
 
 
 class _WaitSpan:
