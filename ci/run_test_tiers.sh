@@ -57,6 +57,11 @@ TIER_FAST=(
   # goldens, flat-vs-tree straggler verdict parity, host observer
   # exchange + crash tolerance, gateway timeline, new debug surfaces.
   test_observe_plane.py
+  # A pipeline of one stage is its stage (ISSUE 25): pipeline_apply at
+  # pp = 1 against the tick loop and the parent's nested checkpoints,
+  # and both flagship cells compiled for a described v5e (two forward
+  # kernel instructions, no collective-permute, peak under 12.5 / 12.8 GiB).
+  test_one_stage_pipeline.py
   test_optimizers.py
   test_overlap.py
   test_parallel.py

@@ -262,8 +262,14 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
         out = pp_lib.pipeline_apply_1f1b(stage_fn, stage_params, xs,
                                          axis_name="pp")
     elif par.pp_schedule == "gpipe":
-        out = pp_lib.pipeline_apply(stage_fn, stage_params, xs,
-                                    axis_name="pp", remat=cfg.remat)
+        # stage_fn checkpoints each layer itself (cfg.remat).  A checkpoint
+        # around the whole stage as well keeps the tick loop's stash at one
+        # stage input a tick, which pays only where there are stages to
+        # fill and drain; at one stage it would run the forward a third
+        # time for nothing.
+        out = pp_lib.pipeline_apply(
+            stage_fn, stage_params, xs, axis_name="pp",
+            remat=cfg.remat and axis_size("pp") > 1)
     else:
         raise ValueError(
             f"unknown pp_schedule {par.pp_schedule!r} (gpipe | 1f1b)")

@@ -11,7 +11,11 @@ Two schedules:
   ``n_microbatches + n_stages - 1`` ticks, so XLA sees a static loop;
   the backward pass — obtained by differentiating through the scan —
   reverses the permutes automatically.  Autodiff stashes one activation
-  per scan tick, so the stash grows with ``n_micro``.
+  per scan tick, so the stash grows with ``n_micro``.  A pipeline of one
+  stage is its stage: when the axis has one member there is nothing to
+  fill, drain or send, and :func:`pipeline_apply` maps the stage over the
+  microbatches in order — no tick loop, no output buffer, no permute of
+  the activation to itself.
 
 * **1F1B** (:func:`pipeline_apply_1f1b`): the Megatron one-forward-
   one-backward schedule as a ``jax.custom_vjp``.  The primal forward IS
@@ -105,15 +109,37 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         drain ticks recompute clamped microbatches whose results are
         never written to the output buffer.
       axis_name: the pipeline mesh axis.
-      remat: rematerialize each stage in the backward pass.
+      remat: rematerialize each stage in the backward pass, so that the
+        tick loop stashes one stage *input* a tick and not the stage's
+        inner residuals.  A ``stage_fn`` that checkpoints its own layers
+        is then recomputed twice in the backward pass (once as a stage,
+        once a layer): with several stages that buys a stash of ticks x
+        one activation where it would be ticks x layers x one activation;
+        with one stage it buys nothing, and such a caller passes
+        ``remat=False`` there (``models/transformer.forward_loss`` does).
+
+    One stage (``axis_size(axis_name) == 1``, static at trace time): the
+    result is ``stage_fn`` applied to each microbatch in order (a plain
+    call for one microbatch, ``lax.map`` for more) — the same operations
+    on the same values as the tick loop, whose ``where(stage == 0, ...)``,
+    masked write and ring permute are identities there.  ``remat`` keeps
+    its meaning.  What a self-checkpointing stage costs without the stage
+    checkpoint is one saved activation a layer and microbatch
+    (layers x mb x sequence x width x itemsize) in place of one a stage;
+    what it saves is one whole forward of the stage a step.
 
     Returns:
       (n_micro, mb, ...) outputs — valid on the **last** stage; other stages
       hold zeros (reduce with a stage mask, see ``last_stage_mask``).
     """
-    if x_microbatches.shape[0] < 1:
+    n_micro = x_microbatches.shape[0]
+    if n_micro < 1:
         raise ValueError("need at least one microbatch")
     fn = jax.checkpoint(stage_fn) if remat else stage_fn
+    if axis_size(axis_name) == 1:
+        if n_micro == 1:
+            return fn(stage_params, x_microbatches[0])[None]
+        return lax.map(partial(fn, stage_params), x_microbatches)
     return _gpipe_forward(fn, stage_params, x_microbatches, axis_name)
 
 
