@@ -57,6 +57,10 @@ TIER_FAST=(
   # goldens, flat-vs-tree straggler verdict parity, host observer
   # exchange + crash tolerance, gateway timeline, new debug surfaces.
   test_observe_plane.py
+  # OLMoE-1B-7B's pieces on the flagship's training path (ISSUE 26):
+  # RoPE, QK-norm, the dropless MoE layer against a dense-mask formula,
+  # router losses, every layout against one device.
+  test_olmoe_layers.py
   # A pipeline of one stage is its stage (ISSUE 25): pipeline_apply at
   # pp = 1 against the tick loop and the parent's nested checkpoints,
   # and both flagship cells compiled for a described v5e (two forward

@@ -16,7 +16,15 @@ training step exercises every parallelism axis the framework supports:
   sharded *through* attention via ring attention
   (parallel/ring_attention.py).
 * **ep**   — optional switch-MoE MLPs with experts sharded over the ``dp``
-  axis and all_to_all routing (parallel/moe.py).
+  axis and all_to_all routing (parallel/moe.py, capacity path).
+
+``TransformerConfig``'s defaults describe the repository's own block
+(learned positions, GELU MLP, tied head).  Its architecture fields turn the
+same training path into a published one: ``rope_theta``, ``qk_norm``,
+``norm_eps``, ``gated_experts`` + ``dropless`` (parallel/moe.py, dropless
+path, experts replicated over the mesh), ``tied_head=False`` and the
+router's two auxiliary losses give OLMoE-1B-7B (arXiv:2409.02060) for
+training.  The serving entry points below cover learned positions only.
 
 Compute dtype defaults to bfloat16 (MXU-native); normalization, softmax and
 loss accumulate in fp32.
@@ -55,6 +63,16 @@ class TransformerConfig(NamedTuple):
     dtype: Any = jnp.bfloat16
     remat: bool = True
     top_k: int = 1                # MoE routes per token (serving + routing)
+    # The architecture, beyond the widths.  The defaults are the block
+    # above; see the module docstring.
+    rope_theta: Optional[float] = None  # None → learned ``pos`` table
+    qk_norm: bool = False         # RMSNorm of the whole projected q and k
+    norm_eps: float = 1e-6
+    gated_experts: bool = False   # (silu(x Wg) * x Wu) Wd; needs dropless
+    dropless: bool = False        # MoE: sort + grouped matmul, no capacity
+    tied_head: bool = True        # False → ``lm_head``, apart from ``embed``
+    aux_loss_coef: float = 0.0    # x router load-balancing loss (dropless)
+    z_loss_coef: float = 0.0      # x router z-loss (dropless)
 
     @property
     def head_dim(self) -> int:
@@ -71,6 +89,10 @@ class ParallelConfig(NamedTuple):
     @property
     def axis_names(self) -> Tuple[str, str, str]:
         return ("dp", "pp", "mp")
+
+
+def _routes_dropless(cfg: TransformerConfig) -> bool:
+    return cfg.n_experts > 0 and cfg.dropless
 
 
 def _split(key, n):
@@ -96,19 +118,38 @@ def init_params(key, cfg: TransformerConfig,
     def rand(kk, *shape, scale=std):
         return (jax.random.normal(kk, shape) * scale).astype(jnp.float32)
 
+    if cfg.gated_experts and not _routes_dropless(cfg):
+        raise ValueError("gated_experts are the dropless path's: the "
+                         "capacity path has GELU experts (parallel/moe.py)")
+    k_embed, k_pos, k_qkv, k_wo = (next(k) for _ in range(4))
     params: Dict[str, Any] = {
-        "embed": rand(next(k), v, d),
-        "pos": rand(next(k), s, d),
+        "embed": rand(k_embed, v, d),
         "final_norm": norm_init(d),
         "layers": {
             "ln1": norm_init(n_pp, lps, d),
             "ln2": norm_init(n_pp, lps, d),
-            "wqkv": rand(next(k), n_pp, lps, d, 3 * h * hd),
-            "wo": rand(next(k), n_pp, lps, h * hd, d,
+            "wqkv": rand(k_qkv, n_pp, lps, d, 3 * h * hd),
+            "wo": rand(k_wo, n_pp, lps, h * hd, d,
                        scale=std / math.sqrt(2 * cfg.n_layers)),
         },
     }
-    if cfg.n_experts > 0:
+    if cfg.rope_theta is None:
+        params["pos"] = rand(k_pos, s, d)
+    if not cfg.tied_head:
+        params["lm_head"] = rand(next(k), v, d)
+    if cfg.qk_norm:
+        params["layers"]["q_norm"] = norm_init(n_pp, lps, h * hd)
+        params["layers"]["k_norm"] = norm_init(n_pp, lps, h * hd)
+    if _routes_dropless(cfg):
+        e = cfg.n_experts
+        params["layers"]["gate"] = rand(next(k), n_pp, lps, d, e)
+        if cfg.gated_experts:
+            params["layers"]["w_gate"] = rand(next(k), n_pp, lps, e, d, ff)
+        params["layers"]["w_up"] = rand(next(k), n_pp, lps, e, d, ff)
+        params["layers"]["w_down"] = rand(
+            next(k), n_pp, lps, e, ff, d,
+            scale=std / math.sqrt(2 * cfg.n_layers))
+    elif cfg.n_experts > 0:
         if cfg.n_experts % par.dp != 0:
             raise ValueError("n_experts must be divisible by dp (=ep) degree")
         params["layers"]["gate"] = rand(next(k), n_pp, lps, d, cfg.n_experts)
@@ -135,32 +176,89 @@ def param_specs(cfg: TransformerConfig, par: ParallelConfig) -> Dict[str, Any]:
         "wqkv": P("pp", None, None, "mp") if megatron else P("pp"),
         "wo": P("pp", None, "mp", None) if megatron else P("pp"),
     }
-    if cfg.n_experts > 0:
+    if cfg.qk_norm:
+        # One scale a feature of q (k), so sharded as the heads are.
+        layers["q_norm"] = P("pp", None, "mp") if megatron else P("pp")
+        layers["k_norm"] = layers["q_norm"]
+    if _routes_dropless(cfg):
+        # Every expert on every device: the sequence-parallel stream is
+        # token-wise, so the block needs no gather, and the gradients
+        # reduce over dp and mp by AD as the other replicated leaves' do.
+        layers["gate"] = P("pp")
+        if cfg.gated_experts:
+            layers["w_gate"] = P("pp")
+        layers["w_up"] = P("pp")
+        layers["w_down"] = P("pp")
+    elif cfg.n_experts > 0:
         layers["gate"] = P("pp")
         layers["w_in"] = P("pp", None, "dp", None, None)   # experts over dp
         layers["w_out"] = P("pp", None, "dp", None, None)
     else:
         layers["w1"] = P("pp", None, None, "mp")
         layers["w2"] = P("pp", None, "mp", None)
-    return {
-        "embed": P(),
-        "pos": P(),
-        "final_norm": P(),
-        "layers": layers,
-    }
+    specs = {"embed": P(), "final_norm": P(), "layers": layers}
+    if cfg.rope_theta is None:
+        specs["pos"] = P()
+    if not cfg.tied_head:
+        specs["lm_head"] = P()
+    return specs
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + 1e-6) * scale).astype(x.dtype)
+    return (xf * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+
+
+def _qk_norm(t, scale, eps: float, axis_name: Optional[str]):
+    """RMSNorm over every feature of a projected q or k — all heads at
+    once, as OLMoE normalises before it splits the heads.  ``t``: (mb, S,
+    local heads, hd); ``scale``: (local heads * hd,).  With the heads
+    sharded over ``axis_name`` the mean square is over all members'."""
+    tf = t.astype(jnp.float32)
+    sq = jnp.sum(tf * tf, axis=(-2, -1), keepdims=True)
+    n = t.shape[-2] * t.shape[-1]
+    if axis_name is not None:
+        sq = lax.psum(sq, axis_name)
+        n *= axis_size(axis_name)
+    return (tf * lax.rsqrt(sq / n + eps)
+            * scale.reshape(t.shape[-2:])).astype(t.dtype)
+
+
+def _rope(t, positions, theta: float):
+    """Rotate-half rotary embedding (HF ``apply_rotary_pos_emb``): with
+    ``t = [t1, t2]`` split at half the head, ``[t1 cos - t2 sin, t2 cos +
+    t1 sin]`` at angle ``position * theta^(-2i/hd)``, in fp32.  ``t``: (mb,
+    S, heads, hd); ``positions``: (S,) global token positions."""
+    half = t.shape[-1] // 2
+    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    tf = t.astype(jnp.float32)
+    t1, t2 = tf[..., :half], tf[..., half:]
+    return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
+                           axis=-1).astype(t.dtype)
+
+
+def _position_qk(cfg: TransformerConfig, lp, q, k, positions, axis_name):
+    """What the configuration does to q and k between the projection and
+    the attention: nothing (learned positions were added to the stream),
+    or OLMoE's QK-norm and rotary positions."""
+    if cfg.qk_norm:
+        q = _qk_norm(q, lp["q_norm"], cfg.norm_eps, axis_name)
+        k = _qk_norm(k, lp["k_norm"], cfg.norm_eps, axis_name)
+    if cfg.rope_theta is not None:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    return q, k
 
 
 def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                      x: jax.Array) -> jax.Array:
     """x: (mb, s_local, d) sequence-sharded over mp. Returns residual add."""
     h_heads, hd = cfg.n_heads, cfg.head_dim
-    hnorm = _rmsnorm(x, lp["ln1"])
+    hnorm = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
     # wqkv layout: (d, h*3*hd) with heads outermost in the fused dim, so an
     # mp shard of the fused dim is a whole-head slice (q,k,v interleaved
     # per head), making column-parallel == head-parallel.
@@ -172,6 +270,8 @@ def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         local_heads = qkv.shape[-1] // (3 * hd)
         qkv = qkv.reshape(mb, s_full, local_heads, 3, hd)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        # The sequence was gathered: positions 0 .. S-1; heads over mp.
+        q, k = _position_qk(cfg, lp, q, k, jnp.arange(s_full), "mp")
         o = ra.full_attention(q, k, v, causal=True)
         o = o.reshape(mb, s_full, local_heads * hd)
         return tp.row_parallel(o, lp["wo"].astype(x.dtype), "mp",
@@ -181,6 +281,10 @@ def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         mb, s_local = qkv.shape[0], qkv.shape[1]
         qkv = qkv.reshape(mb, s_local, h_heads, 3, hd)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        # This member's chunk of the sequence, every head of it.
+        q, k = _position_qk(
+            cfg, lp, q, k,
+            lax.axis_index("mp") * s_local + jnp.arange(s_local), None)
         if cfg.attn_mode == "ulysses":
             from ..parallel.ulysses import ulysses_attention
             o = ulysses_attention(q, k, v, axis_name="mp", causal=True)
@@ -191,8 +295,19 @@ def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
 
 
 def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
-               x: jax.Array) -> jax.Array:
-    hnorm = _rmsnorm(x, lp["ln2"])
+               x: jax.Array):
+    """(The residual add of the MLP, the layer's ``moe.RouterStats`` where
+    a dropless MoE routes and None elsewhere)."""
+    hnorm = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
+    if _routes_dropless(cfg):
+        mb, s_local, d = hnorm.shape
+        y, stats = moe_lib.dropless_moe(
+            moe_lib.GatedMoEParams(
+                gate=lp["gate"], w_gate=lp.get("w_gate"), w_up=lp["w_up"],
+                w_down=lp["w_down"]),
+            hnorm.reshape(mb * s_local, d), cfg.top_k,
+            activation=jax.nn.silu if cfg.gated_experts else jax.nn.gelu)
+        return y.reshape(mb, s_local, d), stats
     if cfg.n_experts > 0:
         mb, s_local, d = hnorm.shape
         tok = hnorm.reshape(mb * s_local, d)
@@ -204,40 +319,49 @@ def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         y = moe_lib.moe_layer(mp_params, tok, "dp",
                               capacity_factor=cfg.capacity_factor,
                               top_k=cfg.top_k)
-        return y.reshape(mb, s_local, d).astype(x.dtype)
+        return y.reshape(mb, s_local, d).astype(x.dtype), None
     hg = tp.gather_sequence(hnorm, "mp", dim=1)
     u = jax.nn.gelu(tp.column_parallel(hg, lp["w1"].astype(x.dtype)))
     return tp.row_parallel(u, lp["w2"].astype(x.dtype), "mp",
-                           scatter_sequence=True)
+                           scatter_sequence=True), None
 
 
 def _make_stage_fn(cfg: TransformerConfig):
-    """stage_fn(stage_params, act) scanning this stage's layers."""
+    """stage_fn(stage_params, act) scanning this stage's layers; with a
+    dropless MoE it returns the activation and the layers' stacked
+    ``moe.RouterStats``."""
+    with_stats = _routes_dropless(cfg)
 
     def layer_fn(act, lp):
         with scope("attn"):
             act = act + _attention_block(cfg, lp, act)
         with scope("mlp"):
-            act = act + _mlp_block(cfg, lp, act)
-        return act, None
+            y, stats = _mlp_block(cfg, lp, act)
+            act = act + y
+        return act, stats
 
     def stage_fn(stage_params, act):
         body = layer_fn
         if cfg.remat:
             body = jax.checkpoint(layer_fn)
-        out, _ = lax.scan(body, act, stage_params)
-        return out
+        out, stats = lax.scan(body, act, stage_params)
+        return (out, stats) if with_stats else out
 
     return stage_fn
 
 
 def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
                  params: Dict[str, Any], tokens: jax.Array,
-                 labels: jax.Array) -> jax.Array:
+                 labels: jax.Array, with_routing: bool = False):
     """Per-device loss body; call inside shard_map over mesh (dp, pp, mp).
 
     tokens/labels: (B_local, S) int32 shards (batch over dp).
-    Returns a replicated scalar loss.
+    Returns a replicated scalar loss: the mean token cross-entropy, plus,
+    with a dropless MoE, ``aux_loss_coef`` x the router's load-balancing
+    loss and ``z_loss_coef`` x its z-loss, each taken over the global
+    batch's tokens layer by layer and averaged over the layers.
+    ``with_routing`` (dropless MoE only) returns ``(loss, routing)``, the
+    replicated dict :func:`make_routing_fn` documents.
     """
     s_full = cfg.seq_len
     mp_size = axis_size("mp")
@@ -247,7 +371,9 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     # Embedding (replicated weights; computed once per device, then the
     # sequence chunk for this mp member is sliced off → sp-sharded stream).
     with scope("embed"):
-        emb = params["embed"][tokens] + params["pos"][None]
+        emb = params["embed"][tokens]
+        if cfg.rope_theta is None:
+            emb = emb + params["pos"][None]
         x = lax.dynamic_slice_in_dim(emb, mp_idx * s_local, s_local, axis=1)
         x = x.astype(cfg.dtype)
 
@@ -255,6 +381,11 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     xs = pp_lib.stack_microbatches(x, par.n_microbatches)
     stage_params = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
     stage_fn = _make_stage_fn(cfg)
+    if _routes_dropless(cfg) and (axis_size("pp") > 1
+                                  or par.pp_schedule != "gpipe"):
+        raise NotImplementedError(
+            "a dropless MoE's router statistics are not carried from stage "
+            "to stage: pp must be 1 (schedule gpipe)")
     if par.pp_schedule == "1f1b":
         # Bounded-stash backward (O(n_stages) microbatch inputs, not
         # O(n_micro) tick residuals); rematerializes inherently, so the
@@ -273,13 +404,19 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     else:
         raise ValueError(
             f"unknown pp_schedule {par.pp_schedule!r} (gpipe | 1f1b)")
+    if _routes_dropless(cfg):
+        # (n_micro, layers, ...) sums over each microbatch's tokens.
+        out, stats = out
+        stats = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stats)
     hidden = pp_lib.unstack_microbatches(out)            # (B_local, s_local, d)
 
-    # Final norm + tied logits + CE on the local sequence chunk.
+    # Final norm + logits (tied to the embedding, or ``lm_head``) + CE on
+    # the local sequence chunk.
     with scope("head"):
-        hidden = _rmsnorm(hidden, params["final_norm"])
+        hidden = _rmsnorm(hidden, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bsd,vd->bsv", hidden.astype(jnp.float32),
-                            params["embed"].astype(jnp.float32))
+                            params["embed" if cfg.tied_head
+                                   else "lm_head"].astype(jnp.float32))
         labels_local = lax.dynamic_slice_in_dim(labels, mp_idx * s_local,
                                                 s_local, axis=1)
         logp = jax.nn.log_softmax(logits, axis=-1)
@@ -291,10 +428,24 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     # only valid on the last pipeline stage → masked psum over pp.
     loss = lax.pmean(lax.pmean(loss_local, "mp"), "dp")
     loss = lax.psum(loss * pp_lib.last_stage_mask("pp"), "pp")
-    return loss
+    if not _routes_dropless(cfg):
+        return loss
+    # A product of two means depends on which tokens are averaged: sum the
+    # statistics over every device's tokens first, so that the loss is the
+    # same on every layout.
+    stats = lax.psum(stats, ("dp", "mp"))
+    n_tokens = tokens.shape[0] * s_full * axis_size("dp")
+    balance, z = moe_lib.router_losses(stats, n_tokens)      # (layers,) each
+    loss = (loss + cfg.aux_loss_coef * jnp.mean(balance)
+            + cfg.z_loss_coef * jnp.mean(z))
+    if not with_routing:
+        return loss
+    return loss, {"assignments": stats.counts,
+                  "load_balancing_loss": balance, "z_loss": z}
 
 
-def make_loss_fn(cfg: TransformerConfig, par: ParallelConfig, mesh):
+def make_loss_fn(cfg: TransformerConfig, par: ParallelConfig, mesh,
+                 with_routing: bool = False):
     """Global-array loss: shard_map of ``forward_loss`` over (dp, pp, mp)."""
     from ..compat import shard_map
     specs = param_specs(cfg, par)
@@ -302,12 +453,33 @@ def make_loss_fn(cfg: TransformerConfig, par: ParallelConfig, mesh):
 
     def loss_of(params, tokens, labels):
         fn = shard_map(
-            lambda p, t, l: forward_loss(cfg, par, p, t, l),
+            lambda p, t, l: forward_loss(cfg, par, p, t, l, with_routing),
             mesh=mesh, in_specs=(specs, data_spec, data_spec),
             out_specs=P(), check_vma=False)
         return fn(params, tokens, labels)
 
     return loss_of
+
+
+def make_routing_fn(cfg: TransformerConfig, par: ParallelConfig, mesh):
+    """``routing(params, tokens, labels)`` for a dropless MoE: what the
+    router did with one global batch, through the training forward itself.
+    A dict of ``assignments`` (layers, experts) — (token, choice) pairs each
+    expert received; ``load`` (layers,) — the busiest expert's assignments
+    over the mean's; ``dropped`` — pairs routed less pairs assigned (0:
+    nothing is clamped); ``load_balancing_loss`` and ``z_loss`` (layers,),
+    uncoefficiented; and the training ``loss``."""
+    loss_of = make_loss_fn(cfg, par, mesh, with_routing=True)
+
+    def routing(params, tokens, labels):
+        loss, r = loss_of(params, tokens, labels)
+        counts = r["assignments"]
+        routed = tokens.size * cfg.top_k * counts.shape[0]
+        return {**r, "loss": loss,
+                "load": jnp.max(counts, axis=-1) / jnp.mean(counts, axis=-1),
+                "dropped": routed - jnp.sum(counts)}
+
+    return jax.jit(routing)
 
 
 def serial_forward_logits(cfg: TransformerConfig, params: Dict[str, Any],
@@ -423,6 +595,16 @@ def synthetic_batch(key, cfg: TransformerConfig, batch: int):
 _NEG_INF = -1e30
 
 
+def _check_servable(cfg: TransformerConfig) -> None:
+    """The serving forward below is the block of the defaults: a learned
+    position table and GELU MLPs / ``w_in``-``w_out`` experts."""
+    if cfg.rope_theta is not None or cfg.qk_norm or cfg.gated_experts \
+            or not cfg.tied_head:
+        raise NotImplementedError(
+            "prefill / decode cover learned positions, a tied head and "
+            "ungated MLPs; this configuration trains only (ROADMAP D1)")
+
+
 def init_kv_pages(cfg: TransformerConfig, n_pages: int,
                   page_size: int) -> Dict[str, jax.Array]:
     """Allocate the paged KV pool: ``k``/``v`` arrays of shape
@@ -487,6 +669,7 @@ def prefill(cfg: TransformerConfig, params: Dict[str, Any],
     positions [0, S).  Returns (fp32 logits (V,) at position length-1,
     updated kv).
     """
+    _check_servable(cfg)
     s = tokens.shape[0]
     page_size = kv["k"].shape[2]
     n_rows = s // page_size
@@ -540,6 +723,7 @@ def decode_step(cfg: TransformerConfig, params: Dict[str, Any],
     their page-table row at a scratch page — the math still runs, the
     writes land somewhere harmless, and the logits are ignored.
     """
+    _check_servable(cfg)
     b, pages_per_slot = page_tables.shape
     page_size = kv["k"].shape[2]
     max_len = pages_per_slot * page_size
@@ -619,6 +803,7 @@ def decode_verify(cfg: TransformerConfig, params: Dict[str, Any],
     with real content.  Positions at or past the table's extent route
     their writes to scratch page 0.
     """
+    _check_servable(cfg)
     b, kq = tokens.shape
     pages_per_slot = page_tables.shape[1]
     page_size = kv["k"].shape[2]
@@ -702,10 +887,15 @@ def draft_params_from(params: Dict[str, Any],
 
 def _mlp_flops_per_token(cfg: TransformerConfig) -> float:
     """Per-token per-layer MLP matmul-FLOPs: dense 4*d*ff; MoE routes
-    top_k experts per token (top_k * 4*d*ff) plus the 2*d*E gate."""
+    top_k experts per token (top_k * 4*d*ff, 6*d*ff with the gate
+    projection of ``gated_experts``) plus the 2*d*E router."""
     d, ff = cfg.d_model, cfg.d_ff
     if cfg.n_experts > 0:
-        return cfg.top_k * 4.0 * d * ff + 2.0 * d * cfg.n_experts
+        # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no such
+        # field and ungated experts.
+        gated = getattr(cfg, "gated_experts", False)
+        per_expert = (6.0 if gated else 4.0) * d * ff
+        return cfg.top_k * per_expert + 2.0 * d * cfg.n_experts
     return 4.0 * d * ff
 
 

@@ -1,25 +1,40 @@
-"""Expert parallelism — switch-style Mixture-of-Experts with all-to-all
-token routing over a mesh axis.
+"""Expert parallelism — Mixture-of-Experts layers, two paths.
+
+**Capacity path** (:func:`moe_layer`, :func:`top_k_routing`): Switch
+Transformer semantics.  Top-k gating with a per-expert capacity, dispatch
+einsum into a static (experts, capacity, d) buffer, two ``lax.all_to_all``
+exchanges over the expert axis, GELU experts (``w_in`` / ``w_out``);
+(token, route) pairs over capacity are dropped and pass through on the
+residual path.  Its ``(T, E, C)`` one-hot tensors bound it to small token
+counts.  Kept for its tests, ``models/moe_transformer.py`` and the
+``n_experts > 0`` configurations of ``models/transformer.py`` that do not
+ask for ``dropless``.
+
+**Dropless path** (:func:`dropless_moe`): what the catalog's MoE models
+use (OLMoE, arXiv:2409.02060).  Softmax over all experts in fp32, top-k,
+the k probabilities used as they are; the (token, choice) pairs sorted by
+expert; one grouped matmul per projection over the ragged groups
+(``lax.ragged_dot``: on TPU a Mosaic kernel whose cost follows the rows,
+not experts x rows); the rows put back in token order and summed with
+their weights in fp32.  No capacity, no ``(T, E, C)`` tensor, nothing
+dropped.  Experts are replicated over the mesh here; experts over an
+``ep`` axis with a ragged exchange is the follow-up.
 
 The reference's only layout-shuffling primitive is alltoall with uneven
 splits (operations.cc:1136-1198, SURVEY.md §2.3 "the only primitive that
-would serve EP/SP-style layouts").  TPU-native, expert parallelism is a
-first-class layer: top-k gating with capacity, dispatch einsum into a
-(experts, capacity, d) buffer — static shapes so XLA can tile the MXU — and
-two ``lax.all_to_all`` exchanges riding ICI.  Dropped tokens (over capacity)
-pass through on the residual path, standard Switch Transformer semantics.
-
-Wire format: the dispatch/combine exchanges optionally ride the EQuARX
-block-scaled int8/int4 wire from ``ops/quantization.py`` — each destination
-rank's chunk is quantized independently (payload + one fp32 scale per
-block travel as two all_to_alls), dequantized to fp32 on arrival.  The
-combine einsum always accumulates in fp32; the wire dtype is never the
-accumulation dtype (the module-wide contract of ops/quantization.py).
+would serve EP/SP-style layouts"); the capacity path's exchanges are its
+TPU-native form.  They optionally ride the EQuARX block-scaled int8/int4
+wire from ``ops/quantization.py`` — each destination rank's chunk is
+quantized independently (payload + one fp32 scale per block travel as two
+all_to_alls), dequantized to fp32 on arrival.  The combine einsum always
+accumulates in fp32; the wire dtype is never the accumulation dtype (the
+module-wide contract of ops/quantization.py).
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -28,6 +43,7 @@ from jax import lax
 
 from ..compat import axis_size
 from ..ops.quantization import QuantSpec, wire_bytes
+from ..utils.profiler import scope
 
 
 class MoEParams(NamedTuple):
@@ -223,3 +239,142 @@ def moe_load_balancing_loss(x: jax.Array, gate: jax.Array,
     frac = jnp.mean(jax.nn.one_hot(expert_idx, n_experts), axis=0)
     mean_prob = jnp.mean(probs, axis=0)
     return n_experts * jnp.sum(frac * mean_prob)
+
+
+# ---------------------------------------------------------------------------
+# Dropless path
+# ---------------------------------------------------------------------------
+
+class GatedMoEParams(NamedTuple):
+    """One layer's experts, all of them on this device.  ``w_gate`` is None
+    for plain (``activation(x w_up) w_down``) experts."""
+    gate: jax.Array              # (d_model, n_experts) router
+    w_gate: Optional[jax.Array]  # (n_experts, d_model, d_ff)
+    w_up: jax.Array              # (n_experts, d_model, d_ff)
+    w_down: jax.Array            # (n_experts, d_ff, d_model)
+
+
+class RouterStats(NamedTuple):
+    """What the router's auxiliary losses are made of, as sums over this
+    device's tokens, so that a caller can add them up over microbatches and
+    mesh axes before it multiplies (:func:`router_losses`)."""
+    counts: jax.Array     # (E,) f32 — (token, choice) pairs sent to expert e
+    prob_sum: jax.Array   # (E,) f32 — sum over tokens of the softmax
+    z_sum: jax.Array      # () f32 — sum over tokens of logsumexp(logits)^2
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_tokens(x, token_of_row, row_of_pair, top_k):
+    """``x[token_of_row]``: every token's row once for each of its ``top_k``
+    choices, in expert order.  Backward is a gather too (row ``row_of_pair[t
+    * top_k + j]`` holds token t's j-th copy), not the scatter-add AD would
+    make of it."""
+    return x[token_of_row]
+
+
+def _rows_of_tokens_fwd(x, token_of_row, row_of_pair, top_k):
+    return x[token_of_row], (row_of_pair, x.shape[0])
+
+
+def _rows_of_tokens_bwd(top_k, res, g):
+    row_of_pair, t = res
+    g = g[row_of_pair].reshape(t, top_k, g.shape[-1])
+    return jnp.sum(g.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(y, perm, inverse):
+    """``y[perm]`` for a permutation and its inverse: the transpose of a
+    permutation is the inverse permutation, a gather again."""
+    return y[perm]
+
+
+def _permute_rows_fwd(y, perm, inverse):
+    return y[perm], inverse
+
+
+def _permute_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def dropless_moe(params: GatedMoEParams, x: jax.Array, top_k: int,
+                 activation: Callable = jax.nn.silu):
+    """Dropless top-k MoE MLP over this device's tokens.
+
+    ``x``: (tokens, d_model) in the compute dtype.  Router logits and
+    softmax in fp32 (the logits at the highest matmul precision: a near-tie
+    between the k-th and the next expert is decided by their last bits, and
+    the matmul is 2 T d E FLOPs); the k largest probabilities weigh their
+    experts as they are, not renormalised.  Expert e computes
+    ``(activation(x w_gate[e]) * (x w_up[e])) w_down[e]`` (without
+    ``w_gate``: ``activation(x w_up[e]) w_down[e]``) for exactly the rows
+    routed to it.  Returns ``(out, RouterStats)``, ``out`` (tokens, d_model)
+    in ``x.dtype``, to be added to the residual by the caller.
+    """
+    t, d = x.shape
+    e = params.gate.shape[1]
+    if not 1 <= top_k <= e:
+        raise ValueError(f"top_k must be in [1, {e}], got {top_k}")
+    with scope("moe_route"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         params.gate.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+        _, top_i = lax.top_k(probs, top_k)                      # (T, k)
+        # The chosen probabilities by a one-hot product, not ``top_k``'s
+        # values or a gather: their transpose is a product too, where
+        # those scatter (T k) scalars into (T, E).
+        top_p = jnp.sum(probs[:, None, :] * jax.nn.one_hot(
+            top_i, e, dtype=probs.dtype), axis=-1)
+        expert_of_pair = top_i.reshape(t * top_k)
+        # Row r of the sorted order holds pair ``pair_of_row[r]`` = token *
+        # top_k + choice; stable, so an expert's rows stay in token order.
+        pair_of_row = jnp.argsort(expert_of_pair, stable=True)
+        row_of_pair = jnp.argsort(pair_of_row)
+        group_sizes = jnp.sum(
+            expert_of_pair[:, None] == jnp.arange(e, dtype=top_i.dtype),
+            axis=0, dtype=jnp.int32)
+        stats = RouterStats(counts=group_sizes.astype(jnp.float32),
+                            prob_sum=jnp.sum(probs, axis=0),
+                            z_sum=jnp.sum(lse * lse))
+    with scope("moe_dispatch"):
+        rows = _rows_of_tokens(x, pair_of_row // top_k, row_of_pair, top_k)
+    with scope("moe_experts"):
+        up = lax.ragged_dot(rows, params.w_up.astype(x.dtype), group_sizes)
+        if params.w_gate is None:
+            hidden = activation(up.astype(jnp.float32))
+        else:
+            gate = lax.ragged_dot(rows, params.w_gate.astype(x.dtype),
+                                  group_sizes)
+            hidden = (activation(gate.astype(jnp.float32))
+                      * up.astype(jnp.float32))
+        y = lax.ragged_dot(hidden.astype(x.dtype),
+                           params.w_down.astype(x.dtype), group_sizes)
+    with scope("moe_dispatch"):
+        y = _permute_rows(y, row_of_pair, pair_of_row).reshape(t, top_k, d)
+        out = jnp.sum(y.astype(jnp.float32) * top_p[..., None], axis=1)
+    return out.astype(x.dtype), stats
+
+
+def router_losses(stats: RouterStats, n_tokens):
+    """(load-balancing loss, router z-loss) of one layer from statistics
+    already summed over every token the losses are taken over.
+
+    Load balancing as HF ``load_balancing_loss_func``: ``E * sum_e f_e *
+    P_e`` with ``f_e`` the share of tokens that chose expert e among their
+    ``top_k`` (the f_e add up to ``top_k``, so a uniform router scores
+    ``top_k``) and ``P_e`` the mean router probability of e.  A product of
+    two means: it depends on which tokens are averaged, hence the sums.
+    z-loss: the mean of ``logsumexp(logits)^2`` (ST-MoE, arXiv:2202.08906).
+    """
+    e = stats.counts.shape[-1]
+    f = stats.counts / n_tokens
+    p = stats.prob_sum / n_tokens
+    return e * jnp.sum(f * p, axis=-1), stats.z_sum / n_tokens

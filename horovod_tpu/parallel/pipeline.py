@@ -123,7 +123,10 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     call for one microbatch, ``lax.map`` for more) — the same operations
     on the same values as the tick loop, whose ``where(stage == 0, ...)``,
     masked write and ring permute are identities there.  ``remat`` keeps
-    its meaning.  What a self-checkpointing stage costs without the stage
+    its meaning.  Here, and only here, the stage may return a pytree (its
+    activation and side outputs, e.g. a MoE router's statistics): every
+    leaf comes back with the leading microbatch axis.  What a
+    self-checkpointing stage costs without the stage
     checkpoint is one saved activation a layer and microbatch
     (layers x mb x sequence x width x itemsize) in place of one a stage;
     what it saves is one whole forward of the stage a step.
@@ -138,7 +141,8 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     fn = jax.checkpoint(stage_fn) if remat else stage_fn
     if axis_size(axis_name) == 1:
         if n_micro == 1:
-            return fn(stage_params, x_microbatches[0])[None]
+            return jax.tree_util.tree_map(
+                lambda y: y[None], fn(stage_params, x_microbatches[0]))
         return lax.map(partial(fn, stage_params), x_microbatches)
     return _gpipe_forward(fn, stage_params, x_microbatches, axis_name)
 

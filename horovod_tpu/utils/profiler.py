@@ -13,8 +13,9 @@ on the host timeline of a captured trace alongside the device steps.
   the coordinator-side view).
 
 * ``scope(name)`` — names a block of the *compiled* training step
-  (``STEP_SCOPES``): a ``jax.named_scope``, so the name lands in every HLO
-  operation's ``op_name`` and from there in a device profile.
+  (``STEP_SCOPES``, ``MOE_SCOPES``): a ``jax.named_scope``, so the name
+  lands in every HLO operation's ``op_name`` and from there in a device
+  profile.
 
 Disable knob: ``HVD_TPU_DISABLE_TRACE_RANGES=1`` (reference knob:
 ``HOROVOD_DISABLE_NVTX_RANGES``, common.h:96).  It governs the host-side
@@ -62,6 +63,10 @@ def op_range(name: str, payload_bytes: Optional[int] = None):
 # The blocks of a compiled training step that ``models/transformer.py`` and
 # ``models/bert.py`` name, each as ``scope(<name>)``.
 STEP_SCOPES = ("embed", "attn", "mlp", "head", "optimizer")
+# The parts of a dropless MoE MLP (``parallel/moe.dropless_moe``), named
+# inside the ``mlp`` block: the router up to the sorted order, the rows
+# gathered into expert order and put back, the grouped matmuls.
+MOE_SCOPES = ("moe_route", "moe_dispatch", "moe_experts")
 
 
 def scope(name: str):
