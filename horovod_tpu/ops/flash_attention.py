@@ -4,10 +4,37 @@ The hot op of the flagship transformer (models/transformer.py). The
 reference framework is model-agnostic middleware and carries no attention
 code (SURVEY.md §5.7); on TPU the attention inner loop is ours to own, and
 a fused kernel is how it belongs on the hardware: Q/K/V tiles stream
-HBM→VMEM, the (bq, bk) score block lives only in VMEM, softmax is the
+HBM→VMEM, the (bk, bq) score block lives only in VMEM, softmax is the
 online (running max / running sum) recurrence so the O(S²) score matrix is
-never materialized in HBM, and both matmuls hit the MXU in fp32
-accumulation.
+never materialized in HBM.
+
+What reaches the MXU is the type the caller passed.  q, k, v and dO go from
+their refs into the nine ``dot_general``s as they are (2 in the forward, 3
+in dQ, 4 in dK/dV), every one accumulating in fp32
+(``preferred_element_type``).  Five of the nine (K·Qᵀ three times, V·dOᵀ
+twice) have two caller operands; the other four take one operand the kernel
+computed in fp32 — Pᵀ in Vᵀ·Pᵀ and Pᵀ·dO, dSᵀ in Kᵀ·dSᵀ and dSᵀ·Q — and
+that one is cast to the other operand's type at the dot, and only there.
+Everything that is softmax is fp32 and computed before the cast: scores,
+scale, mask, running max, ``exp``, ``alpha``, the running sum ``l`` (summed
+from the fp32 probabilities), ``lse``, ``delta``, ``dp - delta`` and all
+scratch accumulators.  So an fp32 caller gets fp32 operands and no cast at
+all, and a bf16 caller — the trainers — the one rounding every bf16
+attention makes.  (Mosaic at the default precision takes one bf16 pass of
+the MXU for fp32 operands too, rounding them on the way in: the type decides
+what is rounded where, not how fast the dots run.  PERF.md section 6, PR 27.)
+
+All three bodies work on the *transposed* score tile, (bk, bq): keys on
+sublanes, queries on lanes.  The row statistics (m, l, lse, delta) are then
+lane-dense (1, bq) rows — the layout lse is stored in — that broadcast down
+the sublanes; the softmax's max and sum reduce over sublanes, elementwise
+between vector registers, not across lanes; and a computed tile enters its
+dot as it lies, (M, K) on the left or (K, N) on the right, so only the
+caller's narrow (block, D) tiles are ever transposed for the MXU.  The
+forward and dQ accumulate (D, bq) and transpose once, at the last key tile.
+On a v5e that orientation, not the dots, is what the time was: the forward
+takes 7.6 ps a live score element against 10.9 with queries on sublanes
+(bare timing at (5, 8192, 16, 64) bf16 causal, PERF.md section 6, PR 27).
 
 Three kernels:
 
@@ -85,6 +112,20 @@ def _compiler_params(n_parallel: int):
 # Forward kernel
 # ---------------------------------------------------------------------------
 
+def _scores_t(q, k, *, causal: bool, scale: float, q_start, k_start):
+    """The transposed score tile Sᵀ = K·Qᵀ · scale, (bk, bq) fp32, from the
+    caller's (bq, D) and (bk, D) tiles as they are; causally masked at the
+    tile's global positions."""
+    st = jax.lax.dot_general(
+        k, q, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if causal:
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+        st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+    return st
+
+
 def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
                 block_q: int, block_k: int):
@@ -108,41 +149,34 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                   # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)                   # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # (bq, bk)
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_scr[:, :1]                                  # (bq, 1)
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        q = q_ref[0, 0]                # (bq, D), the caller's type
+        k = k_ref[0, 0]                # (bk, D)
+        v = v_ref[0, 0]
+        st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
+                       k_start=k_start)                        # (bk, bq)
+        m_prev = m_scr[:1, :]                                  # (1, bq)
+        l_prev = l_scr[:1, :]
+        m_cur = jnp.max(st, axis=0, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.where(m_prev <= _NEG_INF / 2, 0.0,
                           jnp.exp(m_prev - m_new))
-        p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pt = jnp.where(st <= _NEG_INF / 2, 0.0, jnp.exp(st - m_new))
+        l_new = l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            v, pt.astype(v.dtype),     # the one rounding; l_new had fp32 pt
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (D, bq)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
+        m = m_scr[:1, :]
+        l = l_scr[:1, :]
         l_safe = jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = jnp.transpose(acc_scr[:] / l_safe).astype(o_ref.dtype)
         lse = jnp.where(l <= 0.0, _NEG_INF, m + jnp.log(l_safe))
-        lse_ref[0, 0] = jnp.broadcast_to(lse[:, 0][None, :],
-                                         lse_ref.shape[2:])
+        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
 def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
@@ -178,10 +212,12 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
             jax.ShapeDtypeStruct((b, h, sq, d), q_bhsd.dtype),
             jax.ShapeDtypeStruct((b, h, 8, sq), jnp.float32),
         ],
+        # m, l as lane-dense rows (8 sublanes for the (8, 128) tiling, like
+        # lse); the output accumulator transposed, as the body works.
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((8, block_q), jnp.float32),
+            pltpu.VMEM((8, block_q), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
         ],
         compiler_params=_compiler_params(3),
         interpret=interpret,
@@ -213,35 +249,29 @@ def _bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)                  # (bq, D)
-        lse = jnp.transpose(lse_ref[0, 0][:1, :])              # (bq, 1)
-        delta = jnp.transpose(delta_ref[0, 0][:1, :])          # (bq, 1)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.where(jnp.logical_or(s <= _NEG_INF / 2,
-                                     lse <= _NEG_INF / 2),
-                      0.0, jnp.exp(s - lse))
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (bq, bk)
-        ds = p * (dp - delta) * scale
+        q = q_ref[0, 0]                                        # (bq, D)
+        k = k_ref[0, 0]                                        # (bk, D)
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = lse_ref[0, 0][:1, :]                             # (1, bq)
+        delta = delta_ref[0, 0][:1, :]
+        st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
+                       k_start=k_start)                        # (bk, bq)
+        pt = jnp.where(jnp.logical_or(st <= _NEG_INF / 2,
+                                      lse <= _NEG_INF / 2),
+                       0.0, jnp.exp(st - lse))
+        dpt = jax.lax.dot_general(
+            v, do, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (bk, bq)
+        dst = pt * (dpt - delta) * scale
         dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            k, dst.astype(k.dtype),
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (D, bq)
 
     @pl.when(j == nk - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0, 0] = jnp.transpose(dq_scr[:]).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -264,33 +294,28 @@ def _bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                    # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)                    # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = jnp.transpose(lse_ref[0, 0][:1, :])              # (bq, 1)
-        delta = jnp.transpose(delta_ref[0, 0][:1, :])
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # (bq, bk)
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.where(jnp.logical_or(s <= _NEG_INF / 2,
-                                     lse <= _NEG_INF / 2),
-                      0.0, jnp.exp(s - lse))                   # (bq, bk)
+        q = q_ref[0, 0]                                        # (bq, D)
+        k = k_ref[0, 0]                                        # (bk, D)
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = lse_ref[0, 0][:1, :]                             # (1, bq)
+        delta = delta_ref[0, 0][:1, :]
+        st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
+                       k_start=k_start)                        # (bk, bq)
+        pt = jnp.where(jnp.logical_or(st <= _NEG_INF / 2,
+                                      lse <= _NEG_INF / 2),
+                       0.0, jnp.exp(st - lse))                 # (bk, bq)
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do, dimension_numbers=(((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do,
+            dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                # (bk, D)
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                # (bq, bk)
-        ds = p * (dp - delta) * scale
+        dpt = jax.lax.dot_general(
+            v, do, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (bk, bq)
+        dst = pt * (dpt - delta) * scale
         dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
+            dst.astype(q.dtype), q,
+            dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                # (bk, D)
 
     @pl.when(j == nq - 1)
@@ -337,7 +362,7 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
         ],
         out_specs=q_spec(lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q_bhsd.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         compiler_params=_compiler_params(3),
         interpret=interpret,
         name="hvd_flash_bwd_dq",

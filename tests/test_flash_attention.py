@@ -176,3 +176,209 @@ def test_kernel_is_never_chosen_or_interpreted_behind_the_callers_back(
     jax.make_jaxpr(lambda q, k, v: fa.flash_attention_with_lse(
         q, k, v, causal=True))(q, k, v)
     assert seen == [False, False]
+
+
+# ---------------------------------------------------------------------------
+# bf16 callers: the operands of the nine MXU dots are the caller's type, the
+# softmax and every accumulator stay fp32 (ROADMAP Sk).
+# ---------------------------------------------------------------------------
+
+# bf16 keeps 8 significant bits: rounding to it moves a value by at most
+# 2**-8 of itself.  Every bound below is a small count of such roundings.
+U = 2.0 ** -8
+
+
+def _f32_attention(q, k, v, causal, cotangent=None):
+    """The XLA oracle in fp32 on the inputs as they are (bf16 values widened
+    exactly); with a cotangent, (dq, dk, dv) in fp32 too."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    out, vjp = jax.vjp(
+        lambda q, k, v: ra.reference_attention(q, k, v, causal=causal),
+        q, k, v)
+    return out if cotangent is None else vjp(cotangent.astype(jnp.float32))
+
+
+def _rel_l2(a, b):
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _assert_bf16_forward(out, q, k, v, causal):
+    assert out.dtype == jnp.bfloat16
+    ref = _f32_attention(q, k, v, causal)
+    # out = sum(p v) / l with l exact: the cast of p at the PV dot moves
+    # each term by at most U |p v|, so the row by at most U max|v|; the
+    # result's own rounding to bf16 adds U |out| <= U max|v|.
+    bound = 2 * U * float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+    err = np.abs(np.asarray(out, np.float32) - np.asarray(ref))
+    assert err.max() <= bound, (err.max(), bound)
+
+
+def _assert_bf16_grads(grads, q, k, v, g, causal):
+    refs = _f32_attention(q, k, v, causal, cotangent=g)
+    # Three roundings of at most U each separate a gradient from the fp32
+    # one: the cast of P or dS at its dot, the bf16 ``out`` inside delta
+    # (the caller's, as before this change) and the result's own rounding.
+    # They are independent, so the norm moves by well under their sum.
+    for name, got, ref in zip("qkv", grads, refs):
+        assert got.dtype == jnp.bfloat16
+        assert _rel_l2(got, ref) <= 3 * U, (name, _rel_l2(got, ref))
+
+
+@pytest.mark.parametrize("what", ["fwd", "grad"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("causal", [True, False])
+def test_bf16_inputs_match_fp32_attention_to_bf16_rounding(causal, d, what):
+    q, k, v = _qkv(b=1, s=256, h=2, d=d, dtype=jnp.bfloat16, seed=d)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, block_q=128,
+                                  block_k=128, interpret=True)
+
+    if what == "fwd":
+        _assert_bf16_forward(flash(q, k, v), q, k, v, causal)
+    else:
+        g = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.bfloat16)
+        _assert_bf16_grads(jax.vjp(flash, q, k, v)[1](g), q, k, v, g, causal)
+
+
+def test_bf16_long_sequence_sums_l_across_four_key_tiles():
+    """2048 keys in 4 tiles of 512 (the cells' tile): ``l`` and the output
+    accumulator are carried across tiles, rescaled by ``alpha`` each time."""
+    q, k, v = _qkv(b=1, s=2048, h=1, d=64, dtype=jnp.bfloat16, seed=3)
+    assert fa._supported(q, k) == (512, 512)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, interpret=True)
+
+    out, vjp = jax.vjp(flash, q, k, v)
+    _assert_bf16_forward(out, q, k, v, True)
+    g = jax.random.normal(jax.random.PRNGKey(8), q.shape, jnp.bfloat16)
+    _assert_bf16_grads(vjp(g), q, k, v, g, True)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_bf16_lse_is_the_fp32_kernels(causal, monkeypatch):
+    """``l`` is summed from the fp32 probabilities, before the cast at the
+    PV dot: on the same values the bf16 call's ``lse`` is the fp32 call's
+    to fp32 round-off (bf16 x bf16 products are exact in fp32), where a sum
+    of rounded probabilities would sit 2**-9 or so away.  The outputs do
+    differ by the rounding: that the cast is there at all."""
+    q, k, v = _qkv(b=1, s=512, h=2, d=64, dtype=jnp.bfloat16, seed=5)
+    # Four 128-wide key tiles through the public entry point's own tiling.
+    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", "128")
+    out16, lse16 = fa.flash_attention_with_lse(
+        q, k, v, causal=causal, interpret=True)
+    out32, lse32 = fa.flash_attention_with_lse(
+        *(x.astype(jnp.float32) for x in (q, k, v)), causal=causal,
+        interpret=True)
+    assert lse16.dtype == lse32.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse16), np.asarray(lse32),
+                               atol=2e-6, rtol=2e-6)
+    assert _rel_l2(out16, out32) > U / 64
+
+
+def _kernel_bodies(dtype, causal=True):
+    """name -> jaxpr of the three kernel bodies, as the custom VJP traces
+    them for inputs of ``dtype`` (nothing runs)."""
+    x = jax.ShapeDtypeStruct((1, 256, 2, 64), dtype)
+
+    def f(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, block_q=128, block_k=128,
+            interpret=True), q, k, v)
+        return vjp(out)
+
+    bodies = {}
+
+    def visit(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                bodies[name] = (eqn.params["jaxpr"],
+                                eqn.params["grid_mapping"])
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                visit(sub)
+
+    visit(jax.make_jaxpr(f)(x, x, x).jaxpr)
+    return bodies
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+# kernel -> (dots, of them with an operand the kernel computed (P or dS),
+# results written in the caller's type, fp32 scratch accumulators)
+KERNELS = {"hvd_flash_fwd": (2, 1, 1, 3),        # QK', P V; out; m, l, acc
+           "hvd_flash_bwd_dq": (3, 1, 1, 1),     # QK', dO V', dS K; dq
+           "hvd_flash_bwd_dkv": (4, 2, 2, 2)}    # + P' dO, dS' Q; dk, dv
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_the_nine_dots_take_the_callers_type_and_softmax_stays_fp32(
+        dtype, causal):
+    """Does the mechanism engage: 9 of 9 dots on bf16 operands for a bf16
+    caller, 0 of 9 (and no rounding to bf16 anywhere) for an fp32 one; fp32
+    results, softmax and scratch either way."""
+    bodies = _kernel_bodies(dtype, causal)
+    assert set(bodies) == set(KERNELS)
+    n_in_callers_type = 0
+    for name, (body, grid_mapping) in bodies.items():
+        n_dots, n_computed, n_results, n_scratch = KERNELS[name]
+        eqns = list(_eqns(body))
+        dots = [e for e in eqns if e.primitive.name == "dot_general"]
+        assert len(dots) == n_dots, name
+        for e in dots:
+            assert [v.aval.dtype for v in e.invars] == [dtype, dtype], name
+            assert e.outvars[0].aval.dtype == jnp.float32, name
+            assert e.params["preferred_element_type"] == jnp.float32
+            # The bodies work on (bk, bq) score tiles, so that a computed
+            # tile (P', dS') enters its dot as it lies — (M, K) on the left,
+            # (K, N) on the right — and only the caller's narrow (block, D)
+            # tiles are ever transposed for the MXU.
+            (lhs_contract, rhs_contract), _ = e.params["dimension_numbers"]
+            lhs, rhs = e.invars
+            if lhs.aval.shape == (128, 128):
+                assert lhs_contract == (1,), name
+            if rhs.aval.shape == (128, 128):
+                assert rhs_contract == (0,), name
+            n_in_callers_type += 1
+        names = {e.primitive.name for e in eqns}
+        assert ({"exp", "reduce_max", "reduce_sum"}
+                if name == "hvd_flash_fwd" else {"exp"}) <= names, name
+        # Every floating-point value a body computes is fp32 (scores, mask,
+        # m, exp, alpha, l, lse, delta, dp - delta, the rescaled sums);
+        # the caller's type appears only where a ref is read or written
+        # and at the casts counted below.
+        for e in eqns:
+            for out in e.outvars:
+                if (jnp.issubdtype(out.aval.dtype, jnp.floating)
+                        and out.aval.dtype != jnp.float32):
+                    assert e.primitive.name in (
+                        "get", "swap", "convert_element_type"), (name, e)
+        # m, l and the accumulators: the scratch operands are the body's
+        # last arguments, and a write to a ref has the ref's type.
+        assert grid_mapping.num_scratch_operands == n_scratch, name
+        for ref in body.invars[-n_scratch:]:
+            assert ref.aval.dtype == jnp.float32, name
+        converts = [e for e in eqns
+                    if e.primitive.name == "convert_element_type"]
+        to_bf16 = [e for e in converts
+                   if e.params["new_dtype"] == jnp.bfloat16]
+        if dtype == jnp.float32:
+            assert not to_bf16, name
+        else:
+            # One cast at each dot with a computed operand, one for each
+            # result; and nothing widens what the caller passed.
+            assert len(to_bf16) == n_computed + n_results, name
+            assert not [e for e in converts
+                        if e.invars[0].aval.dtype == jnp.bfloat16], name
+    assert n_in_callers_type == 9
