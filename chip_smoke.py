@@ -4,9 +4,10 @@
 Drives the flagship trainer once through the entry points a user calls —
 ``hvd.init()`` -> ``parallel.mesh.create_mesh`` ->
 ``models.transformer.make_train_step`` — at the full width of the
-long-context configuration (``bench.py`` ``bench_longctx`` defaults: vocab
-32768, d_model 1024, 16 heads, d_ff 4096, 12 layers, seq 8192, bf16, remat,
-``attn_mode="megatron"`` so attention runs the Pallas flash kernels), over
+flagship's long-context configuration, stated here and in ``main()``:
+vocab 32768, d_model 1024, 16 heads, d_ff 4096, 12 layers, seq 8192, bf16,
+remat, ``attn_mode="megatron"`` so attention runs the Pallas flash kernels
+(``benchmark/configs/flagship-12l-s8192.json`` holds the same widths), over
 every visible chip, with random weights from a seed:
 
     python chip_smoke.py                       # one process, all chips

@@ -4,7 +4,7 @@
 #
 #   ci/run_test_tiers.sh fast     # tier 1: single-process unit tests
 #   ci/run_test_tiers.sh matrix   # tier 2: multi-process integration
-#   ci/run_test_tiers.sh slow     # tier 3: elastic + slow bench-asserts
+#   ci/run_test_tiers.sh slow     # tier 3: elastic recovery + soaks
 #   ci/run_test_tiers.sh all      # everything, tier by tier
 #
 # Tiers run SEQUENTIALLY and each tier is one pytest invocation: the
@@ -49,8 +49,7 @@ TIER_FAST=(
   # Third mesh dimensions (ISSUE 16): MoE routing/capacity goldens, the
   # (dp, ep) workload vs its no-capacity oracle and the FLOPs-matched
   # dense baseline, 1F1B-vs-GPipe bit parity, the (2,2,2) -> (2,2,1)
-  # 3-axis reshard drill, pipeline_bubble attribution, and MoE serving
-  # (`bench.py --bench moe` prices the scaling/bubble/wire claims).
+  # 3-axis reshard drill, pipeline_bubble attribution, and MoE serving.
   test_moe_pipeline.py
   test_net_resilience.py
   # Fleet-scale observability plane (ISSUE 13): digest merge algebra
@@ -70,8 +69,7 @@ TIER_FAST=(
   test_overlap.py
   test_parallel.py
   # Perf-observatory drill: injected input slowdown must fire the drift
-  # detector with data-component attribution; steady runs stay silent
-  # (`bench.py --bench attribution` prices the hooks for the trajectory).
+  # detector with data-component attribution; steady runs stay silent.
   test_perf_observatory.py
   test_probe_rendezvous.py
   test_quantization.py
@@ -83,13 +81,12 @@ TIER_FAST=(
   # Serving plane (ISSUE 15): admission-policy goldens, prefill/decode
   # parity vs the training-path logits, continuous-vs-static occupancy,
   # hot-swap bit-parity, overload shed, and the train→serve handoff
-  # drill (`bench.py --bench serving` measures the batching win).
+  # drill.
   test_serving.py
   # Production-scale serving (ISSUE 18): radix prefix cache refcount
   # lifecycle + bit-identity drills, chunked prefill, speculative
   # acceptance identity/exactness, policy aging + prefill-budget
-  # goldens, and the KV-page migration codec + token-for-token handoff
-  # (`bench.py --bench serving` grows the four matching arms).
+  # goldens, and the KV-page migration codec + token-for-token handoff.
   test_serving_scale.py
   # Names inside the compiled training step (ISSUE 24): the five hvd_*
   # scopes and three flash-kernel names in both models' lowered step,
@@ -98,14 +95,13 @@ TIER_FAST=(
   # Request-scoped tracing + SLO error budgets (ISSUE 19): sampling
   # determinism, burn-rate goldens, burn-aware policy/autoscaler,
   # span coverage with tracing-on/off bit-identity, the migrated
-  # stitched-trace drill, merge --trace, loop-liveness surface
-  # (`bench.py --bench tracing` prices the <1% overhead bar).
+  # stitched-trace drill, merge --trace, loop-liveness surface.
   test_tracing.py
   test_transformer.py
   # Closed-loop autotuning drill (ISSUE 12): injected comm regression →
   # drift → bounded re-tune → regression-gated rollback → resolution in
   # the report's tuning section, plus the tuning-memory store/warm-start
-  # surface (`bench.py --bench warmstart` measures time-to-best-config).
+  # surface.
   test_tuning_loop.py
   test_utils_ops.py
   # Compiled-plane quantized + topology-scheduled collectives (ISSUE
@@ -135,14 +131,10 @@ TIER_MATRIX=(
   test_torch_extras.py test_torch_frontend.py
 )
 
-# Tier 3 — elastic recovery + slow-marked perf/regression asserts.
+# Tier 3 — elastic recovery + slow-marked soaks.
 TIER_SLOW=(
   test_churn_soak.py
-  # 1000-rank/125-host control-plane soak (ISSUE 13): thousands of
-  # real HTTP requests per mode/scale — slow-marked, NEVER in tier 1
-  # (tier-1 wall time is already near its budget).
-  test_control_plane_soak.py
-  test_eager_bench.py test_elastic.py
+  test_elastic.py
   test_tf_elastic.py
 )
 

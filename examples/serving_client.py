@@ -51,7 +51,7 @@ def main():
                    server=addr)
     print("one request:", json.dumps(out))
 
-    # ...then the same seeded open-loop schedule the bench uses.
+    # ...then a seeded open-loop schedule.
     schedule = synthetic_workload(seed=0, n=12, rate_rps=20.0,
                                   prompt_lens=(4, 16),
                                   output_lens=(4, 16),

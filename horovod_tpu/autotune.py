@@ -164,8 +164,8 @@ class ParameterManager:
     SAME GP and rounded at application — one joint surrogate over the
     mixed space — with a deterministic bootstrap plan that tries both
     values of every toggle before EI takes over (so e.g. hierarchical
-    allreduce is demonstrably tried OFF on a single host, where it loses
-    — BENCH_EAGER.json hierarchical rows).
+    allreduce is demonstrably tried OFF on a single host, where it loses:
+    ``tests/test_autotune.py::test_autotune_disables_hierarchical_on_single_host``).
     """
 
     # log2(bytes): 1 MB .. 256 MB; cycle: 0.5 .. 25 ms; three relaxed

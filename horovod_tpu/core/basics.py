@@ -228,13 +228,12 @@ def init(mesh=None,
                     global_state.config.metrics_port, e)
 
     # --- flight recorder / hang diagnosis ---------------------------------
-    # The recorder itself is always armed (ring-buffer appends are
-    # unmeasurable — bench.py --bench flight_overhead); what init() adds
-    # is the dump/triage plumbing: identity for dumps, the SIGUSR1
-    # trigger, the coordinator clock-offset estimate (piggybacked on the
-    # rendezvous channel every worker already polls), the per-rank debug
-    # endpoint + its KV-published address, and — on the coordinator rank
-    # of launcher-run jobs — the stall→hang-report escalation watchdog.
+    # The recorder itself is always armed; what init() adds is the
+    # dump/triage plumbing: identity for dumps, the SIGUSR1 trigger, the
+    # coordinator clock-offset estimate (piggybacked on the rendezvous
+    # channel every worker already polls), the per-rank debug endpoint +
+    # its KV-published address, and — on the coordinator rank of
+    # launcher-run jobs — the stall→hang-report escalation watchdog.
     if not global_state.config.flight_disable:
         from .. import debug as _debug
         _debug.flight.set_identity(rank=global_state.rank,

@@ -336,8 +336,8 @@ class Config:
     # Performance observatory: step_end() closes a per-step attribution
     # record (compute / exposed comm / hidden comm / input / checkpoint /
     # host gap) and feeds the EWMA/CUSUM drift detector; both default on
-    # (the per-step cost is a handful of cached metric reads — bench.py
-    # --bench attribution pins it under the 1% bar).  peak_tflops grades
+    # (the per-step cost is a handful of cached metric reads).
+    # peak_tflops grades
     # hvd_mfu_ratio: 0 = the chip's spec-sheet peak by exact device kind
     # (metrics/attribution.PEAK_FLOPS_BY_KIND).
     attribution: bool = True
@@ -349,8 +349,7 @@ class Config:
     perf_drift_min_pct: float = 10.0
     perf_drift_cooldown: int = 50
     perf_drift_lookback_s: float = 120.0
-    # Flight recorder: always-on ring buffer (cost is unmeasurable —
-    # bench.py --bench flight_overhead pins it under 1%); the stall →
+    # Flight recorder: always-on ring buffer; the stall →
     # hang-report escalation runs wherever the native controller does.
     flight_disable: bool = False
     flight_capacity: int = 4096

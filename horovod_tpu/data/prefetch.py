@@ -5,7 +5,7 @@ bounded queue; the training thread pops ready batches.  With a transfer
 function (``jax.device_put``) applied *in the producer*, the device
 transfer for batch *i+1* is dispatched while batch *i*'s step executes
 — JAX transfers are async, so a queue depth of 2 gives the classic
-double-buffering (bench.py's host-feed path hand-rolls the same idiom).
+double-buffering.
 
 Correctness properties the tests pin down:
 

@@ -19,9 +19,8 @@ Design constraints:
   event tuple itself.  The sequence counter rides ``itertools.count``
   (same GIL atomicity).  ``snapshot()`` copies the deque in one C-level
   call; a concurrent append at worst adds/drops an edge event.
-* **Unmeasurable off the hot path.**  One ``record()`` is a disabled-
-  check + a tuple + an append (~1 µs); ``bench.py --bench
-  flight_overhead`` pins the total per-step cost under the 1% bar.
+* **Off the hot path.**  One ``record()`` is a disabled-check + a tuple
+  + an append.
 * **Two clocks per event.**  ``t_mono`` (monotonic — durations survive
   wall-clock steps) and ``t_wall`` (wall — cross-rank alignment).  The
   recorder also carries a coordinator clock-offset estimate

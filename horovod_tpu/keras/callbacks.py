@@ -119,8 +119,8 @@ class MetricsCallback(_tf.keras.callbacks.Callback):
     ``step_end(batch_time)`` per batch (driving the step-time histogram
     and — on the ``HVD_TPU_METRICS_SYNC_STEPS`` cadence — the cross-rank
     aggregation + straggler detector), plus an optional per-epoch JSONL
-    snapshot in the same schema ``bench.py`` and the Prometheus endpoint
-    expose (docs/metrics.md).
+    snapshot in the same schema the Prometheus endpoint exposes
+    (docs/metrics.md).
 
     Args:
       jsonl_path: when given, append one registry snapshot per epoch to

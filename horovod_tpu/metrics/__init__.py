@@ -39,8 +39,7 @@ and bytes), the autotuner (samples, applied parameters), and the
 elastic layer (commits, restores, syncs, resets; driver-side rounds,
 failures, blacklists).
 
-See ``docs/metrics.md`` for the schema, scrape example and overhead
-numbers (``bench.py --bench metrics_overhead``).
+See ``docs/metrics.md`` for the schema and a scrape example.
 """
 
 from .registry import (

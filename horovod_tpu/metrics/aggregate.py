@@ -11,7 +11,7 @@ jitted process mesh, or trivially for one process) on an opt-in cadence:
     pays nothing unless the operator asks.
 
 ``step_end`` is the one hook training loops (and
-``keras.callbacks.MetricsCallback`` / ``bench.py``) call per step; it
+``keras.callbacks.MetricsCallback``) call per step; it
 also feeds the local ``hvd_step_time_seconds`` histogram.  Because every
 rank steps in lockstep (SPMD), a step-count cadence is a safe collective
 schedule — no extra coordination needed.

@@ -39,17 +39,14 @@ plus an optional per-step JSONL trail (``HVD_TPU_ATTRIBUTION_JSONL``).
 **Live MFU**: :func:`set_step_flops` declares the model FLOPs one step
 executes per chip (helpers: ``models/resnet.train_flops_per_image``,
 ``models/bert.train_flops_per_seq``,
-``models/transformer.train_flops_per_seq`` — the bench's audited
-accounting, now importable); every ``step_end`` then grades
+``models/transformer.train_flops_per_seq``); every ``step_end`` then grades
 ``hvd_mfu_ratio = flops / (step_time * peak)`` against
 :func:`peak_flops` — ``HVD_TPU_PEAK_TFLOPS`` when set, else the detected
 chip's spec-sheet peak (``PEAK_FLOPS_BY_KIND``, keyed by exact
 ``device_kind``).
 
 Budget: one ``close_step`` is ~a dozen cached-child reads and float
-arithmetic — ``bench.py --bench attribution`` pins the whole
-observatory (attribution + drift detector) under the 1% step bar.
-Disable with ``HVD_TPU_ATTRIBUTION=0`` or :func:`set_enabled`.
+arithmetic.  Disable with ``HVD_TPU_ATTRIBUTION=0`` or :func:`set_enabled`.
 """
 
 from __future__ import annotations
@@ -100,8 +97,8 @@ def set_enabled(flag: Optional[bool]) -> None:
 # ---------------------------------------------------------------------------
 
 # Per-chip peak bf16 FLOP/s keyed by the exact ``device_kind`` jax reports
-# (the spellings of jax._src.mesh_utils) — the single home of the table
-# bench.py grades MFU against.  Source: Google Cloud TPU documentation,
+# (the spellings of jax._src.mesh_utils) — the table ``hvd_mfu_ratio`` is
+# graded against.  Source: Google Cloud TPU documentation,
 # the "System architecture" page of each generation.  A kind that is not
 # here has no peak: no substring guesses a neighbouring generation's.
 PEAK_FLOPS_BY_KIND = {
@@ -123,8 +120,8 @@ def peak_flops() -> Optional[float]:
 
     ``HVD_TPU_PEAK_TFLOPS`` (TFLOP/s) wins when set.  Otherwise the
     detected chip's entry in ``PEAK_FLOPS_BY_KIND``; None off-TPU and
-    for a TPU kind the table does not list (MFU is then not computed,
-    and bench.py's chip modes refuse to run).  Cached after the first
+    for a TPU kind the table does not list (MFU is then not computed).
+    Cached after the first
     resolution — this runs on every ``close_step``, and an env read per
     step is measurable at the <1% budget; :func:`reset_peak_cache`
     re-reads the knob."""
@@ -162,7 +159,7 @@ def _family_read(reg, name: str, histogram: bool = False):
     Reads the slots directly instead of the locked properties: this
     runs every step_end across six families, GIL-atomic attribute reads
     are safe for a monitoring consumer, and the child locks are pure
-    overhead here (bench.py --bench attribution prices this path)."""
+    overhead here."""
     total, gen = 0.0, 0
     for child in reg.children_of(name):
         total += child._sum if histogram else child._value

@@ -29,7 +29,8 @@ where the host's one serving slot is).  Per sync round it:
    ``HVD_TPU_FLEET_OBSERVE_PUSH_S`` cadence.
 
 Coordinator-side cost per sync round drops from O(ranks) snapshots to
-O(hosts) digests — measured by ``bench.py --bench control_plane``.
+O(hosts) digests (``tests/test_observe_plane.py`` holds the merge
+algebra and the flat-vs-tree verdict parity).
 
 All endpoints are HMAC-gated with the launch secret under the
 rendezvous KV scheme (scope ``observe``); without a secret they run

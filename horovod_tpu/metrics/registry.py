@@ -21,8 +21,7 @@ Design constraints, in priority order:
    every subsystem can instrument without dragging in jax/numpy.
 
 Disable switch: ``HVD_TPU_METRICS_DISABLE=1`` (or ``set_enabled(False)``)
-turns every record call into a near-no-op — the knob
-``bench.py --bench metrics_overhead`` measures against.
+turns every record call into a near-no-op.
 """
 
 from __future__ import annotations

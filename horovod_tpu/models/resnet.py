@@ -237,9 +237,8 @@ def synthetic_batch(key, batch: int, image_size: int = 224,
     return images, labels
 
 
-# fwd GFLOP/img @224x224, width 64 (standard torchvision counts) — the
-# bench's audited accounting, importable so training loops can feed
-# hvd.metrics.set_step_flops() with the same figure MFU reports use.
+# fwd GFLOP/img @224x224, width 64 (standard torchvision counts),
+# importable so training loops can feed hvd.metrics.set_step_flops().
 _FWD_GFLOP_PER_IMG = {18: 1.82, 34: 3.68, 50: 4.09, 101: 7.83, 152: 11.53}
 
 

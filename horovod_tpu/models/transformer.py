@@ -564,23 +564,19 @@ def synthetic_batch(key, cfg: TransformerConfig, batch: int):
 
 
 # ---------------------------------------------------------------------------
-# Serving: prefill / decode split over a paged KV cache
+# Serving: one chunk forward over a paged KV cache
 # ---------------------------------------------------------------------------
 #
-# Inference splits the training forward into two entry points sharing a
-# page-pool KV cache (``hvd.serving`` builds the continuous-batching
-# engine on top — docs/serving.md):
-#
-# * :func:`prefill` runs the ordinary causal forward over one prompt
-#   (the training path's math, layer by layer) while writing each
-#   layer's K/V into the prompt's cache pages, and returns the logits
-#   at the last prompt position — the first sampled token.
-# * :func:`decode_step` advances a whole BATCH of sequences by one
-#   token each: per layer it appends the new K/V at each slot's write
-#   position and attends the single query against that slot's gathered
-#   pages.  Shapes depend only on (slots, pages-per-slot, page size) —
-#   never on which requests occupy the slots — so the engine compiles
-#   it exactly once per geometry.
+# Inference is ONE entry point over a page-pool KV cache
+# (``hvd.serving`` builds the continuous-batching engine on top —
+# docs/serving.md): :func:`chunk_forward` advances a whole BATCH of
+# sequences by K tokens each.  Per layer it writes the chunk's K/V at
+# each slot's write positions and attends the K queries against that
+# slot's gathered pages under a per-query causal mask.  A whole prompt
+# is ``lengths = 0`` with K the padded prompt; a decode tick is K = 1.
+# Shapes depend only on (slots, K, pages-per-slot, page size) — never on
+# which requests occupy the slots — so the engine compiles it once per
+# geometry and chunk length.
 #
 # Numerics: scores/softmax/PV accumulate in fp32 exactly like
 # ``ra.reference_attention``; normalization and the vocab head are fp32
@@ -655,141 +651,76 @@ def _moe_mlp_serving(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     return out.astype(tok.dtype)
 
 
-def prefill(cfg: TransformerConfig, params: Dict[str, Any],
-            tokens: jax.Array, length: jax.Array,
-            kv: Dict[str, jax.Array],
-            page_rows: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Run the causal forward over one padded prompt, writing K/V into
-    the cache.
-
-    tokens: (S,) int32, S a static multiple of the page size (padding
-    past ``length`` is arbitrary — causality keeps it out of every
-    valid position's context).  length: dynamic scalar, 1 <= length <= S.
-    page_rows: (S // page_size,) int32 physical page indices receiving
-    positions [0, S).  Returns (fp32 logits (V,) at position length-1,
-    updated kv).
-    """
-    _check_servable(cfg)
-    s = tokens.shape[0]
-    page_size = kv["k"].shape[2]
-    n_rows = s // page_size
-    hd = cfg.head_dim
-    x = (params["embed"][tokens] + params["pos"][:s]).astype(cfg.dtype)
-    x = x[None]                                   # (1, S, d)
-    layers = _flat_layers(params)
-    for l in range(cfg.n_layers):
-        lp = {k: v[l] for k, v in layers.items()}
-        h = _rmsnorm(x, lp["ln1"])
-        qkv = jnp.einsum("bsd,de->bse", h, lp["wqkv"].astype(x.dtype))
-        qkv = qkv.reshape(1, s, cfg.n_heads, 3, hd)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        kv["k"] = kv["k"].at[l, page_rows].set(
-            k[0].reshape(n_rows, page_size, cfg.n_heads, hd))
-        kv["v"] = kv["v"].at[l, page_rows].set(
-            v[0].reshape(n_rows, page_size, cfg.n_heads, hd))
-        o = ra.full_attention(q, k, v, causal=True)
-        x = x + jnp.einsum("bse,ed->bsd", o.reshape(1, s, -1),
-                           lp["wo"].astype(x.dtype))
-        h = _rmsnorm(x, lp["ln2"])
-        if cfg.n_experts > 0:
-            y = _moe_mlp_serving(cfg, lp, h.reshape(s, -1))
-            x = x + y.reshape(1, s, -1)
-        else:
-            u = jax.nn.gelu(jnp.einsum("bsd,df->bsf", h,
-                                       lp["w1"].astype(x.dtype)))
-            x = x + jnp.einsum("bsf,fd->bsd", u, lp["w2"].astype(x.dtype))
-    hidden = _rmsnorm(x, params["final_norm"])           # (1, S, d)
-    last = lax.dynamic_index_in_dim(hidden[0], length - 1, axis=0,
-                                    keepdims=False)      # (d,)
-    logits = jnp.einsum("d,vd->v", last.astype(jnp.float32),
-                        params["embed"].astype(jnp.float32))
-    return logits, kv
-
-
-def decode_step(cfg: TransformerConfig, params: Dict[str, Any],
-                tokens: jax.Array, lengths: jax.Array,
-                kv: Dict[str, jax.Array],
-                page_tables: jax.Array
-                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Advance every slot by one token against its paged cache.
-
-    tokens: (B,) int32 — the input token each slot consumes this step
-    (written at position ``lengths[b]``).  lengths: (B,) int32 context
-    sizes BEFORE this step.  page_tables: (B, pages_per_slot) int32 —
-    logical position p of slot b lives in physical page
-    ``page_tables[b, p // page_size]`` at offset ``p % page_size``.
-    Returns (fp32 logits (B, V) predicting each slot's next token,
-    updated kv).  Slots the caller considers inactive should point
-    their page-table row at a scratch page — the math still runs, the
-    writes land somewhere harmless, and the logits are ignored.
-    """
-    _check_servable(cfg)
-    b, pages_per_slot = page_tables.shape
-    page_size = kv["k"].shape[2]
-    max_len = pages_per_slot * page_size
+def _serving_layer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
+                   l: int, x: jax.Array, kv: Dict[str, jax.Array],
+                   page_tables: jax.Array, write_page: jax.Array,
+                   write_off: jax.Array, mask: jax.Array
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One transformer layer of :func:`chunk_forward`: layer ``l``'s
+    parameters ``lp`` and its pages ``kv[...][l]``.  x: (B, K, d).
+    write_page/write_off: (B, K) where each chunk position's K/V lands.
+    mask: (B, K, max_len) — which cached positions each query may read.
+    The layer's pages are addressed inside the stacked pool, not handed
+    in as a slab: the write is then a scatter into the donated buffer,
+    where a slab in and out costs a copy of the layer's pool each way.
+    Returns (x, kv)."""
+    b, kq, _ = x.shape
     hd = cfg.head_dim
     scale = 1.0 / (hd ** 0.5)
-    write_page = jnp.take_along_axis(
-        page_tables, (lengths // page_size)[:, None], axis=1)[:, 0]
-    write_off = lengths % page_size
-    x = (params["embed"][tokens] + params["pos"][lengths]).astype(cfg.dtype)
-    layers = _flat_layers(params)
-    k_pos = jnp.arange(max_len)
-    mask = k_pos[None] <= lengths[:, None]               # (B, max_len)
-    for l in range(cfg.n_layers):
-        lp = {k: v[l] for k, v in layers.items()}
-        h = _rmsnorm(x, lp["ln1"])
-        qkv = jnp.einsum("bd,de->be", h, lp["wqkv"].astype(x.dtype))
-        qkv = qkv.reshape(b, cfg.n_heads, 3, hd)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        kv["k"] = kv["k"].at[l, write_page, write_off].set(k)
-        kv["v"] = kv["v"].at[l, write_page, write_off].set(v)
-        # Gather AFTER the write so position lengths[b] (this token) is
-        # in its own context, matching the causal training forward.
-        k_ctx = kv["k"][l][page_tables].reshape(
-            b, max_len, cfg.n_heads, hd)
-        v_ctx = kv["v"][l][page_tables].reshape(
-            b, max_len, cfg.n_heads, hd)
-        s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
-                       k_ctx.astype(jnp.float32)) * scale
-        s = jnp.where(mask[:, None], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhk,bkhd->bhd", p,
-                       v_ctx.astype(jnp.float32)).astype(x.dtype)
-        x = x + jnp.einsum("be,ed->bd", o.reshape(b, -1),
-                           lp["wo"].astype(x.dtype))
-        h = _rmsnorm(x, lp["ln2"])
-        if cfg.n_experts > 0:
-            x = x + _moe_mlp_serving(cfg, lp, h)
-        else:
-            u = jax.nn.gelu(jnp.einsum("bd,df->bf", h,
-                                       lp["w1"].astype(x.dtype)))
-            x = x + jnp.einsum("bf,fd->bd", u, lp["w2"].astype(x.dtype))
-    hidden = _rmsnorm(x, params["final_norm"])           # (B, d)
-    logits = jnp.einsum("bd,vd->bv", hidden.astype(jnp.float32),
-                        params["embed"].astype(jnp.float32))
-    return logits, kv
+    h = _rmsnorm(x, lp["ln1"])
+    qkv = jnp.einsum("bkd,de->bke", h, lp["wqkv"].astype(x.dtype))
+    qkv = qkv.reshape(b, kq, cfg.n_heads, 3, hd)
+    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    kv["k"] = kv["k"].at[l, write_page, write_off].set(k)
+    kv["v"] = kv["v"].at[l, write_page, write_off].set(v)
+    # Gather AFTER the write: the chunk attends to itself, with the
+    # per-query causal mask keeping later chunk positions out.
+    k_ctx = kv["k"][l][page_tables].reshape(b, -1, cfg.n_heads, hd)
+    v_ctx = kv["v"][l][page_tables].reshape(b, -1, cfg.n_heads, hd)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   k_ctx.astype(jnp.float32)) * scale
+    s = jnp.where(mask[:, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p,
+                   v_ctx.astype(jnp.float32)).astype(x.dtype)
+    x = x + jnp.einsum("bke,ed->bkd", o.reshape(b, kq, -1),
+                       lp["wo"].astype(x.dtype))
+    h = _rmsnorm(x, lp["ln2"])
+    if cfg.n_experts > 0:
+        y = _moe_mlp_serving(cfg, lp, h.reshape(b * kq, -1))
+        x = x + y.reshape(b, kq, -1)
+    else:
+        u = jax.nn.gelu(jnp.einsum("bkd,df->bkf", h,
+                                   lp["w1"].astype(x.dtype)))
+        x = x + jnp.einsum("bkf,fd->bkd", u, lp["w2"].astype(x.dtype))
+    return x, kv
 
 
-def decode_verify(cfg: TransformerConfig, params: Dict[str, Any],
+def chunk_forward(cfg: TransformerConfig, params: Dict[str, Any],
                   tokens: jax.Array, lengths: jax.Array,
                   kv: Dict[str, jax.Array],
                   page_tables: jax.Array
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Advance every slot by K tokens in ONE forward — the verify-k /
-    chunked-prefill kernel.
+    """Advance every slot by K tokens in ONE forward against its paged
+    cache — the only function that writes the cache.
 
     tokens: (B, K) int32 — token j of slot b is written at position
-    ``lengths[b] + j`` (its K/V land in the page the slot's table maps
-    that position to).  lengths: (B,) int32 context sizes BEFORE the
-    call.  Returns (fp32 logits (B, K, V), updated kv) where
-    ``logits[b, j]`` predicts the token AFTER ``tokens[b, j]`` —
-    position ``lengths[b] + j`` attends every cached position ``<=``
-    itself, so K = 1 computes exactly :func:`decode_step`'s math.
+    ``lengths[b] + j``.  lengths: (B,) int32 context sizes BEFORE the
+    call.  page_tables: (B, pages_per_slot) int32 — logical position p
+    of slot b lives in physical page ``page_tables[b, p // page_size]``
+    at offset ``p % page_size``.  Returns (fp32 logits (B, K, V),
+    updated kv) where ``logits[b, j]`` predicts the token AFTER
+    ``tokens[b, j]`` — position ``lengths[b] + j`` attends every cached
+    position ``<=`` itself.  Slots the caller considers inactive should
+    point their page-table row at scratch page 0 — the math still runs,
+    the writes land somewhere harmless, and the logits are ignored.
 
-    Three callers share this one entry point (docs/serving.md):
+    Four callers share this one entry point (docs/serving.md):
 
-    * **chunked prefill** — a prompt chunk at offset ``lengths[b]``
+    * **decode tick** — K = 1: every slot consumes its last sampled
+      token and ``logits[:, 0]`` predicts the next;
+    * **(chunked) prefill** — a whole prompt is ``lengths[b]`` = 0 with
+      K the padded prompt; a prompt chunk at offset ``lengths[b]``
       interleaves into decode iterations instead of stalling them;
     * **prefix-cache suffix prefill** — ``lengths[b]`` > 0 names the
       cached-prefix length, only the suffix recomputes;
@@ -801,15 +732,13 @@ def decode_verify(cfg: TransformerConfig, params: Dict[str, Any],
     every such position is ≥ the slot's post-call valid length, so it
     is masked out of every later read until the position is rewritten
     with real content.  Positions at or past the table's extent route
-    their writes to scratch page 0.
+    their writes to scratch page 0, and their position-table index is
+    clamped to the table's last row.
     """
     _check_servable(cfg)
-    b, kq = tokens.shape
-    pages_per_slot = page_tables.shape[1]
+    kq = tokens.shape[1]
     page_size = kv["k"].shape[2]
-    max_len = pages_per_slot * page_size
-    hd = cfg.head_dim
-    scale = 1.0 / (hd ** 0.5)
+    max_len = page_tables.shape[1] * page_size
     pos = lengths[:, None] + jnp.arange(kq, dtype=lengths.dtype)[None]
     pos_c = jnp.minimum(pos, max_len - 1)
     write_page = jnp.take_along_axis(page_tables, pos_c // page_size,
@@ -820,39 +749,11 @@ def decode_verify(cfg: TransformerConfig, params: Dict[str, Any],
          + params["pos"][jnp.minimum(pos, cfg.seq_len - 1)]
          ).astype(cfg.dtype)                              # (B, K, d)
     layers = _flat_layers(params)
-    k_pos = jnp.arange(max_len)
-    mask = k_pos[None, None, :] <= pos[:, :, None]        # (B, K, max_len)
+    mask = jnp.arange(max_len)[None, None, :] <= pos[:, :, None]
     for l in range(cfg.n_layers):
         lp = {k: v[l] for k, v in layers.items()}
-        h = _rmsnorm(x, lp["ln1"])
-        qkv = jnp.einsum("bkd,de->bke", h, lp["wqkv"].astype(x.dtype))
-        qkv = qkv.reshape(b, kq, cfg.n_heads, 3, hd)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        kv["k"] = kv["k"].at[l, write_page, write_off].set(k)
-        kv["v"] = kv["v"].at[l, write_page, write_off].set(v)
-        # Gather AFTER the write: the chunk attends to itself, with the
-        # per-query causal mask keeping later chunk positions out.
-        k_ctx = kv["k"][l][page_tables].reshape(b, max_len, cfg.n_heads,
-                                                hd)
-        v_ctx = kv["v"][l][page_tables].reshape(b, max_len, cfg.n_heads,
-                                                hd)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                       k_ctx.astype(jnp.float32)) * scale
-        s = jnp.where(mask[:, None], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p,
-                       v_ctx.astype(jnp.float32)).astype(x.dtype)
-        x = x + jnp.einsum("bke,ed->bkd", o.reshape(b, kq, -1),
-                           lp["wo"].astype(x.dtype))
-        h = _rmsnorm(x, lp["ln2"])
-        if cfg.n_experts > 0:
-            y = _moe_mlp_serving(cfg, lp, h.reshape(b * kq, -1))
-            x = x + y.reshape(b, kq, -1)
-        else:
-            u = jax.nn.gelu(jnp.einsum("bkd,df->bkf", h,
-                                       lp["w1"].astype(x.dtype)))
-            x = x + jnp.einsum("bkf,fd->bkd", u,
-                               lp["w2"].astype(x.dtype))
+        x, kv = _serving_layer(cfg, lp, l, x, kv, page_tables, write_page,
+                               write_off, mask)
     hidden = _rmsnorm(x, params["final_norm"])           # (B, K, d)
     logits = jnp.einsum("bkd,vd->bkv", hidden.astype(jnp.float32),
                         params["embed"].astype(jnp.float32))
@@ -899,28 +800,14 @@ def _mlp_flops_per_token(cfg: TransformerConfig) -> float:
     return 4.0 * d * ff
 
 
-def decode_flops_per_token(cfg: TransformerConfig, context: int) -> float:
-    """Matmul-FLOPs for one decode step of one sequence at the given
-    context size — the serving bench's audited accounting (projections
-    + vocab head + the query-against-context attention).  MoE configs
-    count only the routed experts (top_k of E), not the all-experts
-    einsum the serving kernel evaluates — the accounting tracks the
-    algorithmic cost expert-parallel execution pays per token."""
-    d, L, v = cfg.d_model, cfg.n_layers, cfg.vocab_size
-    dense = L * (8.0 * d * d + _mlp_flops_per_token(cfg)) + 2.0 * d * v
-    attn = L * 4.0 * context * d
-    return dense + attn
-
-
 def train_flops_per_seq(cfg: TransformerConfig) -> float:
     """Matmul-FLOPs for one causal-LM training sequence (train = 3x
-    fwd) — the bench's audited accounting, importable so training loops
-    can feed ``hvd.metrics.set_step_flops()`` with the same figure MFU
-    reports use.  Dense per token 8d^2 (qkv+proj) + 4*d*ff (mlp) per
-    layer + 2dV vocab head; causal attention 2*S^2*d per layer per seq
-    (half the bidirectional 4*S^2*d — the mask zeroes the upper
-    triangle).  MoE configs count the routed top_k experts + gate per
-    token (``_mlp_flops_per_token``)."""
+    fwd), importable so training loops can feed
+    ``hvd.metrics.set_step_flops()``.  Dense per token 8d^2 (qkv+proj)
+    + 4*d*ff (mlp) per layer + 2dV vocab head; causal attention
+    2*S^2*d per layer per seq (half the bidirectional 4*S^2*d — the
+    mask zeroes the upper triangle).  MoE configs count the routed
+    top_k experts + gate per token (``_mlp_flops_per_token``)."""
     d, L, s, v = (cfg.d_model, cfg.n_layers, cfg.seq_len,
                   cfg.vocab_size)
     dense = s * (L * (8.0 * d * d + _mlp_flops_per_token(cfg)) + 2.0 * d * v)

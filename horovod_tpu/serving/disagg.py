@@ -22,7 +22,7 @@ adopts silently.
 
 In-process (:func:`migrate`) and over-the-wire (:func:`send` /
 :func:`receive`) paths share :func:`encode_bundle`/:func:`decode_bundle`
-— the bench's disaggregated arm and the migration drill exercise the
+— the migration drill (``tests/test_serving_scale.py``) exercises the
 same bytes either way.  docs/serving.md#disaggregated-prefill-decode.
 """
 
@@ -121,7 +121,8 @@ def decode_bundle(blob: bytes
 
 def wire_ratio(bits: int, n: int, block: int = 256) -> float:
     """fp32 bytes / quantized wire bytes for an n-element page tensor
-    (the bench discloses this next to the measured tokens/sec)."""
+    (int8 at block 256 approaches 4·256/(256+4) ≈ 3.94:
+    ``tests/test_serving_scale.py``)."""
     return (4.0 * n) / Q.page_wire_bytes(n, _spec_for(bits, block))
 
 

@@ -3,21 +3,23 @@
 One engine owns one replica's decode slots.  Its step loop is
 token-level batch recomposition: every iteration advances queued
 prefill work within the chunk budget, advances every decoding slot
-(one batched ``decode_step`` — or one speculative round when a draft
-model is attached), and retires finished sequences mid-batch — there
-is no static-batch barrier, so a long generation never holds hostage
-the slots of its finished neighbors.
+(one batched ``tfm.chunk_forward`` at K = 1 — or one speculative round
+when a draft model is attached), and retires finished sequences
+mid-batch — there is no static-batch barrier, so a long generation
+never holds hostage the slots of its finished neighbors.
 
 Geometry is fixed at construction: ``slots`` decode slots, a page pool
-of ``page_tokens``-token KV pages, ``max_len`` context per slot.  The
-decode step is jit-compiled ONCE per (slot count, page geometry):
-admission only changes *array contents* (page tables, lengths, input
-tokens), never shapes, so admitting or retiring a request can never
-trigger a recompile (``decode_traces`` counts retraces; tests pin it
-at 1).  Prompt prefill runs through ``tfm.decode_verify`` — the
-multi-token chunk kernel — compiled once per power-of-two page-row
-bucket; a chunk is padded to its bucket and the kernel's position
-masking keeps the padding out of every valid context.
+of ``page_tokens``-token KV pages, ``max_len`` context per slot.  Every
+compiled model call is ``tfm.chunk_forward`` — tokens (B, K) against the
+paged cache — built by :meth:`DecodeEngine._chunk_fn` per (caller, B,
+K).  The decode tick is (slots, 1), compiled ONCE per (slot count, page
+geometry): admission only changes *array contents* (page tables,
+lengths, input tokens), never shapes, so admitting or retiring a
+request can never trigger a recompile (``decode_traces`` counts
+retraces; tests pin it at 1).  Prompt prefill is (1, K), compiled once
+per power-of-two page-row bucket; a chunk is padded to its bucket and
+the forward's position masking keeps the padding out of every valid
+context.
 
 Production-scale serving (ISSUE 18) composes three optional planes on
 the same geometry:
@@ -31,9 +33,9 @@ the same geometry:
   processed per iteration, so a long prompt interleaves into decode
   iterations instead of stalling every co-batched request's TTFT;
 * **speculative decoding** (``speculative.py``): an attached draft
-  proposes k tokens per round and one batched ``decode_verify`` scores
-  them — greedy acceptance is exact, seeded sampling preserves the
-  target distribution.
+  proposes k tokens per round and one batched ``tfm.chunk_forward``
+  (K = k+1) scores them — greedy acceptance is exact, seeded sampling
+  preserves the target distribution.
 
 Slot bookkeeping (page tables, lengths, free lists, the prefix trie)
 lives on the host; only the page pools stay device-resident (donated
@@ -226,6 +228,9 @@ class DecodeEngine:
     :meth:`admit`/:meth:`step`; :meth:`swap_params` may be called from
     any thread (it only parks the tree under a lock)."""
 
+    _TRACE_COUNTER = {"decode": "decode_traces", "target": "prefill_traces",
+                      "verify": "verify_traces", "draft": None}
+
     def __init__(self, cfg: tfm.TransformerConfig, params,
                  slots: Optional[int] = None,
                  page_tokens: Optional[int] = None,
@@ -237,12 +242,12 @@ class DecodeEngine:
                  draft: Optional[spec.DraftSpec] = None):
         from ..core.config import Config, get_bool, get_int
         import jax
-        # MoE configs (cfg.n_experts > 0) serve through the same two
-        # entry points: tfm.decode_verify / tfm.decode_step route per
-        # token at inference and evaluate experts via all-experts
-        # einsums whose expert dim partitions over an ``ep`` mesh axis
-        # when the caller places w_in/w_out with a NamedSharding over
-        # experts — expert weights stay sharded through every step.
+        # MoE configs (cfg.n_experts > 0) serve through the same
+        # forward: tfm.chunk_forward routes per token at inference and
+        # evaluates experts via all-experts einsums whose expert dim
+        # partitions over an ``ep`` mesh axis when the caller places
+        # w_in/w_out with a NamedSharding over experts — expert weights
+        # stay sharded through every step.
         self.cfg = cfg
         # Same clamps Config.from_env applies: a garbage env knob must
         # not zero-divide the engine (these read the raw env so an
@@ -301,13 +306,6 @@ class DecodeEngine:
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._last_evicted = 0       # pages evicted by the last alloc
-
-        def _decode(p, tokens, lengths, kv, page_tables):
-            self.decode_traces += 1      # trace-time side effect:
-            return tfm.decode_step(      # retrace == recompile evidence
-                cfg, p, tokens, lengths, kv, page_tables)
-
-        self._decode = jax.jit(_decode, donate_argnums=(3,))
         self._chunk_fns: Dict[Any, Any] = {}
         self._jit = jax.jit
 
@@ -317,7 +315,6 @@ class DecodeEngine:
         # COW copies cover the draft's K/V for free.
         self._draft: Optional[spec.DraftSpec] = None
         self._draft_kv = None
-        self._draft_decode = None
         if draft is not None:
             draft = dataclasses.replace(
                 draft, k=min(32, max(1, int(draft.k))))
@@ -325,11 +322,6 @@ class DecodeEngine:
             self._draft = draft
             self._draft_kv = tfm.init_kv_pages(
                 draft.cfg, n_pages + 1, self.page_tokens)
-            dcfg = draft.cfg
-            self._draft_decode = jax.jit(
-                lambda p, t, ln, kv, tb: tfm.decode_step(
-                    dcfg, p, t, ln, kv, tb),
-                donate_argnums=(3,))
 
     # -- capacity ----------------------------------------------------------
 
@@ -403,23 +395,23 @@ class DecodeEngine:
     # -- compiled entry points ---------------------------------------------
 
     def _chunk_fn(self, which: str, b: int, kq: int):
-        """decode_verify jitted per (model, batch, chunk length).
-        ``which``: "target" counts into prefill_traces for single-slot
-        prompt chunks and verify_traces for batched verify rounds."""
+        """``tfm.chunk_forward`` jitted per (caller, batch, chunk
+        length).  ``which`` names the caller and with it the retrace
+        counter (``_TRACE_COUNTER``): the decode tick (kq = 1),
+        single-slot prompt chunks ("target"), batched verify rounds;
+        "draft" runs the draft's config and counts nowhere."""
         key = (which, b, kq)
         fn = self._chunk_fns.get(key)
         if fn is None:
-            if which == "draft":
-                cfg, counter = self._draft.cfg, None
-            elif which == "verify":
-                cfg, counter = self.cfg, "verify_traces"
-            else:
-                cfg, counter = self.cfg, "prefill_traces"
+            cfg = self._draft.cfg if which == "draft" else self.cfg
+            counter = self._TRACE_COUNTER[which]
 
             def _chunk(p, tokens, lengths, kv, tables):
                 if counter is not None:
+                    # Trace-time side effect: a retrace is the evidence
+                    # of a recompile.
                     setattr(self, counter, getattr(self, counter) + 1)
-                return tfm.decode_verify(cfg, p, tokens, lengths, kv,
+                return tfm.chunk_forward(cfg, p, tokens, lengths, kv,
                                          tables)
 
             fn = self._jit(_chunk, donate_argnums=(3,))
@@ -702,10 +694,10 @@ class DecodeEngine:
                 if i not in dec:
                     lengths[i] = 0
                     table[i, :] = 0
-        logits, self._kv = self._decode(
-            self._params, jnp.asarray(tokens),
+        logits, self._kv = self._chunk_fn("decode", self.slots, 1)(
+            self._params, jnp.asarray(tokens[:, None]),
             jnp.asarray(lengths), self._kv, jnp.asarray(table))
-        logits = np.asarray(logits)
+        logits = np.asarray(logits)[:, 0]
         wall = time.perf_counter() - t0
         self.steps += 1
         m = _metrics()
@@ -751,11 +743,11 @@ class DecodeEngine:
         proposals = np.zeros((n, k), np.int32)
         draft_logits = np.zeros((n, k, self.cfg.vocab_size), np.float32)
         for t in range(k + 1):
-            lg, self._draft_kv = self._draft_decode(
-                ds.params, jnp.asarray(tokens), jnp.asarray(d_len),
-                self._draft_kv, tbl_j)
+            lg, self._draft_kv = self._chunk_fn("draft", n, 1)(
+                ds.params, jnp.asarray(tokens[:, None]),
+                jnp.asarray(d_len), self._draft_kv, tbl_j)
             if t < k:
-                lg = np.asarray(lg)
+                lg = np.asarray(lg)[:, 0]
                 for i, st in decoding:
                     if st.request.temperature > 0:
                         p = spec.probs(lg[i], st.request.temperature)

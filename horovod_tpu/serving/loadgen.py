@@ -1,5 +1,5 @@
-"""Open-loop load driving — shared by the bench, the tests, and the
-client walkthrough.
+"""Open-loop load driving — shared by the tests, the load client and
+the client walkthrough.
 
 :func:`synthetic_workload` draws a seeded open-loop request schedule
 (Poisson arrivals, mixed prompt/output lengths); :func:`drive` runs one
@@ -8,8 +8,9 @@ iteration the HTTP serving loop uses, and returns per-request results
 plus occupancy accounting.  ``continuous=False`` is the static-batch
 arm: admission only happens when EVERY slot is free (the classic
 batch barrier), which is exactly what the continuous engine's
-mid-batch retire/admit removes — ``bench.py --bench serving`` measures
-the difference.
+mid-batch retire/admit removes
+(``tests/test_serving.py::test_continuous_beats_static_occupancy`` holds
+the occupancy difference with identical outputs).
 """
 
 from __future__ import annotations
@@ -26,8 +27,7 @@ from .engine import DecodeEngine, Request, record_shed
 
 def percentile(values: List[float], p: float) -> Optional[float]:
     """Nearest-rank percentile of an unsorted sample (None when empty)
-    — the one TTFT-summary implementation the bench and the load
-    client share."""
+    — the TTFT summary of the load client."""
     if not values:
         return None
     ordered = sorted(values)

@@ -2,9 +2,10 @@
 
 A small draft model (typically a layer-prefix of the target —
 ``tfm.draft_config`` / ``tfm.draft_params_from``) proposes ``k``
-tokens autoregressively; the flagship scores all of them in ONE
-batched ``tfm.decode_verify`` forward (K = k+1 query positions per
-slot: the pending input token plus the k proposals).  The engine then
+tokens autoregressively (k+1 calls of ``tfm.chunk_forward`` at K = 1);
+the flagship scores all of them in ONE batched ``tfm.chunk_forward``
+(K = k+1 query positions per slot: the pending input token plus the k
+proposals).  The engine then
 accepts a prefix of the proposals per slot:
 
 * **greedy** (temperature 0): accept while the proposal equals the
@@ -25,8 +26,9 @@ accepts a prefix of the proposals per slot:
 Per accepted run of j proposals the engine emits j+1 tokens (the
 bonus/correction comes free from the same verify forward), so the
 target runs one big forward per ~(j+1) tokens instead of j+1 small
-ones — the speedup is ``(1 + mean_accepted) × cost_ratio`` and the
-bench measures it end to end.  docs/serving.md#speculative-decoding.
+ones — the speedup is ``(1 + mean_accepted) × cost_ratio``; no
+benchmark cell measures it yet (PERF.md section 7).
+docs/serving.md#speculative-decoding.
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """``python -m horovod_tpu.serving.submit`` — the open-loop load client.
 
 Fires a seeded synthetic workload (Poisson arrivals, mixed
-prompt/output lengths — the same :func:`~.loadgen.synthetic_workload`
-schedule the bench uses) at a running serving replica and prints a
+prompt/output lengths — :func:`~.loadgen.synthetic_workload`) at a
+running serving replica and prints a
 latency summary::
 
     python -m horovod_tpu.serving.submit --server host:28643 \\

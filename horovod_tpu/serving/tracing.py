@@ -18,8 +18,8 @@ Sampling is **seeded and deterministic**: the sample decision is a
 pure function of the trace id and ``HVD_TPU_TRACE_SAMPLE`` — two
 replicas (or two runs under the same seed) sample the same requests,
 and an unsampled request pays one attribute check per potential span
-(the flight recorder's <1% overhead discipline, bench-asserted by
-``bench.py --bench tracing``).  A client header's sampled flag wins
+(``tests/test_tracing.py`` holds the sampling determinism and the
+tracing-on/off bit-identity).  A client header's sampled flag wins
 over the local rate, so an operator can force-trace one request
 without touching the knob.
 
@@ -147,7 +147,7 @@ def mint(request_id: str, header: Optional[str] = None,
 def span(ctx: Optional[TraceContext], stage: str, **fields) -> None:
     """Emit one span as a ``trace.<stage>`` flight event named by the
     trace id.  No-op (one None/flag check) when the context is absent
-    or unsampled — the hot-path cost the tracing bench pins."""
+    or unsampled."""
     if ctx is None or not ctx.sampled:
         return
     from ..debug import flight

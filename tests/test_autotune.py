@@ -123,7 +123,7 @@ def test_parameter_manager_pinned_toggle_never_flips():
 
 def test_parameter_manager_disables_losing_toggle():
     """Synthetic oracle for VERDICT r4 #2: hierarchical allreduce costs
-    23% (the single-host regime BENCH_EAGER.json documents at 256 MB);
+    23% (what a CPU host measured over loopback at 256 MB on one host);
     the tuner must freeze with it DISABLED even when the job starts with
     it enabled."""
     applied = []
@@ -212,7 +212,8 @@ HIER_AUTOTUNE_WORKER = textwrap.dedent("""
 
     # ONE 128MB tensor per step: the hierarchical-allreduce single-host
     # penalty only manifests at large per-RESPONSE payloads
-    # (BENCH_EAGER.json: 0.83x at 64MB, 0.77x at 256MB, parity at 1MB),
+    # (a CPU host over loopback, hierarchical against flat: 0.83x at
+    # 64MB, 0.77x at 256MB, parity at 1MB),
     # and a single tensor keeps fusion-threshold proposals from
     # splitting the payload into small responses that hide the signal.
     n_t, elems = 1, 32 * 1024 * 1024
@@ -250,8 +251,7 @@ def test_autotune_disables_hierarchical_on_single_host(tmp_path, monkeypatch):
     physical host is pure overhead, and the tuner must turn it off.
 
     Topology: -H localhost:2,127.0.0.1:2 advertises the single machine
-    as 2 "nodes" x 2 ranks — BENCH_EAGER.json's hierarchical_shm regime
-    (HVD_TPU_LOCAL_SIZE=2), where the cross-"node" leader phases buy
+    as 2 "nodes" x 2 ranks (HVD_TPU_LOCAL_SIZE=2), where the cross-"node" leader phases buy
     nothing and cost ~40% at 128MB (hier/flat ~1.43x measured); with
     local_size=4 (one node) hierarchical degrades to near-parity and
     there is nothing to tune away.  The job starts WITH

@@ -516,8 +516,10 @@ def test_moe_prefill_and_decode_match_per_token_oracle():
     toks = jax.random.randint(jax.random.PRNGKey(4), (16,), 0,
                               _SRV_CFG.vocab_size, jnp.int32)
     kv = tfm.init_kv_pages(_SRV_CFG, 5, 4)
-    logits_p, kv = tfm.prefill(_SRV_CFG, params, toks, jnp.int32(12),
-                               kv, jnp.arange(1, 5, dtype=jnp.int32))
+    table = jnp.arange(1, 5, dtype=jnp.int32)[None]
+    logits_p, kv = tfm.chunk_forward(_SRV_CFG, params, toks[None],
+                                     jnp.zeros((1,), jnp.int32), kv, table)
+    logits_p = logits_p[0, 11]
     flat = {"embed": params["embed"], "pos": params["pos"],
             "final_norm": params["final_norm"],
             "layers": tfm._flat_layers(params)}
@@ -529,9 +531,9 @@ def test_moe_prefill_and_decode_match_per_token_oracle():
                                np.asarray(oracle[0, -1]), atol=2e-4)
     assert int(jnp.argmax(logits_p)) == int(jnp.argmax(oracle[0, -1]))
 
-    ld, kv = tfm.decode_step(_SRV_CFG, params, toks[12][None],
-                             jnp.array([12], jnp.int32), kv,
-                             jnp.arange(1, 5, dtype=jnp.int32)[None])
+    ld, kv = tfm.chunk_forward(_SRV_CFG, params, toks[12][None, None],
+                               jnp.array([12], jnp.int32), kv, table)
+    ld = ld[:, 0]
     oracle13 = moet.serial_forward_logits(ocfg, flat, toks[None, :13])
     np.testing.assert_allclose(np.asarray(ld[0]),
                                np.asarray(oracle13[0, -1]), atol=2e-4)

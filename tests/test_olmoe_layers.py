@@ -273,6 +273,16 @@ def test_step_names_the_parts_of_the_moe_block():
         assert f"hvd_mlp/hvd_{name}" in text, name
 
 
-def test_serving_entry_points_refuse_rotary_positions():
+@pytest.mark.parametrize("field", [
+    {"rope_theta": 10000.0}, {"qk_norm": True},
+    {"gated_experts": True}, {"tied_head": False}],
+    ids=lambda f: next(iter(f)))
+def test_serving_forward_refuses_what_it_does_not_compute(field):
+    """Each of the four fields ``_check_servable`` names refuses alone,
+    before the forward touches its arguments."""
+    servable = tfm.TransformerConfig()
+    tfm._check_servable(servable)
     with pytest.raises(NotImplementedError, match="learned positions"):
-        tfm.prefill(CFG, {}, jnp.zeros((8,), jnp.int32), 1, {}, None)
+        tfm.chunk_forward(servable._replace(**field), {},
+                          jnp.zeros((1, 8), jnp.int32),
+                          jnp.zeros((1,), jnp.int32), {}, None)

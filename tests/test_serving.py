@@ -135,9 +135,10 @@ def test_policy_deterministic():
 def test_prefill_matches_training_logits(params):
     prompt = np.array([3, 9, 1, 17, 30, 2, 5, 11], np.int32)  # == 1 page
     kv = tfm.init_kv_pages(CFG, n_pages=3, page_size=PAGE)
-    logits, kv = tfm.prefill(CFG, params, jnp.asarray(prompt),
-                             jnp.int32(len(prompt)), kv,
-                             jnp.asarray([1], jnp.int32))
+    logits, kv = tfm.chunk_forward(CFG, params, jnp.asarray(prompt)[None],
+                                   jnp.zeros((1,), jnp.int32), kv,
+                                   jnp.asarray([[1]], jnp.int32))
+    logits = logits[0, len(prompt) - 1]
     oracle = tfm.serial_forward_logits(CFG, params,
                                        jnp.asarray(prompt)[None])
     np.testing.assert_allclose(np.asarray(logits),
@@ -153,9 +154,10 @@ def test_prefill_padded_prompt_matches(params):
     kv = tfm.init_kv_pages(CFG, n_pages=3, page_size=PAGE)
     tokens = np.full((PAGE,), 63, np.int32)
     tokens[:5] = prompt
-    logits, _ = tfm.prefill(CFG, params, jnp.asarray(tokens),
-                            jnp.int32(5), kv,
-                            jnp.asarray([1], jnp.int32))
+    logits, _ = tfm.chunk_forward(CFG, params, jnp.asarray(tokens)[None],
+                                  jnp.zeros((1,), jnp.int32), kv,
+                                  jnp.asarray([[1]], jnp.int32))
+    logits = logits[0, 4]
     oracle = tfm.serial_forward_logits(CFG, params,
                                        jnp.asarray(prompt)[None])
     np.testing.assert_allclose(np.asarray(logits),
@@ -163,7 +165,7 @@ def test_prefill_padded_prompt_matches(params):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_decode_step_matches_training_logits(params):
+def test_decode_tick_matches_training_logits(params):
     # Greedy-generate 6 tokens through the paged decode path; every
     # step's next-token distribution must match the training-path
     # forward over the growing sequence (fp32-accumulation caveats →
@@ -182,6 +184,53 @@ def test_decode_step_matches_training_logits(params):
             CFG, params, jnp.asarray(np.array(seq, np.int32))[None])
         assert tok == int(np.argmax(oracle[0, -1]))
         seq.append(tok)
+
+
+@pytest.mark.parametrize("kq,length", [(1, 16), (4, 16), (4, 14)],
+                         ids=["k1-at-extent", "k4-at-extent",
+                              "k4-crossing"])
+def test_positions_past_the_table_extent_write_scratch_only(params, kq,
+                                                            length):
+    # chunk_forward's overflow contract: a position at or past the
+    # table's extent (2 pages = 16 positions here) writes scratch page 0
+    # and nothing else — not the slot's last page (where the clamped
+    # index points), not a neighbour's.
+    rng = np.random.default_rng(0)
+    kv = tfm.init_kv_pages(CFG, n_pages=5, page_size=PAGE)
+    full = rng.integers(0, CFG.vocab_size, (2, 16)).astype(np.int32)
+    _, kv = tfm.chunk_forward(
+        CFG, params, jnp.asarray(full), jnp.zeros((2,), jnp.int32), kv,
+        jnp.asarray([[1, 2], [3, 4]], jnp.int32))
+    before = {n: np.asarray(a) for n, a in kv.items()}
+    table = jnp.asarray([[1, 2]], jnp.int32)
+    chunk = rng.integers(0, CFG.vocab_size, (1, kq)).astype(np.int32)
+    n_in = max(0, 16 - length)
+    want = dict(before)
+    if n_in:
+        # What the in-range head of the chunk writes on its own.
+        _, head = tfm.chunk_forward(
+            CFG, params, jnp.asarray(chunk[:, :n_in]),
+            jnp.asarray([length], jnp.int32), dict(kv), table)
+        want = {n: np.asarray(a) for n, a in head.items()}
+        assert not np.array_equal(want["k"][:, 2], before["k"][:, 2])
+    logits, after = tfm.chunk_forward(
+        CFG, params, jnp.asarray(chunk), jnp.asarray([length], jnp.int32),
+        dict(kv), table)
+    assert np.all(np.isfinite(np.asarray(logits)))
+    for n in ("k", "v"):
+        got = np.asarray(after[n])
+        # Pages 1, 3, 4 and every row below ``length``: bit for bit.
+        np.testing.assert_array_equal(got[:, [1, 3, 4]],
+                                      before[n][:, [1, 3, 4]])
+        np.testing.assert_array_equal(got[:, 2, :length - PAGE],
+                                      before[n][:, 2, :length - PAGE])
+        # Rows from ``length`` up: the in-range head's own K/V, which
+        # an overflow write through the clamped index would replace.
+        np.testing.assert_allclose(got[:, 2], want[n][:, 2],
+                                   rtol=1e-5, atol=1e-6)
+        # Scratch: only the clamped offset's row is written.
+        assert np.any(got[:, 0, PAGE - 1] != 0.0)
+        np.testing.assert_array_equal(got[:, 0, :PAGE - 1], 0.0)
 
 
 def test_kv_page_geometry():
