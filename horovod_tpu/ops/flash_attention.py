@@ -36,6 +36,29 @@ On a v5e that orientation, not the dots, is what the time was: the forward
 takes 7.6 ps a live score element against 10.9 with queries on sublanes
 (bare timing at (5, 8192, 16, 64) bf16 causal, PERF.md section 6, PR 27).
 
+The tile is up to 1024 x 1024 (``_supported``: the widest of 1024, 512, 256,
+128 that divides each length, so 8192 and 4096 take 1024 on both sides, 1536
+and BERT's 512 take 512).  The kernels pay per grid step and per re-read of
+the operand they stream past the accumulator they keep resident (queries for
+the forward and dQ, keys for dK/dV), not per FLOP: a wider resident side
+halves the steps and the re-reads, a wider streamed side halves the steps
+again, and on a causal grid fewer steps are dead (at 512 x 512 a (batch,
+head) of 8192 walks 256 steps for 136 live tiles, at 1024 x 1024 64 for 36;
+a dead step is not free, 0.5-0.6 us with 512 keys a block: its K / V blocks
+are fetched before ``pl.when`` skips it).  Bare on a v5e, (5, 8192, 16, 64) bf16
+causal, ms a call (PERF.md section 6, PR 29):
+
+    tile (bq x bk)   512x512  1024x512  512x1024  1024x1024  2048x1024
+    forward            20.33     16.45     18.13      15.33      14.71
+    dQ                 22.55     18.33     18.70      18.11      19.01
+    dK/dV              27.00     25.60     24.18      22.97      24.13 *
+
+(* needs ``vmem_limit_bytes`` of 32 MiB.)  Narrower tiles lose badly (256 x
+256: twice the time), 2048 wins for the forward at head 64 alone (at head 128
+3.55 against 3.50), and 1024 x 1024 is the widest all three compile on
+inside Mosaic's default 16 MiB of scoped VMEM — for heads up to 256 bytes a
+row (bf16 128, fp32 64); wider heads keep 512 (``_WIDE_BLOCK_ROW_BYTES``).
+
 Three kernels:
 
 * ``_fwd_kernel``      — out + logsumexp, online softmax over K/V tiles.
@@ -75,12 +98,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
-_BLOCK_CANDIDATES = (512, 256, 128)
+_BLOCK_CANDIDATES = (1024, 512, 256, 128)
+# A dimension no candidate divides is taken whole up to this length, and a
+# head too wide for a 1024-row tile (below) tiles at most this wide.
+_NARROW_BLOCK = 512
+# Bytes of one row of an operand block (head_dim x itemsize) up to which
+# all three kernels compile on 1024 x 1024 tiles inside Mosaic's default
+# scoped VMEM, 16 MiB on a v5e: bf16 heads up to 128, fp32 heads up to 64,
+# fp32 also at ``highest`` precision.  At twice that dK/dV no longer fits
+# (bf16 head 256: 21.1 MiB; fp32 head 128: 18.5 MiB, 22.0 at ``highest``;
+# compiled for a described v5e, PERF.md section 6, PR 29).
+_WIDE_BLOCK_ROW_BYTES = 256
 
 
-def _pick_block(size: int, env: str = "") -> Optional[int]:
-    """Largest 128-aligned divisor block, else the whole dim (Mosaic's
-    equal-to-array-dim exemption) when small enough to fit VMEM tiles.
+def _pick_block(size: int, widest: int, env: str = "") -> Optional[int]:
+    """Widest 128-aligned candidate up to ``widest`` that divides ``size``,
+    else the whole dim (Mosaic's equal-to-array-dim exemption) when small
+    enough to fit VMEM tiles, else None: the kernels cannot tile ``size``.
 
     ``env`` names an override variable (HVD_TPU_FLASH_BLOCK_Q/K) for
     silicon block-size tuning: the override must divide the dimension,
@@ -90,17 +124,18 @@ def _pick_block(size: int, env: str = "") -> Optional[int]:
             forced = int(os.environ.get(env, "0"))
         except ValueError:
             forced = 0  # non-numeric override: ignore, auto-select
-        # Same legality envelope as auto-selection: a 128-aligned
-        # divisor, or the whole (small) dim — anything else would fail
-        # Mosaic's lane alignment / VMEM fit on silicon.
+        # A 128-aligned divisor no wider than the widest candidate (a sweep
+        # may try it at any head; auto-selection is stricter), or the whole
+        # (small) dim — anything else would fail Mosaic's lane alignment /
+        # VMEM fit on silicon.
         if forced > 0 and size % forced == 0 and (
-                (forced % 128 == 0 and forced <= 512)
-                or (forced == size and size <= 512)):
+                (forced % 128 == 0 and forced <= _BLOCK_CANDIDATES[0])
+                or (forced == size and size <= _NARROW_BLOCK)):
             return forced
     for c in _BLOCK_CANDIDATES:
-        if size % c == 0 and c <= size:
+        if c <= widest and size % c == 0:
             return c
-    return size if size <= 512 else None
+    return size if size <= _NARROW_BLOCK else None
 
 
 def _compiler_params(n_parallel: int):
@@ -450,12 +485,18 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _supported(q, k) -> Optional[Tuple[int, int]]:
+    """The (block_q, block_k) all three kernels tile these (B, S, H, D)
+    shapes with, or None where they cannot.  Read from the two sequence
+    lengths, and from the head's width and type for what fits VMEM."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if d % 8 != 0 or d > 512:
         return None
-    bq = _pick_block(sq, env="HVD_TPU_FLASH_BLOCK_Q")
-    bk = _pick_block(sk, env="HVD_TPU_FLASH_BLOCK_K")
+    row_bytes = d * jnp.dtype(q.dtype).itemsize
+    widest = (_BLOCK_CANDIDATES[0] if row_bytes <= _WIDE_BLOCK_ROW_BYTES
+              else _NARROW_BLOCK)
+    bq = _pick_block(sq, widest, env="HVD_TPU_FLASH_BLOCK_Q")
+    bk = _pick_block(sk, widest, env="HVD_TPU_FLASH_BLOCK_K")
     if bq is None or bk is None:
         return None
     return bq, bk

@@ -138,11 +138,18 @@ def test_block_size_env_override(monkeypatch):
     for bad in ("96", "64", "1024"):
         monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", bad)
         assert fa._supported(q, k)[0] == 256, bad
-    # A 128-aligned divisor above the 512 VMEM cap is rejected too:
-    # s=1024 forced to 1024 falls back to the auto-selected 512.
-    q2, k2, _ = _qkv(s=1024)
+    # A 128-aligned divisor up to the widest candidate is legal, also at a
+    # head auto-selection keeps to 512 for (fp32, 128: a sweep may try it).
+    q2, k2, _ = _qkv(s=1024, d=128)
+    monkeypatch.delenv("HVD_TPU_FLASH_BLOCK_Q")
+    assert fa._supported(q2, k2) == (512, 128)
     monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "1024")
-    assert fa._supported(q2, k2)[0] == 512
+    assert fa._supported(q2, k2) == (1024, 128)
+    # One above the widest candidate is rejected: s=2048 forced to 2048
+    # falls back to the auto-selected 1024.
+    q3, k3, _ = _qkv(s=2048)
+    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "2048")
+    assert fa._supported(q3, k3)[0] == 1024
 
 
 def test_kernel_is_never_chosen_or_interpreted_behind_the_callers_back(
@@ -243,19 +250,23 @@ def test_bf16_inputs_match_fp32_attention_to_bf16_rounding(causal, d, what):
         _assert_bf16_grads(jax.vjp(flash, q, k, v)[1](g), q, k, v, g, causal)
 
 
-def test_bf16_long_sequence_sums_l_across_four_key_tiles():
-    """2048 keys in 4 tiles of 512 (the cells' tile): ``l`` and the output
-    accumulator are carried across tiles, rescaled by ``alpha`` each time."""
+@pytest.mark.parametrize("causal", [True, False])
+def test_bf16_long_sequence_sums_l_across_four_key_tiles(causal):
+    """2048 keys in 4 tiles of 512 under 1024 queries: ``l``, the output
+    accumulator and dQ are carried across four tiles, the first two rescaled
+    by ``alpha`` each time.  (The chooser's own tile at 2048, the cells', is
+    1024 x 1024 — two key tiles; ``block_k`` keeps the four.)"""
     q, k, v = _qkv(b=1, s=2048, h=1, d=64, dtype=jnp.bfloat16, seed=3)
-    assert fa._supported(q, k) == (512, 512)
+    assert fa._supported(q, k) == (1024, 1024)
 
     def flash(q, k, v):
-        return fa.flash_attention(q, k, v, causal=True, interpret=True)
+        return fa.flash_attention(q, k, v, causal=causal, block_k=512,
+                                  interpret=True)
 
     out, vjp = jax.vjp(flash, q, k, v)
-    _assert_bf16_forward(out, q, k, v, True)
+    _assert_bf16_forward(out, q, k, v, causal)
     g = jax.random.normal(jax.random.PRNGKey(8), q.shape, jnp.bfloat16)
-    _assert_bf16_grads(vjp(g), q, k, v, g, True)
+    _assert_bf16_grads(vjp(g), q, k, v, g, causal)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -291,20 +302,19 @@ def _kernel_bodies(dtype, causal=True):
             interpret=True), q, k, v)
         return vjp(out)
 
-    bodies = {}
+    return {eqn.params["name"]: (eqn.params["jaxpr"],
+                                 eqn.params["grid_mapping"])
+            for eqn in _pallas_eqns(jax.make_jaxpr(f)(x, x, x).jaxpr)}
 
-    def visit(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                name = eqn.params["name"]
-                bodies[name] = (eqn.params["jaxpr"],
-                                eqn.params["grid_mapping"])
-                continue
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                visit(sub)
 
-    visit(jax.make_jaxpr(f)(x, x, x).jaxpr)
-    return bodies
+def _pallas_eqns(jaxpr):
+    """Every pallas_call equation under ``jaxpr``, not looking inside one."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_eqns(sub)
 
 
 def _eqns(jaxpr):
@@ -382,3 +392,214 @@ def test_the_nine_dots_take_the_callers_type_and_softmax_stays_fp32(
             assert not [e for e in converts
                         if e.invars[0].aval.dtype == jnp.bfloat16], name
     assert n_in_callers_type == 9
+
+
+# ---------------------------------------------------------------------------
+# Tiles up to 1024 x 1024 (ROADMAP Sk): chosen from the two lengths, and from
+# the head's width and type for what fits VMEM.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s,block", [(256, 256), (384, 128), (512, 512),
+                                     (1536, 512), (2048, 1024),
+                                     (4096, 1024), (8192, 1024)])
+def test_tile_is_chosen_from_the_lengths(s, block, d):
+    """The cells' shapes among them: the flagship (8192, head 64) and OLMoE
+    (4096, head 128) take 1024 x 1024, BERT (512) the 512 x 512 it had."""
+    x = jax.ShapeDtypeStruct((1, s, 2, d), jnp.bfloat16)
+    assert fa._supported(x, x) == (block, block)
+
+
+@pytest.mark.parametrize("dtype,d,block", [
+    (jnp.bfloat16, 128, 1024), (jnp.bfloat16, 256, 512),
+    (jnp.bfloat16, 512, 512), (jnp.float32, 64, 1024),
+    (jnp.float32, 128, 512), (jnp.float32, 256, 512)])
+def test_a_wide_head_keeps_the_512_tile(dtype, d, block):
+    """1024 x 1024 only where a row of an operand block is at most 256
+    bytes: beyond, dK/dV's blocks and score temporaries pass Mosaic's
+    default scoped VMEM (compiled for a described v5e, PERF.md PR 29)."""
+    x = jax.ShapeDtypeStruct((1, 2048, 2, d), dtype)
+    assert fa._supported(x, x) == (block, block)
+    y = jax.ShapeDtypeStruct((1, 1536, 2, d), dtype)   # each side its own
+    assert fa._supported(x, y) == (block, 512)
+
+
+def _pallas_calls(fn, *args):
+    """name -> (grid, block shapes of inputs + outputs, scratch shapes) of
+    every pallas_call ``fn`` traces (nothing runs)."""
+    calls = {}
+    for eqn in _pallas_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        gm = eqn.params["grid_mapping"]
+        scratch = eqn.params["jaxpr"].invars[-gm.num_scratch_operands:]
+        calls[eqn.params["name"]] = (
+            tuple(gm.grid),
+            [tuple(getattr(b, "block_size", b) for b in bm.block_shape)
+             for bm in gm.block_mappings],
+            [tuple(v.aval.shape) for v in scratch])
+    return calls
+
+
+def _fwd_and_bwd(**kwargs):
+    def f(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, **kwargs), q, k, v)
+        return out, vjp(out)
+    return f
+
+
+def _expected_calls(b, h, sq, sk, d, bq, bk):
+    """The three calls' grids, blocks and scratch on one (bq, bk) pair."""
+    grid = (b, h, sq // bq, sk // bk)
+    off, row = (1, 2), (1, 1, 8, bq)
+    qt, kt = (1, 1, bq, d), (1, 1, bk, d)
+    ins = [off, qt, kt, kt]
+    return {
+        "hvd_flash_fwd": (grid, ins + [qt, row],
+                          [(8, bq), (8, bq), (d, bq)]),
+        "hvd_flash_bwd_dq": (grid, ins + [qt, row, row, qt], [(d, bq)]),
+        "hvd_flash_bwd_dkv": ((b, h, sk // bk, sq // bq),
+                              ins + [qt, row, row, kt, kt],
+                              [(bk, d), (bk, d)])}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_at_512_the_program_is_the_one_it_was(causal):
+    """BERT's length admits no 1024 tile: forward and backward trace to the
+    text an explicit (512, 512) gives, with the grids and blocks the three
+    calls had before 1024 was a candidate."""
+    x = jax.ShapeDtypeStruct((2, 512, 12, 64), jnp.bfloat16)
+    auto = _fwd_and_bwd(causal=causal)
+    explicit = _fwd_and_bwd(causal=causal, block_q=512, block_k=512)
+    assert (str(jax.make_jaxpr(auto)(x, x, x))
+            == str(jax.make_jaxpr(explicit)(x, x, x)))
+    assert _pallas_calls(auto, x, x, x) == _expected_calls(
+        2, 12, 512, 512, 64, 512, 512)
+
+
+@pytest.mark.parametrize("s,h,d", [(8192, 16, 64), (4096, 16, 128)])
+def test_at_the_long_cells_shapes_every_call_is_on_1024_tiles(s, h, d):
+    x = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16)
+    assert _pallas_calls(_fwd_and_bwd(causal=True), x, x, x) == (
+        _expected_calls(1, h, s, s, d, 1024, 1024))
+
+
+def _xla_out_lse_grads(q, k, v, g, causal, q_offset=0, kv_offset=0):
+    """(out, lse, (dq, dk, dv)) of the XLA path in fp32, offsets and all."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    scale = 1.0 / q.shape[-1] ** 0.5
+    (out, lse), vjp = jax.vjp(
+        lambda q, k, v: fa._xla_attention_with_lse(
+            q, k, v, causal, scale, q_offset, kv_offset), q, k, v)
+    return out, lse, vjp((g.astype(jnp.float32), jnp.zeros_like(lse)))
+
+
+# The chooser's own pair at these lengths, and the two unequal ones an
+# override (or unequal lengths) puts under the same three bodies.
+TILES = [(1024, 1024), (1024, 512), (512, 1024)]
+
+
+def _force_tile(monkeypatch, tile):
+    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(tile[0]))
+    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(tile[1]))
+
+
+def _assert_fp32_matches_xla(q, k, v, causal, q_offset=0, kv_offset=0):
+    """Forward, ``lse``, dQ, dK, dV of the kernels against the XLA path, at
+    the fp32 tests' tolerances."""
+    out, lse = fa.flash_attention_with_lse(
+        q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
+        interpret=True)
+    g = jax.random.normal(jax.random.PRNGKey(11), q.shape, q.dtype)
+    grads = jax.vjp(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
+        interpret=True), q, k, v)[1](g)
+    ref_out, ref_lse, ref_grads = _xla_out_lse_grads(
+        q, k, v, g, causal, q_offset, kv_offset)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               atol=2e-2, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               atol=2e-2, rtol=1e-3)
+    for got, ref in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=5e-2, rtol=1e-2)
+
+
+@pytest.mark.parametrize("tile", TILES)
+@pytest.mark.parametrize("causal", [True, False])
+def test_fp32_on_wide_tiles_matches_xla(causal, tile, monkeypatch):
+    q, k, v = _qkv(b=1, s=2048, h=1, d=64, seed=4)
+    assert fa._supported(q, k) == TILES[0]
+    _force_tile(monkeypatch, tile)
+    assert fa._supported(q, k) == tile
+    _assert_fp32_matches_xla(q, k, v, causal)
+
+
+@pytest.mark.parametrize("tile", [TILES[0], TILES[2]])   # (1024, 512): above
+@pytest.mark.parametrize("causal", [True, False])
+def test_bf16_on_wide_tiles_keeps_its_bounds(causal, tile):
+    q, k, v = _qkv(b=1, s=2048, h=1, d=64, dtype=jnp.bfloat16, seed=5)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, block_q=tile[0],
+                                  block_k=tile[1], interpret=True)
+
+    out, vjp = jax.vjp(flash, q, k, v)
+    _assert_bf16_forward(out, q, k, v, causal)
+    g = jax.random.normal(jax.random.PRNGKey(8), q.shape, jnp.bfloat16)
+    _assert_bf16_grads(vjp(g), q, k, v, g, causal)
+
+
+@pytest.mark.parametrize("tile", TILES)
+@pytest.mark.parametrize("q_offset,kv_offset", [
+    (2048, 0),     # q a later chunk than k: every tile live, nothing masked
+    (0, 2048),     # q an earlier chunk: every tile dead, out 0, lse -inf
+    (512, 0),      # chunks overlap: the diagonal cuts tiles off their
+    (0, 512),      # corners, some wholly live, some wholly dead
+])
+def test_ring_shard_offsets_on_wide_tiles(q_offset, kv_offset, tile,
+                                          monkeypatch):
+    """The ``live`` test and the mask at global positions."""
+    q, k, v = _qkv(b=1, s=2048, h=1, d=64, seed=6)
+    _force_tile(monkeypatch, tile)
+    assert fa._supported(q, k) == tile
+    _assert_fp32_matches_xla(q, k, v, True, q_offset, kv_offset)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_1024_queries_on_1536_keys(causal):
+    """Sq != Sk: each side's tile follows its own length, here to an
+    unequal pair with no override (the queries are the last 1024 positions
+    of the keys' 1536 when causal)."""
+    q, _, _ = _qkv(b=1, s=1024, h=1, d=64, seed=9)
+    _, k, v = _qkv(b=1, s=1536, h=1, d=64, seed=10)
+    assert fa._supported(q, k) == (1024, 512)
+    _assert_fp32_matches_xla(q, k, v, causal, q_offset=512 if causal else 0)
+
+
+@pytest.mark.parametrize("dtype,tile", [(jnp.float32, t) for t in TILES]
+                         + [(jnp.bfloat16, TILES[0])])
+def test_ring_flash_on_wide_tiles(dtype, tile, monkeypatch):
+    """Two shards of 2048 through ``_ring_flash``: rank 0's second step is
+    the fully masked earlier-chunk case, rank 1's the later-chunk one; the
+    backward walk hands ``_bwd_call`` the chooser's pair at every step."""
+    mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
+    q, k, v = _qkv(b=1, s=4096, h=1, d=64, dtype=dtype, seed=12)
+    g = jax.random.normal(jax.random.PRNGKey(13), q.shape, dtype)
+    _force_tile(monkeypatch, tile)
+    assert fa._supported(q[:, :2048], k[:, :2048]) == tile
+    f = shard_map(
+        lambda q, k, v: ra.ring_attention(q, k, v, "sp", causal=True,
+                                          use_flash=True, interpret=True),
+        mesh=mesh, in_specs=(P(None, "sp"),) * 3,
+        out_specs=P(None, "sp"), check_vma=False)
+    out, vjp = jax.vjp(f, q, k, v)
+    if dtype == jnp.bfloat16:
+        _assert_bf16_forward(out, q, k, v, True)
+        _assert_bf16_grads(vjp(g), q, k, v, g, True)
+        return
+    ref_out, _, ref_grads = _xla_out_lse_grads(q, k, v, g, True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               atol=2e-2, rtol=1e-3)
+    for got, ref in zip(vjp(g), ref_grads):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=5e-2, rtol=1e-2)
