@@ -51,6 +51,12 @@ TIER_FAST=(
   # dense baseline, 1F1B-vs-GPipe bit parity, the (2,2,2) -> (2,2,1)
   # 3-axis reshard drill, pipeline_bubble attribution, and MoE serving.
   test_moe_pipeline.py
+  # Nemotron 3's hybrid on the training path (ISSUE 31): the chunked
+  # state-space scan against the recurrence, the conv and gated norm, the
+  # shares of heads and experts summing to the whole layer, the sigmoid
+  # router and its held share against dense formulas, the balancer, every
+  # dp layout against one device, the three refusals.
+  test_nemotron_layers.py
   test_net_resilience.py
   # Fleet-scale observability plane (ISSUE 13): digest merge algebra
   # goldens, flat-vs-tree straggler verdict parity, host observer

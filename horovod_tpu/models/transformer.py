@@ -24,7 +24,13 @@ same training path into a published one: ``rope_theta``, ``qk_norm``,
 ``norm_eps``, ``gated_experts`` + ``dropless`` (parallel/moe.py, dropless
 path, experts replicated over the mesh), ``tied_head=False`` and the
 router's two auxiliary losses give OLMoE-1B-7B (arXiv:2409.02060) for
-training.  The serving entry points below cover learned positions only.
+training.  ``layer_pattern`` turns the one block into a period of blocks of
+several kinds, one mixer each — ``M`` a Mamba-2 state-space mixer
+(ops/ssd.py), ``E`` an expert MLP, ``*`` grouped-query attention — with
+``n_experts_held`` (a share of the routed experts), a sigmoid router,
+experts in a latent space and a shared expert: Nemotron 3's hybrid
+(``nemotron_h``) as one rank of its deployment, on ``dp`` alone.  The
+serving entry points below cover learned positions only.
 
 Compute dtype defaults to bfloat16 (MXU-native); normalization, softmax and
 loss accumulate in fp32.
@@ -43,6 +49,7 @@ from jax import lax
 from ..compat import axis_size
 from jax.sharding import PartitionSpec as P
 
+from ..ops import ssd
 from ..parallel import moe as moe_lib
 from ..parallel import pipeline as pp_lib
 from ..parallel import ring_attention as ra
@@ -73,10 +80,38 @@ class TransformerConfig(NamedTuple):
     tied_head: bool = True        # False → ``lm_head``, apart from ``embed``
     aux_loss_coef: float = 0.0    # x router load-balancing loss (dropless)
     z_loss_coef: float = 0.0      # x router z-loss (dropless)
+    # Blocks of several kinds.  One letter a block of one period, each block
+    # ``x + mixer(RMSNorm(x))``: "M" state-space mixer, "E" expert MLP
+    # (dropless), "*" attention.  ``n_layers`` is a multiple of its length.
+    # None → the block above (attention then MLP) ``n_layers`` times.
+    layer_pattern: Optional[str] = None
+    learned_positions: bool = True  # False (and no rope_theta) → none at all
+    n_kv_heads: Optional[int] = None    # "*" blocks; None → n_heads
+    attn_head_dim: Optional[int] = None  # None → d_model // n_heads
+    # "M" blocks (Mamba-2): heads of ``ssm_head_dim``, ``ssm_groups`` groups
+    # of B / C of ``ssm_state``, a causal conv of ``ssm_conv`` taps, the scan
+    # in chunks of ``ssm_chunk``.  dt_bias is drawn so that softplus(dt_bias)
+    # is log-uniform in [min, max], floored.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_range: Tuple[float, float, float] = (1e-3, 1e-1, 1e-4)
+    # The dropless router and what it routes to (parallel/moe.py).
+    router_scoring: str = "softmax"   # | "sigmoid": choice by score + bias
+    router_renormalise: bool = False  # chosen weights over their sum
+    router_scale: float = 1.0
+    n_experts_held: Optional[int] = None  # experts 0..held-1 live here
+    expert_buffer_factor: float = 4.0  # held experts' rows: x the mean
+    moe_latent: int = 0           # > 0: experts work in a latent space
+    shared_expert_ff: int = 0     # > 0: an expert every token takes
+    expert_activation: Optional[str] = None  # None → silu gated, else gelu
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
 
 
 class ParallelConfig(NamedTuple):
@@ -95,6 +130,66 @@ def _routes_dropless(cfg: TransformerConfig) -> bool:
     return cfg.n_experts > 0 and cfg.dropless
 
 
+def _experts_held(cfg: TransformerConfig) -> int:
+    return cfg.n_experts if cfg.n_experts_held is None else cfg.n_experts_held
+
+
+def _holds_a_share(cfg: TransformerConfig) -> bool:
+    return _routes_dropless(cfg) and _experts_held(cfg) < cfg.n_experts
+
+
+def _has_pos_table(cfg: TransformerConfig) -> bool:
+    return cfg.rope_theta is None and cfg.learned_positions
+
+
+# A pattern's letters, the key of each kind's parameters under ``layers``
+# and the step scope its blocks run under.
+BLOCK_KINDS = {"M": ("ssm", "ssm"), "E": ("moe", "mlp"), "*": ("attn", "attn")}
+_ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
+
+def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
+    """What a patterned or share-holding model runs on: ``dp`` alone."""
+    if cfg.layer_pattern is not None:
+        bad = set(cfg.layer_pattern) - set(BLOCK_KINDS)
+        if bad or not cfg.layer_pattern:
+            raise ValueError(
+                f"layer_pattern {cfg.layer_pattern!r}: letters are "
+                f"{sorted(BLOCK_KINDS)}")
+        if cfg.n_layers % len(cfg.layer_pattern):
+            raise ValueError(
+                f"n_layers {cfg.n_layers} is not a multiple of the pattern's "
+                f"{len(cfg.layer_pattern)} blocks")
+        if ("E" in cfg.layer_pattern) != _routes_dropless(cfg):
+            raise ValueError('an "E" block is a dropless expert MLP: '
+                             "n_experts and dropless go with it")
+        if "M" in cfg.layer_pattern and (
+                cfg.ssm_heads < 1 or cfg.ssm_heads % cfg.ssm_groups):
+            raise ValueError(f"ssm_heads {cfg.ssm_heads} do not divide into "
+                             f"ssm_groups {cfg.ssm_groups}")
+        if cfg.rope_theta is not None or cfg.qk_norm:
+            raise NotImplementedError(
+                "a patterned model's attention blocks take no rotary "
+                "position and no QK-norm yet (ROADMAP M4)")
+        if cfg.n_heads % (cfg.n_kv_heads or cfg.n_heads):
+            raise ValueError(f"n_heads {cfg.n_heads} is not a multiple of "
+                             f"n_kv_heads {cfg.n_kv_heads}")
+        if par.mp > 1 or par.pp > 1 or par.pp_schedule != "gpipe":
+            raise NotImplementedError(
+                "a model with a layer_pattern runs on dp alone: its mixers "
+                "are neither sharded over mp nor staged over pp (ROADMAP M7)")
+    elif cfg.n_kv_heads not in (None, cfg.n_heads) or cfg.moe_latent \
+            or cfg.shared_expert_ff:
+        raise ValueError("n_kv_heads, moe_latent and shared_expert_ff are a "
+                         "patterned model's: set layer_pattern")
+    if _holds_a_share(cfg) and (par.mp > 1 or par.pp > 1):
+        raise NotImplementedError(
+            f"a layer that holds {_experts_held(cfg)} of {cfg.n_experts} "
+            "experts runs on dp alone: the exchange that brings the other "
+            "ranks' tokens is not written (ROADMAP M2)")
+
+
 def _split(key, n):
     return jax.random.split(key, n)
 
@@ -106,6 +201,7 @@ def init_params(key, cfg: TransformerConfig,
     d, ff, v, s = cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.seq_len
     h, hd = cfg.n_heads, cfg.head_dim
     n_pp = par.pp
+    _check_layout(cfg, par)
     if cfg.n_layers % n_pp != 0:
         raise ValueError(f"n_layers {cfg.n_layers} not divisible by pp {n_pp}")
     lps = cfg.n_layers // n_pp  # layers per stage
@@ -125,29 +221,32 @@ def init_params(key, cfg: TransformerConfig,
     params: Dict[str, Any] = {
         "embed": rand(k_embed, v, d),
         "final_norm": norm_init(d),
-        "layers": {
-            "ln1": norm_init(n_pp, lps, d),
-            "ln2": norm_init(n_pp, lps, d),
-            "wqkv": rand(k_qkv, n_pp, lps, d, 3 * h * hd),
-            "wo": rand(k_wo, n_pp, lps, h * hd, d,
-                       scale=std / math.sqrt(2 * cfg.n_layers)),
-        },
     }
-    if cfg.rope_theta is None:
+    if _has_pos_table(cfg):
         params["pos"] = rand(k_pos, s, d)
     if not cfg.tied_head:
         params["lm_head"] = rand(next(k), v, d)
+    if cfg.layer_pattern is not None:
+        params["layers"] = _init_pattern_layers(next(k), cfg)
+        return params
+    params["layers"] = {
+        "ln1": norm_init(n_pp, lps, d),
+        "ln2": norm_init(n_pp, lps, d),
+        "wqkv": rand(k_qkv, n_pp, lps, d, 3 * h * hd),
+        "wo": rand(k_wo, n_pp, lps, h * hd, d,
+                   scale=std / math.sqrt(2 * cfg.n_layers)),
+    }
     if cfg.qk_norm:
         params["layers"]["q_norm"] = norm_init(n_pp, lps, h * hd)
         params["layers"]["k_norm"] = norm_init(n_pp, lps, h * hd)
     if _routes_dropless(cfg):
-        e = cfg.n_experts
+        e, held = cfg.n_experts, _experts_held(cfg)
         params["layers"]["gate"] = rand(next(k), n_pp, lps, d, e)
         if cfg.gated_experts:
-            params["layers"]["w_gate"] = rand(next(k), n_pp, lps, e, d, ff)
-        params["layers"]["w_up"] = rand(next(k), n_pp, lps, e, d, ff)
+            params["layers"]["w_gate"] = rand(next(k), n_pp, lps, held, d, ff)
+        params["layers"]["w_up"] = rand(next(k), n_pp, lps, held, d, ff)
         params["layers"]["w_down"] = rand(
-            next(k), n_pp, lps, e, ff, d,
+            next(k), n_pp, lps, held, ff, d,
             scale=std / math.sqrt(2 * cfg.n_layers))
     elif cfg.n_experts > 0:
         if cfg.n_experts % par.dp != 0:
@@ -165,8 +264,107 @@ def init_params(key, cfg: TransformerConfig,
     return params
 
 
+def pattern_counts(cfg: TransformerConfig) -> Dict[str, int]:
+    """{kind: its blocks in one period} for the kinds the pattern has."""
+    return {BLOCK_KINDS[c][0]: cfg.layer_pattern.count(c)
+            for c in BLOCK_KINDS if c in cfg.layer_pattern}
+
+
+def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
+    """A patterned model's blocks, stacked by kind: every leaf is (1 stage,
+    periods, blocks of the kind in a period, ...).  Weights as the block
+    above's (normal 0.02, the projections that write the residual scaled by
+    1 / sqrt(2 n_layers)) except what a state-space scan's behaviour hangs
+    on, by Mamba-2's published scheme: ``dt_bias`` the inverse softplus of a
+    log-uniform draw in ``ssm_dt_range``, ``a_log = log U(1, 16)``, ``d_skip``
+    1, and the depthwise conv U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it."""
+    d, std = cfg.d_model, 0.02
+    periods = cfg.n_layers // len(cfg.layer_pattern)
+    out_scale = std / math.sqrt(2 * cfg.n_layers)
+    keys = iter(_split(key, 24))
+    layers: Dict[str, Any] = {}
+
+    def stacked(n):
+        def ones(*shape):
+            return jnp.ones((1, periods, n) + shape, jnp.float32)
+
+        def rand(*shape, scale=std):
+            return (jax.random.normal(next(keys), (1, periods, n) + shape)
+                    * scale).astype(jnp.float32)
+
+        def uniform(*shape, lo, hi):
+            return jax.random.uniform(next(keys), (1, periods, n) + shape,
+                                      jnp.float32, lo, hi)
+        return ones, rand, uniform
+
+    for kind, n in pattern_counts(cfg).items():
+        ones, rand, uniform = stacked(n)
+        if kind == "ssm":
+            h, hp = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
+            conv = hp + 2 * cfg.ssm_groups * cfg.ssm_state
+            dt_min, dt_max, dt_floor = cfg.ssm_dt_range
+            dt = jnp.maximum(jnp.exp(uniform(h, lo=math.log(dt_min),
+                                             hi=math.log(dt_max))), dt_floor)
+            bound = 1.0 / math.sqrt(cfg.ssm_conv)
+            layers[kind] = {
+                "ln": ones(d),
+                # columns [z | x | B | C | dt]: z and x head-major (H, P),
+                # B and C group-major (G, N), dt a head.
+                "w_in": rand(d, hp + conv + h),
+                "conv_w": uniform(conv, cfg.ssm_conv, lo=-bound, hi=bound),
+                "conv_b": uniform(conv, lo=-bound, hi=bound),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+                "a_log": jnp.log(uniform(h, lo=1.0, hi=16.0)),
+                "d_skip": ones(h),
+                "norm": ones(hp),
+                "w_out": rand(hp, d, scale=out_scale),
+            }
+        elif kind == "attn":
+            hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, \
+                cfg.head_dim
+            layers[kind] = {
+                "ln": ones(d), "wq": rand(d, hq * hd),
+                "wk": rand(d, hkv * hd), "wv": rand(d, hkv * hd),
+                "wo": rand(hq * hd, d, scale=out_scale),
+            }
+        else:
+            e, held, ff = cfg.n_experts, _experts_held(cfg), cfg.d_ff
+            width = cfg.moe_latent or d
+            blk = {"ln": ones(d), "gate": rand(d, e)}
+            if cfg.router_scoring == "sigmoid":
+                # The choice's correction bias: a buffer, zero until a
+                # trainer's balancing rule moves it; outside the gradient.
+                blk["router_bias"] = 0.0 * ones(e)
+            if cfg.moe_latent:
+                blk["w_latent_in"] = rand(d, width)
+                blk["w_latent_out"] = rand(width, d, scale=out_scale)
+            if cfg.gated_experts:
+                blk["w_gate"] = rand(held, width, ff)
+            blk["w_up"] = rand(held, width, ff)
+            blk["w_down"] = rand(held, ff, width,
+                                 scale=std if cfg.moe_latent else out_scale)
+            if cfg.shared_expert_ff:
+                blk["shared_up"] = rand(d, cfg.shared_expert_ff)
+                blk["shared_down"] = rand(cfg.shared_expert_ff, d,
+                                          scale=out_scale)
+            layers[kind] = blk
+    return layers
+
+
 def param_specs(cfg: TransformerConfig, par: ParallelConfig) -> Dict[str, Any]:
     """PartitionSpec pytree matching ``init_params`` (mesh axes dp/pp/mp)."""
+    _check_layout(cfg, par)
+    if cfg.layer_pattern is not None:
+        # On dp alone: every leaf replicated, its gradient reduced by AD.
+        shapes = jax.eval_shape(lambda: _init_pattern_layers(
+            jax.random.PRNGKey(0), cfg))
+        specs = {"embed": P(), "final_norm": P(),
+                 "layers": jax.tree_util.tree_map(lambda _: P("pp"), shapes)}
+        if _has_pos_table(cfg):
+            specs["pos"] = P()
+        if not cfg.tied_head:
+            specs["lm_head"] = P()
+        return specs
     megatron = cfg.attn_mode == "megatron"
     layers: Dict[str, Any] = {
         "ln1": P("pp"),
@@ -197,7 +395,7 @@ def param_specs(cfg: TransformerConfig, par: ParallelConfig) -> Dict[str, Any]:
         layers["w1"] = P("pp", None, None, "mp")
         layers["w2"] = P("pp", None, "mp", None)
     specs = {"embed": P(), "final_norm": P(), "layers": layers}
-    if cfg.rope_theta is None:
+    if _has_pos_table(cfg):
         specs["pos"] = P()
     if not cfg.tied_head:
         specs["lm_head"] = P()
@@ -294,6 +492,25 @@ def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         return jnp.einsum("bse,ed->bsd", o, lp["wo"].astype(x.dtype))
 
 
+def _expert_activation(cfg: TransformerConfig):
+    return _ACTIVATIONS[cfg.expert_activation or
+                        ("silu" if cfg.gated_experts else "gelu")]
+
+
+def _route_experts(cfg: TransformerConfig, lp: Dict[str, jax.Array],
+                   tok: jax.Array, router_x: Optional[jax.Array] = None):
+    """``moe.dropless_moe`` as the configuration spells it: the router's
+    scoring, the experts held and their activation.  ``tok``: (T, width)."""
+    return moe_lib.dropless_moe(
+        moe_lib.GatedMoEParams(
+            gate=lp["gate"], w_gate=lp.get("w_gate"), w_up=lp["w_up"],
+            w_down=lp["w_down"], bias=lp.get("router_bias")),
+        tok, cfg.top_k, activation=_expert_activation(cfg),
+        router=moe_lib.Router(cfg.router_scoring, cfg.router_renormalise,
+                              cfg.router_scale),
+        router_x=router_x, buffer_factor=cfg.expert_buffer_factor)
+
+
 def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                x: jax.Array):
     """(The residual add of the MLP, the layer's ``moe.RouterStats`` where
@@ -301,12 +518,7 @@ def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     hnorm = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
     if _routes_dropless(cfg):
         mb, s_local, d = hnorm.shape
-        y, stats = moe_lib.dropless_moe(
-            moe_lib.GatedMoEParams(
-                gate=lp["gate"], w_gate=lp.get("w_gate"), w_up=lp["w_up"],
-                w_down=lp["w_down"]),
-            hnorm.reshape(mb * s_local, d), cfg.top_k,
-            activation=jax.nn.silu if cfg.gated_experts else jax.nn.gelu)
+        y, stats = _route_experts(cfg, lp, hnorm.reshape(mb * s_local, d))
         return y.reshape(mb, s_local, d), stats
     if cfg.n_experts > 0:
         mb, s_local, d = hnorm.shape
@@ -326,10 +538,127 @@ def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                            scatter_sequence=True), None
 
 
+def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
+               x: jax.Array) -> jax.Array:
+    """A Mamba-2 mixer (ops/ssd.py) on the normed stream.  x: (mb, S, d)."""
+    mb, s, _ = x.shape
+    h, p, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state)
+    hp, gn = h * p, g * n
+    hnorm = _rmsnorm(x, lp["ln"], cfg.norm_eps)
+    proj = jnp.einsum("bsd,de->bse", hnorm, lp["w_in"].astype(x.dtype))
+    z, xbc, dt = jnp.split(proj, [hp, 2 * hp + 2 * gn], axis=-1)
+    with scope("ssm_conv"):
+        xbc = ssd.causal_conv1d(xbc, lp["conv_w"], lp["conv_b"])
+        xbc = jax.nn.silu(xbc.astype(jnp.float32)).astype(x.dtype)
+    xs, b, c = jnp.split(xbc, [hp, hp + gn], axis=-1)
+    with scope("ssm_scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+        y = ssd.ssd_scan(xs.reshape(mb, s, h, p), dt, -jnp.exp(lp["a_log"]),
+                         b.reshape(mb, s, g, n), c.reshape(mb, s, g, n),
+                         lp["d_skip"], cfg.ssm_chunk)
+    y = ssd.gated_group_rmsnorm(y.reshape(mb, s, hp), z, lp["norm"], g,
+                                cfg.norm_eps)
+    return jnp.einsum("bse,ed->bsd", y, lp["w_out"].astype(x.dtype))
+
+
+def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
+               x: jax.Array) -> jax.Array:
+    """Causal attention with ``n_kv_heads`` key / value heads, query head i
+    reading head i // (n_heads / n_kv_heads), no position encoding.  Each
+    K / V head is repeated across its query heads before the kernels (their
+    index maps taking several query heads a K / V block is ROADMAP M4)."""
+    mb, s, _ = x.shape
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+    hnorm = _rmsnorm(x, lp["ln"], cfg.norm_eps)
+
+    def heads(w, n):
+        return jnp.einsum("bsd,de->bse", hnorm,
+                          w.astype(x.dtype)).reshape(mb, s, n, hd)
+
+    q, k, v = heads(lp["wq"], hq), heads(lp["wk"], hkv), heads(lp["wv"], hkv)
+    if hkv != hq:
+        k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
+    o = ra.full_attention(q, k, v, causal=True)
+    return jnp.einsum("bse,ed->bsd", o.reshape(mb, s, hq * hd),
+                      lp["wo"].astype(x.dtype))
+
+
+def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
+                  x: jax.Array):
+    """(An "E" block's output, its ``moe.RouterStats``): the routed experts
+    on the normed stream, or on its projection into ``moe_latent`` features
+    with the router still reading the stream (LatentMoE), plus the shared
+    expert's ``act(h V1) V2`` on the stream."""
+    mb, s, d = x.shape
+    tok = _rmsnorm(x, lp["ln"], cfg.norm_eps).reshape(mb * s, d)
+    if cfg.moe_latent:
+        with scope("moe_latent"):
+            latent = jnp.dot(tok, lp["w_latent_in"].astype(x.dtype))
+        y, stats = _route_experts(cfg, lp, latent, router_x=tok)
+        with scope("moe_latent"):
+            y = jnp.dot(y, lp["w_latent_out"].astype(x.dtype))
+    else:
+        y, stats = _route_experts(cfg, lp, tok)
+    if cfg.shared_expert_ff:
+        with scope("moe_shared"):
+            hidden = _expert_activation(cfg)(
+                jnp.dot(tok, lp["shared_up"].astype(x.dtype)
+                        ).astype(jnp.float32))
+            y = y + jnp.dot(hidden.astype(x.dtype),
+                            lp["shared_down"].astype(x.dtype))
+    return y.reshape(mb, s, d), stats
+
+
+def _make_pattern_stage_fn(cfg: TransformerConfig):
+    """stage_fn(stage_params, act) for a ``layer_pattern``: a scan over the
+    periods, inside one period its blocks in the pattern's order, each
+    under its step scope and (``cfg.remat``) its own checkpoint.  Returns
+    the activation and, where the pattern routes, the "E" blocks'
+    ``moe.RouterStats`` stacked (periods, blocks a period, ...)."""
+    mixers = {"ssm": _ssm_mixer, "attn": _gqa_mixer, "moe": _expert_mixer}
+    with_stats = "E" in cfg.layer_pattern
+
+    def block(kind, scope_name):
+        def run(act, lp):
+            with scope(scope_name):
+                out = mixers[kind](cfg, lp, act)
+                y, stats = out if kind == "moe" else (out, None)
+                return act + y, stats
+        return jax.checkpoint(run) if cfg.remat else run
+
+    blocks = {kind: block(kind, name) for kind, name in BLOCK_KINDS.values()}
+    # The period as (kind, which of the kind's blocks in a period).
+    order, seen = [], {}
+    for letter in cfg.layer_pattern:
+        kind = BLOCK_KINDS[letter][0]
+        order.append((kind, seen.get(kind, 0)))
+        seen[kind] = order[-1][1] + 1
+
+    def period_fn(act, period_params):
+        stats = []
+        for kind, j in order:
+            lp = jax.tree_util.tree_map(lambda a: a[j], period_params[kind])
+            act, st = blocks[kind](act, lp)
+            if st is not None:
+                stats.append(st)
+        if not stats:
+            return act, None
+        return act, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
+
+    def stage_fn(stage_params, act):
+        out, stats = lax.scan(period_fn, act, stage_params)
+        return (out, stats) if with_stats else out
+
+    return stage_fn
+
+
 def _make_stage_fn(cfg: TransformerConfig):
     """stage_fn(stage_params, act) scanning this stage's layers; with a
     dropless MoE it returns the activation and the layers' stacked
     ``moe.RouterStats``."""
+    if cfg.layer_pattern is not None:
+        return _make_pattern_stage_fn(cfg)
     with_stats = _routes_dropless(cfg)
 
     def layer_fn(act, lp):
@@ -363,6 +692,7 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     ``with_routing`` (dropless MoE only) returns ``(loss, routing)``, the
     replicated dict :func:`make_routing_fn` documents.
     """
+    _check_layout(cfg, par)
     s_full = cfg.seq_len
     mp_size = axis_size("mp")
     s_local = s_full // mp_size
@@ -372,7 +702,7 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     # sequence chunk for this mp member is sliced off → sp-sharded stream).
     with scope("embed"):
         emb = params["embed"][tokens]
-        if cfg.rope_theta is None:
+        if _has_pos_table(cfg):
             emb = emb + params["pos"][None]
         x = lax.dynamic_slice_in_dim(emb, mp_idx * s_local, s_local, axis=1)
         x = x.astype(cfg.dtype)
@@ -440,7 +770,7 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
             + cfg.z_loss_coef * jnp.mean(z))
     if not with_routing:
         return loss
-    return loss, {"assignments": stats.counts,
+    return loss, {"assignments": stats.counts, "dropped": stats.dropped,
                   "load_balancing_loss": balance, "z_loss": z}
 
 
@@ -468,18 +798,68 @@ def make_routing_fn(cfg: TransformerConfig, par: ParallelConfig, mesh):
     expert received; ``load`` (layers,) — the busiest expert's assignments
     over the mean's; ``dropped`` — pairs routed less pairs assigned (0:
     nothing is clamped); ``load_balancing_loss`` and ``z_loss`` (layers,),
-    uncoefficiented; and the training ``loss``."""
+    uncoefficiented; and the training ``loss``.  A patterned model's
+    leading axes are (periods, "E" blocks a period).  Where a layer holds a
+    share of its experts, ``assignments`` still covers every expert the
+    router has (what a balancing rule reads), ``held_rows`` (layers,) are
+    the pairs that fell on experts held here and ``dropped`` those of them
+    that found no row in the static buffer."""
     loss_of = make_loss_fn(cfg, par, mesh, with_routing=True)
+    held = _experts_held(cfg)
 
     def routing(params, tokens, labels):
         loss, r = loss_of(params, tokens, labels)
         counts = r["assignments"]
-        routed = tokens.size * cfg.top_k * counts.shape[0]
+        routed = tokens.size * cfg.top_k * math.prod(counts.shape[:-1])
         return {**r, "loss": loss,
                 "load": jnp.max(counts, axis=-1) / jnp.mean(counts, axis=-1),
-                "dropped": routed - jnp.sum(counts)}
+                "held_rows": jnp.sum(counts[..., :held], axis=-1),
+                "dropped": (jnp.sum(r["dropped"]) if _holds_a_share(cfg)
+                            else routed - jnp.sum(counts))}
 
     return jax.jit(routing)
+
+
+def make_router_balancer(cfg: TransformerConfig, par: ParallelConfig, mesh,
+                         rounds: int = 12, gain: float = 0.02):
+    """``balance(params, tokens, labels) -> params`` for a patterned model
+    with a sigmoid router: the correction bias moved until, on this batch,
+    every expert of every "E" block is chosen about equally often.
+
+    A router with seeded weights is not balanced: past the first block the
+    tokens share a direction, an expert's logit carries an offset common to
+    all of them, and the busiest expert takes 5-12 x the mean.  A trained
+    model's bias is what cancels that (DeepSeek-V3's rule, arXiv:2412.19437,
+    moves it a step a batch towards the under-used experts); a checkpoint
+    brings its value, seeded weights bring none.  This runs the rule to its
+    fixed point in proportional form, ``rounds`` forward passes of ``b_e +=
+    gain * log(target share / share_e)`` with the share floored at a tenth
+    of the target (in score units: 0.02 damps it; 0.05 oscillates), after
+    which busiest / mean is about 1.05 on the batch and 1.2 on fresh ones
+    (chip runs, PERF.md PR 31).  The bias stays a buffer outside the
+    gradient, and nothing updates it afterwards."""
+    if cfg.layer_pattern is None or "E" not in cfg.layer_pattern \
+            or cfg.router_scoring != "sigmoid":
+        raise ValueError("only a patterned model's sigmoid router has a "
+                         "correction bias to balance")
+    loss_of = make_loss_fn(cfg, par, mesh, with_routing=True)
+    target = cfg.top_k / cfg.n_experts
+
+    def with_bias(params, bias):
+        moe = {**params["layers"]["moe"], "router_bias": bias}
+        return {**params, "layers": {**params["layers"], "moe": moe}}
+
+    def balance(params, tokens, labels):
+        def one_round(_, bias):
+            _, r = loss_of(with_bias(params, bias), tokens, labels)
+            share = r["assignments"][None] / tokens.size
+            return bias + gain * jnp.log(
+                target / jnp.maximum(share, 0.1 * target))
+
+        return with_bias(params, lax.fori_loop(
+            0, rounds, one_round, params["layers"]["moe"]["router_bias"]))
+
+    return balance
 
 
 def serial_forward_logits(cfg: TransformerConfig, params: Dict[str, Any],
@@ -594,11 +974,20 @@ _NEG_INF = -1e30
 def _check_servable(cfg: TransformerConfig) -> None:
     """The serving forward below is the block of the defaults: a learned
     position table and GELU MLPs / ``w_in``-``w_out`` experts."""
-    if cfg.rope_theta is not None or cfg.qk_norm or cfg.gated_experts \
-            or not cfg.tied_head:
+    refused = [name for name, on in [
+        ("rotary positions", cfg.rope_theta is not None),
+        ("no position table", not cfg.learned_positions),
+        ("QK-norm", cfg.qk_norm), ("gated experts", cfg.gated_experts),
+        ("an untied head", not cfg.tied_head),
+        ("a layer_pattern (state-space, grouped-query and latent-expert "
+         "blocks)", cfg.layer_pattern is not None),
+        ("a share of the experts held", _holds_a_share(cfg)),
+        ("a sigmoid router", cfg.router_scoring != "softmax")] if on]
+    if refused:
         raise NotImplementedError(
-            "prefill / decode cover learned positions, a tied head and "
-            "ungated MLPs; this configuration trains only (ROADMAP D1)")
+            "chunk_forward serves learned positions, a tied head, equal "
+            "q / k / v heads and ungated MLPs; this configuration has "
+            + ", ".join(refused) + " and trains only (ROADMAP M1)")
 
 
 def init_kv_pages(cfg: TransformerConfig, n_pages: int,
@@ -800,6 +1189,30 @@ def _mlp_flops_per_token(cfg: TransformerConfig) -> float:
     return 4.0 * d * ff
 
 
+def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
+    """Forward matmul-FLOPs a token of one patterned block, as this device
+    computes it: the heads and experts it holds, causal scores halved, the
+    scan as the chunked algorithm's four products."""
+    d, s = cfg.d_model, cfg.seq_len
+    if letter == "M":
+        h, p, g, n, q = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                         cfg.ssm_state, cfg.ssm_chunk)
+        proj = 2.0 * d * (2 * h * p + 2 * g * n + h) + 2.0 * h * p * d
+        conv = 2.0 * cfg.ssm_conv * (h * p + 2 * g * n)
+        scan = 2.0 * q * n * g + 2.0 * q * p * h + 4.0 * p * n * h
+        return proj + conv + scan
+    if letter == "*":
+        hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+        return 2.0 * d * hd * (2 * hq + 2 * hkv) + 2.0 * s * hq * hd
+    width = cfg.moe_latent or d
+    mats = 3.0 if cfg.gated_experts else 2.0
+    routed = (cfg.top_k * _experts_held(cfg) / cfg.n_experts
+              * mats * 2.0 * width * cfg.d_ff)
+    latent = 4.0 * d * width if cfg.moe_latent else 0.0
+    return (2.0 * d * cfg.n_experts + latent + routed
+            + 4.0 * d * cfg.shared_expert_ff)
+
+
 def train_flops_per_seq(cfg: TransformerConfig) -> float:
     """Matmul-FLOPs for one causal-LM training sequence (train = 3x
     fwd), importable so training loops can feed
@@ -810,6 +1223,10 @@ def train_flops_per_seq(cfg: TransformerConfig) -> float:
     top_k experts + gate per token (``_mlp_flops_per_token``)."""
     d, L, s, v = (cfg.d_model, cfg.n_layers, cfg.seq_len,
                   cfg.vocab_size)
+    # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no pattern.
+    if getattr(cfg, "layer_pattern", None) is not None:
+        return 3.0 * s * (2.0 * d * v + (L // len(cfg.layer_pattern)) * sum(
+            _block_flops_per_token(cfg, c) for c in cfg.layer_pattern))
     dense = s * (L * (8.0 * d * d + _mlp_flops_per_token(cfg)) + 2.0 * d * v)
     attn = L * 2.0 * s * s * d
     return 3.0 * (dense + attn)

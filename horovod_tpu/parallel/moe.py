@@ -18,7 +18,17 @@ expert; one grouped matmul per projection over the ragged groups
 not experts x rows); the rows put back in token order and summed with
 their weights in fp32.  No capacity, no ``(T, E, C)`` tensor, nothing
 dropped.  Experts are replicated over the mesh here; experts over an
-``ep`` axis with a ragged exchange is the follow-up.
+``ep`` axis with a ragged exchange is the follow-up (ROADMAP M2).
+
+The router is a function of a :class:`Router`: ``softmax`` as above, or
+``sigmoid`` scores chosen by score + a correction bias and weighed by the
+scores alone, renormalised over the chosen and scaled (DeepSeek-V3's
+scheme, arXiv:2412.19437, which Nemotron 3's LatentMoE uses).  A layer may
+also **hold a share** of the experts it routes over (the one-chip half of
+expert parallelism): ``w_up`` / ``w_down`` then have fewer experts than the
+router has outputs, the layer computes the part of the result its own
+experts give, through a static buffer of rows, and what the absent experts
+would have added is left out.  Nothing stands in for them.
 
 The reference's only layout-shuffling primitive is alltoall with uneven
 splits (operations.cc:1136-1198, SURVEY.md §2.3 "the only primitive that
@@ -246,12 +256,23 @@ def moe_load_balancing_loss(x: jax.Array, gate: jax.Array,
 # ---------------------------------------------------------------------------
 
 class GatedMoEParams(NamedTuple):
-    """One layer's experts, all of them on this device.  ``w_gate`` is None
-    for plain (``activation(x w_up) w_down``) experts."""
-    gate: jax.Array              # (d_model, n_experts) router
-    w_gate: Optional[jax.Array]  # (n_experts, d_model, d_ff)
-    w_up: jax.Array              # (n_experts, d_model, d_ff)
-    w_down: jax.Array            # (n_experts, d_ff, d_model)
+    """One layer's router and the experts this device holds: all of them,
+    or the first ``n_held`` of the router's ``n_experts`` (a share).
+    ``w_gate`` is None for plain (``activation(x w_up) w_down``) experts;
+    ``bias`` is a sigmoid router's correction bias, a buffer that moves the
+    choice and not the weights and takes no gradient."""
+    gate: jax.Array              # (d_router_in, n_experts) router
+    w_gate: Optional[jax.Array]  # (n_held, d_model, d_ff)
+    w_up: jax.Array              # (n_held, d_model, d_ff)
+    w_down: jax.Array            # (n_held, d_ff, d_model)
+    bias: Optional[jax.Array] = None    # (n_experts,)
+
+
+class Router(NamedTuple):
+    """How a dropless router scores the experts and weighs the chosen."""
+    scoring: str = "softmax"     # "softmax" | "sigmoid" (choice by s + bias)
+    renormalise: bool = False    # chosen weights over their sum
+    scale: float = 1.0           # x the weights (``routed_scaling_factor``)
 
 
 class RouterStats(NamedTuple):
@@ -261,6 +282,9 @@ class RouterStats(NamedTuple):
     counts: jax.Array     # (E,) f32 — (token, choice) pairs sent to expert e
     prob_sum: jax.Array   # (E,) f32 — sum over tokens of the softmax
     z_sum: jax.Array      # () f32 — sum over tokens of logsumexp(logits)^2
+    # () f32 — pairs routed to an expert held here that found no row in the
+    # buffer (a layer that holds every expert has no buffer: 0).
+    dropped: jax.Array = 0.0
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -303,36 +327,206 @@ def _permute_rows_bwd(inverse, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-def dropless_moe(params: GatedMoEParams, x: jax.Array, top_k: int,
-                 activation: Callable = jax.nn.silu):
-    """Dropless top-k MoE MLP over this device's tokens.
-
-    ``x``: (tokens, d_model) in the compute dtype.  Router logits and
-    softmax in fp32 (the logits at the highest matmul precision: a near-tie
-    between the k-th and the next expert is decided by their last bits, and
-    the matmul is 2 T d E FLOPs); the k largest probabilities weigh their
-    experts as they are, not renormalised.  Expert e computes
-    ``(activation(x w_gate[e]) * (x w_up[e])) w_down[e]`` (without
-    ``w_gate``: ``activation(x w_up[e]) w_down[e]``) for exactly the rows
-    routed to it.  Returns ``(out, RouterStats)``, ``out`` (tokens, d_model)
-    in ``x.dtype``, to be added to the residual by the caller.
-    """
-    t, d = x.shape
-    e = params.gate.shape[1]
-    if not 1 <= top_k <= e:
-        raise ValueError(f"top_k must be in [1, {e}], got {top_k}")
-    with scope("moe_route"):
-        logits = jnp.dot(x.astype(jnp.float32),
-                         params.gate.astype(jnp.float32),
-                         precision=lax.Precision.HIGHEST)
+def _scores(params: GatedMoEParams, x: jax.Array, top_k: int,
+            router: Router):
+    """(scores (T, E) fp32, the chosen experts (T, k), logsumexp of the
+    logits (T,) — zeros for a sigmoid router, which has no z-loss).  The
+    logits at the highest matmul precision: a near-tie between the k-th
+    and the next expert is decided by their last bits."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     params.gate.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if router.scoring == "softmax":
         lse = jax.nn.logsumexp(logits, axis=-1)
         probs = jnp.exp(logits - lse[:, None])
         _, top_i = lax.top_k(probs, top_k)                      # (T, k)
+        return probs, top_i, lse
+    if router.scoring != "sigmoid":
+        raise ValueError(f"unknown router scoring {router.scoring!r} "
+                         "(softmax | sigmoid)")
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if params.bias is None else (
+        scores + lax.stop_gradient(params.bias.astype(jnp.float32)))
+    _, top_i = lax.top_k(choice, top_k)
+    return scores, top_i, jnp.zeros(logits.shape[:1], jnp.float32)
+
+
+def _weigh(p, router: Router):
+    """The chosen experts' scores (0 elsewhere) as the weights of their
+    outputs: as they are, or over their sum, and scaled."""
+    if router.renormalise:
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
+    return p if router.scale == 1.0 else p * router.scale
+
+
+@jax.custom_vjp
+def _held_rows(x, token_of_row, row_of_pair, pair_kept):
+    """``x[token_of_row]``: the rows of the buffer, in expert order.  Row
+    ``row_of_pair[t, e]`` holds token t's copy for held expert e where
+    ``pair_kept[t, e]``; backward gathers those and adds them, a gather
+    where AD would scatter-add."""
+    return x[token_of_row]
+
+
+def _held_rows_fwd(x, token_of_row, row_of_pair, pair_kept):
+    return x[token_of_row], (row_of_pair, pair_kept)
+
+
+def _held_rows_bwd(res, g):
+    row_of_pair, pair_kept = res
+    picked = jnp.where(pair_kept[..., None], g[row_of_pair], 0)
+    return (jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None, None)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row, row_used):
+    """(T, held, d): ``y[row_of_pair[t, e]]`` where the pair has a row,
+    else 0.  Backward is the gather the other way (row r came from pair
+    ``pair_of_row[r]``), 0 for the buffer's unused rows."""
+    return jnp.where(pair_kept[..., None], y[row_of_pair], 0)
+
+
+def _rows_to_pairs_fwd(y, row_of_pair, pair_kept, pair_of_row, row_used):
+    return (_rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row, row_used),
+            (pair_of_row, row_used))
+
+
+def _rows_to_pairs_bwd(res, g):
+    pair_of_row, row_used = res
+    flat = g.reshape(-1, g.shape[-1])
+    return (jnp.where(row_used[:, None], flat[pair_of_row], 0),
+            None, None, None, None)
+
+
+_rows_to_pairs.defvjp(_rows_to_pairs_fwd, _rows_to_pairs_bwd)
+
+
+def _grouped_experts(params: GatedMoEParams, rows, group_sizes, activation,
+                     row_used=None):
+    """``(activation(rows w_gate) * (rows w_up)) w_down`` (without
+    ``w_gate``: ``activation(rows w_up) w_down``), each row by the expert
+    of its group, as grouped matmuls.  ``row_used`` (a held share's buffer)
+    masks the rows past the last group, which belong to no expert:
+    whatever the grouped matmul leaves there goes no further."""
+    dtype = rows.dtype
+    up = lax.ragged_dot(rows, params.w_up.astype(dtype), group_sizes)
+    if params.w_gate is None:
+        hidden = activation(up.astype(jnp.float32))
+    else:
+        gate = lax.ragged_dot(rows, params.w_gate.astype(dtype), group_sizes)
+        hidden = (activation(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32))
+    if row_used is not None:
+        hidden = jnp.where(row_used[:, None], hidden, 0)
+    return lax.ragged_dot(hidden.astype(dtype), params.w_down.astype(dtype),
+                          group_sizes)
+
+
+def held_row_buffer(tokens: int, top_k: int, n_held: int, n_experts: int,
+                    factor: float) -> int:
+    """Rows of the static buffer a share-holding layer computes: ``factor``
+    x the mean (tokens x top_k x held / routed), a multiple of 8, never
+    more than every (token, held expert) pair."""
+    mean = tokens * top_k * n_held / n_experts
+    rows = -(-int(math.ceil(mean * factor)) // 8) * 8
+    return max(8, min(rows, tokens * n_held))
+
+
+def _held_experts(params: GatedMoEParams, x, weights, chosen, activation,
+                  row_buffer: int):
+    """The held experts' part of the result.  ``weights``: (T, n_held)
+    fp32 and ``chosen`` (T, n_held) bool, whether the token chose the
+    expert.  The chosen (token, held expert) pairs are sorted by expert;
+    the first ``row_buffer`` rows are computed, the rest dropped and
+    counted.  Returns (out (T, d) fp32, pairs dropped ())."""
+    t, d = x.shape
+    n_held = params.w_up.shape[0]
+    with scope("moe_route"):
+        counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)        # (n_held,)
+        # Pair (t, e) sorts under its expert, an unchosen one after all.
+        key = jnp.where(chosen, jnp.arange(n_held, dtype=jnp.int32), n_held)
+        pair_of_row = jnp.argsort(key.reshape(t * n_held), stable=True)
+        row_of_pair = jnp.argsort(pair_of_row).reshape(t, n_held)
+        pair_of_row = pair_of_row[:row_buffer]
+        ends = jnp.minimum(jnp.cumsum(counts), row_buffer)
+        group_sizes = jnp.diff(ends, prepend=0)
+        row_used = jnp.arange(row_buffer) < ends[-1]
+        pair_kept = chosen & (row_of_pair < row_buffer)
+        row_of_pair = jnp.minimum(row_of_pair, row_buffer - 1)
+        dropped = (jnp.sum(counts) - ends[-1]).astype(jnp.float32)
+    with scope("moe_dispatch"):
+        rows = _held_rows(x, pair_of_row // n_held, row_of_pair, pair_kept)
+    with scope("moe_experts"):
+        y = _grouped_experts(params, rows, group_sizes, activation, row_used)
+    with scope("moe_dispatch"):
+        y = _rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row, row_used)
+        out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
+    return out, dropped
+
+
+def dropless_moe(params: GatedMoEParams, x: jax.Array, top_k: int,
+                 activation: Callable = jax.nn.silu,
+                 router: Router = Router(),
+                 router_x: Optional[jax.Array] = None,
+                 buffer_factor: float = 4.0):
+    """Dropless top-k MoE MLP over this device's tokens.
+
+    ``x``: (tokens, d_model) in the compute dtype.  Router logits and
+    scores in fp32 (the logits at the highest matmul precision: a near-tie
+    between the k-th and the next expert is decided by their last bits, and
+    the matmul is 2 T d E FLOPs) from ``router_x`` where the router reads
+    another tensor than the experts do (LatentMoE: the hidden state, the
+    experts its latent projection), else from ``x``.  With the default
+    :class:`Router` the k largest softmax probabilities weigh their experts
+    as they are, not renormalised.  Expert e computes ``(activation(x
+    w_gate[e]) * (x w_up[e])) w_down[e]`` (without ``w_gate``:
+    ``activation(x w_up[e]) w_down[e]``) for exactly the rows routed to it.
+    Returns ``(out, RouterStats)``, ``out`` (tokens, d_model) in
+    ``x.dtype``, to be added to the residual by the caller.
+
+    Where ``params`` holds fewer experts than the router has outputs, they
+    are experts 0 .. n_held - 1 of a layer whose others live elsewhere: the
+    router scores, chooses and weighs over all of them, the result is the
+    held experts' part alone, and their rows go through a static buffer of
+    ``buffer_factor`` x the mean (:func:`held_row_buffer`); a row beyond it
+    is dropped and counted in ``RouterStats.dropped``.  ``counts`` stays
+    what the router chose, over all its outputs, held or not.
+    """
+    t, d = x.shape
+    e = params.gate.shape[1]
+    n_held = params.w_up.shape[0]
+    if not 1 <= top_k <= e:
+        raise ValueError(f"top_k must be in [1, {e}], got {top_k}")
+    if n_held > e:
+        raise ValueError(f"{n_held} experts held of {e} routed")
+    with scope("moe_route"):
+        probs, top_i, lse = _scores(
+            params, x if router_x is None else router_x, top_k, router)
+    if n_held < e:
+        with scope("moe_route"):
+            # (T, E) in {0, 1}: the token chose the expert.  Summed over
+            # the choices inside one fusion; no (T, k, E) tensor is kept.
+            member = jnp.sum(jax.nn.one_hot(top_i, e, dtype=probs.dtype),
+                             axis=1)
+            weights = _weigh(probs * member, router)[:, :n_held]
+        out, dropped = _held_experts(
+            params, x, weights, member[:, :n_held] > 0, activation,
+            held_row_buffer(t, top_k, n_held, e, buffer_factor))
+        stats = RouterStats(
+            counts=jnp.sum(member, axis=0), prob_sum=jnp.sum(probs, axis=0),
+            z_sum=jnp.sum(lse * lse), dropped=dropped)
+        return out.astype(x.dtype), stats
+    with scope("moe_route"):
         # The chosen probabilities by a one-hot product, not ``top_k``'s
         # values or a gather: their transpose is a product too, where
         # those scatter (T k) scalars into (T, E).
-        top_p = jnp.sum(probs[:, None, :] * jax.nn.one_hot(
-            top_i, e, dtype=probs.dtype), axis=-1)
+        top_p = _weigh(jnp.sum(probs[:, None, :] * jax.nn.one_hot(
+            top_i, e, dtype=probs.dtype), axis=-1), router)
+    with scope("moe_route"):
         expert_of_pair = top_i.reshape(t * top_k)
         # Row r of the sorted order holds pair ``pair_of_row[r]`` = token *
         # top_k + choice; stable, so an expert's rows stay in token order.
@@ -343,20 +537,12 @@ def dropless_moe(params: GatedMoEParams, x: jax.Array, top_k: int,
             axis=0, dtype=jnp.int32)
         stats = RouterStats(counts=group_sizes.astype(jnp.float32),
                             prob_sum=jnp.sum(probs, axis=0),
-                            z_sum=jnp.sum(lse * lse))
+                            z_sum=jnp.sum(lse * lse),
+                            dropped=jnp.float32(0.0))
     with scope("moe_dispatch"):
         rows = _rows_of_tokens(x, pair_of_row // top_k, row_of_pair, top_k)
     with scope("moe_experts"):
-        up = lax.ragged_dot(rows, params.w_up.astype(x.dtype), group_sizes)
-        if params.w_gate is None:
-            hidden = activation(up.astype(jnp.float32))
-        else:
-            gate = lax.ragged_dot(rows, params.w_gate.astype(x.dtype),
-                                  group_sizes)
-            hidden = (activation(gate.astype(jnp.float32))
-                      * up.astype(jnp.float32))
-        y = lax.ragged_dot(hidden.astype(x.dtype),
-                           params.w_down.astype(x.dtype), group_sizes)
+        y = _grouped_experts(params, rows, group_sizes, activation)
     with scope("moe_dispatch"):
         y = _permute_rows(y, row_of_pair, pair_of_row).reshape(t, top_k, d)
         out = jnp.sum(y.astype(jnp.float32) * top_p[..., None], axis=1)

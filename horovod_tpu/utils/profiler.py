@@ -13,7 +13,8 @@ on the host timeline of a captured trace alongside the device steps.
   the coordinator-side view).
 
 * ``scope(name)`` — names a block of the *compiled* training step
-  (``STEP_SCOPES``, ``MOE_SCOPES``): a ``jax.named_scope``, so the name
+  (``STEP_SCOPES``, ``MOE_SCOPES``, ``SSM_SCOPES``,
+  ``LATENT_MOE_SCOPES``): a ``jax.named_scope``, so the name
   lands in every HLO operation's ``op_name`` and from there in a device
   profile.
 
@@ -67,6 +68,13 @@ STEP_SCOPES = ("embed", "attn", "mlp", "head", "optimizer")
 # inside the ``mlp`` block: the router up to the sorted order, the rows
 # gathered into expert order and put back, the grouped matmuls.
 MOE_SCOPES = ("moe_route", "moe_dispatch", "moe_experts")
+# A patterned model's state-space block (``models/transformer._ssm_mixer``)
+# — it stands beside ``attn`` and ``mlp`` as a block of the step — and
+# inside it the causal conv and the chunked scan.
+SSM_SCOPES = ("ssm", "ssm_conv", "ssm_scan")
+# Inside the ``mlp`` block of a latent-space expert layer: both latent
+# projections, and the shared expert.
+LATENT_MOE_SCOPES = ("moe_latent", "moe_shared")
 
 
 def scope(name: str):
