@@ -36,8 +36,9 @@ class BertConfig(NamedTuple):
     n_layers: int = 12
     seq_len: int = 512
     dtype: Any = jnp.bfloat16
-    # True/"full" = per-layer rematerialization; "dots" = save matmul
-    # outputs only (jax dots_with_no_batch_dims_saveable); False = none.
+    # True/"full" = per-layer rematerialization (the flash forward's output
+    # and lse kept); "dots" = save matmul outputs only (jax
+    # dots_with_no_batch_dims_saveable); False = none.
     remat: Any = True
 
     @property
@@ -149,8 +150,9 @@ def _encode(cfg: BertConfig, params, tokens, *, sharded: bool):
     def body(act, lp):
         return _encoder_layer(cfg, lp, act, sharded=sharded), None
 
-    # remat True/"full": recompute everything in bwd (lowest memory,
-    # ~4/3x hardware FLOPs).  "dots": save matmul outputs, recompute
+    # remat True/"full": recompute everything in bwd but the flash forward
+    # kernel, whose output and lse are kept (lowest memory but for that one
+    # activation a layer).  "dots": save matmul outputs, recompute
     # only the cheap elementwise chain — near remat-off compute at a
     # fraction of remat-off memory (the standard transformer policy).
     if cfg.remat == "dots":
@@ -158,7 +160,7 @@ def _encode(cfg: BertConfig, params, tokens, *, sharded: bool):
             body,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     elif cfg.remat:
-        fn = jax.checkpoint(body)
+        fn = ra.checkpoint_keeping_attention(body)
     else:
         fn = body
     x, _ = lax.scan(fn, x, params["layers"])

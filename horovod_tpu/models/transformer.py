@@ -613,7 +613,8 @@ def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
 def _make_pattern_stage_fn(cfg: TransformerConfig):
     """stage_fn(stage_params, act) for a ``layer_pattern``: a scan over the
     periods, inside one period its blocks in the pattern's order, each
-    under its step scope and (``cfg.remat``) its own checkpoint.  Returns
+    under its step scope and (``cfg.remat``) its own checkpoint, which
+    keeps a "*" block's flash forward output and lse.  Returns
     the activation and, where the pattern routes, the "E" blocks'
     ``moe.RouterStats`` stacked (periods, blocks a period, ...)."""
     mixers = {"ssm": _ssm_mixer, "attn": _gqa_mixer, "moe": _expert_mixer}
@@ -625,7 +626,7 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
                 out = mixers[kind](cfg, lp, act)
                 y, stats = out if kind == "moe" else (out, None)
                 return act + y, stats
-        return jax.checkpoint(run) if cfg.remat else run
+        return ra.checkpoint_keeping_attention(run) if cfg.remat else run
 
     blocks = {kind: block(kind, name) for kind, name in BLOCK_KINDS.values()}
     # The period as (kind, which of the kind's blocks in a period).
@@ -654,9 +655,10 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
 
 
 def _make_stage_fn(cfg: TransformerConfig):
-    """stage_fn(stage_params, act) scanning this stage's layers; with a
-    dropless MoE it returns the activation and the layers' stacked
-    ``moe.RouterStats``."""
+    """stage_fn(stage_params, act) scanning this stage's layers, each
+    (``cfg.remat``) under one checkpoint that keeps the flash forward's
+    output and lse; with a dropless MoE it returns the activation and the
+    layers' stacked ``moe.RouterStats``."""
     if cfg.layer_pattern is not None:
         return _make_pattern_stage_fn(cfg)
     with_stats = _routes_dropless(cfg)
@@ -672,7 +674,7 @@ def _make_stage_fn(cfg: TransformerConfig):
     def stage_fn(stage_params, act):
         body = layer_fn
         if cfg.remat:
-            body = jax.checkpoint(layer_fn)
+            body = ra.checkpoint_keeping_attention(layer_fn)
         out, stats = lax.scan(body, act, stage_params)
         return (out, stats) if with_stats else out
 

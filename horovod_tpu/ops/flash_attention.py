@@ -72,6 +72,20 @@ Public API:
   composition hook ring attention (parallel/ring_attention.py) uses to
   merge per-ring-step partials into an exact global softmax.
 
+Under a layer checkpoint the forward kernel runs once.  A bare
+``jax.checkpoint`` saves nothing a custom VJP made, so the backward's
+recompute of a layer ran ``hvd_flash_fwd`` a second time to remake ``out``
+and ``lse`` for ``_flash_bwd``.  ``_flash_fwd`` names the two
+(``SAVED_OUT``, ``SAVED_LSE``) and the models' layer checkpoint saves those
+names (``parallel/ring_attention.checkpoint_keeping_attention``): the
+recompute stops at the named values and the backward reads what the forward
+wrote.  The named output is the (B, S, H·D) reshape, and both the primal
+output and the residual are that value reshaped back: a name on a copy that
+nothing downstream reads saves the copy and reruns the kernel, and a
+(B, S, H, D) value stacked by the layers' scan pads a head of 64 to 128
+lanes (PERF.md section 6, PR 32).  ``flash_attention_with_lse`` — ring
+attention's hook, under a custom VJP of its own — sets no names.
+
 Layout is (batch, seq, heads, head_dim) throughout, matching the rest of
 the framework. ``q_offset``/``kv_offset`` globalize the causal mask when
 q/k are shards of a longer sequence (they are traced values under
@@ -93,10 +107,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+# ``jax.ad_checkpoint.checkpoint_name``s of what the differentiated forward
+# made: the output as (B, S, H·D) and the (B, H, S) fp32 ``lse``.
+SAVED_OUT = "flash_attention_out"
+SAVED_LSE = "flash_attention_lse"
 
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
 # A dimension no candidate divides is taken whole up to this length, and a
@@ -461,6 +481,14 @@ def _flash_impl(q, k, v, offsets, causal, scale, block_q, block_k,
 def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret):
     out, lse = _flash_impl(q, k, v, offsets, causal, scale, block_q,
                            block_k, interpret)
+    # Named for ``checkpoint_keeping_attention``.  The primal output and the
+    # residual are both the named value reshaped back: were either the
+    # kernel's own output beside a named copy, the recompute would need the
+    # kernel again to make it.
+    b, s, h, d = out.shape
+    out = checkpoint_name(out.reshape(b, s, h * d), SAVED_OUT)
+    out = out.reshape(b, s, h, d)
+    lse = checkpoint_name(lse, SAVED_LSE)
     return out, (q, k, v, offsets, out, lse)
 
 
@@ -470,9 +498,12 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     dot = g.transpose(0, 2, 1, 3)
-    outt = out.transpose(0, 2, 1, 3)
-    delta = jnp.sum(dot.astype(jnp.float32) * outt.astype(jnp.float32),
-                    axis=-1)                                   # (B, H, Sq)
+    # In the layout ``g`` and the saved ``out`` arrive in: from a transposed
+    # ``out`` XLA widened the saved (B, S, H·D) stack's slice to fp32 and
+    # transposed that, 3 ms a layer in the BERT cell (PERF.md section 6,
+    # PR 32).
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1)                # (B, H, Sq)
     dq, dk, dv = _bwd_call(qt, kt, vt, dot, lse, delta, offsets,
                            causal=causal, scale=scale, block_q=block_q,
                            block_k=block_k, interpret=interpret)

@@ -267,6 +267,19 @@ def reference_attention(q, k, v, causal: bool = True,
     return out.astype(q.dtype)
 
 
+def checkpoint_keeping_attention(layer_fn):
+    """``jax.checkpoint(layer_fn)`` for a layer that calls
+    :func:`full_attention`: the backward recomputes the layer but for the
+    flash forward kernel, whose output and ``lse`` are saved by name
+    (``ops/flash_attention.py`` ``_flash_fwd``) — one (B, S, H·D) activation
+    and one fp32 row statistic a layer.  The XLA path sets no names and is
+    recomputed whole."""
+    from ..ops import flash_attention as fa
+    return jax.checkpoint(
+        layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
+            fa.SAVED_OUT, fa.SAVED_LSE))
+
+
 def full_attention(q, k, v, causal: bool = True,
                    scale: Optional[float] = None,
                    use_flash: Optional[bool] = None) -> jax.Array:

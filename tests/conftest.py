@@ -59,6 +59,19 @@ def _fresh_recovery_tier():
         mod.reset_chaos()
 
 
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    """Off the chip the dispatch takes the XLA branch and a Mosaic kernel
+    cannot run: ask for the kernels and run them in the Pallas interpreter.
+    Steering in the test, no option of the program."""
+    from horovod_tpu.ops import flash_attention as fa
+    monkeypatch.setenv("HVD_TPU_FLASH", "1")
+    real = fa.flash_attention
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        lambda *a, **kw: real(*a, **kw, interpret=True))
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _no_stray_background_threads():
     """No non-daemon background thread started during the suite may

@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from _flash_kernels import ONCE, kernel_calls
 from horovod_tpu.models import bert
+from horovod_tpu.parallel import ring_attention as ra
 from horovod_tpu.parallel.mesh import create_mesh
 
 
@@ -143,3 +145,50 @@ def test_remat_modes_same_loss_and_grad(remat):
                     jax.tree_util.tree_leaves(base_g)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-6)
+
+
+# -- what the layer checkpoint keeps (ROADMAP Sr) ----------------------------
+
+def _kept_loss_and_grads(remat):
+    """(loss-and-gradients of ``_encode``'s scan under the gathered head at
+    a length the interpreted kernels take, its parameters)."""
+    cfg = CFG._replace(seq_len=128, n_layers=3, remat=remat)
+    params = bert.init_params(jax.random.PRNGKey(0), cfg)
+    inputs, positions, labels = bert.synthetic_mlm_batch(
+        jax.random.PRNGKey(1), cfg, 2)
+    return jax.jit(jax.value_and_grad(
+        lambda p: bert.serial_forward_loss(cfg, p, inputs, labels,
+                                           positions=positions))), params
+
+
+def test_a_checkpointed_encoder_layer_calls_the_forward_kernel_once(
+        interpreted_kernels, monkeypatch):
+    """``remat=True`` keeps the flash forward's output and lse: one call of
+    each kernel in the gradient's program (the scan's body, printed once),
+    where the bare checkpoint's holds the forward and its recompute — as
+    ``remat="dots"``, which this leaves alone, still does."""
+    fn, params = _kept_loss_and_grads(True)
+    assert kernel_calls(jax.make_jaxpr(fn)(params)) == ONCE
+    twice = {**ONCE, "hvd_flash_fwd": 2}
+    fn, params = _kept_loss_and_grads("dots")
+    assert kernel_calls(jax.make_jaxpr(fn)(params)) == twice
+    monkeypatch.setattr(ra, "checkpoint_keeping_attention", jax.checkpoint)
+    fn, params = _kept_loss_and_grads(True)
+    assert kernel_calls(jax.make_jaxpr(fn)(params)) == twice
+
+
+def test_keeping_the_forward_leaves_berts_gradients_alone(
+        interpreted_kernels, monkeypatch):
+    """Loss and gradients are the bare checkpoint's and ``remat=False``'s
+    to the last bits (the kernels' path alone is held to the bit in
+    test_flash_attention.py; whole programs XLA fuses each in its own
+    way)."""
+    fn, params = _kept_loss_and_grads(True)
+    kept = jax.tree_util.tree_leaves(fn(params))
+    plain = jax.tree_util.tree_leaves(_kept_loss_and_grads(False)[0](params))
+    monkeypatch.setattr(ra, "checkpoint_keeping_attention", jax.checkpoint)
+    bare = jax.tree_util.tree_leaves(_kept_loss_and_grads(True)[0](params))
+    for a, b, c in zip(kept, bare, plain):
+        for other in (b, c):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(other),
+                                       rtol=1e-4, atol=1e-6)

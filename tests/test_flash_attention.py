@@ -6,9 +6,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 from horovod_tpu.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from _flash_kernels import ONCE, kernel_calls
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel import ring_attention as ra
 
@@ -603,3 +605,153 @@ def test_ring_flash_on_wide_tiles(dtype, tile, monkeypatch):
     for got, ref in zip(vjp(g), ref_grads):
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=5e-2, rtol=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# The layer checkpoint keeps what the forward kernel made (ROADMAP Sr): its
+# output and lse are saved by name, so the recompute never runs the forward
+# kernel a second time.
+# ---------------------------------------------------------------------------
+
+N_LAYERS = 3
+
+
+def _scanned_layers(h, d, dtype, attention):
+    """``grads(wrap)`` of a three-layer scan of qkv -> attention -> wo, each
+    layer wrapped by ``wrap`` (a checkpoint, or nothing), with its inputs."""
+    b, s, dm = 1, 128, h * d
+    keys = jax.random.split(jax.random.PRNGKey(21), 3)
+    params = {
+        "wqkv": (jax.random.normal(keys[0], (N_LAYERS, dm, 3 * dm))
+                 * dm ** -0.5).astype(dtype),
+        "wo": (jax.random.normal(keys[1], (N_LAYERS, dm, dm))
+               * dm ** -0.5).astype(dtype)}
+    x = jax.random.normal(keys[2], (b, s, dm)).astype(dtype)
+
+    def layer(act, lp):
+        qkv = jnp.einsum("bsd,de->bse", act, lp["wqkv"]).reshape(
+            b, s, h, 3, d)
+        o = attention(qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :])
+        return act + jnp.einsum("bse,ed->bsd", o.reshape(b, s, dm),
+                                lp["wo"]), None
+
+    def grads(wrap):
+        def loss(params, x):
+            out, _ = lax.scan(wrap(layer), x, params)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1))
+
+    return grads, (params, x)
+
+
+def _leaves(tree):
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(tree)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])
+def test_keeping_the_forward_calls_it_once_and_moves_no_bit(d, causal, dtype):
+    """Under the keeping checkpoint the gradient's program holds one call
+    of each kernel where the bare checkpoint's holds the forward twice, and
+    the gradients are, bit for bit, the bare checkpoint's and those of no
+    checkpoint at all."""
+    grads, args = _scanned_layers(
+        2, d, dtype, lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, interpret=True))
+    kept = grads(ra.checkpoint_keeping_attention)
+    bare = grads(jax.checkpoint)
+    plain = grads(lambda layer: layer)
+    assert kernel_calls(jax.make_jaxpr(kept)(*args)) == ONCE
+    assert kernel_calls(jax.make_jaxpr(plain)(*args)) == ONCE
+    assert kernel_calls(jax.make_jaxpr(bare)(*args)) == {
+        **ONCE, "hvd_flash_fwd": 2}
+    got = _leaves(jax.jit(kept)(*args))
+    for other in (bare, plain):
+        for a, b in zip(got, _leaves(jax.jit(other)(*args))):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def test_what_the_backward_reads_is_the_named_value():
+    """The primal output and the residual ``out`` are one value, the named
+    (B, S, H·D) activation reshaped back, and the residual ``lse`` is the
+    named one: nothing downstream reads the kernel's own output."""
+    offsets = jnp.zeros((1, 2), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: fa._flash_fwd(q, k, v, offsets, True, 0.125, 128,
+                                      128, True))(
+        *_qkv(b=1, s=128, h=2, d=64)).jaxpr
+    made_by = {out: eqn for eqn in jaxpr.eqns for out in eqn.outvars}
+    # out, then the residuals (q, k, v, offsets, out, lse).
+    primal, *_, res_out, res_lse = jaxpr.outvars
+    assert primal is res_out
+    reshape = made_by[primal]
+    assert reshape.primitive.name == "reshape"
+    named = made_by[reshape.invars[0]]
+    assert named.primitive.name == "name"
+    assert named.params["name"] == fa.SAVED_OUT
+    assert named.outvars[0].aval.shape == (1, 128, 2 * 64)
+    assert made_by[res_lse].primitive.name == "name"
+    assert made_by[res_lse].params["name"] == fa.SAVED_LSE
+    assert res_lse.aval.shape == (1, 2, 128)
+    assert res_lse.aval.dtype == jnp.float32
+    assert not any(n.startswith("hvd_") for n in (fa.SAVED_OUT, fa.SAVED_LSE))
+
+
+def test_a_named_copy_beside_the_kernels_own_output_keeps_both_forwards(
+        monkeypatch):
+    """The mutation the call count is there to catch: name a copy, return
+    and keep the kernel's own output, and the recompute needs the kernel
+    again."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    def fwd(q, k, v, offsets, *static):
+        out, lse = fa._flash_impl(q, k, v, offsets, *static)
+        checkpoint_name(out.reshape(*out.shape[:2], -1), fa.SAVED_OUT)
+        return out, (q, k, v, offsets, out,
+                     checkpoint_name(lse, fa.SAVED_LSE))
+
+    mutant = jax.custom_vjp(fa._flash.fun,
+                            nondiff_argnums=fa._flash.nondiff_argnums)
+    mutant.defvjp(fwd, fa._flash_bwd)
+    monkeypatch.setattr(fa, "_flash", mutant)
+    grads, args = _scanned_layers(
+        2, 64, jnp.float32, lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, interpret=True))
+    calls = kernel_calls(jax.make_jaxpr(
+        grads(ra.checkpoint_keeping_attention))(*args))
+    assert calls["hvd_flash_fwd"] == 2
+
+
+def _without_policy(jaxpr) -> str:
+    """A jaxpr's text less the checkpoint equations' ``policy=`` parameter,
+    which prints the policy function's address."""
+    return "\n".join(ln for ln in str(jaxpr).splitlines()
+                     if "policy=" not in ln)
+
+
+@pytest.mark.parametrize("path", ["xla", "ring"])
+def test_a_path_that_sets_no_names_is_recomputed_whole_as_before(
+        path, sp_mesh):
+    """The XLA attention and ``_ring_flash`` (a custom VJP of its own over
+    ``flash_attention_with_lse``) name nothing: under the keeping checkpoint
+    their gradient traces to the bare checkpoint's program."""
+    if path == "xla":
+        def attention(q, k, v):
+            return ra.full_attention(q, k, v, causal=True, use_flash=False)
+    else:
+        attention = shard_map(
+            lambda q, k, v: ra.ring_attention(q, k, v, "sp", causal=True,
+                                              use_flash=True, interpret=True),
+            mesh=sp_mesh, in_specs=(P(None, "sp"),) * 3,
+            out_specs=P(None, "sp"), check_vma=False)
+
+    grads, args = _scanned_layers(2, 32, jnp.float32, attention)
+    kept = jax.make_jaxpr(grads(ra.checkpoint_keeping_attention))(*args)
+    bare = jax.make_jaxpr(grads(jax.checkpoint))(*args)
+    assert fa.SAVED_OUT not in str(kept) and fa.SAVED_LSE not in str(kept)
+    assert _without_policy(kept) == _without_policy(bare)
+    if path == "ring":
+        # Forward and recompute, four ring steps each, unrolled.
+        assert kernel_calls(kept) == kernel_calls(bare)
+        assert kernel_calls(kept)["hvd_flash_fwd"] > 1

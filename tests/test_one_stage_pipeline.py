@@ -37,7 +37,6 @@ from benchmark.trace.scopes import KERNELS        # noqa: E402
 from test_benchmark_compile_v5e import GIB, topo  # noqa: E402,F401
 from test_benchmark_compile_v5e_names import (    # noqa: E402,F401
     KERNEL, compiled_cells)
-from test_step_scopes import interpreted_kernels  # noqa: E402,F401
 
 CFG = tfm.TransformerConfig(
     vocab_size=64, d_model=32, n_heads=2, d_ff=64, n_layers=2, seq_len=128,
@@ -236,9 +235,9 @@ def test_flagship_loss_and_gradients_equal_the_parents(
 
 @pytest.mark.parametrize("workload, max_gib", [
     ("flagship-s8192-train-1chip", 12.5),
-    ("flagship-s8192-train-dp2mp2", 12.8),
+    ("flagship-s8192-train-dp2mp2", 13.8),
 ])
-def test_flagship_cell_compiles_with_two_forwards_and_no_permute(
+def test_flagship_cell_compiles_with_one_forward_and_no_permute(
         compiled_cells, workload, max_gib):
     hlo, mem = compiled_cells(workload)
     names = KERNEL.findall(hlo)
@@ -247,11 +246,13 @@ def test_flagship_cell_compiles_with_two_forwards_and_no_permute(
     print(f"{workload}: kernel instructions {count}, arguments "
           f"{mem.argument_size_in_bytes / GIB:.2f} temporaries "
           f"{mem.temp_size_in_bytes / GIB:.2f} peak {peak:.2f} GiB per device")
-    # The forward and one recompute a layer in the backward loop; the third,
-    # the recompute of the whole stage, is gone.
-    assert count == {"hvd_flash_fwd": 2, "hvd_flash_bwd_dq": 1,
+    # The forward alone: the recompute of the whole stage went with the
+    # stage checkpoint (PR 25), the layer's recompute of the kernel with the
+    # layer checkpoint keeping its output and lse (PR 32; the twelve saved
+    # (B, S, H·D) stacks are the four-chip cell's 12.47 -> 13.45 GiB).
+    assert count == {"hvd_flash_fwd": 1, "hvd_flash_bwd_dq": 1,
                      "hvd_flash_bwd_dkv": 1}, names
-    assert len(names) == 4, names
+    assert len(names) == 3, names
     assert " collective-permute(" not in hlo
     assert " collective-permute-start(" not in hlo
     assert peak <= max_gib

@@ -13,12 +13,11 @@ import optax
 import pytest
 
 import horovod_tpu as hvd
+from _flash_kernels import KERNELS
 from horovod_tpu.models import bert, transformer as tfm
-from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel.mesh import create_mesh
 from horovod_tpu.utils import profiler
 
-KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
 TFM_CFG = tfm.TransformerConfig(
     vocab_size=64, d_model=32, n_heads=2, d_ff=64, n_layers=2, seq_len=128,
     dtype=jnp.float32, remat=True)
@@ -26,18 +25,6 @@ TFM_PAR = tfm.ParallelConfig(dp=2, pp=1, mp=2, n_microbatches=1)
 BERT_CFG = bert.BertConfig(
     vocab_size=64, d_model=32, n_heads=2, d_ff=64, n_layers=2, seq_len=128,
     dtype=jnp.float32, remat=True)
-
-
-@pytest.fixture
-def interpreted_kernels(monkeypatch):
-    """Off the chip the dispatch takes the XLA branch and a Mosaic kernel
-    cannot run: ask for the kernels and run them in the Pallas interpreter.
-    Steering in the test, no option of the program."""
-    monkeypatch.setenv("HVD_TPU_FLASH", "1")
-    real = fa.flash_attention
-    monkeypatch.setattr(
-        fa, "flash_attention",
-        lambda *a, **kw: real(*a, **kw, interpret=True))
 
 
 def transformer_step():
