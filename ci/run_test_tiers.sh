@@ -43,7 +43,17 @@ TIER_FAST=(
   test_collectives.py test_data_pipeline.py test_debug_flight.py
   test_dispatch.py
   test_flash_attention.py
+  # The flash kernels with a sliding window (ISSUE 33): forward and the
+  # three gradients against the reference's mask, grids that walk the band
+  # only, the tile rule, the callers' window arguments.
+  test_flash_attention_window.py
   test_fleet.py
+  # Laguna's mix of windowed and full attention on the training path
+  # (ISSUE 33): YaRN frequencies and the half-head rotation by hand, the
+  # attention blocks, gate and dense MLP against the reference's equations,
+  # the shares of heads and experts summing to the whole layer, leading
+  # blocks, and what the new fields' defaults leave as it was.
+  test_laguna_layers.py
   test_launch_flags.py
   test_metrics.py
   # Third mesh dimensions (ISSUE 16): MoE routing/capacity goldens, the

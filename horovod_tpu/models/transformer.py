@@ -29,8 +29,13 @@ several kinds, one mixer each — ``M`` a Mamba-2 state-space mixer
 (ops/ssd.py), ``E`` an expert MLP, ``*`` grouped-query attention — with
 ``n_experts_held`` (a share of the routed experts), a sigmoid router,
 experts in a latent space and a shared expert: Nemotron 3's hybrid
-(``nemotron_h``) as one rank of its deployment, on ``dp`` alone.  The
-serving entry points below cover learned positions only.
+(``nemotron_h``) as one rank of its deployment, on ``dp`` alone.  Two more
+letters — ``W`` sliding-window attention with its own head count and rotary
+base, ``D`` a gated dense MLP — with ``leading_pattern`` (blocks that run
+once, in front of the scanned periods), rotary positions on a share of the
+head with YaRN-scaled frequencies and a per-head output gate give Laguna's
+mix of windowed and full attention (``laguna``).  The serving entry points
+below cover learned positions only.
 
 Compute dtype defaults to bfloat16 (MXU-native); normalization, softmax and
 loss accumulate in fp32.
@@ -38,6 +43,7 @@ loss accumulate in fp32.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -54,7 +60,7 @@ from ..parallel import moe as moe_lib
 from ..parallel import pipeline as pp_lib
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
-from ..utils.profiler import scope
+from ..utils.profiler import DENSE_MLP_SCOPE, scope
 
 
 class TransformerConfig(NamedTuple):
@@ -108,6 +114,25 @@ class TransformerConfig(NamedTuple):
     moe_latent: int = 0           # > 0: experts work in a latent space
     shared_expert_ff: int = 0     # > 0: an expert every token takes
     expert_activation: Optional[str] = None  # None → silu gated, else gelu
+    # Blocks that run once, in front of the scanned periods (letters as
+    # ``layer_pattern``'s, but for "E"): a model's leading dense layers.
+    # They count in ``n_layers``.
+    leading_pattern: str = ""
+    # "W" blocks: "*" with ``attn_window`` keys a query (its own included),
+    # ``window_heads`` query heads (None → n_heads) over the same
+    # ``n_kv_heads``, and rotary positions at ``window_rope_theta`` on the
+    # whole head (None → none).
+    attn_window: Optional[int] = None
+    window_heads: Optional[int] = None
+    window_rope_theta: Optional[float] = None
+    # "*" blocks of a pattern rotate at ``rope_theta``: the first
+    # ``rope_fraction`` of each head, the rest passing; ``rope_yarn`` =
+    # (factor, original positions, beta_fast, beta_slow, attention factor)
+    # scales the frequencies as HF ``_compute_yarn_parameters`` does.
+    rope_fraction: float = 1.0
+    rope_yarn: Optional[Tuple[float, int, float, float, float]] = None
+    attn_gate: bool = False       # "*" / "W": head i's output x sigmoid(h Wg)_i
+    dense_ff: int = 0             # "D" blocks: (silu(h W1) * h W3) W2
 
     @property
     def head_dim(self) -> int:
@@ -144,7 +169,8 @@ def _has_pos_table(cfg: TransformerConfig) -> bool:
 
 # A pattern's letters, the key of each kind's parameters under ``layers``
 # and the step scope its blocks run under.
-BLOCK_KINDS = {"M": ("ssm", "ssm"), "E": ("moe", "mlp"), "*": ("attn", "attn")}
+BLOCK_KINDS = {"M": ("ssm", "ssm"), "E": ("moe", "mlp"), "*": ("attn", "attn"),
+               "W": ("swa", "attn"), "D": ("dense", "mlp")}
 _ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
                 "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
@@ -152,37 +178,59 @@ _ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
 def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
     """What a patterned or share-holding model runs on: ``dp`` alone."""
     if cfg.layer_pattern is not None:
-        bad = set(cfg.layer_pattern) - set(BLOCK_KINDS)
+        letters = cfg.leading_pattern + cfg.layer_pattern
+        bad = set(letters) - set(BLOCK_KINDS)
         if bad or not cfg.layer_pattern:
             raise ValueError(
-                f"layer_pattern {cfg.layer_pattern!r}: letters are "
-                f"{sorted(BLOCK_KINDS)}")
-        if cfg.n_layers % len(cfg.layer_pattern):
+                f"layer_pattern {cfg.layer_pattern!r} after leading_pattern "
+                f"{cfg.leading_pattern!r}: letters are {sorted(BLOCK_KINDS)}")
+        if (cfg.n_layers - len(cfg.leading_pattern)) % len(cfg.layer_pattern):
             raise ValueError(
-                f"n_layers {cfg.n_layers} is not a multiple of the pattern's "
-                f"{len(cfg.layer_pattern)} blocks")
+                f"n_layers {cfg.n_layers} is not {len(cfg.leading_pattern)} "
+                f"leading blocks and a multiple of the pattern's "
+                f"{len(cfg.layer_pattern)}")
+        if "E" in cfg.leading_pattern:
+            raise NotImplementedError(
+                'an "E" block cannot lead: the router statistics are stacked '
+                "by period")
         if ("E" in cfg.layer_pattern) != _routes_dropless(cfg):
             raise ValueError('an "E" block is a dropless expert MLP: '
                              "n_experts and dropless go with it")
-        if "M" in cfg.layer_pattern and (
+        if "M" in letters and (
                 cfg.ssm_heads < 1 or cfg.ssm_heads % cfg.ssm_groups):
             raise ValueError(f"ssm_heads {cfg.ssm_heads} do not divide into "
                              f"ssm_groups {cfg.ssm_groups}")
-        if cfg.rope_theta is not None or cfg.qk_norm:
+        if cfg.qk_norm:
             raise NotImplementedError(
-                "a patterned model's attention blocks take no rotary "
-                "position and no QK-norm yet (ROADMAP M4)")
-        if cfg.n_heads % (cfg.n_kv_heads or cfg.n_heads):
-            raise ValueError(f"n_heads {cfg.n_heads} is not a multiple of "
-                             f"n_kv_heads {cfg.n_kv_heads}")
+                "a patterned model's attention blocks take rotary positions "
+                "(rope_theta, window_rope_theta) and no QK-norm yet")
+        if "W" in letters and not cfg.attn_window:
+            raise ValueError('a "W" block is sliding-window attention: '
+                             "attn_window goes with it")
+        if "D" in letters and not cfg.dense_ff:
+            raise ValueError('a "D" block is a gated dense MLP: dense_ff '
+                             "goes with it")
+        hkv = cfg.n_kv_heads or cfg.n_heads
+        if cfg.n_heads % hkv or (cfg.window_heads or hkv) % hkv:
+            raise ValueError(
+                f"n_heads {cfg.n_heads} and window_heads {cfg.window_heads} "
+                f"are not multiples of n_kv_heads {cfg.n_kv_heads}")
+        if not 0.0 < cfg.rope_fraction <= 1.0 or \
+                cfg.head_dim * cfg.rope_fraction % 2:
+            raise ValueError(f"rope_fraction {cfg.rope_fraction} of a head "
+                             f"of {cfg.head_dim} is not a whole even share")
         if par.mp > 1 or par.pp > 1 or par.pp_schedule != "gpipe":
             raise NotImplementedError(
                 "a model with a layer_pattern runs on dp alone: its mixers "
                 "are neither sharded over mp nor staged over pp (ROADMAP M7)")
     elif cfg.n_kv_heads not in (None, cfg.n_heads) or cfg.moe_latent \
-            or cfg.shared_expert_ff:
-        raise ValueError("n_kv_heads, moe_latent and shared_expert_ff are a "
-                         "patterned model's: set layer_pattern")
+            or cfg.shared_expert_ff or cfg.leading_pattern \
+            or cfg.attn_window or cfg.attn_gate or cfg.dense_ff \
+            or cfg.rope_yarn or cfg.rope_fraction != 1.0:
+        raise ValueError(
+            "n_kv_heads, moe_latent, shared_expert_ff, leading_pattern, "
+            "attn_window, attn_gate, dense_ff, rope_yarn and rope_fraction "
+            "are a patterned model's: set layer_pattern")
     if _holds_a_share(cfg) and (par.mp > 1 or par.pp > 1):
         raise NotImplementedError(
             f"a layer that holds {_experts_held(cfg)} of {cfg.n_experts} "
@@ -264,41 +312,53 @@ def init_params(key, cfg: TransformerConfig,
     return params
 
 
-def pattern_counts(cfg: TransformerConfig) -> Dict[str, int]:
-    """{kind: its blocks in one period} for the kinds the pattern has."""
-    return {BLOCK_KINDS[c][0]: cfg.layer_pattern.count(c)
-            for c in BLOCK_KINDS if c in cfg.layer_pattern}
+def pattern_counts(cfg: TransformerConfig, leading: bool = False
+                   ) -> Dict[str, int]:
+    """{kind: its blocks in one period} for the kinds the pattern has;
+    ``leading``: {kind: its blocks} of ``leading_pattern``."""
+    pattern = cfg.leading_pattern if leading else cfg.layer_pattern
+    return {BLOCK_KINDS[c][0]: pattern.count(c)
+            for c in BLOCK_KINDS if c in pattern}
+
+
+def _n_periods(cfg: TransformerConfig) -> int:
+    return (cfg.n_layers - len(cfg.leading_pattern)) // len(cfg.layer_pattern)
 
 
 def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
     """A patterned model's blocks, stacked by kind: every leaf is (1 stage,
-    periods, blocks of the kind in a period, ...).  Weights as the block
-    above's (normal 0.02, the projections that write the residual scaled by
-    1 / sqrt(2 n_layers)) except what a state-space scan's behaviour hangs
-    on, by Mamba-2's published scheme: ``dt_bias`` the inverse softplus of a
-    log-uniform draw in ``ssm_dt_range``, ``a_log = log U(1, 16)``, ``d_skip``
-    1, and the depthwise conv U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it."""
+    periods, blocks of the kind in a period, ...); under ``leading`` the
+    blocks of ``leading_pattern`` by kind, (1 stage, blocks of the kind,
+    ...).  Weights as the block above's (normal 0.02, the projections that
+    write the residual scaled by 1 / sqrt(2 n_layers)) except what a
+    state-space scan's behaviour hangs on, by Mamba-2's published scheme:
+    ``dt_bias`` the inverse softplus of a log-uniform draw in
+    ``ssm_dt_range``, ``a_log = log U(1, 16)``, ``d_skip`` 1, and the
+    depthwise conv U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it."""
     d, std = cfg.d_model, 0.02
-    periods = cfg.n_layers // len(cfg.layer_pattern)
     out_scale = std / math.sqrt(2 * cfg.n_layers)
-    keys = iter(_split(key, 24))
-    layers: Dict[str, Any] = {}
 
-    def stacked(n):
+    def key_stream():
+        # 24 at a time, the first 24 as they always were drawn.
+        n = 0
+        while True:
+            yield from _split(jax.random.fold_in(key, n) if n else key, 24)
+            n += 1
+
+    keys = key_stream()
+
+    def init_kind(kind, lead):
         def ones(*shape):
-            return jnp.ones((1, periods, n) + shape, jnp.float32)
+            return jnp.ones(lead + shape, jnp.float32)
 
         def rand(*shape, scale=std):
-            return (jax.random.normal(next(keys), (1, periods, n) + shape)
+            return (jax.random.normal(next(keys), lead + shape)
                     * scale).astype(jnp.float32)
 
         def uniform(*shape, lo, hi):
-            return jax.random.uniform(next(keys), (1, periods, n) + shape,
+            return jax.random.uniform(next(keys), lead + shape,
                                       jnp.float32, lo, hi)
-        return ones, rand, uniform
 
-    for kind, n in pattern_counts(cfg).items():
-        ones, rand, uniform = stacked(n)
         if kind == "ssm":
             h, hp = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
             conv = hp + 2 * cfg.ssm_groups * cfg.ssm_state
@@ -306,7 +366,7 @@ def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
             dt = jnp.maximum(jnp.exp(uniform(h, lo=math.log(dt_min),
                                              hi=math.log(dt_max))), dt_floor)
             bound = 1.0 / math.sqrt(cfg.ssm_conv)
-            layers[kind] = {
+            return {
                 "ln": ones(d),
                 # columns [z | x | B | C | dt]: z and x head-major (H, P),
                 # B and C group-major (G, N), dt a head.
@@ -319,36 +379,56 @@ def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
                 "norm": ones(hp),
                 "w_out": rand(hp, d, scale=out_scale),
             }
-        elif kind == "attn":
-            hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, \
-                cfg.head_dim
-            layers[kind] = {
+        if kind in ("attn", "swa"):
+            hq, hkv, hd = _attn_heads(cfg, kind), \
+                cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+            blk = {
                 "ln": ones(d), "wq": rand(d, hq * hd),
                 "wk": rand(d, hkv * hd), "wv": rand(d, hkv * hd),
                 "wo": rand(hq * hd, d, scale=out_scale),
             }
-        else:
-            e, held, ff = cfg.n_experts, _experts_held(cfg), cfg.d_ff
-            width = cfg.moe_latent or d
-            blk = {"ln": ones(d), "gate": rand(d, e)}
-            if cfg.router_scoring == "sigmoid":
-                # The choice's correction bias: a buffer, zero until a
-                # trainer's balancing rule moves it; outside the gradient.
-                blk["router_bias"] = 0.0 * ones(e)
-            if cfg.moe_latent:
-                blk["w_latent_in"] = rand(d, width)
-                blk["w_latent_out"] = rand(width, d, scale=out_scale)
+            if cfg.attn_gate:
+                blk["w_head_gate"] = rand(d, hq)
+            return blk
+        if kind == "dense":
+            return {"ln": ones(d), "w_gate": rand(d, cfg.dense_ff),
+                    "w_up": rand(d, cfg.dense_ff),
+                    "w_down": rand(cfg.dense_ff, d, scale=out_scale)}
+        e, held, ff = cfg.n_experts, _experts_held(cfg), cfg.d_ff
+        width = cfg.moe_latent or d
+        blk = {"ln": ones(d), "gate": rand(d, e)}
+        if cfg.router_scoring == "sigmoid":
+            # The choice's correction bias: a buffer, zero until a
+            # trainer's balancing rule moves it; outside the gradient.
+            blk["router_bias"] = 0.0 * ones(e)
+        if cfg.moe_latent:
+            blk["w_latent_in"] = rand(d, width)
+            blk["w_latent_out"] = rand(width, d, scale=out_scale)
+        if cfg.gated_experts:
+            blk["w_gate"] = rand(held, width, ff)
+        blk["w_up"] = rand(held, width, ff)
+        blk["w_down"] = rand(held, ff, width,
+                             scale=std if cfg.moe_latent else out_scale)
+        if cfg.shared_expert_ff:
             if cfg.gated_experts:
-                blk["w_gate"] = rand(held, width, ff)
-            blk["w_up"] = rand(held, width, ff)
-            blk["w_down"] = rand(held, ff, width,
-                                 scale=std if cfg.moe_latent else out_scale)
-            if cfg.shared_expert_ff:
-                blk["shared_up"] = rand(d, cfg.shared_expert_ff)
-                blk["shared_down"] = rand(cfg.shared_expert_ff, d,
-                                          scale=out_scale)
-            layers[kind] = blk
+                blk["shared_gate"] = rand(d, cfg.shared_expert_ff)
+            blk["shared_up"] = rand(d, cfg.shared_expert_ff)
+            blk["shared_down"] = rand(cfg.shared_expert_ff, d,
+                                      scale=out_scale)
+        return blk
+
+    layers = {kind: init_kind(kind, (1, _n_periods(cfg), n))
+              for kind, n in pattern_counts(cfg).items()}
+    if cfg.leading_pattern:
+        layers["leading"] = {
+            kind: init_kind(kind, (1, n))
+            for kind, n in pattern_counts(cfg, leading=True).items()}
     return layers
+
+
+def _attn_heads(cfg: TransformerConfig, kind: str) -> int:
+    """Query heads of an attention block of ``kind`` ("attn" | "swa")."""
+    return (cfg.window_heads or cfg.n_heads) if kind == "swa" else cfg.n_heads
 
 
 def param_specs(cfg: TransformerConfig, par: ParallelConfig) -> Dict[str, Any]:
@@ -423,20 +503,55 @@ def _qk_norm(t, scale, eps: float, axis_name: Optional[str]):
             * scale.reshape(t.shape[-2:])).astype(t.dtype)
 
 
-def _rope(t, positions, theta: float):
-    """Rotate-half rotary embedding (HF ``apply_rotary_pos_emb``): with
-    ``t = [t1, t2]`` split at half the head, ``[t1 cos - t2 sin, t2 cos +
-    t1 sin]`` at angle ``position * theta^(-2i/hd)``, in fp32.  ``t``: (mb,
-    S, heads, hd); ``positions``: (S,) global token positions."""
-    half = t.shape[-1] // 2
-    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+def _yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """The ``dim // 2`` rotary frequencies ``theta^(-2i/dim)`` scaled as HF
+    ``_compute_yarn_parameters`` scales them, ``yarn`` = (factor, original
+    positions, beta_fast, beta_slow, _): a frequency that turns more than
+    ``beta_fast`` times over the original context stays, one that turns
+    less than ``beta_slow`` times is divided by ``factor``, and between the
+    two correction dimensions (floored, ceiled) a linear ramp blends them."""
+    factor, original, beta_fast, beta_slow = yarn[:4]
+    inv_freq = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+            ).astype(np.float32)
+
+
+def _rope(t, positions, theta: float, fraction: float = 1.0, yarn=None):
+    """Rotate-half rotary embedding (HF ``apply_rotary_pos_emb``) on the
+    first ``fraction`` of each head, the rest passing: with the rotary part
+    ``[t1, t2]`` split at its half, ``[t1 cos - t2 sin, t2 cos + t1 sin]``
+    at angle ``position * theta^(-2i/rot)``, in fp32; with ``yarn`` the
+    frequencies are :func:`_yarn_inv_freq`'s and cos and sin are multiplied
+    by its attention factor.  ``t``: (mb, S, heads, hd); ``positions``:
+    (S,) global token positions."""
+    rot = int(t.shape[-1] * fraction)
+    half = rot // 2
+    if yarn is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                    / half))
+    else:
+        inv_freq = jnp.asarray(_yarn_inv_freq(rot, theta, yarn))
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     tf = t.astype(jnp.float32)
-    t1, t2 = tf[..., :half], tf[..., half:]
-    return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
-                           axis=-1).astype(t.dtype)
+    t1, t2 = tf[..., :half], tf[..., half:rot]
+    parts = [t1 * cos - t2 * sin, t2 * cos + t1 * sin]
+    if rot < t.shape[-1]:
+        parts.append(tf[..., rot:])
+    return jnp.concatenate(parts, axis=-1).astype(t.dtype)
 
 
 def _position_qk(cfg: TransformerConfig, lp, q, k, positions, axis_name):
@@ -563,13 +678,22 @@ def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
 
 
 def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
-               x: jax.Array) -> jax.Array:
+               x: jax.Array, kind: str = "attn") -> jax.Array:
     """Causal attention with ``n_kv_heads`` key / value heads, query head i
-    reading head i // (n_heads / n_kv_heads), no position encoding.  Each
-    K / V head is repeated across its query heads before the kernels (their
-    index maps taking several query heads a K / V block is ROADMAP M4)."""
+    reading head i // (heads / n_kv_heads).  A "*" block (``kind`` "attn")
+    sees every earlier key and rotates q and k at ``rope_theta`` (None:
+    no position encoding) on ``rope_fraction`` of the head, ``rope_yarn``
+    scaling the frequencies; a "W" block ("swa") has ``window_heads`` query
+    heads, sees ``attn_window`` keys and rotates the whole head at
+    ``window_rope_theta``.  With ``attn_gate`` head i's output is multiplied
+    by ``sigmoid(h Wg)_i``, a scalar a head and token from the block's
+    normed input.  Each K / V head is repeated across its query heads
+    before the kernels (their index maps taking several query heads a K / V
+    block is ROADMAP M4)."""
     mb, s, _ = x.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+    windowed = kind == "swa"
+    hq, hkv, hd = (_attn_heads(cfg, kind), cfg.n_kv_heads or cfg.n_heads,
+                   cfg.head_dim)
     hnorm = _rmsnorm(x, lp["ln"], cfg.norm_eps)
 
     def heads(w, n):
@@ -577,11 +701,39 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                           w.astype(x.dtype)).reshape(mb, s, n, hd)
 
     q, k, v = heads(lp["wq"], hq), heads(lp["wk"], hkv), heads(lp["wv"], hkv)
+    theta, fraction, yarn = (
+        (cfg.window_rope_theta, 1.0, None) if windowed
+        else (cfg.rope_theta, cfg.rope_fraction, cfg.rope_yarn))
+    if theta is not None:
+        with scope("attn_rope"):
+            q, k = (_rope(t, jnp.arange(s), theta, fraction, yarn)
+                    for t in (q, k))
     if hkv != hq:
         k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
-    o = ra.full_attention(q, k, v, causal=True)
+    o = ra.full_attention(q, k, v, causal=True,
+                          window=cfg.attn_window if windowed else None)
+    if cfg.attn_gate:
+        with scope("attn_gate"):
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", hnorm, lp["w_head_gate"].astype(x.dtype)
+            ).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
     return jnp.einsum("bse,ed->bsd", o.reshape(mb, s, hq * hd),
                       lp["wo"].astype(x.dtype))
+
+
+def _dense_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
+                 x: jax.Array) -> jax.Array:
+    """A "D" block: the gated MLP ``(silu(h W1) * h W3) W2`` at
+    ``dense_ff`` on the normed stream, silu and the product in fp32."""
+    hnorm = _rmsnorm(x, lp["ln"], cfg.norm_eps)
+    with scope(DENSE_MLP_SCOPE):
+        def up(w):
+            return jnp.einsum("bsd,df->bsf", hnorm,
+                              w.astype(x.dtype)).astype(jnp.float32)
+        hidden = jax.nn.silu(up(lp["w_gate"])) * up(lp["w_up"])
+        return jnp.einsum("bsf,fd->bsd", hidden.astype(x.dtype),
+                          lp["w_down"].astype(x.dtype))
 
 
 def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
@@ -589,7 +741,8 @@ def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     """(An "E" block's output, its ``moe.RouterStats``): the routed experts
     on the normed stream, or on its projection into ``moe_latent`` features
     with the router still reading the stream (LatentMoE), plus the shared
-    expert's ``act(h V1) V2`` on the stream."""
+    expert on the stream: ``act(h V1) V2``, or with ``gated_experts``
+    ``(act(h Vg) * h V1) V2`` as the routed experts are."""
     mb, s, d = x.shape
     tok = _rmsnorm(x, lp["ln"], cfg.norm_eps).reshape(mb * s, d)
     if cfg.moe_latent:
@@ -602,22 +755,28 @@ def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         y, stats = _route_experts(cfg, lp, tok)
     if cfg.shared_expert_ff:
         with scope("moe_shared"):
+            def up(w):
+                return jnp.dot(tok, w.astype(x.dtype)).astype(jnp.float32)
             hidden = _expert_activation(cfg)(
-                jnp.dot(tok, lp["shared_up"].astype(x.dtype)
-                        ).astype(jnp.float32))
+                up(lp["shared_gate" if cfg.gated_experts else "shared_up"]))
+            if cfg.gated_experts:
+                hidden = hidden * up(lp["shared_up"])
             y = y + jnp.dot(hidden.astype(x.dtype),
                             lp["shared_down"].astype(x.dtype))
     return y.reshape(mb, s, d), stats
 
 
 def _make_pattern_stage_fn(cfg: TransformerConfig):
-    """stage_fn(stage_params, act) for a ``layer_pattern``: a scan over the
-    periods, inside one period its blocks in the pattern's order, each
-    under its step scope and (``cfg.remat``) its own checkpoint, which
-    keeps a "*" block's flash forward output and lse.  Returns
-    the activation and, where the pattern routes, the "E" blocks'
-    ``moe.RouterStats`` stacked (periods, blocks a period, ...)."""
-    mixers = {"ssm": _ssm_mixer, "attn": _gqa_mixer, "moe": _expert_mixer}
+    """stage_fn(stage_params, act) for a ``layer_pattern``: the blocks of
+    ``leading_pattern`` once, then a scan over the periods, inside one
+    period its blocks in the pattern's order, each under its step scope and
+    (``cfg.remat``) its own checkpoint, which keeps a "*" or "W" block's
+    flash forward output and lse.  Returns the activation and, where the
+    pattern routes, the "E" blocks' ``moe.RouterStats`` stacked (periods,
+    blocks a period, ...)."""
+    mixers = {"ssm": _ssm_mixer, "attn": _gqa_mixer, "moe": _expert_mixer,
+              "swa": functools.partial(_gqa_mixer, kind="swa"),
+              "dense": _dense_mixer}
     with_stats = "E" in cfg.layer_pattern
 
     def block(kind, scope_name):
@@ -629,25 +788,38 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
         return ra.checkpoint_keeping_attention(run) if cfg.remat else run
 
     blocks = {kind: block(kind, name) for kind, name in BLOCK_KINDS.values()}
-    # The period as (kind, which of the kind's blocks in a period).
-    order, seen = [], {}
-    for letter in cfg.layer_pattern:
-        kind = BLOCK_KINDS[letter][0]
-        order.append((kind, seen.get(kind, 0)))
-        seen[kind] = order[-1][1] + 1
 
-    def period_fn(act, period_params):
+    def in_order(pattern):
+        """(kind, which of the kind's blocks) for each letter."""
+        order, seen = [], {}
+        for letter in pattern:
+            kind = BLOCK_KINDS[letter][0]
+            order.append((kind, seen.get(kind, 0)))
+            seen[kind] = order[-1][1] + 1
+        return order
+
+    def run_blocks(order, act, params):
         stats = []
         for kind, j in order:
-            lp = jax.tree_util.tree_map(lambda a: a[j], period_params[kind])
+            lp = jax.tree_util.tree_map(lambda a: a[j], params[kind])
             act, st = blocks[kind](act, lp)
             if st is not None:
                 stats.append(st)
+        return act, stats
+
+    leading, period = in_order(cfg.leading_pattern), in_order(
+        cfg.layer_pattern)
+
+    def period_fn(act, period_params):
+        act, stats = run_blocks(period, act, period_params)
         if not stats:
             return act, None
         return act, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
 
     def stage_fn(stage_params, act):
+        if leading:
+            stage_params = dict(stage_params)
+            act, _ = run_blocks(leading, act, stage_params.pop("leading"))
         out, stats = lax.scan(period_fn, act, stage_params)
         return (out, stats) if with_stats else out
 
@@ -1193,8 +1365,9 @@ def _mlp_flops_per_token(cfg: TransformerConfig) -> float:
 
 def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
     """Forward matmul-FLOPs a token of one patterned block, as this device
-    computes it: the heads and experts it holds, causal scores halved, the
-    scan as the chunked algorithm's four products."""
+    computes it: the heads and experts it holds, causal scores halved, a
+    window's over its band, the scan as the chunked algorithm's four
+    products."""
     d, s = cfg.d_model, cfg.seq_len
     if letter == "M":
         h, p, g, n, q = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
@@ -1203,16 +1376,25 @@ def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
         conv = 2.0 * cfg.ssm_conv * (h * p + 2 * g * n)
         scan = 2.0 * q * n * g + 2.0 * q * p * h + 4.0 * p * n * h
         return proj + conv + scan
-    if letter == "*":
-        hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
-        return 2.0 * d * hd * (2 * hq + 2 * hkv) + 2.0 * s * hq * hd
+    if letter in "*W":
+        hq = _attn_heads(cfg, BLOCK_KINDS[letter][0])
+        hkv, hd = cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+        # (query, key) pairs a query: the causal half, or the band's
+        # window S - window (window - 1) / 2 pairs a sequence.
+        w = min(cfg.attn_window, s) if letter == "W" else 0
+        pairs = w - w * (w - 1) / (2.0 * s) if w else s / 2.0
+        gate = 2.0 * d * hq if cfg.attn_gate else 0.0
+        return (2.0 * d * hd * (2 * hq + 2 * hkv) + gate
+                + 4.0 * pairs * hq * hd)
+    if letter == "D":
+        return 6.0 * d * cfg.dense_ff
     width = cfg.moe_latent or d
     mats = 3.0 if cfg.gated_experts else 2.0
     routed = (cfg.top_k * _experts_held(cfg) / cfg.n_experts
               * mats * 2.0 * width * cfg.d_ff)
     latent = 4.0 * d * width if cfg.moe_latent else 0.0
     return (2.0 * d * cfg.n_experts + latent + routed
-            + 4.0 * d * cfg.shared_expert_ff)
+            + mats * 2.0 * d * cfg.shared_expert_ff)
 
 
 def train_flops_per_seq(cfg: TransformerConfig) -> float:
@@ -1227,8 +1409,9 @@ def train_flops_per_seq(cfg: TransformerConfig) -> float:
                   cfg.vocab_size)
     # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no pattern.
     if getattr(cfg, "layer_pattern", None) is not None:
-        return 3.0 * s * (2.0 * d * v + (L // len(cfg.layer_pattern)) * sum(
-            _block_flops_per_token(cfg, c) for c in cfg.layer_pattern))
+        blocks = cfg.leading_pattern + _n_periods(cfg) * cfg.layer_pattern
+        return 3.0 * s * (2.0 * d * v + sum(
+            _block_flops_per_token(cfg, c) for c in blocks))
     dense = s * (L * (8.0 * d * d + _mlp_flops_per_token(cfg)) + 2.0 * d * v)
     attn = L * 2.0 * s * s * d
     return 3.0 * (dense + attn)

@@ -59,6 +59,15 @@ causal, ms a call (PERF.md section 6, PR 29):
 inside Mosaic's default 16 MiB of scoped VMEM — for heads up to 256 bytes a
 row (bf16 128, fp32 64); wider heads keep 512 (``_WIDE_BLOCK_ROW_BYTES``).
 
+A call with a ``window`` (each query sees the ``window`` keys up to and
+including its own) runs the same three bodies over a grid that walks the
+band only: the streamed axis has ``_band_extent`` steps a resident tile
+whatever the sequence length (two for a window and tiles of 512), its
+index map offset from the resident tile's index and clamped at the
+sequence's ends.  Its kernels are named ``hvd_flash_fwd_win``,
+``hvd_flash_bwd_dq_win`` and ``hvd_flash_bwd_dkv_win``; a call without a
+window traces to what it always did.
+
 Three kernels:
 
 * ``_fwd_kernel``      — out + logsumexp, online softmax over K/V tiles.
@@ -67,7 +76,8 @@ Three kernels:
 
 Public API:
 
-* ``flash_attention(q, k, v, causal=…)`` — differentiable (custom VJP).
+* ``flash_attention(q, k, v, causal=…, window=…)`` — differentiable (custom
+  VJP).
 * ``flash_attention_with_lse`` — also returns logsumexp rows, which is the
   composition hook ring attention (parallel/ring_attention.py) uses to
   merge per-ring-step partials into an exact global softmax.
@@ -158,6 +168,13 @@ def _pick_block(size: int, widest: int, env: str = "") -> Optional[int]:
     return size if size <= _NARROW_BLOCK else None
 
 
+def _win(window: Optional[int]) -> str:
+    """A windowed call's kernels carry their own names: the same prefixes,
+    so a reader that matches kernels by prefix counts them, and a suffix
+    that tells them apart."""
+    return "" if window is None else "_win"
+
+
 def _compiler_params(n_parallel: int):
     return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
@@ -167,26 +184,98 @@ def _compiler_params(n_parallel: int):
 # Forward kernel
 # ---------------------------------------------------------------------------
 
-def _scores_t(q, k, *, causal: bool, scale: float, q_start, k_start):
+def _scores_t(q, k, *, causal: bool, scale: float, q_start, k_start,
+              window: Optional[int] = None):
     """The transposed score tile Sᵀ = K·Qᵀ · scale, (bk, bq) fp32, from the
     caller's (bq, D) and (bk, D) tiles as they are; causally masked at the
-    tile's global positions."""
+    tile's global positions, and with a ``window`` to the band ``q_pos -
+    window < k_pos <= q_pos``."""
     st = jax.lax.dot_general(
         k, q, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     if causal:
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+        keep = q_pos >= k_pos
+        if window is not None:
+            keep = jnp.logical_and(keep, q_pos - k_pos < window)
+        st = jnp.where(keep, st, _NEG_INF)
     return st
+
+
+# ---------------------------------------------------------------------------
+# The band of a windowed call
+# ---------------------------------------------------------------------------
+#
+# With a window the streamed axis of each grid walks only the tiles the band
+# ``q_pos - window < k_pos <= q_pos`` touches: ``_band_extent`` steps a
+# resident tile, a number that depends on the two tile widths and the window
+# and not on the sequence.  Step j of resident tile i is streamed tile
+# ``_band_first(i) + j``; where that falls off either end of the sequence the
+# index map clamps it to the end's tile (the block it names is then the one
+# the neighbouring step reads, so nothing is fetched twice) and the body
+# skips the step.
+
+def _floordiv(a, b: int):
+    """a // b for a >= 0: a Python int, or a traced int32 of a grid."""
+    return a // b if isinstance(a, int) else lax.div(a, jnp.int32(b))
+
+
+def _band_first(i, block_res: int, block_str: int, window: int,
+                keys_streamed: bool):
+    """First streamed tile the band touches for resident tile ``i`` (may be
+    negative).  Keys streamed past resident queries (forward, dQ): the tile
+    of key ``i * bq - (window - 1)``.  Queries streamed past resident keys
+    (dK/dV): the tile of query ``i * bk``."""
+    if not keys_streamed:
+        return _floordiv(i * block_res, block_str)
+    back = -(-(window - 1) // block_str)        # tiles a window reaches back
+    return _floordiv(i * block_res + back * block_str - (window - 1),
+                     block_str) - back
+
+
+def _band_extent(n_res: int, block_res: int, block_str: int, window: int,
+                 keys_streamed: bool) -> int:
+    """Streamed tiles the band touches for one resident tile, at most."""
+    reach = block_res - 1 + (0 if keys_streamed else window - 1)
+    return max((i * block_res + reach) // block_str
+               - _band_first(i, block_res, block_str, window, keys_streamed)
+               + 1 for i in range(n_res))
+
+
+def _streamed_tile(i, j, n_str: int, block_res: int, block_str: int,
+                   window: Optional[int], keys_streamed: bool):
+    """(streamed tile of grid step (i, j), whether it lies in the sequence).
+    Without a window the grid walks every tile: j itself."""
+    if window is None:
+        return j, True
+    t = _band_first(i, block_res, block_str, window, keys_streamed) + j
+    return t, jnp.logical_and(t >= 0, t < n_str)
+
+
+def _tile_live(q_start, k_start, block_q: int, block_k: int, causal: bool,
+               window: Optional[int], inside):
+    """Whether a score tile has an unmasked pair.  Causal: unless every
+    (q, k) has q_pos < k_pos; with a window also unless every k lies at or
+    before q_pos - window; a step clamped at the sequence's end is none."""
+    if not causal:
+        return True
+    live = q_start + block_q - 1 >= k_start
+    if window is not None:
+        live = jnp.logical_and(
+            jnp.logical_and(live, k_start + block_k - 1 > q_start - window),
+            inside)
+    return live
 
 
 def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, window: Optional[int] = None,
+                n_str: int = 0):
     i = pl.program_id(2)          # q tile
-    j = pl.program_id(3)          # k tile (innermost: scratch carries over j)
+    j = pl.program_id(3)          # k step (innermost: scratch carries over j)
     nk = pl.num_programs(3)
+    kt, inside = _streamed_tile(i, j, n_str, block_q, block_k, window, True)
 
     @pl.when(j == 0)
     def _init():
@@ -197,10 +286,9 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     q_off = off_ref[0, 0]
     kv_off = off_ref[0, 1]
     q_start = q_off + i * block_q
-    k_start = kv_off + j * block_k
-
-    # Causal: the tile is live unless every (q, k) pair has q_pos < k_pos.
-    live = (q_start + block_q - 1 >= k_start) if causal else True
+    k_start = kv_off + kt * block_k
+    live = _tile_live(q_start, k_start, block_q, block_k, causal, window,
+                      inside)
 
     @pl.when(live)
     def _compute():
@@ -208,7 +296,7 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0, 0]                # (bk, D)
         v = v_ref[0, 0]
         st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
-                       k_start=k_start)                        # (bk, bq)
+                       k_start=k_start, window=window)         # (bk, bq)
         m_prev = m_scr[:1, :]                                  # (1, bq)
         l_prev = l_scr[:1, :]
         m_cur = jnp.max(st, axis=0, keepdims=True)
@@ -234,14 +322,32 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
+def _streamed_index(n_res: int, n_str: int, block_res: int, block_str: int,
+                    window: Optional[int], keys_streamed: bool):
+    """(steps of the streamed grid axis, grid indices (i, j) -> the streamed
+    tile's block index): every tile without a window, with one the band's
+    tiles clamped into the sequence."""
+    if window is None:
+        return n_str, lambda i, j: j
+    steps = _band_extent(n_res, block_res, block_str, window, keys_streamed)
+
+    def tile(i, j):
+        return jnp.clip(_band_first(i, block_res, block_str, window,
+                                    keys_streamed) + j, 0, n_str - 1)
+    return steps, tile
+
+
 def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
-              block_q, block_k, interpret):
+              block_q, block_k, interpret, window=None):
     b, h, sq, d = q_bhsd.shape
     sk = k_bhsd.shape[2]
     nq, nk = sq // block_q, sk // block_k
-    grid = (b, h, nq, nk)
+    steps, kt = _streamed_index(nq, nk, block_q, block_k, window, True)
+    grid = (b, h, nq, steps)
     kern = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k)
+    if window is not None:
+        kern = functools.partial(kern, window=window, n_str=nk)
     out, lse = pl.pallas_call(
         kern,
         grid=grid,
@@ -251,9 +357,9 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h, j, 0)),
+                         lambda b, h, i, j: (b, h, kt(i, j), 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h, j, 0)),
+                         lambda b, h, i, j: (b, h, kt(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
@@ -276,7 +382,7 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
         ],
         compiler_params=_compiler_params(3),
         interpret=interpret,
-        name="hvd_flash_fwd",
+        name="hvd_flash_fwd" + _win(window),
     )(offsets, q_bhsd, k_bhsd, v_bhsd)
     return out, lse[:, :, 0, :]
 
@@ -287,10 +393,12 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
 
 def _bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_scr, *, causal: bool, scale: float,
-                   block_q: int, block_k: int):
+                   block_q: int, block_k: int, window: Optional[int] = None,
+                   n_str: int = 0):
     i = pl.program_id(2)          # q tile
-    j = pl.program_id(3)          # k tile (innermost)
+    j = pl.program_id(3)          # k step (innermost)
     nk = pl.num_programs(3)
+    kt, inside = _streamed_tile(i, j, n_str, block_q, block_k, window, True)
 
     @pl.when(j == 0)
     def _init():
@@ -299,8 +407,9 @@ def _bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_off = off_ref[0, 0]
     kv_off = off_ref[0, 1]
     q_start = q_off + i * block_q
-    k_start = kv_off + j * block_k
-    live = (q_start + block_q - 1 >= k_start) if causal else True
+    k_start = kv_off + kt * block_k
+    live = _tile_live(q_start, k_start, block_q, block_k, causal, window,
+                      inside)
 
     @pl.when(live)
     def _compute():
@@ -311,7 +420,7 @@ def _bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse = lse_ref[0, 0][:1, :]                             # (1, bq)
         delta = delta_ref[0, 0][:1, :]
         st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
-                       k_start=k_start)                        # (bk, bq)
+                       k_start=k_start, window=window)         # (bk, bq)
         pt = jnp.where(jnp.logical_or(st <= _NEG_INF / 2,
                                       lse <= _NEG_INF / 2),
                        0.0, jnp.exp(st - lse))
@@ -331,10 +440,12 @@ def _bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
-                    scale: float, block_q: int, block_k: int):
+                    scale: float, block_q: int, block_k: int,
+                    window: Optional[int] = None, n_str: int = 0):
     i = pl.program_id(2)          # k tile
-    j = pl.program_id(3)          # q tile (innermost)
+    j = pl.program_id(3)          # q step (innermost)
     nq = pl.num_programs(3)
+    qt, inside = _streamed_tile(i, j, n_str, block_k, block_q, window, False)
 
     @pl.when(j == 0)
     def _init():
@@ -343,9 +454,10 @@ def _bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     q_off = off_ref[0, 0]
     kv_off = off_ref[0, 1]
-    q_start = q_off + j * block_q
+    q_start = q_off + qt * block_q
     k_start = kv_off + i * block_k
-    live = (q_start + block_q - 1 >= k_start) if causal else True
+    live = _tile_live(q_start, k_start, block_q, block_k, causal, window,
+                      inside)
 
     @pl.when(live)
     def _compute():
@@ -356,7 +468,7 @@ def _bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse = lse_ref[0, 0][:1, :]                             # (1, bq)
         delta = delta_ref[0, 0][:1, :]
         st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
-                       k_start=k_start)                        # (bk, bq)
+                       k_start=k_start, window=window)         # (bk, bq)
         pt = jnp.where(jnp.logical_or(st <= _NEG_INF / 2,
                                       lse <= _NEG_INF / 2),
                        0.0, jnp.exp(st - lse))                 # (bk, bq)
@@ -380,7 +492,7 @@ def _bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
-              causal, scale, block_q, block_k, interpret):
+              causal, scale, block_q, block_k, interpret, window=None):
     b, h, sq, d = q_bhsd.shape
     sk = k_bhsd.shape[2]
     nq, nk = sq // block_q, sk // block_k
@@ -401,16 +513,23 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
     def row_spec(ix):
         return pl.BlockSpec((1, 1, 8, block_q), ix)
 
-    # dQ: grid over (q tiles, k tiles), k innermost.
+    def kernel(body, n_str):
+        kern = functools.partial(body, causal=causal, scale=scale,
+                                 block_q=block_q, block_k=block_k)
+        if window is not None:
+            kern = functools.partial(kern, window=window, n_str=n_str)
+        return kern
+
+    # dQ: grid over (q tiles, k steps), k innermost.
+    k_steps, kt = _streamed_index(nq, nk, block_q, block_k, window, True)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k),
-        grid=(b, h, nq, nk),
+        kernel(_bwd_dq_kernel, nk),
+        grid=(b, h, nq, k_steps),
         in_specs=[
             off_spec,
             q_spec(lambda b, h, i, j: (b, h, i, 0)),
-            k_spec(lambda b, h, i, j: (b, h, j, 0)),
-            k_spec(lambda b, h, i, j: (b, h, j, 0)),
+            k_spec(lambda b, h, i, j: (b, h, kt(i, j), 0)),
+            k_spec(lambda b, h, i, j: (b, h, kt(i, j), 0)),
             q_spec(lambda b, h, i, j: (b, h, i, 0)),
             row_spec(lambda b, h, i, j: (b, h, 0, i)),
             row_spec(lambda b, h, i, j: (b, h, 0, i)),
@@ -420,22 +539,22 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
         scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         compiler_params=_compiler_params(3),
         interpret=interpret,
-        name="hvd_flash_bwd_dq",
+        name="hvd_flash_bwd_dq" + _win(window),
     )(offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
 
-    # dK/dV: grid over (k tiles, q tiles), q innermost.
+    # dK/dV: grid over (k tiles, q steps), q innermost.
+    q_steps, qt = _streamed_index(nk, nq, block_k, block_q, window, False)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k),
-        grid=(b, h, nk, nq),
+        kernel(_bwd_dkv_kernel, nq),
+        grid=(b, h, nk, q_steps),
         in_specs=[
             off_spec,
-            q_spec(lambda b, h, i, j: (b, h, j, 0)),
+            q_spec(lambda b, h, i, j: (b, h, qt(i, j), 0)),
             k_spec(lambda b, h, i, j: (b, h, i, 0)),
             k_spec(lambda b, h, i, j: (b, h, i, 0)),
-            q_spec(lambda b, h, i, j: (b, h, j, 0)),
-            row_spec(lambda b, h, i, j: (b, h, 0, j)),
-            row_spec(lambda b, h, i, j: (b, h, 0, j)),
+            q_spec(lambda b, h, i, j: (b, h, qt(i, j), 0)),
+            row_spec(lambda b, h, i, j: (b, h, 0, qt(i, j))),
+            row_spec(lambda b, h, i, j: (b, h, 0, qt(i, j))),
         ],
         out_specs=[
             k_spec(lambda b, h, i, j: (b, h, i, 0)),
@@ -451,7 +570,7 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
         ],
         compiler_params=_compiler_params(3),
         interpret=interpret,
-        name="hvd_flash_bwd_dkv",
+        name="hvd_flash_bwd_dkv" + _win(window),
     )(offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
     return dq, dk, dv
 
@@ -460,27 +579,29 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
 # Differentiable entry points (custom VJP on (B, S, H, D) layout)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, offsets, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
+           window=None):
     out, _ = _flash_impl(q, k, v, offsets, causal, scale, block_q, block_k,
-                         interpret)
+                         interpret, window)
     return out
 
 
 def _flash_impl(q, k, v, offsets, causal, scale, block_q, block_k,
-                interpret):
+                interpret, window=None):
     qt = q.transpose(0, 2, 1, 3)      # (B, H, S, D)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out, lse = _fwd_call(qt, kt, vt, offsets, causal=causal, scale=scale,
                          block_q=block_q, block_k=block_k,
-                         interpret=interpret)
+                         interpret=interpret, window=window)
     return out.transpose(0, 2, 1, 3), lse
 
 
-def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
+               window=None):
     out, lse = _flash_impl(q, k, v, offsets, causal, scale, block_q,
-                           block_k, interpret)
+                           block_k, interpret, window)
     # Named for ``checkpoint_keeping_attention``.  The primal output and the
     # residual are both the named value reshaped back: were either the
     # kernel's own output beside a named copy, the recompute would need the
@@ -492,7 +613,7 @@ def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret):
     return out, (q, k, v, offsets, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
     q, k, v, offsets, out, lse = res
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -506,7 +627,8 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
                     axis=-1).transpose(0, 2, 1)                # (B, H, Sq)
     dq, dk, dv = _bwd_call(qt, kt, vt, dot, lse, delta, offsets,
                            causal=causal, scale=scale, block_q=block_q,
-                           block_k=block_k, interpret=interpret)
+                           block_k=block_k, interpret=interpret,
+                           window=window)
     d_off = np.zeros(offsets.shape, dtype=jax.dtypes.float0)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3), d_off)
@@ -515,10 +637,12 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _supported(q, k) -> Optional[Tuple[int, int]]:
+def _supported(q, k, window: Optional[int] = None
+               ) -> Optional[Tuple[int, int]]:
     """The (block_q, block_k) all three kernels tile these (B, S, H, D)
     shapes with, or None where they cannot.  Read from the two sequence
-    lengths, and from the head's width and type for what fits VMEM."""
+    lengths, from the head's width and type for what fits VMEM, and from
+    the window where the call has one."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if d % 8 != 0 or d > 512:
@@ -526,6 +650,8 @@ def _supported(q, k) -> Optional[Tuple[int, int]]:
     row_bytes = d * jnp.dtype(q.dtype).itemsize
     widest = (_BLOCK_CANDIDATES[0] if row_bytes <= _WIDE_BLOCK_ROW_BYTES
               else _NARROW_BLOCK)
+    if window is not None:
+        widest = min(widest, _window_block(window))
     bq = _pick_block(sq, widest, env="HVD_TPU_FLASH_BLOCK_Q")
     bk = _pick_block(sk, widest, env="HVD_TPU_FLASH_BLOCK_K")
     if bq is None or bk is None:
@@ -533,22 +659,62 @@ def _supported(q, k) -> Optional[Tuple[int, int]]:
     return bq, bk
 
 
+def _window_block(window: int) -> int:
+    """The widest tile of a windowed call: the widest candidate no wider
+    than the window.  A band ``window`` wide under square tiles of b
+    computes ``b + window`` (rounded up to tiles) score elements a query
+    row for ``window`` live, in ``window / b + 1`` steps a resident tile:
+    wider than the window is mostly dead elements, narrower is more steps.
+    Bare on a v5e, (2, 8192, 36, 128) bf16, window 512, ms a call (PERF.md
+    section 6, PR 33):
+
+        tile (bq x bk)  1024x1024  1024x512  512x1024  512x512  512x256  256x256  128x128
+        forward             6.18      5.37      6.03     4.71     5.28     6.69    13.51
+        dQ                  6.28      5.24      5.80     4.58     5.46     5.80    13.63
+        dK/dV               8.64      8.46      7.21     5.60     6.31     7.11    12.81
+    """
+    return next((c for c in _BLOCK_CANDIDATES if c <= window),
+                _BLOCK_CANDIDATES[-1])
+
+
+def _checked_window(window, causal, q, k, q_offset, kv_offset):
+    """``window`` as the kernels take it: None where it masks nothing (a
+    window that reaches the first key is the causal call, bit for bit)."""
+    if window is None:
+        return None
+    if window < 1:
+        raise ValueError(f"window {window} must be at least 1")
+    if not causal:
+        raise ValueError("a window is a causal call's: q_pos - window < "
+                         "k_pos <= q_pos")
+    if q.shape[1] != k.shape[1] or not (
+            isinstance(q_offset, int) and isinstance(kv_offset, int)
+            and q_offset == kv_offset == 0):
+        raise NotImplementedError(
+            "a windowed call takes one whole sequence: equal lengths and "
+            "no offsets (the band's grid is laid out at trace time)")
+    return None if window >= k.shape[1] else int(window)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, scale: Optional[float] = None,
                     q_offset=0, kv_offset=0,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jax.Array:
     """Differentiable fused attention; (B, S, H, D) in and out.
 
-    A shape the kernels cannot tile (``_supported`` is None) takes the
-    XLA path with the same semantics."""
+    ``window`` (causal only) keeps for each query the ``window`` keys up to
+    and including its own.  A shape the kernels cannot tile (``_supported``
+    is None) takes the XLA path with the same semantics."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    blocks = _supported(q, k)
+    window = _checked_window(window, causal, q, k, q_offset, kv_offset)
+    blocks = _supported(q, k, window)
     if blocks is None:
         out, _ = _xla_attention_with_lse(q, k, v, causal, scale,
-                                         q_offset, kv_offset)
+                                         q_offset, kv_offset, window)
         return out
     bq, bk = blocks
     if block_q:
@@ -565,7 +731,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         [jnp.asarray(q_offset, jnp.int32),
          jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)
     return _flash(q, k, v, offsets, causal, float(scale), bq, bk,
-                  bool(interpret))
+                  bool(interpret), window)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,
@@ -591,7 +757,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
                        blocks[1], bool(interpret))
 
 
-def _xla_attention_with_lse(q, k, v, causal, scale, q_offset, kv_offset):
+def _xla_attention_with_lse(q, k, v, causal, scale, q_offset, kv_offset,
+                            window=None):
     """XLA fallback with identical (out, lse) semantics."""
     sq, sk = q.shape[1], k.shape[1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -600,6 +767,8 @@ def _xla_attention_with_lse(q, k, v, causal, scale, q_offset, kv_offset):
         q_pos = q_offset + jnp.arange(sq)
         k_pos = kv_offset + jnp.arange(sk)
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     m = jnp.max(s, axis=-1)
     m_safe = jnp.maximum(m, _NEG_INF / 2)

@@ -228,13 +228,20 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    axis_name: str, causal: bool = True,
                    scale: Optional[float] = None,
                    use_flash: Optional[bool] = None,
-                   interpret: bool = False) -> jax.Array:
+                   interpret: bool = False,
+                   window: Optional[int] = None) -> jax.Array:
     """Exact attention over a sequence-sharded axis via K/V ring rotation.
 
     Call inside ``shard_map``; returns the local (B, Sq, H, D) output shard.
     ``interpret`` runs the flash kernels in the Pallas interpreter (CPU
-    tests); it is never chosen here.
+    tests); it is never chosen here.  A ``window`` is refused: a band that
+    crosses shards would visit only the neighbouring ring steps, which the
+    walk does not know how to skip.
     """
+    if window is not None:
+        raise NotImplementedError(
+            "ring attention takes no sliding window: windowed layers run "
+            "through full_attention (attn_mode 'megatron')")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     from ..ops import flash_attention as fa
@@ -250,17 +257,24 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def reference_attention(q, k, v, causal: bool = True,
-                        scale: Optional[float] = None) -> jax.Array:
-    """Pure-XLA unsharded attention — the numerics oracle for tests."""
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jax.Array:
+    """Pure-XLA unsharded attention — the numerics oracle for tests.
+    ``window`` (causal only): each query sees the ``window`` keys up to and
+    including its own, ``q_pos - window < k_pos <= q_pos``."""
     b, sq, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
+    if window is not None and not causal:
+        raise ValueError("a window is a causal call's")
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         q_pos = jnp.arange(sq)
         k_pos = jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
@@ -282,14 +296,18 @@ def checkpoint_keeping_attention(layer_fn):
 
 def full_attention(q, k, v, causal: bool = True,
                    scale: Optional[float] = None,
-                   use_flash: Optional[bool] = None) -> jax.Array:
+                   use_flash: Optional[bool] = None,
+                   window: Optional[int] = None) -> jax.Array:
     """Unsharded attention (same layout as ring_attention). Dispatches to
-    the fused Pallas kernel on TPU, XLA einsums elsewhere."""
+    the fused Pallas kernel on TPU, XLA einsums elsewhere.  ``window``: a
+    sliding window of that many keys a query, its own included."""
     if use_flash is None:
         from ..ops import flash_attention as fa
         use_flash = (_flash_enabled(k.shape[1]) and
-                     fa._supported(q, k) is not None)
+                     fa._supported(q, k, window) is not None)
     if use_flash:
         from ..ops import flash_attention as fa
-        return fa.flash_attention(q, k, v, causal=causal, scale=scale)
-    return reference_attention(q, k, v, causal=causal, scale=scale)
+        return fa.flash_attention(q, k, v, causal=causal, scale=scale,
+                                  window=window)
+    return reference_attention(q, k, v, causal=causal, scale=scale,
+                               window=window)

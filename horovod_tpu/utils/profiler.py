@@ -14,7 +14,8 @@ on the host timeline of a captured trace alongside the device steps.
 
 * ``scope(name)`` — names a block of the *compiled* training step
   (``STEP_SCOPES``, ``MOE_SCOPES``, ``SSM_SCOPES``,
-  ``LATENT_MOE_SCOPES``): a ``jax.named_scope``, so the name
+  ``LATENT_MOE_SCOPES``, ``ATTN_PART_SCOPES``, ``DENSE_MLP_SCOPE``): a
+  ``jax.named_scope``, so the name
   lands in every HLO operation's ``op_name`` and from there in a device
   profile.
 
@@ -75,6 +76,12 @@ SSM_SCOPES = ("ssm", "ssm_conv", "ssm_scan")
 # Inside the ``mlp`` block of a latent-space expert layer: both latent
 # projections, and the shared expert.
 LATENT_MOE_SCOPES = ("moe_latent", "moe_shared")
+# Inside the ``attn`` block of a patterned model: the rotary positions of q
+# and k, and the per-head gate on the attention's output.
+ATTN_PART_SCOPES = ("attn_rope", "attn_gate")
+# A patterned model's gated dense MLP block, inside ``mlp`` (what is left of
+# ``mlp`` is then the expert blocks').
+DENSE_MLP_SCOPE = "mlp_dense"
 
 
 def scope(name: str):

@@ -588,7 +588,11 @@ def _adasum_quadratic_descent(comp, steps=80, lr=0.5, dim=33):
     w = jnp.zeros(dim, jnp.float32)
     for _ in range(steps):
         g = f(w, jnp.asarray(cs)).reshape(N, dim)[0]
-        w = w - lr * g
+        # One collective program in flight at a time: queued behind each
+        # other on a busy host, the eight virtual devices' threads of step
+        # k + 1 can hold the pool while step k waits at its rendezvous, and
+        # XLA aborts the process when that wait times out.
+        w = jax.block_until_ready(w - lr * g)
     w = np.asarray(w)
     loss = 0.5 * np.mean(np.sum((w[None] - cs) ** 2, axis=1))
     return w, float(loss)
