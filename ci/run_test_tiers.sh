@@ -53,6 +53,14 @@ TIER_FAST=(
   # it refuses, and the backward compiled for a described v5e.
   test_flash_backward_pass.py
   test_fleet.py
+  # The pause sentinel (ISSUE 36): garbage collections and the heartbeat in
+  # the registry, the flight recorder, the log and a trace; arming under
+  # init() / shutdown() and flight_disable.  With it the one file of
+  # tests/benchmark_tests/ a tier holds (the others run under the driver's
+  # `pytest tests/`): the reader that lays those marks against the device's
+  # idle gaps, which is the sentinel's other half.
+  test_host_pause.py
+  benchmark_tests/test_benchmark_trace_host.py
   # Laguna's mix of windowed and full attention on the training path
   # (ISSUE 33): YaRN frequencies and the half-head rotation by hand, the
   # attention blocks, gate and dense MLP against the reference's equations,

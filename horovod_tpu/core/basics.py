@@ -243,6 +243,10 @@ def init(mesh=None,
                              round=global_state.elastic_round,
                              wire=global_state.config.compression)
         _debug.install_signal_handler()
+        # The pause sentinel (debug/pause.py): collections and a heartbeat
+        # on the profiler's clock, in the registry and, when the process
+        # stops for long, in the recorder and on the log.
+        _debug.pause.arm()
         _rdv = _os.environ.get("HVD_TPU_RENDEZVOUS_ADDR")
         if _rdv:
             try:
@@ -330,11 +334,13 @@ def shutdown() -> None:
     """Tear down the runtime (reference: horovod_shutdown, operations.cc)."""
     # Stop the hang watchdog BEFORE the controller it polls goes away
     # (its thread is named hvd-tpu-*, so a leak fails the test suite's
-    # stray-thread check).  The debug HTTP endpoint, like the metrics
-    # server, deliberately stays up across elastic resets.
+    # stray-thread check), and the pause sentinel's heartbeat with it.
+    # The debug HTTP endpoint, like the metrics server, deliberately stays
+    # up across elastic resets.
     try:
         from .. import debug as _debug
         _debug.stop_stall_watchdog()
+        _debug.pause.disarm()
         _debug.flight.record("shutdown", None)
     except Exception:  # noqa: BLE001 - best-effort teardown
         pass

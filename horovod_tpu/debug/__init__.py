@@ -6,6 +6,10 @@ The diagnosis half of observability (``hvd.metrics`` is the live half):
 * :mod:`~horovod_tpu.debug.flight` — per-rank ring buffer of structured
   events from every subsystem that can block a step; dump via
   :func:`dump`, SIGUSR1, or ``GET /debug/flight``.
+* :mod:`~horovod_tpu.debug.pause` — the pause sentinel: garbage
+  collections and a heartbeat on the profiler's clock and in the metrics
+  registry; a stop of the process as a ``pause`` flight event and one
+  warning line.
 * :mod:`~horovod_tpu.debug.http` — ``/debug/flight`` + ``/debug/stacks``
   endpoints on the shared BackgroundHTTPServer scaffold (also mounted on
   the metrics server when one is running).
@@ -27,7 +31,7 @@ The diagnosis half of observability (``hvd.metrics`` is the live half):
 See docs/debugging.md for the worked hang-triage example.
 """
 
-from . import flight
+from . import flight, pause
 from .flight import (FlightRecorder, dump, estimate_clock_offset,
                      install_signal_handler, record, recorder, set_enabled,
                      snapshot)
@@ -77,7 +81,8 @@ def build_regression_report(event, **kwargs):
 
 
 __all__ = [
-    "flight", "FlightRecorder", "record", "recorder", "snapshot", "dump",
+    "flight", "pause", "FlightRecorder", "record", "recorder", "snapshot",
+    "dump",
     "set_enabled", "install_signal_handler", "estimate_clock_offset",
     "serve", "serve_and_publish", "stop_serving",
     "start_stall_watchdog", "stop_stall_watchdog",

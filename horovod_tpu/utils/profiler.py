@@ -1,11 +1,14 @@
 """Profiler trace ranges — the TPU-native analog of NVTX op ranges.
 
 The reference wraps every enqueued collective in an NVTX range so Nsight
-shows per-op spans (nvtx_op_range.h, operations.cc:1018-1033), disabled by
-``HOROVOD_DISABLE_NVTX_RANGES``.  On TPU the profiler is XProf/TensorBoard;
+shows per-op spans (nvtx_op_range.h, operations.cc:1018-1033).  On TPU the
+profiler is XProf/TensorBoard;
 ``jax.profiler.TraceAnnotation`` plays NVTX's role: annotated spans appear
 on the host timeline of a captured trace alongside the device steps.
 
+* ``host_span(name)`` — one span on the host timeline, on the device
+  trace's clock; what ``op_range`` and the pause sentinel
+  (``debug/pause.py``: ``hvd.gc.gen<n>``, ``hvd.tick``) are made of.
 * ``op_range(name, payload_bytes=…)`` — context manager for one collective.
 * ``start_trace(logdir)`` / ``stop_trace()`` — programmatic capture, the
   analog of ``hvd.start_timeline``/``stop_timeline`` for device profiles
@@ -19,42 +22,43 @@ on the host timeline of a captured trace alongside the device steps.
   lands in every HLO operation's ``op_name`` and from there in a device
   profile.
 
-Disable knob: ``HVD_TPU_DISABLE_TRACE_RANGES=1`` (reference knob:
-``HOROVOD_DISABLE_NVTX_RANGES``, common.h:96).  It governs the host-side
-ranges only; ``scope`` has no knob, because it has no run-time cost.
+No knob (the reference's ``HOROVOD_DISABLE_NVTX_RANGES``, common.h:96, has
+no analog): a ``TraceAnnotation`` costs nothing worth naming while no trace
+is being taken, and ``scope`` has no run-time cost at all.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from typing import Optional
 
 
-def _enabled() -> bool:
-    return os.environ.get("HVD_TPU_DISABLE_TRACE_RANGES", "") != "1" and \
-        os.environ.get("HOROVOD_DISABLE_NVTX_RANGES", "") != "1"
+def host_span(name: str):
+    """A ``jax.profiler.TraceAnnotation``: entered and left (``with``, or
+    ``__enter__`` / ``__exit__`` on one thread) it is a span on the host
+    timeline of whatever trace is being taken, on the clock the device
+    planes share; with no trace being taken it is a flag test.  None where
+    the profiler cannot be had: profiling must never break what it
+    names."""
+    try:
+        import jax.profiler as _prof
+        return _prof.TraceAnnotation(name)
+    except Exception:
+        return None
 
 
 @contextlib.contextmanager
 def op_range(name: str, payload_bytes: Optional[int] = None):
     """Annotate one collective on the profiler timeline.  Cheap no-op when
-    ranges are disabled or no trace is being captured.
+    no trace is being captured.
 
     Only annotation *setup* is guarded — exceptions raised by the wrapped
     block must propagate untouched (a swallowed yield would mask every
     eager-collective failure behind a generator error)."""
-    ann = None
-    if _enabled():
-        try:
-            import jax.profiler as _prof
-            label = name if payload_bytes is None else \
-                f"{name}#bytes={payload_bytes}"
-            ann = _prof.TraceAnnotation(label)
-        except Exception:
-            ann = None  # profiling must never break the op
+    ann = host_span(name if payload_bytes is None
+                    else f"{name}#bytes={payload_bytes}")
     if ann is None:
         yield
     else:
@@ -90,8 +94,7 @@ def scope(name: str):
     HLO operation the block lowers to (under AD it can also sit inside
     ``jvp(...)`` / ``transpose(...)``), which a device profile shows as the
     operation's ``tf_op``.  It exists only while tracing, costs nothing when
-    the compiled step runs, and so is not subject to
-    ``HVD_TPU_DISABLE_TRACE_RANGES``."""
+    the compiled step runs."""
     import jax
     return jax.named_scope("hvd_" + name)
 

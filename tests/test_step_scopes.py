@@ -61,9 +61,10 @@ def test_scope_is_a_named_scope_with_the_hvd_prefix():
 
 
 def test_scope_has_no_knob(monkeypatch):
-    """``HVD_TPU_DISABLE_TRACE_RANGES`` switches the host ranges off, not
-    the scopes of the compiled step: they cost nothing at run time."""
-    monkeypatch.setenv("HVD_TPU_DISABLE_TRACE_RANGES", "1")
+    """Neither the scopes of the compiled step nor the host ranges have a
+    knob: whatever the environment holds (here the reference's
+    ``HOROVOD_DISABLE_NVTX_RANGES``), the name is in the lowered step."""
+    monkeypatch.setenv("HOROVOD_DISABLE_NVTX_RANGES", "1")
 
     def f(x):
         with profiler.scope("optimizer"):

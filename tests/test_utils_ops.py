@@ -62,18 +62,31 @@ def test_checkpoint_nonzero_rank_skips(tmp_path):
 # Profiler trace ranges (NVTX-analog, utils/profiler.py)
 # ---------------------------------------------------------------------------
 
-def test_op_range_is_safe_noop(monkeypatch):
-    from horovod_tpu.utils.profiler import op_range, _enabled
-    with op_range("hvd.allreduce.x", 128):
+def test_op_range_is_emitted_whatever_the_environment_holds(monkeypatch,
+                                                            tmp_path):
+    """The host ranges have no knob: with no trace being taken a range is a
+    safe no-op, and under a trace it is on the host timeline even where the
+    reference's ``HOROVOD_DISABLE_NVTX_RANGES`` is set (a TraceAnnotation
+    costs nothing worth a knob when no trace is being taken)."""
+    from jax.profiler import ProfileData
+    from horovod_tpu.utils import profiler
+    with profiler.op_range("hvd.allreduce.x", 128):
         y = 1 + 1
     assert y == 2
-    monkeypatch.setenv("HVD_TPU_DISABLE_TRACE_RANGES", "1")
-    assert not _enabled()
-    with op_range("hvd.allreduce.x"):
-        pass
-    monkeypatch.delenv("HVD_TPU_DISABLE_TRACE_RANGES")
+    assert not hasattr(profiler, "_enabled")
     monkeypatch.setenv("HOROVOD_DISABLE_NVTX_RANGES", "1")
-    assert not _enabled()  # reference knob honored too
+    with profiler.trace(str(tmp_path)):
+        with profiler.op_range("hvd.allreduce.x", 128):
+            pass
+        with profiler.host_span("hvd.plain"):
+            pass
+    import glob
+    import os
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert {"hvd.allreduce.x#bytes=128", "hvd.plain"} <= names
 
 
 def test_eager_collectives_pass_through_ranges():
