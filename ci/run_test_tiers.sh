@@ -125,6 +125,11 @@ TIER_FAST=(
   # determinism, burn-rate goldens, burn-aware policy/autoscaler,
   # span coverage with tracing-on/off bit-identity, the migrated
   # stitched-trace drill, merge --trace, loop-liveness surface.
+  # The mp rings of parallel/tensor_parallel.py (ISSUE 37): the four-chip
+  # flagship step compiled for a described v5e:2x2 has a matmul between
+  # every mp collective-permute's start and done, no synchronous gather or
+  # scatter over mp, the dp all-reduce, and a peak under 14 GiB.
+  test_tp_overlap_schedule.py
   test_tracing.py
   test_transformer.py
   # Closed-loop autotuning drill (ISSUE 12): injected comm regression →

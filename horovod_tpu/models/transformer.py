@@ -43,6 +43,7 @@ loss accumulate in fp32.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
@@ -60,7 +61,9 @@ from ..parallel import moe as moe_lib
 from ..parallel import pipeline as pp_lib
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
-from ..utils.profiler import DENSE_MLP_SCOPE, scope
+from ..utils.profiler import DENSE_MLP_SCOPE, TP_RING_SCOPES, scope
+
+GATHER_RING, SCATTER_RING = TP_RING_SCOPES
 
 
 class TransformerConfig(NamedTuple):
@@ -567,6 +570,14 @@ def _position_qk(cfg: TransformerConfig, lp, q, k, positions, axis_name):
     return q, k
 
 
+def _ring_scope(form: str):
+    """The scope of one form of ``parallel/tensor_parallel.py``'s ring —
+    where there is a ring: one ``mp`` member's calls are the plain
+    collectives', and named as they were."""
+    return (scope(form) if axis_size("mp") > 1
+            else contextlib.nullcontext())
+
+
 def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                      x: jax.Array) -> jax.Array:
     """x: (mb, s_local, d) sequence-sharded over mp. Returns residual add."""
@@ -577,18 +588,18 @@ def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     # per head), making column-parallel == head-parallel.
     if cfg.attn_mode == "megatron":
         # gather sequence → heads-sharded attention → scatter sequence back.
-        hg = tp.gather_sequence(hnorm, "mp", dim=1)          # (mb, S, d)
-        qkv = tp.column_parallel(hg, lp["wqkv"].astype(x.dtype))
+        local_heads = lp["wqkv"].shape[-1] // (3 * hd)
+        with _ring_scope(GATHER_RING):            # (mb, S, heads/mp, 3, hd)
+            qkv = tp.gather_column_parallel(
+                hnorm, lp["wqkv"], "mp", features=(local_heads, 3, hd))
         mb, s_full = qkv.shape[0], qkv.shape[1]
-        local_heads = qkv.shape[-1] // (3 * hd)
-        qkv = qkv.reshape(mb, s_full, local_heads, 3, hd)
         q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
         # The sequence was gathered: positions 0 .. S-1; heads over mp.
         q, k = _position_qk(cfg, lp, q, k, jnp.arange(s_full), "mp")
         o = ra.full_attention(q, k, v, causal=True)
-        o = o.reshape(mb, s_full, local_heads * hd)
-        return tp.row_parallel(o, lp["wo"].astype(x.dtype), "mp",
-                               scatter_sequence=True)
+        with _ring_scope(SCATTER_RING):      # o: (mb, S, heads/mp, hd) as is
+            return tp.row_parallel(o, lp["wo"], "mp", scatter_sequence=True,
+                                   feature_dims=2)
     else:  # ring/ulysses: sequence stays sharded through attention
         qkv = jnp.einsum("bsd,de->bse", hnorm, lp["wqkv"].astype(x.dtype))
         mb, s_local = qkv.shape[0], qkv.shape[1]
@@ -647,10 +658,11 @@ def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                               capacity_factor=cfg.capacity_factor,
                               top_k=cfg.top_k)
         return y.reshape(mb, s_local, d).astype(x.dtype), None
-    hg = tp.gather_sequence(hnorm, "mp", dim=1)
-    u = jax.nn.gelu(tp.column_parallel(hg, lp["w1"].astype(x.dtype)))
-    return tp.row_parallel(u, lp["w2"].astype(x.dtype), "mp",
-                           scatter_sequence=True), None
+    with _ring_scope(GATHER_RING):   # tokenwise from here: rows in ring order
+        u = tp.gather_column_parallel_ring(hnorm, lp["w1"], "mp")
+    u = jax.tree_util.tree_map(jax.nn.gelu, u)
+    with _ring_scope(SCATTER_RING):
+        return tp.row_parallel(u, lp["w2"], "mp", scatter_sequence=True), None
 
 
 def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],

@@ -6,7 +6,8 @@ One package per parallelism axis, composable inside one ``shard_map``:
   vocabulary (``DATA``/``FSDP``/``TENSOR``/``SEQUENCE``/``PIPELINE``/
   ``EXPERT``).
 * :mod:`.tensor_parallel` — Megatron column/row-parallel matmuls and
-  the sequence-parallel gather/scatter pair.
+  the sequence-parallel gather/scatter pair, over more than one member
+  as rings of ``ppermute`` beside the matmuls' pieces.
 * :mod:`.ring_attention` — exact blockwise ring attention (sequence
   stays sharded through attention); :mod:`.ulysses` — the all_to_all
   head-scatter alternative.
@@ -48,7 +49,8 @@ from .pipeline import (
 # would silently hand back the function).  Reach it via the submodule.
 from .ring_attention import full_attention, reference_attention
 from .tensor_parallel import (
-    column_parallel, gather_sequence, row_parallel,
+    column_parallel, gather_column_parallel, gather_column_parallel_ring,
+    gather_sequence, row_parallel,
     vocab_parallel_cross_entropy, vocab_parallel_logits,
 )
 from .ulysses import ulysses_attention
@@ -65,7 +67,8 @@ __all__ = [
     "note_bubble", "pipeline_apply", "pipeline_apply_1f1b",
     "stack_microbatches", "unstack_microbatches",
     "full_attention", "reference_attention",
-    "column_parallel", "gather_sequence", "row_parallel",
+    "column_parallel", "gather_column_parallel",
+    "gather_column_parallel_ring", "gather_sequence", "row_parallel",
     "vocab_parallel_cross_entropy", "vocab_parallel_logits",
     "ulysses_attention",
 ]

@@ -11,6 +11,8 @@ from jax.sharding import PartitionSpec as P
 from horovod_tpu.compat import shard_map
 
 import horovod_tpu as hvd
+from horovod_tpu.metrics.registry import registry
+from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel import moe as moe_lib
 from horovod_tpu.parallel import pipeline as pp_lib
 from horovod_tpu.parallel import ring_attention as ra
@@ -185,6 +187,241 @@ def test_vocab_parallel_cross_entropy():
     expected = -jnp.take_along_axis(log_probs, labels[:, None], axis=1)[:, 0]
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
+
+
+# The ring forms of the sequence-parallel gather -> matmul and matmul ->
+# scatter (``gather_column_parallel[_ring]``, ``row_parallel(...,
+# scatter_sequence=True)``) against the plain collectives they replace.
+
+# The four-chip flagship cell's reference check: |loss difference| and the
+# worst gradient leaf's relative L2 (benchmark/reference/flagship.py).
+CELL_LOSS_TOL, CELL_GRAD_REL_L2 = 2e-3, 0.03
+
+
+def _plain_gather_matmul(x, w):
+    return jnp.einsum("...i,io->...o",
+                      jax.lax.all_gather(x, "mp", axis=1, tiled=True), w)
+
+
+def _plain_matmul_scatter(u, w):
+    return jax.lax.psum_scatter(jnp.einsum("...i,io->...o", u, w), "mp",
+                                scatter_dimension=1, tiled=True)
+
+
+def _causal_mean(h):
+    """Stands where attention stands: row s reads rows 0 .. s, so a chunk
+    out of sequence order changes every row behind it."""
+    n = jnp.arange(1, h.shape[1] + 1, dtype=jnp.float32)[None, :, None]
+    return (jnp.cumsum(h.astype(jnp.float32), axis=1) / n).astype(h.dtype)
+
+
+# (the block between the gather and the scatter, the width of a member's
+# shard of the first matmul): attention-shaped, rows in sequence order
+# through a narrow product; MLP-shaped, tokenwise through a wide one.
+TP_SHAPES = {"attention": 96, "mlp": 640}
+
+
+def _tp_block(shape, ring):
+    if shape == "attention":
+        def block(act, w_a, w_b):
+            h = (tp.gather_column_parallel(act, w_a, "mp") if ring
+                 else _plain_gather_matmul(act, w_a))
+            h = _causal_mean(h)
+            return (tp.row_parallel(h, w_b, "mp", scatter_sequence=True)
+                    if ring else _plain_matmul_scatter(h, w_b))
+    else:
+        def block(act, w_a, w_b):
+            if not ring:
+                return _plain_matmul_scatter(
+                    jax.nn.gelu(_plain_gather_matmul(act, w_a)), w_b)
+            u = jax.tree_util.tree_map(
+                jax.nn.gelu, tp.gather_column_parallel_ring(act, w_a, "mp"))
+            return tp.row_parallel(u, w_b, "mp", scatter_sequence=True)
+    return block
+
+
+def _tp_stack_loss_and_grads(mesh, shape, ring, x, w_a, w_b):
+    """Loss and gradients through two scanned, checkpointed layers of
+    ``act + block(act)``, as the trainer stacks its layers."""
+    block = _tp_block(shape, ring)
+
+    def stack(x, w_a, w_b):
+        def layer(act, w):
+            return act + block(act, *w), None
+        return jax.lax.scan(jax.checkpoint(layer), x, (w_a, w_b))[0]
+
+    fn = shard_map(stack, mesh=mesh,
+                   in_specs=(P(None, "mp"), P(None, None, "mp"),
+                             P(None, "mp")),
+                   out_specs=P(None, "mp"), check_vma=False)
+
+    def loss(x, w_a, w_b):
+        return jnp.mean(jnp.sin(fn(x, w_a, w_b).astype(jnp.float32)))
+
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(x, w_a, w_b)
+
+
+def _rel_l2(a, b):
+    a, b = (np.asarray(t, np.float32) for t in (a, b))
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("shape", sorted(TP_SHAPES))
+@pytest.mark.parametrize("mp, s_loc", [(1, 256), (2, 256), (4, 256),
+                                       (8, 256), (2, 258)])
+def test_ring_gather_and_scatter_match_the_plain_collectives(
+        mp, s_loc, shape, dtype):
+    """Values and gradients, through a scanned and checkpointed two-layer
+    stack, of the ring forms against all_gather -> einsum and einsum ->
+    psum_scatter.  258 rows a chunk: four pieces of 64 and 65."""
+    hvd.init()
+    mesh = create_mesh({"mp": mp}, devices=jax.devices()[:mp])
+    mb, d, mid = 2, 512, TP_SHAPES[shape] * mp
+    kx, ka, kb = jax.random.split(jax.random.PRNGKey(mp + s_loc), 3)
+    x = jax.random.normal(kx, (mb, mp * s_loc, d), dtype)
+    w_a = (jax.random.normal(ka, (2, d, mid)) * d ** -0.5).astype(dtype)
+    w_b = (jax.random.normal(kb, (2, mid, d)) * mid ** -0.5).astype(dtype)
+    ring_loss, ring_grads = _tp_stack_loss_and_grads(
+        mesh, shape, True, x, w_a, w_b)
+    loss, grads = _tp_stack_loss_and_grads(mesh, shape, False, x, w_a, w_b)
+    if mp == 1:                       # the plain collectives themselves
+        assert float(ring_loss) == float(loss)
+        for ring_g, g in zip(ring_grads, grads):
+            np.testing.assert_array_equal(np.asarray(ring_g), np.asarray(g))
+        return
+    loss_tol, grad_tol = ((1e-6, 1e-5) if dtype == jnp.float32
+                          else (CELL_LOSS_TOL, CELL_GRAD_REL_L2))
+    assert abs(float(ring_loss) - float(loss)) <= loss_tol
+    for ring_g, g in zip(ring_grads, grads):
+        assert ring_g.dtype == g.dtype
+        assert _rel_l2(ring_g, g) <= grad_tol
+
+
+def test_ring_pieces_come_from_the_shapes():
+    """The four-chip flagship cell's four matmuls (10 x 4096 rows a chunk,
+    bf16) are all slower on the wire than on the MXU: four pieces each.  A
+    gather in front of a product wide enough to hide it is not cut (its
+    pieces are copies), a scatter behind one always in two (the add); a
+    chunk too light for the wire is left whole, and a cut that does not
+    divide leaves pieces a row apart."""
+    d, item = 1024, 2
+
+    def count(s_loc, width_moved, k, n, fused_add, lead=10):
+        rows = lead * s_loc
+        return tp._pieces(s_loc, rows * width_moved * item,
+                          2.0 * rows * k * n, fused_add)
+
+    assert len(count(4096, d, d, 1536, False)) == 4           # -> wqkv
+    assert len(count(4096, d, d, 2048, False)) == 4           # -> w1
+    assert len(count(4096, d, 512, d, True)) == 4             # wo ->
+    assert len(count(4096, d, 2048, d, True)) == 4            # w2 ->
+    assert len(count(4096, d, d, 4096, False)) == 1     # the matmul hides it
+    assert len(count(4096, d, d, 2816, False)) == 2
+    assert len(count(4096, d, 8192, d, True)) == 2      # an add needs two
+    assert count(16, 32, 32, 32, True, lead=1) == ((0, 16),)
+    assert count(258, 512, 512, 96, False, lead=4) == (
+        (0, 64), (64, 129), (129, 193), (193, 258))
+
+
+def _parent_attention_block(cfg, lp, x):
+    """``models/transformer._attention_block`` (megatron mode) as it stood
+    before the ring: gather -> einsum -> attention -> einsum -> scatter."""
+    hd = cfg.head_dim
+    hnorm = tfm._rmsnorm(x, lp["ln1"], cfg.norm_eps)
+    hg = tp.gather_sequence(hnorm, "mp", dim=1)
+    qkv = tp.column_parallel(hg, lp["wqkv"].astype(x.dtype))
+    mb, s_full = qkv.shape[0], qkv.shape[1]
+    local_heads = qkv.shape[-1] // (3 * hd)
+    qkv = qkv.reshape(mb, s_full, local_heads, 3, hd)
+    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    q, k = tfm._position_qk(cfg, lp, q, k, jnp.arange(s_full), "mp")
+    o = ra.full_attention(q, k, v, causal=True)
+    o = o.reshape(mb, s_full, local_heads * hd)
+    partial = jnp.einsum("...i,io->...o", o, lp["wo"].astype(x.dtype))
+    return jax.lax.psum_scatter(partial, "mp", scatter_dimension=1,
+                                tiled=True)
+
+
+def _parent_mlp_block(cfg, lp, x):
+    hnorm = tfm._rmsnorm(x, lp["ln2"], cfg.norm_eps)
+    hg = tp.gather_sequence(hnorm, "mp", dim=1)
+    u = jax.nn.gelu(tp.column_parallel(hg, lp["w1"].astype(x.dtype)))
+    partial = jnp.einsum("...i,io->...o", u, lp["w2"].astype(x.dtype))
+    return jax.lax.psum_scatter(partial, "mp", scatter_dimension=1,
+                                tiled=True)
+
+
+def _ring_counts():
+    return {form: registry().counter("hvd_tp_ring_matmuls_built_total",
+                                     form=form).value
+            for form in ("gather", "scatter")}
+
+
+def _one_layer(mp):
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=64, n_heads=4, d_ff=128, n_layers=1,
+        seq_len=32)       # bf16 compute on fp32 weights: the casts are in
+    par = tfm.ParallelConfig(dp=1, pp=1, mp=mp)
+    mesh = create_mesh({"dp": 1, "pp": 1, "mp": mp},
+                       devices=jax.devices()[:mp])
+    layers = tfm.init_params(jax.random.PRNGKey(3), cfg, par)["layers"]
+    specs = tfm.param_specs(cfg, par)["layers"]
+    x = jax.random.normal(jax.random.PRNGKey(4),
+                          (2, cfg.seq_len, cfg.d_model), cfg.dtype)
+
+    def sharded(block):
+        def on_device(lp, x):
+            lp = jax.tree_util.tree_map(lambda a: a[0, 0], lp)
+            out = block(cfg, lp, x)
+            return out[0] if isinstance(out, tuple) else out
+
+        fn = shard_map(on_device, mesh=mesh,
+                       in_specs=(specs, P(None, "mp")),
+                       out_specs=P(None, "mp"), check_vma=False)
+        return lambda lp, x: jnp.sum(jnp.sin(fn(lp, x)))
+
+    return sharded, layers, x
+
+
+@pytest.mark.parametrize("block, parent", [
+    ("_attention_block", _parent_attention_block),
+    ("_mlp_block", _parent_mlp_block)])
+def test_at_mp_1_a_block_is_the_plain_gather_einsum_scatter(block, parent):
+    """One member: the jaxpr is the parent's — no ring, no piece written in
+    place — and outputs and gradients are its to the bit."""
+    hvd.init()
+    sharded, layers, x = _one_layer(mp=1)
+    before = _ring_counts()
+    ours = jax.value_and_grad(sharded(getattr(tfm, block)), argnums=(0, 1))
+    theirs = jax.value_and_grad(sharded(parent), argnums=(0, 1))
+    jaxpr = str(jax.make_jaxpr(ours)(layers, x))
+    assert "ppermute" not in jaxpr and "dynamic_update_slice" not in jaxpr
+    assert jaxpr == str(jax.make_jaxpr(theirs)(layers, x))
+    assert _ring_counts() == before
+    got, want = jax.jit(ours)(layers, x), jax.jit(theirs)(layers, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_ring_counter_reads_what_was_built():
+    """Trace-time: a layer at mp = 1 builds no ring matmul, at mp = 2 two
+    gathers and two scatters (and their like again under AD's own trace is
+    not counted: the transposes are not new calls)."""
+    hvd.init()
+
+    def trace(mp):
+        sharded, layers, x = _one_layer(mp)
+        before = _ring_counts()
+        for block in (tfm._attention_block, tfm._mlp_block):
+            jax.make_jaxpr(sharded(block))(layers, x)
+        return {form: n - before[form]
+                for form, n in _ring_counts().items()}
+
+    assert trace(1) == {"gather": 0, "scatter": 0}
+    assert trace(2) == {"gather": 2, "scatter": 2}
 
 
 # ---------------------------------------------------------------------------
