@@ -38,7 +38,14 @@ trap cleanup_orphans EXIT INT TERM
 
 # Tier 1 — fast, single-process: model/op/unit layers (~5 min).
 TIER_FAST=(
-  test_basics.py test_bert.py test_checkpoint_engine.py test_chips.py
+  test_basics.py test_bert.py
+  # The flash kernels under the block-diffusion mask (ISSUE 39): forward and
+  # the three gradients against the dense mask, the walk of the live tiles
+  # (exactly the tiles with an unmasked pair, n in 1, 2, 4), the tile rule,
+  # the counter, the refusals, and the flagship's, BERT's and Laguna's
+  # attention calls traced to the parent's text.
+  test_block_diffusion_attention.py
+  test_checkpoint_engine.py test_chips.py
   test_ci_tiers.py
   test_collectives.py test_data_pipeline.py test_debug_flight.py
   test_dispatch.py
@@ -106,7 +113,18 @@ TIER_FAST=(
   # Flat-shard layout math goldens (ISSUE 14): 1-D + (dp, mp) nested
   # reshard arithmetic every durability tier leans on.
   test_reshard.py
-  test_resnet.py test_response_cache.py test_timeline.py
+  test_resnet.py test_response_cache.py
+  # SDAR's block-diffusion training on the training path (ISSUE 39): the
+  # doubled sequence against a plain block-causal forward block by block,
+  # per-head QK-norm and the wrapped positions against the reference, the
+  # eight expert shares summing to the whole layer, the published parameter
+  # count, the host-side noise, the refusals.  With it the cell's own
+  # benchmark tests (system against reference, the controls, the readers)
+  # and its step compiled for a described v5e.
+  test_sdar_layers.py
+  benchmark_tests/test_benchmark_sdar.py
+  benchmark_tests/test_benchmark_compile_v5e_sdar.py
+  test_timeline.py
   # Serving plane (ISSUE 15): admission-policy goldens, prefill/decode
   # parity vs the training-path logits, continuous-vs-static occupancy,
   # hot-swap bit-parity, overload shed, and the train→serve handoff

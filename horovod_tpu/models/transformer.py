@@ -34,8 +34,15 @@ letters — ``W`` sliding-window attention with its own head count and rotary
 base, ``D`` a gated dense MLP — with ``leading_pattern`` (blocks that run
 once, in front of the scanned periods), rotary positions on a share of the
 head with YaRN-scaled frequencies and a per-head output gate give Laguna's
-mix of windowed and full attention (``laguna``).  The serving entry points
-below cover learned positions only.
+mix of windowed and full attention (``laguna``).  ``diffusion_block``
+turns the step itself into block-diffusion training (BD3-LMs,
+arXiv:2503.09573; SDAR, arXiv:2510.06303): a sequence of L tokens goes
+through the stack as 2L positions, a noised copy and then the clean copy,
+positions 0 .. L-1 twice, under a mask in which a noised block sees itself
+and the clean blocks before it; logits are taken from the noised half and
+the cross-entropy is weighted by a third batch array (the noise is data:
+:func:`noised_batch`).  ``head_qk_norm`` is an RMSNorm over each head of q
+and of k.  The serving entry points below cover learned positions only.
 
 Compute dtype defaults to bfloat16 (MXU-native); normalization, softmax and
 loss accumulate in fp32.
@@ -136,6 +143,12 @@ class TransformerConfig(NamedTuple):
     rope_yarn: Optional[Tuple[float, int, float, float, float]] = None
     attn_gate: bool = False       # "*" / "W": head i's output x sigmoid(h Wg)_i
     dense_ff: int = 0             # "D" blocks: (silu(h W1) * h W3) W2
+    # Block-diffusion training of a patterned model: ``tokens`` are 2 x
+    # ``seq_len`` positions (the noised copy, then the clean one) in blocks
+    # of ``diffusion_block``, ``labels`` and a third batch array ``weights``
+    # ``seq_len``; see ``forward_loss``.
+    diffusion_block: Optional[int] = None
+    head_qk_norm: bool = False    # "*" / "W": RMSNorm over each head of q, k
 
     @property
     def head_dim(self) -> int:
@@ -222,18 +235,43 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
                 cfg.head_dim * cfg.rope_fraction % 2:
             raise ValueError(f"rope_fraction {cfg.rope_fraction} of a head "
                              f"of {cfg.head_dim} is not a whole even share")
+        if cfg.diffusion_block is not None:
+            if cfg.diffusion_block < 1 or cfg.seq_len % cfg.diffusion_block:
+                raise ValueError(
+                    f"seq_len {cfg.seq_len} is not whole blocks of "
+                    f"diffusion_block {cfg.diffusion_block}")
+            if set(letters) & set("WM"):
+                raise NotImplementedError(
+                    "diffusion_block goes with \"*\" attention blocks: a "
+                    "sliding window (\"W\", attn_window) or a state-space "
+                    "scan (\"M\") over the doubled sequence would cross "
+                    "from the noised copy into the clean one")
+            if _has_pos_table(cfg):
+                raise NotImplementedError(
+                    "diffusion_block wraps rotary positions (rope_theta) at "
+                    "seq_len; a learned position table is not laid over the "
+                    "doubled sequence")
+            if cfg.attn_mode != "megatron":
+                raise NotImplementedError(
+                    f"diffusion_block with attn_mode {cfg.attn_mode!r}: the "
+                    "block-diffusion mask runs through full_attention "
+                    "(attn_mode 'megatron'); ring and Ulysses attention "
+                    "refuse it")
         if par.mp > 1 or par.pp > 1 or par.pp_schedule != "gpipe":
             raise NotImplementedError(
                 "a model with a layer_pattern runs on dp alone: its mixers "
-                "are neither sharded over mp nor staged over pp (ROADMAP M7)")
+                "are neither sharded over mp nor staged over pp (ROADMAP "
+                "M7); nor is a diffusion_block's doubled sequence")
     elif cfg.n_kv_heads not in (None, cfg.n_heads) or cfg.moe_latent \
             or cfg.shared_expert_ff or cfg.leading_pattern \
             or cfg.attn_window or cfg.attn_gate or cfg.dense_ff \
-            or cfg.rope_yarn or cfg.rope_fraction != 1.0:
+            or cfg.rope_yarn or cfg.rope_fraction != 1.0 \
+            or cfg.diffusion_block is not None or cfg.head_qk_norm:
         raise ValueError(
             "n_kv_heads, moe_latent, shared_expert_ff, leading_pattern, "
-            "attn_window, attn_gate, dense_ff, rope_yarn and rope_fraction "
-            "are a patterned model's: set layer_pattern")
+            "attn_window, attn_gate, dense_ff, rope_yarn, rope_fraction, "
+            "diffusion_block and head_qk_norm are a patterned model's: set "
+            "layer_pattern")
     if _holds_a_share(cfg) and (par.mp > 1 or par.pp > 1):
         raise NotImplementedError(
             f"a layer that holds {_experts_held(cfg)} of {cfg.n_experts} "
@@ -392,6 +430,8 @@ def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
             }
             if cfg.attn_gate:
                 blk["w_head_gate"] = rand(d, hq)
+            if cfg.head_qk_norm:
+                blk["q_norm"], blk["k_norm"] = ones(hd), ones(hd)
             return blk
         if kind == "dense":
             return {"ln": ones(d), "w_gate": rand(d, cfg.dense_ff),
@@ -697,11 +737,18 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     no position encoding) on ``rope_fraction`` of the head, ``rope_yarn``
     scaling the frequencies; a "W" block ("swa") has ``window_heads`` query
     heads, sees ``attn_window`` keys and rotates the whole head at
-    ``window_rope_theta``.  With ``attn_gate`` head i's output is multiplied
-    by ``sigmoid(h Wg)_i``, a scalar a head and token from the block's
-    normed input.  Each K / V head is repeated across its query heads
-    before the kernels (their index maps taking several query heads a K / V
-    block is ROADMAP M4)."""
+    ``window_rope_theta``.  Positions are 0 .. S-1, or with
+    ``diffusion_block`` 0 .. S/2-1 twice (the noised copy and the clean
+    copy of the same tokens), and the mask is then the block-diffusion
+    one: a noised query sees its own noised block and the clean blocks
+    before it, a clean query the clean blocks up to its own.  With
+    ``head_qk_norm`` each head of q and of k is RMS-normalised (one scale
+    vector of ``head_dim`` for q, one for k, shared by the heads) before
+    the rotation.  With ``attn_gate`` head i's output is multiplied by
+    ``sigmoid(h Wg)_i``, a scalar a head and token from the block's normed
+    input.  Each K / V head is repeated across its query heads before the
+    kernels (their index maps taking several query heads a K / V block is
+    ROADMAP M4)."""
     mb, s, _ = x.shape
     windowed = kind == "swa"
     hq, hkv, hd = (_attn_heads(cfg, kind), cfg.n_kv_heads or cfg.n_heads,
@@ -713,17 +760,25 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                           w.astype(x.dtype)).reshape(mb, s, n, hd)
 
     q, k, v = heads(lp["wq"], hq), heads(lp["wk"], hkv), heads(lp["wv"], hkv)
+    if cfg.head_qk_norm:
+        with scope("attn_qknorm"):
+            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     theta, fraction, yarn = (
         (cfg.window_rope_theta, 1.0, None) if windowed
         else (cfg.rope_theta, cfg.rope_fraction, cfg.rope_yarn))
     if theta is not None:
         with scope("attn_rope"):
-            q, k = (_rope(t, jnp.arange(s), theta, fraction, yarn)
+            def positions():          # one arange a tensor, as ever
+                at = jnp.arange(s)
+                return at if cfg.diffusion_block is None else at % (s // 2)
+            q, k = (_rope(t, positions(), theta, fraction, yarn)
                     for t in (q, k))
     if hkv != hq:
         k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
     o = ra.full_attention(q, k, v, causal=True,
-                          window=cfg.attn_window if windowed else None)
+                          window=cfg.attn_window if windowed else None,
+                          diffusion_block=cfg.diffusion_block)
     if cfg.attn_gate:
         with scope("attn_gate"):
             gate = jax.nn.sigmoid(jnp.einsum(
@@ -867,7 +922,8 @@ def _make_stage_fn(cfg: TransformerConfig):
 
 def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
                  params: Dict[str, Any], tokens: jax.Array,
-                 labels: jax.Array, with_routing: bool = False):
+                 labels: jax.Array, with_routing: bool = False,
+                 weights: Optional[jax.Array] = None):
     """Per-device loss body; call inside shard_map over mesh (dp, pp, mp).
 
     tokens/labels: (B_local, S) int32 shards (batch over dp).
@@ -877,11 +933,26 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     batch's tokens layer by layer and averaged over the layers.
     ``with_routing`` (dropless MoE only) returns ``(loss, routing)``, the
     replicated dict :func:`make_routing_fn` documents.
+
+    With ``cfg.diffusion_block``: ``tokens`` (B_local, 2 S) — the noised
+    copy of a sequence, then its clean copy — and ``labels`` and fp32
+    ``weights`` (B_local, S).  All 2 S positions go through the stack; the
+    final norm and the head run on the first S (the noised copy) alone, and
+    the loss is ``sum(weights * -log softmax(logits)[labels])`` over the
+    global batch's B x S positions, divided by B x S.
     """
     _check_layout(cfg, par)
     s_full = cfg.seq_len
     mp_size = axis_size("mp")
-    s_local = s_full // mp_size
+    diffusion = cfg.diffusion_block is not None
+    if diffusion != (weights is not None) or (
+            diffusion and tokens.shape[1] != 2 * s_full):
+        raise ValueError(
+            "a diffusion_block configuration, and no other, takes tokens of "
+            "2 x seq_len positions and, after labels, weights of seq_len: "
+            f"got tokens {tokens.shape}, weights "
+            f"{None if weights is None else weights.shape}")
+    s_local = tokens.shape[1] // mp_size
     mp_idx = lax.axis_index("mp")
 
     # Embedding (replicated weights; computed once per device, then the
@@ -929,6 +1000,9 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     # Final norm + logits (tied to the embedding, or ``lm_head``) + CE on
     # the local sequence chunk.
     with scope("head"):
+        if diffusion:
+            # The noised copy alone is scored (mp is 1: the halves are whole).
+            hidden, s_local = hidden[:, :s_full], s_full
         hidden = _rmsnorm(hidden, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bsd,vd->bsv", hidden.astype(jnp.float32),
                             params["embed" if cfg.tied_head
@@ -938,11 +1012,20 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
         logp = jax.nn.log_softmax(logits, axis=-1)
         ll = jnp.take_along_axis(logp, labels_local[..., None],
                                  axis=-1)[..., 0]
-        loss_local = -jnp.mean(ll)
+        if diffusion:
+            loss_local = -jnp.sum(ll * weights.astype(jnp.float32))
+        else:
+            loss_local = -jnp.mean(ll)
 
     # Average over sequence chunks (mp) and batch shards (dp); the loss is
     # only valid on the last pipeline stage → masked psum over pp.
-    loss = lax.pmean(lax.pmean(loss_local, "mp"), "dp")
+    if diffusion:
+        # A sum of the shards' sums over the global count, not a mean of
+        # means.
+        loss = lax.psum(loss_local, "dp") / (
+            labels.size * axis_size("dp"))
+    else:
+        loss = lax.pmean(lax.pmean(loss_local, "mp"), "dp")
     loss = lax.psum(loss * pp_lib.last_stage_mask("pp"), "pp")
     if not _routes_dropless(cfg):
         return loss
@@ -950,7 +1033,7 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     # statistics over every device's tokens first, so that the loss is the
     # same on every layout.
     stats = lax.psum(stats, ("dp", "mp"))
-    n_tokens = tokens.shape[0] * s_full * axis_size("dp")
+    n_tokens = tokens.size * axis_size("dp")      # the positions routed
     balance, z = moe_lib.router_losses(stats, n_tokens)      # (layers,) each
     loss = (loss + cfg.aux_loss_coef * jnp.mean(balance)
             + cfg.z_loss_coef * jnp.mean(z))
@@ -962,24 +1045,29 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
 
 def make_loss_fn(cfg: TransformerConfig, par: ParallelConfig, mesh,
                  with_routing: bool = False):
-    """Global-array loss: shard_map of ``forward_loss`` over (dp, pp, mp)."""
+    """Global-array loss: shard_map of ``forward_loss`` over (dp, pp, mp),
+    ``loss_of(params, tokens, labels)``; a ``diffusion_block``
+    configuration's takes its third batch array, ``weights``, last."""
     from ..compat import shard_map
     specs = param_specs(cfg, par)
     data_spec = P("dp")
 
-    def loss_of(params, tokens, labels):
+    def loss_of(params, tokens, labels, *weights):
         fn = shard_map(
-            lambda p, t, l: forward_loss(cfg, par, p, t, l, with_routing),
-            mesh=mesh, in_specs=(specs, data_spec, data_spec),
+            lambda p, t, l, *w: forward_loss(cfg, par, p, t, l, with_routing,
+                                             *w),
+            mesh=mesh, in_specs=(specs,) + (data_spec,) * (2 + len(weights)),
             out_specs=P(), check_vma=False)
-        return fn(params, tokens, labels)
+        return fn(params, tokens, labels, *weights)
 
     return loss_of
 
 
 def make_routing_fn(cfg: TransformerConfig, par: ParallelConfig, mesh):
-    """``routing(params, tokens, labels)`` for a dropless MoE: what the
-    router did with one global batch, through the training forward itself.
+    """``routing(params, tokens, labels)`` (and ``weights`` with a
+    ``diffusion_block``, whose 2 x seq_len positions are all routed) for a
+    dropless MoE: what the router did with one global batch, through the
+    training forward itself.
     A dict of ``assignments`` (layers, experts) — (token, choice) pairs each
     expert received; ``load`` (layers,) — the busiest expert's assignments
     over the mean's; ``dropped`` — pairs routed less pairs assigned (0:
@@ -993,8 +1081,8 @@ def make_routing_fn(cfg: TransformerConfig, par: ParallelConfig, mesh):
     loss_of = make_loss_fn(cfg, par, mesh, with_routing=True)
     held = _experts_held(cfg)
 
-    def routing(params, tokens, labels):
-        loss, r = loss_of(params, tokens, labels)
+    def routing(params, tokens, labels, *weights):
+        loss, r = loss_of(params, tokens, labels, *weights)
         counts = r["assignments"]
         routed = tokens.size * cfg.top_k * math.prod(counts.shape[:-1])
         return {**r, "loss": loss,
@@ -1094,15 +1182,17 @@ def make_train_step(cfg: TransformerConfig, par: ParallelConfig, mesh,
     """Build a jitted train step over the (dp, pp, mp) mesh.
 
     Returns (train_step, shard_params) where ``train_step(params, opt_state,
-    tokens, labels) -> (params, opt_state, loss)``.  Differentiation happens
-    *outside* shard_map, so gradient reductions over every axis come from AD
-    transposes — no hand-written grad sync.
+    tokens, labels) -> (params, opt_state, loss)`` (a ``diffusion_block``
+    configuration's takes ``weights`` after ``labels``).  Differentiation
+    happens *outside* shard_map, so gradient reductions over every axis come
+    from AD transposes — no hand-written grad sync.
     """
     specs = param_specs(cfg, par)
     loss_of = make_loss_fn(cfg, par, mesh)
 
-    def train_step(params, opt_state, tokens, labels):
-        loss, grads = jax.value_and_grad(loss_of)(params, tokens, labels)
+    def train_step(params, opt_state, tokens, labels, *weights):
+        loss, grads = jax.value_and_grad(loss_of)(params, tokens, labels,
+                                                  *weights)
         with scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = jax.tree_util.tree_map(lambda p, u: p + u, params,
@@ -1122,11 +1212,43 @@ def make_train_step(cfg: TransformerConfig, par: ParallelConfig, mesh,
 
 
 def synthetic_batch(key, cfg: TransformerConfig, batch: int):
+    """A random batch for ``cfg``: (tokens, next-token labels), or for a
+    ``diffusion_block`` configuration :func:`noised_batch`'s three arrays,
+    the mask token being the vocabulary's last id and the data the others."""
     kt, kl = jax.random.split(key)
+    # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no such field.
+    block = getattr(cfg, "diffusion_block", None)
+    if block is not None:
+        ids = jax.random.randint(kt, (batch, cfg.seq_len), 0,
+                                 cfg.vocab_size - 1, dtype=jnp.int32)
+        return noised_batch(kl, ids, block, cfg.vocab_size - 1)
     tokens = jax.random.randint(kt, (batch, cfg.seq_len), 0, cfg.vocab_size,
                                 dtype=jnp.int32)
     labels = jnp.roll(tokens, -1, axis=1)
     return tokens, labels
+
+
+def noised_batch(key, ids: jax.Array, block: int, mask_id: int,
+                 floor: float = 1e-3):
+    """The three arrays of a block-diffusion step from clean ``ids`` (B, L),
+    made outside the step (the program knows nothing of the schedule).  Each
+    block of ``block`` positions draws ``t = floor + (1 - floor) u``, ``u ~
+    U(0, 1)`` (the linear schedule ``alpha_t = 1 - t``, one t a block as
+    BD3-LMs, the floor as LLaDA), and each of its positions is replaced by
+    ``mask_id`` independently with probability t.  Returns ``tokens`` (B,
+    2 L) int32 — the noised copy, then the clean one — ``labels`` = ``ids``,
+    and ``weights`` (B, L) fp32 = masked / t, the NELBO's ``-alpha'_t / (1 -
+    alpha_t)`` on the masked positions and 0 elsewhere."""
+    b, length = ids.shape
+    if length % block:
+        raise ValueError(f"{length} positions are not whole blocks of {block}")
+    ku, km = jax.random.split(key)
+    t = floor + (1.0 - floor) * jax.random.uniform(ku, (b, length // block))
+    t = jnp.repeat(t, block, axis=1)
+    masked = jax.random.uniform(km, (b, length)) < t
+    noised = jnp.where(masked, jnp.int32(mask_id), ids)
+    return (jnp.concatenate([noised, ids], axis=1).astype(jnp.int32), ids,
+            (masked / t).astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -1168,6 +1290,9 @@ def _check_servable(cfg: TransformerConfig) -> None:
         ("a layer_pattern (state-space, grouped-query and latent-expert "
          "blocks)", cfg.layer_pattern is not None),
         ("a share of the experts held", _holds_a_share(cfg)),
+        ("a diffusion_block (generation by blocks: ROADMAP M10)",
+         cfg.diffusion_block is not None),
+        ("per-head QK-norm", cfg.head_qk_norm),
         ("a sigmoid router", cfg.router_scoring != "softmax")] if on]
     if refused:
         raise NotImplementedError(
@@ -1392,9 +1517,12 @@ def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
         hq = _attn_heads(cfg, BLOCK_KINDS[letter][0])
         hkv, hd = cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
         # (query, key) pairs a query: the causal half, or the band's
-        # window S - window (window - 1) / 2 pairs a sequence.
+        # window S - window (window - 1) / 2 pairs a sequence, or under the
+        # block-diffusion mask S^2 + S block pairs over 2 S positions.
         w = min(cfg.attn_window, s) if letter == "W" else 0
         pairs = w - w * (w - 1) / (2.0 * s) if w else s / 2.0
+        if cfg.diffusion_block is not None:
+            pairs = (s + cfg.diffusion_block) / 2.0
         gate = 2.0 * d * hq if cfg.attn_gate else 0.0
         return (2.0 * d * hd * (2 * hq + 2 * hkv) + gate
                 + 4.0 * pairs * hq * hd)
@@ -1410,8 +1538,8 @@ def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
 
 
 def train_flops_per_seq(cfg: TransformerConfig) -> float:
-    """Matmul-FLOPs for one causal-LM training sequence (train = 3x
-    fwd), importable so training loops can feed
+    """Matmul-FLOPs for one training sequence of ``seq_len`` data tokens
+    (train = 3x fwd), importable so training loops can feed
     ``hvd.metrics.set_step_flops()``.  Dense per token 8d^2 (qkv+proj)
     + 4*d*ff (mlp) per layer + 2dV vocab head; causal attention
     2*S^2*d per layer per seq (half the bidirectional 4*S^2*d — the
@@ -1422,7 +1550,10 @@ def train_flops_per_seq(cfg: TransformerConfig) -> float:
     # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no pattern.
     if getattr(cfg, "layer_pattern", None) is not None:
         blocks = cfg.leading_pattern + _n_periods(cfg) * cfg.layer_pattern
-        return 3.0 * s * (2.0 * d * v + sum(
+        # Block diffusion: a token is two positions in the blocks, one at
+        # the head.
+        through = 2.0 if cfg.diffusion_block is not None else 1.0
+        return 3.0 * s * (2.0 * d * v + through * sum(
             _block_flops_per_token(cfg, c) for c in blocks))
     dense = s * (L * (8.0 * d * d + _mlp_flops_per_token(cfg)) + 2.0 * d * v)
     attn = L * 2.0 * s * s * d

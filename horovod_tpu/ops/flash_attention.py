@@ -79,6 +79,29 @@ Its kernels are named ``hvd_flash_fwd_win``, ``hvd_flash_bwd_dq_win`` and
 ``hvd_flash_bwd_dkv_win``; a call without a window traces to what it always
 did.
 
+A call with a ``diffusion_block`` (block-diffusion training: the sequence is
+a noised copy and then the clean copy of the same L tokens, in blocks; a
+noised query sees its own noised block and the clean blocks strictly before
+it, a clean query the clean blocks up to its own, nobody else a noised key)
+runs the same bodies again, ``_scores_t`` masking a tile by the rule from
+the tile's global starts, over grids that follow the mask: the tile is
+picked from the *half* (one width for both sides: 4096 takes 1024 at a bf16
+head of 128), and with n = L / tile tiles a half each grid walks the n^2 + 2n
+tiles of the 4 n^2 that hold an unmasked pair (24 of 64 at n = 4; a causal
+call of the same 2L positions has 36 live).  The step-to-tile map is
+``_bd_streamed``: the forward n + 1 steps a resident query tile, the
+backward pass 2n a resident key tile, a step past a resident tile's live run
+naming the block its neighbour read (nothing fetched) and skipped by the
+body, as the band's clamped steps are: n^2 skipped of the forward's 2 n^2 +
+2n steps, 3 n^2 - 2n of the backward pass's 4 n^2.  The kernels are named
+``hvd_flash_fwd_bd``, ``hvd_flash_bwd_dq_bd`` and ``hvd_flash_bwd_dkv_bd``,
+dQ's resident scratch covers all 2L queries (24 MiB of VMEM at 8192 x 128),
+and a trace-time counter, ``hvd_flash_tiles_built_total{kernel, state}``,
+holds the live and skipped steps each grid was built with.  Bare on a v5e,
+(1, 8192, 32, 128) bf16, block 4, ms a call (PERF.md section 6, PR 39): the
+forward 5.57 and the backward 9.02, where the causal call of the same
+length takes 7.65 and 11.52 for half as many live pairs again.
+
 Three kernels, two of which see scores:
 
 * ``_fwd_kernel`` (``hvd_flash_fwd``) — out + logsumexp, online softmax over
@@ -106,8 +129,8 @@ Three kernels, two of which see scores:
 
 Public API:
 
-* ``flash_attention(q, k, v, causal=…, window=…)`` — differentiable (custom
-  VJP).
+* ``flash_attention(q, k, v, causal=…, window=…, diffusion_block=…)`` —
+  differentiable (custom VJP).
 * ``flash_attention_with_lse`` — also returns logsumexp rows, which is the
   composition hook ring attention (parallel/ring_attention.py) uses to
   merge per-ring-step partials into an exact global softmax.
@@ -150,6 +173,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..metrics.registry import registry
 
 _NEG_INF = -1e30
 
@@ -199,10 +224,12 @@ def _pick_block(size: int, widest: int, env: str = "") -> Optional[int]:
     return size if size <= _NARROW_BLOCK else None
 
 
-def _win(window: Optional[int]) -> str:
-    """A windowed call's kernels carry their own names: the same prefixes,
-    so a reader that matches kernels by prefix counts them, and a suffix
-    that tells them apart."""
+def _suffix(window: Optional[int], bd=None) -> str:
+    """A windowed or block-diffusion call's kernels carry their own names:
+    the same prefixes, so a reader that matches kernels by prefix counts
+    them, and a suffix that tells them apart."""
+    if bd is not None:
+        return "_bd"
     return "" if window is None else "_win"
 
 
@@ -211,14 +238,20 @@ def _win(window: Optional[int]) -> str:
 # ---------------------------------------------------------------------------
 
 def _scores_t(q, k, *, causal: bool, scale: float, q_start, k_start,
-              window: Optional[int] = None):
+              window: Optional[int] = None, bd=None):
     """The transposed score tile Sᵀ = K·Qᵀ · scale, (bk, bq) fp32, from the
     caller's (bq, D) and (bk, D) tiles as they are; causally masked at the
-    tile's global positions, and with a ``window`` to the band ``q_pos -
-    window < k_pos <= q_pos``."""
+    tile's global positions, with a ``window`` to the band ``q_pos -
+    window < k_pos <= q_pos``, with ``bd`` by the block-diffusion rule."""
     st = jax.lax.dot_general(
         k, q, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
+    if bd is not None:
+        q_lo, k_lo, ahead, behind, shift = _bd_quadrant(q_start, k_start, bd)
+        kb = (k_lo + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)) >> shift
+        qb = (q_lo + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)) >> shift
+        keep = jnp.logical_and(kb <= qb - ahead, kb >= qb - behind)
+        return jnp.where(keep, st, _NEG_INF)
     if causal:
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
@@ -270,9 +303,13 @@ def _band_extent(n_res: int, block_res: int, block_str: int, window: int,
 
 
 def _streamed_tile(i, j, n_str: int, block_res: int, block_str: int,
-                   window: Optional[int], keys_streamed: bool):
-    """(streamed tile of grid step (i, j), whether it lies in the sequence).
-    Without a window the grid walks every tile: j itself."""
+                   window: Optional[int], keys_streamed: bool, bd=None):
+    """(streamed tile of grid step (i, j), whether the step is one of the
+    resident tile's own): the step-to-tile map the mask gives.  Without a
+    mask of its own the grid walks every tile, j itself; a window's walks
+    the band, a block-diffusion call's the live tiles."""
+    if bd is not None:
+        return _bd_streamed(i, j, n_str // 2, keys_streamed)
     if window is None:
         return j, True
     t = _band_first(i, block_res, block_str, window, keys_streamed) + j
@@ -280,10 +317,18 @@ def _streamed_tile(i, j, n_str: int, block_res: int, block_str: int,
 
 
 def _tile_live(q_start, k_start, block_q: int, block_k: int, causal: bool,
-               window: Optional[int], inside):
+               window: Optional[int], inside, bd=None):
     """Whether a score tile has an unmasked pair.  Causal: unless every
     (q, k) has q_pos < k_pos; with a window also unless every k lies at or
-    before q_pos - window; a step clamped at the sequence's end is none."""
+    before q_pos - window; a step clamped at the sequence's end is none.
+    Block diffusion: a step of the resident tile's live run, unless the
+    quadrant's rule leaves the tile's blocks no pair (a block as wide as the
+    tile hides the clean copy of a noised tile's own positions)."""
+    if bd is not None:
+        q_lo, k_lo, ahead, behind, shift = _bd_quadrant(q_start, k_start, bd)
+        return jnp.logical_and(inside, jnp.logical_and(
+            (k_lo >> shift) <= ((q_lo + block_q - 1) >> shift) - ahead,
+            ((k_lo + block_k - 1) >> shift) >= (q_lo >> shift) - behind))
     if not causal:
         return True
     live = q_start + block_q - 1 >= k_start
@@ -293,15 +338,97 @@ def _tile_live(q_start, k_start, block_q: int, block_k: int, causal: bool,
             inside)
     return live
 
+# ---------------------------------------------------------------------------
+# The mask and the walk of a block-diffusion call
+# ---------------------------------------------------------------------------
+#
+# The sequence is two halves of L positions: a noised copy (positions 0 ..
+# L-1) and the clean copy (L .. 2L-1) of the same L tokens, in blocks of
+# ``block`` positions, ``beta(p) = (p mod L) // block``.  Query q sees key k
+# iff  both noised and beta(q) == beta(k);  or q noised, k clean and
+# beta(k) < beta(q);  or both clean and beta(k) <= beta(q).  Nobody else sees
+# a noised key.  A tile divides the half, so it lies in one quadrant, and
+# every quadrant's rule is  beta(q) - behind <= beta(k) <= beta(q) - ahead
+# for two scalars of the quadrant (``_bd_quadrant``).
+#
+# With n = L / tile the square has 4 n^2 tiles of which n^2 + 2n hold an
+# unmasked pair, and each grid walks those alone (``_bd_streamed``): the
+# forward n + 1 steps a resident query tile (a noised tile r reads clean key
+# tiles n .. n + r, then its own noised tile; a clean tile n + r reads n ..
+# n + r), the backward pass 2n steps a resident key tile (a noised tile c
+# reads query tile c alone; a clean tile n + c reads noised query tiles c ..
+# n - 1, then clean ones n + c .. 2n - 1).  A step past a resident tile's
+# live run names the block the run's last step read, so nothing is fetched,
+# and the body skips it: n^2 of the forward's 2 n^2 + 2n steps and 3 n^2 -
+# 2n of the backward pass's 4 n^2.
+
+_FAR = 1 << 24      # more blocks than any sequence has
+
+
+def _bd_quadrant(q_start, k_start, bd):
+    """For the tile at these global starts, ``bd`` = (block, positions a
+    half): (the tile's first query's position in its half, its first key's,
+    ``ahead``, ``behind``, log2 of the block — a power of two, so a
+    position's block is a shift).  A query of block b sees the keys of
+    blocks b - behind .. b - ahead of the key's half."""
+    block, half = bd
+    q_noised, k_noised = q_start < half, k_start < half
+    ahead = jnp.where(k_noised, jnp.where(q_noised, 0, _FAR),
+                      jnp.where(q_noised, 1, 0))
+    behind = jnp.where(k_noised, 0, _FAR)
+    return (q_start - jnp.where(q_noised, 0, half),
+            k_start - jnp.where(k_noised, 0, half), ahead, behind,
+            block.bit_length() - 1)
+
+
+def _bd_streamed(i, j, n: int, keys_streamed: bool):
+    """(streamed tile of step j of resident tile i, whether the step is in
+    the tile's live run) with n tiles a half; Python ints or a grid's traced
+    int32s.  Keys streamed past resident queries: the forward.  Queries
+    streamed past resident keys: the backward pass."""
+    if isinstance(i, int):
+        where, least = (lambda c, a, b: a if c else b), min
+    else:
+        where, least = jnp.where, jnp.minimum
+    noised = i < n
+    r = where(noised, i, i - n)
+    if keys_streamed:
+        last = where(noised, r + 1, r)
+        step = least(j, last)
+        tile = where(step > r, r, n + step)     # past the clean run: its own
+    else:
+        last = where(noised, 0, 2 * (n - r) - 1)
+        step = least(j, last)
+        tile = where(noised, r, where(step < n - r, r + step, step + 2 * r))
+    return tile, j <= last
+
+
+def _bd_steps(n: int, keys_streamed: bool) -> int:
+    return n + 1 if keys_streamed else 2 * n
+
+
+def _bd_built(kernel: str, n: int, keys_streamed: bool, calls: int) -> None:
+    """Trace-time count of the tile steps a block-diffusion call's grid was
+    built with, live and skipped, over its ``calls`` (batch, head)s."""
+    live = n * n + 2 * n
+    for state, steps in (("live", live),
+                         ("skipped",
+                          2 * n * _bd_steps(n, keys_streamed) - live)):
+        registry().counter(
+            "hvd_flash_tiles_built_total",
+            "tile steps of the block-diffusion flash grids traced, by "
+            "kernel and state", kernel=kernel, state=state).inc(calls * steps)
+
 
 def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
                 block_q: int, block_k: int, window: Optional[int] = None,
-                n_str: int = 0):
+                n_str: int = 0, bd=None):
     i = pl.program_id(2)          # q tile
     j = pl.program_id(3)          # k step (innermost: scratch carries over j)
     nk = pl.num_programs(3)
-    kt, inside = _streamed_tile(i, j, n_str, block_q, block_k, window, True)
+    kt, inside = _streamed_tile(i, j, n_str, block_q, block_k, window, True,
+                                bd)
 
     @pl.when(j == 0)
     def _init():
@@ -314,7 +441,7 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     q_start = q_off + i * block_q
     k_start = kv_off + kt * block_k
     live = _tile_live(q_start, k_start, block_q, block_k, causal, window,
-                      inside)
+                      inside, bd)
 
     @pl.when(live)
     def _compute():
@@ -322,7 +449,7 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0, 0]                # (bk, D)
         v = v_ref[0, 0]
         st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
-                       k_start=k_start, window=window)         # (bk, bq)
+                       k_start=k_start, window=window, bd=bd)  # (bk, bq)
         m_prev = m_scr[:1, :]                                  # (1, bq)
         l_prev = l_scr[:1, :]
         m_cur = jnp.max(st, axis=0, keepdims=True)
@@ -349,10 +476,14 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _streamed_index(n_res: int, n_str: int, block_res: int, block_str: int,
-                    window: Optional[int], keys_streamed: bool):
+                    window: Optional[int], keys_streamed: bool, bd=None):
     """(steps of the streamed grid axis, grid indices (i, j) -> the streamed
-    tile's block index): every tile without a window, with one the band's
-    tiles clamped into the sequence."""
+    tile's block index): every tile without a mask of its own, with a
+    window the band's tiles clamped into the sequence, with ``bd`` the
+    resident tile's live tiles and past them the last of those."""
+    if bd is not None:
+        return (_bd_steps(n_str // 2, keys_streamed),
+                lambda i, j: _bd_streamed(i, j, n_str // 2, keys_streamed)[0])
     if window is None:
         return n_str, lambda i, j: j
     steps = _band_extent(n_res, block_res, block_str, window, keys_streamed)
@@ -364,16 +495,19 @@ def _streamed_index(n_res: int, n_str: int, block_res: int, block_str: int,
 
 
 def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
-              block_q, block_k, interpret, window=None):
+              block_q, block_k, interpret, window=None, bd=None):
     b, h, sq, d = q_bhsd.shape
     sk = k_bhsd.shape[2]
     nq, nk = sq // block_q, sk // block_k
-    steps, kt = _streamed_index(nq, nk, block_q, block_k, window, True)
+    steps, kt = _streamed_index(nq, nk, block_q, block_k, window, True, bd)
     grid = (b, h, nq, steps)
     kern = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k)
     if window is not None:
         kern = functools.partial(kern, window=window, n_str=nk)
+    if bd is not None:
+        kern = functools.partial(kern, n_str=nk, bd=bd)
+        _bd_built("hvd_flash_fwd_bd", nk // 2, True, b * h)
     out, lse = pl.pallas_call(
         kern,
         grid=grid,
@@ -409,7 +543,7 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
         interpret=interpret,
-        name="hvd_flash_fwd" + _win(window),
+        name="hvd_flash_fwd" + _suffix(window, bd),
     )(offsets, q_bhsd, k_bhsd, v_bhsd)
     return out, lse[:, :, 0, :]
 
@@ -421,7 +555,7 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
 def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dqt_ref, dk_scr, dv_scr, dq_scr, *,
                 causal: bool, scale: float, block_q: int, block_k: int,
-                window: Optional[int] = None, n_str: int = 0):
+                window: Optional[int] = None, n_str: int = 0, bd=None):
     """The one pass over the score tiles: a key tile resident, query tiles
     streamed past it.  dK and dV of the resident tile are summed over the
     inner axis; dQᵀ of every query tile of the (batch, head) lives in
@@ -430,7 +564,8 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     j = pl.program_id(3)          # q step (innermost)
     nk = pl.num_programs(2)
     nq = pl.num_programs(3)
-    qt, inside = _streamed_tile(i, j, n_str, block_k, block_q, window, False)
+    qt, inside = _streamed_tile(i, j, n_str, block_k, block_q, window, False,
+                                bd)
 
     @pl.when(j == 0)
     def _init():
@@ -446,7 +581,7 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_start = q_off + qt * block_q
     k_start = kv_off + i * block_k
     live = _tile_live(q_start, k_start, block_q, block_k, causal, window,
-                      inside)
+                      inside, bd)
 
     @pl.when(live)
     def _compute():
@@ -457,7 +592,7 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse = lse_ref[0, 0][:1, :]                             # (1, bq)
         delta = delta_ref[0, 0][:1, :]
         st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
-                       k_start=k_start, window=window)         # (bk, bq)
+                       k_start=k_start, window=window, bd=bd)  # (bk, bq)
         pt = jnp.where(jnp.logical_or(st <= _NEG_INF / 2,
                                       lse <= _NEG_INF / 2),
                        0.0, jnp.exp(st - lse))                 # (bk, bq)
@@ -519,7 +654,8 @@ def _bwd_vmem_limit(sq: int, d: int, dtype) -> int:
 
 
 def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
-              causal, scale, block_q, block_k, interpret, window=None):
+              causal, scale, block_q, block_k, interpret, window=None,
+              bd=None):
     b, h, sq, d = q_bhsd.shape
     sk = k_bhsd.shape[2]
     nq, nk = sq // block_q, sk // block_k
@@ -547,10 +683,14 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
                              block_q=block_q, block_k=block_k)
     if window is not None:
         kern = functools.partial(kern, window=window, n_str=nq)
+    if bd is not None:
+        kern = functools.partial(kern, n_str=nq, bd=bd)
+        _bd_built("hvd_flash_bwd_dkv_bd", nq // 2, False, b * h)
 
     # The pass: grid over (k tiles, q steps), q innermost.  dQᵀ's block is
     # the whole (batch, head)'s, resident from its first step to its last.
-    q_steps, qt = _streamed_index(nk, nq, block_k, block_q, window, False)
+    q_steps, qt = _streamed_index(nk, nq, block_k, block_q, window, False,
+                                  bd)
     dk, dv, dqt = pl.pallas_call(
         kern,
         grid=(b, h, nk, q_steps),
@@ -587,7 +727,7 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
                                  "arbitrary"),
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
-        name="hvd_flash_bwd_dkv" + _win(window),
+        name="hvd_flash_bwd_dkv" + _suffix(window, bd),
     )(offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
 
     # dQ: no dot, no exp; a read and a write of dQ, ``hb`` heads a step.
@@ -604,7 +744,7 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-        name="hvd_flash_bwd_dq" + _win(window),
+        name="hvd_flash_bwd_dq" + _suffix(window, bd),
     )(dqt)
     return dq, dk, dv
 
@@ -613,29 +753,29 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
 # Differentiable entry points (custom VJP on (B, S, H, D) layout)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
-           window=None):
+           window=None, bd=None):
     out, _ = _flash_impl(q, k, v, offsets, causal, scale, block_q, block_k,
-                         interpret, window)
+                         interpret, window, bd)
     return out
 
 
 def _flash_impl(q, k, v, offsets, causal, scale, block_q, block_k,
-                interpret, window=None):
+                interpret, window=None, bd=None):
     qt = q.transpose(0, 2, 1, 3)      # (B, H, S, D)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out, lse = _fwd_call(qt, kt, vt, offsets, causal=causal, scale=scale,
                          block_q=block_q, block_k=block_k,
-                         interpret=interpret, window=window)
+                         interpret=interpret, window=window, bd=bd)
     return out.transpose(0, 2, 1, 3), lse
 
 
 def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
-               window=None):
+               window=None, bd=None):
     out, lse = _flash_impl(q, k, v, offsets, causal, scale, block_q,
-                           block_k, interpret, window)
+                           block_k, interpret, window, bd)
     # Named for ``checkpoint_keeping_attention``.  The primal output and the
     # residual are both the named value reshaped back: were either the
     # kernel's own output beside a named copy, the recompute would need the
@@ -647,7 +787,8 @@ def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
     return out, (q, k, v, offsets, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, bd, res,
+               g):
     q, k, v, offsets, out, lse = res
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -662,7 +803,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
     dq, dk, dv = _bwd_call(qt, kt, vt, dot, lse, delta, offsets,
                            causal=causal, scale=scale, block_q=block_q,
                            block_k=block_k, interpret=interpret,
-                           window=window)
+                           window=window, bd=bd)
     d_off = np.zeros(offsets.shape, dtype=jax.dtypes.float0)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3), d_off)
@@ -671,13 +812,16 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _supported(q, k, window: Optional[int] = None
+def _supported(q, k, window: Optional[int] = None,
+               diffusion_block: Optional[int] = None
                ) -> Optional[Tuple[int, int]]:
     """The (block_q, block_k) all three kernels tile these (B, S, H, D)
     shapes with, or None where they cannot.  Read from the two sequence
     lengths, from the head's width and type for what fits VMEM (a tile's
     blocks, and the queries' whole dQ in the backward), and from the window
-    where the call has one."""
+    where the call has one.  A block-diffusion call's tile is one width for
+    both sides, the widest candidate that divides the *half* (the walk
+    counts in tiles of a half) and that the block divides."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if d % 8 != 0 or d > 512:
@@ -687,8 +831,15 @@ def _supported(q, k, window: Optional[int] = None
               else _NARROW_BLOCK)
     if window is not None:
         widest = min(widest, _window_block(window))
-    bq = _pick_block(sq, widest, env="HVD_TPU_FLASH_BLOCK_Q")
-    bk = _pick_block(sk, widest, env="HVD_TPU_FLASH_BLOCK_K")
+    if diffusion_block is not None:
+        if sq != sk or sq % 2:
+            return None
+        bq = bk = next((c for c in _BLOCK_CANDIDATES
+                        if c <= widest and (sq // 2) % c == 0
+                        and c % diffusion_block == 0), None)
+    else:
+        bq = _pick_block(sq, widest, env="HVD_TPU_FLASH_BLOCK_Q")
+        bk = _pick_block(sk, widest, env="HVD_TPU_FLASH_BLOCK_K")
     if bq is None or bk is None:
         return None
     if _bwd_vmem_limit(sq, d, q.dtype) > _VMEM_CEILING_BYTES:
@@ -733,25 +884,56 @@ def _checked_window(window, causal, q, k, q_offset, kv_offset):
     return None if window >= k.shape[1] else int(window)
 
 
+def _checked_diffusion(diffusion_block, causal, window, q, k, q_offset,
+                       kv_offset):
+    """``diffusion_block`` as the kernels take it: (block, positions a
+    half), or None for a call without the mask."""
+    if diffusion_block is None:
+        return None
+    block = int(diffusion_block)
+    if block < 1 or block & (block - 1):
+        raise ValueError(f"diffusion_block {diffusion_block} must be a "
+                         "power of two (it divides the kernels' tile)")
+    if not causal or window is not None:
+        raise ValueError("a block-diffusion mask is a causal call's, and "
+                         "exclusive with a window")
+    if q.shape[1] != k.shape[1] or q.shape[1] % (2 * block) or not (
+            isinstance(q_offset, int) and isinstance(kv_offset, int)
+            and q_offset == kv_offset == 0):
+        raise NotImplementedError(
+            "a block-diffusion call takes one whole doubled sequence: equal "
+            "lengths, two halves of whole blocks and no offsets (the walk "
+            "of the live tiles is laid out at trace time)")
+    return block, q.shape[1] // 2
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, scale: Optional[float] = None,
                     q_offset=0, kv_offset=0,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    diffusion_block: Optional[int] = None) -> jax.Array:
     """Differentiable fused attention; (B, S, H, D) in and out.
 
     ``window`` (causal only) keeps for each query the ``window`` keys up to
-    and including its own.  A shape the kernels cannot tile (``_supported``
-    is None) takes the XLA path with the same semantics."""
+    and including its own.  ``diffusion_block`` (causal only, no window):
+    the sequence is a noised copy and then the clean copy of the same
+    tokens, in blocks of that many positions; a noised query sees its own
+    noised block and the clean blocks strictly before it, a clean query the
+    clean blocks up to its own (:func:`diffusion_mask`).  A shape the
+    kernels cannot tile (``_supported`` is None) takes the XLA path with the
+    same semantics."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    bd = _checked_diffusion(diffusion_block, causal, window, q, k, q_offset,
+                            kv_offset)
     window = _checked_window(window, causal, q, k, q_offset, kv_offset)
-    blocks = _supported(q, k, window)
+    blocks = _supported(q, k, window, diffusion_block)
     if blocks is None:
-        out, _ = _xla_attention_with_lse(q, k, v, causal, scale,
-                                         q_offset, kv_offset, window)
+        out, _ = _xla_attention_with_lse(q, k, v, causal, scale, q_offset,
+                                         kv_offset, window, diffusion_block)
         return out
     bq, bk = blocks
     if block_q:
@@ -764,43 +946,68 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             raise ValueError(
                 f"block_k={block_k} must divide seq_k={k.shape[1]}")
         bk = block_k
+    if bd is not None and (bq != bk or bd[1] % bq or bq % bd[0]):
+        raise ValueError(
+            f"a block-diffusion call has one tile width, which divides the "
+            f"half ({bd[1]}) and which the block ({bd[0]}) divides; got "
+            f"block_q={bq} block_k={bk}")
     offsets = jnp.stack(
         [jnp.asarray(q_offset, jnp.int32),
          jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)
     return _flash(q, k, v, offsets, causal, float(scale), bq, bk,
-                  bool(interpret), window)
+                  bool(interpret), window, bd)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              scale: Optional[float] = None,
                              q_offset=0, kv_offset=0,
-                             interpret: bool = False):
+                             interpret: bool = False,
+                             diffusion_block: Optional[int] = None):
     """Non-differentiable primitive returning (out, lse).
 
     ``lse`` is (B, H, Sq) fp32 — the softmax log-normalizer per query row,
     ``_NEG_INF`` where the row saw no unmasked key. Ring attention merges
     per-step (out, lse) pairs with :func:`combine_blocks`.
     """
-    blocks = _supported(q, k)
+    bd = _checked_diffusion(diffusion_block, causal, None, q, k, q_offset,
+                            kv_offset)
+    blocks = _supported(q, k, None, diffusion_block)
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if blocks is None:
-        return _xla_attention_with_lse(q, k, v, causal, scale,
-                                       q_offset, kv_offset)
+        return _xla_attention_with_lse(q, k, v, causal, scale, q_offset,
+                                       kv_offset, None, diffusion_block)
     offsets = jnp.stack(
         [jnp.asarray(q_offset, jnp.int32),
          jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)
     return _flash_impl(q, k, v, offsets, causal, float(scale), blocks[0],
-                       blocks[1], bool(interpret))
+                       blocks[1], bool(interpret), None, bd)
+
+
+def diffusion_mask(length: int, block: int) -> jax.Array:
+    """The (length, length) boolean mask of a block-diffusion call, query by
+    key, built densely from the rule: ``length`` is the doubled sequence's,
+    a noised half and then the clean half, ``beta(p) = (p mod half) //
+    block``."""
+    half = length // 2
+    pos = jnp.arange(length)
+    noised, beta = pos < half, (pos % half) // block
+    qn, kn = noised[:, None], noised[None, :]
+    qb, kb = beta[:, None], beta[None, :]
+    return ((qn & kn & (qb == kb)) | (qn & ~kn & (kb < qb))
+            | (~qn & ~kn & (kb <= qb)))
 
 
 def _xla_attention_with_lse(q, k, v, causal, scale, q_offset, kv_offset,
-                            window=None):
+                            window=None, diffusion_block=None):
     """XLA fallback with identical (out, lse) semantics."""
     sq, sk = q.shape[1], k.shape[1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    if causal:
+    if diffusion_block is not None:
+        s = jnp.where(diffusion_mask(sq, diffusion_block)[None, None], s,
+                      _NEG_INF)
+    elif causal:
         q_pos = q_offset + jnp.arange(sq)
         k_pos = kv_offset + jnp.arange(sk)
         mask = q_pos[:, None] >= k_pos[None, :]

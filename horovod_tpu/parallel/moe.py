@@ -405,6 +405,42 @@ def _rows_to_pairs_bwd(res, g):
 _rows_to_pairs.defvjp(_rows_to_pairs_fwd, _rows_to_pairs_bwd)
 
 
+@jax.custom_vjp
+def _combine_pairs(y, weights, row_of_pair, pair_kept, pair_of_row, row_used):
+    """(T, d) fp32: ``sum_e weights[t, e] * y[row_of_pair[t, e]]`` over the
+    pairs that have a row: ``_rows_to_pairs`` and the weighted sum behind it
+    as one function whose backward stays in row space (row r came from pair
+    ``pair_of_row[r]``): ``dy[r] = g[token of r] * weight of r`` and
+    ``dweights`` from one dot a row, 0 for the buffer's unused rows.  AD's
+    transpose of the sum broadcasts ``g`` to every (token, held expert)
+    pair — (T, held, d) in fp32, 3 GiB at 24,576 positions, 16 held and
+    2048 features — and the layer's recompute gathers the (T, held, d) rows
+    again for nothing but that product; here neither exists.  Same values,
+    same roundings."""
+    picked = jnp.where(pair_kept[..., None], y[row_of_pair], 0)
+    return jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1)
+
+
+def _combine_pairs_fwd(y, weights, row_of_pair, pair_kept, pair_of_row,
+                       row_used):
+    return (_combine_pairs(y, weights, row_of_pair, pair_kept, pair_of_row,
+                           row_used),
+            (y, weights, row_of_pair, pair_kept, pair_of_row, row_used))
+
+
+def _combine_pairs_bwd(res, g):
+    y, weights, row_of_pair, pair_kept, pair_of_row, row_used = res
+    g_rows = g[pair_of_row // weights.shape[1]]                  # (R, d) fp32
+    w_rows = jnp.where(row_used, weights.reshape(-1)[pair_of_row], 0)
+    dy = (g_rows * w_rows[:, None]).astype(y.dtype)
+    dots = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)      # (R,)
+    dw = jnp.where(pair_kept, dots[row_of_pair], 0).astype(weights.dtype)
+    return dy, dw, None, None, None, None
+
+
+_combine_pairs.defvjp(_combine_pairs_fwd, _combine_pairs_bwd)
+
+
 def _grouped_experts(params: GatedMoEParams, rows, group_sizes, activation,
                      row_used=None):
     """``(activation(rows w_gate) * (rows w_up)) w_down`` (without
@@ -424,6 +460,20 @@ def _grouped_experts(params: GatedMoEParams, rows, group_sizes, activation,
         hidden = jnp.where(row_used[:, None], hidden, 0)
     return lax.ragged_dot(hidden.astype(dtype), params.w_down.astype(dtype),
                           group_sizes)
+
+
+# Rows at least this wide are combined by ``_combine_pairs``; narrower ones by
+# ``_rows_to_pairs`` and AD's sum.  What the row-space backward saves goes
+# with the row's width (the pair-space cotangent and the recompute's gather
+# of (T, held, d)); what it adds does not (a gather of (T, held) scalars and
+# of the buffer's rows of ``g`` in fp32).  Measured on a v5e, tokens/s/chip,
+# pairs against the other form (PERF.md section 6, PR 39): rows of 3072
+# (Laguna's cell) +5.1 %, rows of 1024 (Nemotron's latent space) -2.5 %; at
+# 2048 with 16 held the pair-space form does not fit the chip at all.  Two
+# points and a fit: where between 1024 and 3072 the forms cross is not
+# measured, and the narrow form goes once the row-space backward gathers no
+# more than it does (PERF.md section 7).
+_ROW_SPACE_WIDTH = 2048
 
 
 def held_row_buffer(tokens: int, top_k: int, n_held: int, n_experts: int,
@@ -463,8 +513,13 @@ def _held_experts(params: GatedMoEParams, x, weights, chosen, activation,
     with scope("moe_experts"):
         y = _grouped_experts(params, rows, group_sizes, activation, row_used)
     with scope("moe_dispatch"):
-        y = _rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row, row_used)
-        out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
+        if d >= _ROW_SPACE_WIDTH:
+            out = _combine_pairs(y, weights, row_of_pair, pair_kept,
+                                 pair_of_row, row_used)
+        else:
+            y = _rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row,
+                               row_used)
+            out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
     return out, dropped
 
 

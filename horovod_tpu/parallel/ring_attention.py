@@ -229,19 +229,27 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    scale: Optional[float] = None,
                    use_flash: Optional[bool] = None,
                    interpret: bool = False,
-                   window: Optional[int] = None) -> jax.Array:
+                   window: Optional[int] = None,
+                   diffusion_block: Optional[int] = None) -> jax.Array:
     """Exact attention over a sequence-sharded axis via K/V ring rotation.
 
     Call inside ``shard_map``; returns the local (B, Sq, H, D) output shard.
     ``interpret`` runs the flash kernels in the Pallas interpreter (CPU
     tests); it is never chosen here.  A ``window`` is refused: a band that
     crosses shards would visit only the neighbouring ring steps, which the
-    walk does not know how to skip.
+    walk does not know how to skip.  So is a ``diffusion_block``: the mask
+    is laid out over one whole doubled sequence, and a shard of it is
+    neither half.
     """
     if window is not None:
         raise NotImplementedError(
             "ring attention takes no sliding window: windowed layers run "
             "through full_attention (attn_mode 'megatron')")
+    if diffusion_block is not None:
+        raise NotImplementedError(
+            "ring attention takes no block-diffusion mask: the doubled "
+            "sequence runs whole through full_attention (attn_mode "
+            "'megatron', mp 1)")
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     from ..ops import flash_attention as fa
@@ -258,10 +266,14 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def reference_attention(q, k, v, causal: bool = True,
                         scale: Optional[float] = None,
-                        window: Optional[int] = None) -> jax.Array:
+                        window: Optional[int] = None,
+                        diffusion_block: Optional[int] = None) -> jax.Array:
     """Pure-XLA unsharded attention — the numerics oracle for tests.
     ``window`` (causal only): each query sees the ``window`` keys up to and
-    including its own, ``q_pos - window < k_pos <= q_pos``."""
+    including its own, ``q_pos - window < k_pos <= q_pos``.
+    ``diffusion_block`` (causal only, no window): the block-diffusion mask
+    over a noised and a clean half, built densely
+    (``ops/flash_attention.diffusion_mask``)."""
     b, sq, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -269,7 +281,12 @@ def reference_attention(q, k, v, causal: bool = True,
         raise ValueError("a window is a causal call's")
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    if causal:
+    if diffusion_block is not None:
+        from ..ops import flash_attention as fa
+        fa._checked_diffusion(diffusion_block, causal, window, q, k, 0, 0)
+        s = jnp.where(fa.diffusion_mask(sq, diffusion_block)[None, None], s,
+                      _NEG_INF)
+    elif causal:
         q_pos = jnp.arange(sq)
         k_pos = jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= k_pos[None, :]
@@ -297,17 +314,23 @@ def checkpoint_keeping_attention(layer_fn):
 def full_attention(q, k, v, causal: bool = True,
                    scale: Optional[float] = None,
                    use_flash: Optional[bool] = None,
-                   window: Optional[int] = None) -> jax.Array:
+                   window: Optional[int] = None,
+                   diffusion_block: Optional[int] = None) -> jax.Array:
     """Unsharded attention (same layout as ring_attention). Dispatches to
     the fused Pallas kernel on TPU, XLA einsums elsewhere.  ``window``: a
-    sliding window of that many keys a query, its own included."""
+    sliding window of that many keys a query, its own included.
+    ``diffusion_block``: the sequence is a noised and a clean copy of the
+    same tokens under the block-diffusion mask
+    (``ops/flash_attention.flash_attention``)."""
     if use_flash is None:
         from ..ops import flash_attention as fa
         use_flash = (_flash_enabled(k.shape[1]) and
-                     fa._supported(q, k, window) is not None)
+                     fa._supported(q, k, window, diffusion_block) is not None)
     if use_flash:
         from ..ops import flash_attention as fa
         return fa.flash_attention(q, k, v, causal=causal, scale=scale,
-                                  window=window)
+                                  window=window,
+                                  diffusion_block=diffusion_block)
     return reference_attention(q, k, v, causal=causal, scale=scale,
-                               window=window)
+                               window=window,
+                               diffusion_block=diffusion_block)

@@ -49,18 +49,24 @@ def _head_to_seq_sharded(x, axis_name):
 def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       axis_name: str, causal: bool = True,
                       scale: Optional[float] = None,
-                      window: Optional[int] = None) -> jax.Array:
+                      window: Optional[int] = None,
+                      diffusion_block: Optional[int] = None) -> jax.Array:
     """Exact attention over a sequence-sharded axis via head resharding.
 
     q, k, v: (B, S_local, H, D) shards; returns the (B, S_local, H, D)
     output shard.  Requires H divisible by the axis size.  A ``window`` is
     refused, as ring attention refuses it: no model runs windowed layers
-    sequence-parallel yet.
+    sequence-parallel yet.  So is a ``diffusion_block``.
     """
     if window is not None:
         raise NotImplementedError(
             "ulysses attention takes no sliding window: windowed layers run "
             "through full_attention (attn_mode 'megatron')")
+    if diffusion_block is not None:
+        raise NotImplementedError(
+            "ulysses attention takes no block-diffusion mask: the doubled "
+            "sequence runs whole through full_attention (attn_mode "
+            "'megatron', mp 1)")
     sp = axis_size(axis_name)
     h = q.shape[2]
     if h % sp != 0:

@@ -82,8 +82,9 @@ SSM_SCOPES = ("ssm", "ssm_conv", "ssm_scan")
 # projections, and the shared expert.
 LATENT_MOE_SCOPES = ("moe_latent", "moe_shared")
 # Inside the ``attn`` block of a patterned model: the rotary positions of q
-# and k, and the per-head gate on the attention's output.
-ATTN_PART_SCOPES = ("attn_rope", "attn_gate")
+# and k, the per-head gate on the attention's output, and the per-head
+# RMSNorm of q and k (``head_qk_norm``).
+ATTN_PART_SCOPES = ("attn_rope", "attn_gate", "attn_qknorm")
 # A patterned model's gated dense MLP block, inside ``mlp`` (what is left of
 # ``mlp`` is then the expert blocks').
 DENSE_MLP_SCOPE = "mlp_dense"

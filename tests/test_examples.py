@@ -140,3 +140,17 @@ def test_chip_smoke_refuses_without_a_tpu():
     assert r.returncode != 0
     assert "platform cpu" in r.stderr
     assert r.stdout.strip() == ""
+
+
+@pytest.mark.timeout(300)
+def test_block_diffusion_lm_trains_on_the_virtual_mesh():
+    """A tiny block-diffusion configuration (``diffusion_block``: the
+    doubled sequence, the third batch array from ``synthetic_batch`` /
+    ``noised_batch``) trains on two virtual devices."""
+    r = _run([os.path.join(EXAMPLES, "block_diffusion_lm.py"), "--steps",
+              "7"])              # prints steps 0, 5 and the last
+    assert r.returncode == 0, r.stderr[-2000:]
+    losses = [float(ln.split()[-1]) for ln in r.stdout.splitlines()
+              if ln.startswith("step")]
+    assert len(losses) == 3 and all(x == x and x < 20.0 for x in losses)
+    assert "128 positions" in r.stdout

@@ -262,8 +262,11 @@ def test_the_step_carries_the_new_scopes_and_the_routing_counter():
     tokens, labels = tfm.synthetic_batch(jax.random.PRNGKey(3), cfg, 2)
     hlo = jax.jit(jax.grad(tfm.make_loss_fn(cfg, par, mesh))).lower(
         params, tokens, labels).as_text(debug_info=True)
-    for name in profiler.ATTN_PART_SCOPES + (profiler.DENSE_MLP_SCOPE,
-                                             "moe_shared", "moe_route"):
+    # (``ATTN_PART_SCOPES`` also names the per-head QK-norm, which this
+    # model does not have.)
+    assert set(profiler.ATTN_PART_SCOPES) >= {"attn_rope", "attn_gate"}
+    for name in ("attn_rope", "attn_gate", profiler.DENSE_MLP_SCOPE,
+                 "moe_shared", "moe_route"):
         assert f"hvd_{name}" in hlo, name
     routing = tfm.make_routing_fn(cfg, par, mesh)(params, tokens, labels)
     assert routing["assignments"].shape == (1, 1, 16)
