@@ -122,6 +122,10 @@ TIER_FAST=(
   # benchmark tests (system against reference, the controls, the readers)
   # and its step compiled for a described v5e.
   test_sdar_layers.py
+  # The held experts' rows summed into their tokens over the buffer's live
+  # rows (ISSUE 40): the held path against the pair-space formulas, the
+  # loop's edge cases, the three families' models, the traced step's shape.
+  test_moe_token_sums.py
   benchmark_tests/test_benchmark_sdar.py
   benchmark_tests/test_benchmark_compile_v5e_sdar.py
   test_timeline.py

@@ -28,7 +28,10 @@ also **hold a share** of the experts it routes over (the one-chip half of
 expert parallelism): ``w_up`` / ``w_down`` then have fewer experts than the
 router has outputs, the layer computes the part of the result its own
 experts give, through a static buffer of rows, and what the absent experts
-would have added is left out.  Nothing stands in for them.
+would have added is left out.  Nothing stands in for them.  The buffer is
+sized for the worst case and mostly padding, so what moves rows of width d
+walks its rows, and where it can its live rows only (``_token_sums``), never
+the (token, held expert) pairs, of which one in sixteen or fewer is chosen.
 
 The reference's only layout-shuffling primitive is alltoall with uneven
 splits (operations.cc:1136-1198, SURVEY.md §2.3 "the only primitive that
@@ -52,6 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..compat import axis_size
+from ..metrics.registry import registry
 from ..ops.quantization import QuantSpec, wire_bytes
 from ..utils.profiler import scope
 
@@ -359,86 +363,110 @@ def _weigh(p, router: Router):
     return p if router.scale == 1.0 else p * router.scale
 
 
+# Rows of the buffer's live prefix a trip of ``_token_sums``' loop adds.
+_SUM_CHUNK = 512
+
+
+def _token_sums(z, scale, token_of_row, n_live, tokens: int, site: str):
+    """(tokens, d) fp32: token t's sum of the buffer's live rows that are its
+    own, ``z[r] * scale[r]`` in fp32 (``scale`` None: ``z[r]``); 0 for a
+    token with no row.  Both sums of the held path are this one: the
+    combine's forward (``scale`` the row's weight) and the dispatch's
+    backward.
+
+    Taken over the buffer's live prefix, rows 0 .. ``n_live`` - 1, not over
+    every (token, held expert) pair: ``_SUM_CHUNK`` rows a trip, as they lie
+    in the buffer, scatter-added into the (tokens, d) result the loop carries;
+    the trip count is read from ``n_live``, so the buffer's padding (three
+    quarters of it at a factor of 4) costs nothing, and nothing but the result
+    is as large as a token's row for every token.  The loop is inside
+    custom-VJP rules, which AD never sees."""
+    _token_sums_built(site)
+    rows, d = z.shape
+    chunk = min(_SUM_CHUNK, rows)
+
+    def trip(c, out):
+        # The last trip of a buffer that is no multiple of the chunk starts
+        # early; the rows it met before go nowhere, as the padding does.
+        at = jnp.minimum(c * chunk, rows - chunk)
+        zs = lax.dynamic_slice(z, (at, 0), (chunk, d)).astype(jnp.float32)
+        if scale is not None:
+            zs = zs * lax.dynamic_slice(scale, (at,), (chunk,))[:, None]
+        row = at + jnp.arange(chunk)
+        token = jnp.where((row >= c * chunk) & (row < n_live),
+                          lax.dynamic_slice(token_of_row, (at,), (chunk,)),
+                          tokens)
+        return out.at[token].add(zs, mode="drop")
+
+    return lax.fori_loop(0, (n_live + chunk - 1) // chunk, trip,
+                         jnp.zeros((tokens, d), jnp.float32))
+
+
+def _token_sums_built(site: str) -> None:
+    """Trace-time count of the row-space sums built, by site: none for a
+    layer that holds every expert."""
+    registry().counter(
+        "hvd_moe_token_sums_built_total",
+        "held experts' sums of buffer rows into their tokens traced, by site",
+        site=site).inc()
+
+
 @jax.custom_vjp
-def _held_rows(x, token_of_row, row_of_pair, pair_kept):
-    """``x[token_of_row]``: the rows of the buffer, in expert order.  Row
-    ``row_of_pair[t, e]`` holds token t's copy for held expert e where
-    ``pair_kept[t, e]``; backward gathers those and adds them, a gather
-    where AD would scatter-add."""
+def _held_rows(x, token_of_row, n_live):
+    """``x[token_of_row]``: the rows of the buffer, in expert order, of
+    which the first ``n_live`` are live.  Backward adds a token's live rows
+    of ``g`` (:func:`_token_sums`)."""
     return x[token_of_row]
 
 
-def _held_rows_fwd(x, token_of_row, row_of_pair, pair_kept):
-    return x[token_of_row], (row_of_pair, pair_kept)
+def _held_rows_fwd(x, token_of_row, n_live):
+    return x[token_of_row], (token_of_row, n_live, x.shape[0])
 
 
 def _held_rows_bwd(res, g):
-    row_of_pair, pair_kept = res
-    picked = jnp.where(pair_kept[..., None], g[row_of_pair], 0)
-    return (jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None, None)
+    token_of_row, n_live, tokens = res
+    dx = _token_sums(g, None, token_of_row, n_live, tokens, "dispatch_bwd")
+    return dx.astype(g.dtype), None, None
 
 
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
 @jax.custom_vjp
-def _rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row, row_used):
-    """(T, held, d): ``y[row_of_pair[t, e]]`` where the pair has a row,
-    else 0.  Backward is the gather the other way (row r came from pair
-    ``pair_of_row[r]``), 0 for the buffer's unused rows."""
-    return jnp.where(pair_kept[..., None], y[row_of_pair], 0)
-
-
-def _rows_to_pairs_fwd(y, row_of_pair, pair_kept, pair_of_row, row_used):
-    return (_rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row, row_used),
-            (pair_of_row, row_used))
-
-
-def _rows_to_pairs_bwd(res, g):
-    pair_of_row, row_used = res
-    flat = g.reshape(-1, g.shape[-1])
-    return (jnp.where(row_used[:, None], flat[pair_of_row], 0),
-            None, None, None, None)
-
-
-_rows_to_pairs.defvjp(_rows_to_pairs_fwd, _rows_to_pairs_bwd)
-
-
-@jax.custom_vjp
-def _combine_pairs(y, weights, row_of_pair, pair_kept, pair_of_row, row_used):
+def _combine_rows(y, weights, row_of_pair, pair_kept, pair_of_row, n_live):
     """(T, d) fp32: ``sum_e weights[t, e] * y[row_of_pair[t, e]]`` over the
-    pairs that have a row: ``_rows_to_pairs`` and the weighted sum behind it
-    as one function whose backward stays in row space (row r came from pair
-    ``pair_of_row[r]``): ``dy[r] = g[token of r] * weight of r`` and
-    ``dweights`` from one dot a row, 0 for the buffer's unused rows.  AD's
-    transpose of the sum broadcasts ``g`` to every (token, held expert)
-    pair — (T, held, d) in fp32, 3 GiB at 24,576 positions, 16 held and
-    2048 features — and the layer's recompute gathers the (T, held, d) rows
-    again for nothing but that product; here neither exists.  Same values,
-    same roundings."""
-    picked = jnp.where(pair_kept[..., None], y[row_of_pair], 0)
-    return jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1)
+    pairs that have a row, forward and backward in row space (row r came
+    from pair ``pair_of_row[r]``; the first ``n_live`` rows are live).
+    Forward: :func:`_token_sums` of the rows times their weights.  Backward:
+    ``dy[r] = g[token of r] * weight of r`` and ``dweights`` from one dot a
+    row, 0 for the buffer's unused rows.  No array has a row for every
+    (token, held expert) pair: AD's transpose of the pair-space sum broadcast
+    ``g`` to (T, held, d) in fp32, 3 GiB at 24,576 positions, 16 held and
+    2048 features."""
+    t, n_held = weights.shape
+    return _token_sums(y, weights.reshape(-1)[pair_of_row],
+                       pair_of_row // n_held, n_live, t, "combine")
 
 
-def _combine_pairs_fwd(y, weights, row_of_pair, pair_kept, pair_of_row,
-                       row_used):
-    return (_combine_pairs(y, weights, row_of_pair, pair_kept, pair_of_row,
-                           row_used),
-            (y, weights, row_of_pair, pair_kept, pair_of_row, row_used))
+def _combine_rows_fwd(y, weights, row_of_pair, pair_kept, pair_of_row,
+                      n_live):
+    return (_combine_rows(y, weights, row_of_pair, pair_kept, pair_of_row,
+                          n_live),
+            (y, weights, row_of_pair, pair_kept, pair_of_row, n_live))
 
 
-def _combine_pairs_bwd(res, g):
-    y, weights, row_of_pair, pair_kept, pair_of_row, row_used = res
+def _combine_rows_bwd(res, g):
+    y, weights, row_of_pair, pair_kept, pair_of_row, n_live = res
     g_rows = g[pair_of_row // weights.shape[1]]                  # (R, d) fp32
-    w_rows = jnp.where(row_used, weights.reshape(-1)[pair_of_row], 0)
+    w_rows = jnp.where(jnp.arange(y.shape[0]) < n_live,
+                       weights.reshape(-1)[pair_of_row], 0)
     dy = (g_rows * w_rows[:, None]).astype(y.dtype)
     dots = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)      # (R,)
     dw = jnp.where(pair_kept, dots[row_of_pair], 0).astype(weights.dtype)
     return dy, dw, None, None, None, None
 
 
-_combine_pairs.defvjp(_combine_pairs_fwd, _combine_pairs_bwd)
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 def _grouped_experts(params: GatedMoEParams, rows, group_sizes, activation,
@@ -462,20 +490,6 @@ def _grouped_experts(params: GatedMoEParams, rows, group_sizes, activation,
                           group_sizes)
 
 
-# Rows at least this wide are combined by ``_combine_pairs``; narrower ones by
-# ``_rows_to_pairs`` and AD's sum.  What the row-space backward saves goes
-# with the row's width (the pair-space cotangent and the recompute's gather
-# of (T, held, d)); what it adds does not (a gather of (T, held) scalars and
-# of the buffer's rows of ``g`` in fp32).  Measured on a v5e, tokens/s/chip,
-# pairs against the other form (PERF.md section 6, PR 39): rows of 3072
-# (Laguna's cell) +5.1 %, rows of 1024 (Nemotron's latent space) -2.5 %; at
-# 2048 with 16 held the pair-space form does not fit the chip at all.  Two
-# points and a fit: where between 1024 and 3072 the forms cross is not
-# measured, and the narrow form goes once the row-space backward gathers no
-# more than it does (PERF.md section 7).
-_ROW_SPACE_WIDTH = 2048
-
-
 def held_row_buffer(tokens: int, top_k: int, n_held: int, n_experts: int,
                     factor: float) -> int:
     """Rows of the static buffer a share-holding layer computes: ``factor``
@@ -492,7 +506,13 @@ def _held_experts(params: GatedMoEParams, x, weights, chosen, activation,
     fp32 and ``chosen`` (T, n_held) bool, whether the token chose the
     expert.  The chosen (token, held expert) pairs are sorted by expert;
     the first ``row_buffer`` rows are computed, the rest dropped and
-    counted.  Returns (out (T, d) fp32, pairs dropped ())."""
+    counted.  The buffer's rows are in expert order with the unchosen pairs
+    behind all chosen ones, so rows 0 .. ``n_live`` - 1 are live and the rest
+    is padding.  Rows go into the buffer by one gather of its rows and
+    come back to their tokens (the combine; the dispatch's backward) by
+    :func:`_token_sums` over the live prefix: nothing in this path has a
+    row of width d for every (token, held expert) pair.  Returns (out (T, d)
+    fp32, pairs dropped ())."""
     t, d = x.shape
     n_held = params.w_up.shape[0]
     with scope("moe_route"):
@@ -504,22 +524,18 @@ def _held_experts(params: GatedMoEParams, x, weights, chosen, activation,
         pair_of_row = pair_of_row[:row_buffer]
         ends = jnp.minimum(jnp.cumsum(counts), row_buffer)
         group_sizes = jnp.diff(ends, prepend=0)
-        row_used = jnp.arange(row_buffer) < ends[-1]
+        n_live = ends[-1]
+        row_used = jnp.arange(row_buffer) < n_live
         pair_kept = chosen & (row_of_pair < row_buffer)
         row_of_pair = jnp.minimum(row_of_pair, row_buffer - 1)
-        dropped = (jnp.sum(counts) - ends[-1]).astype(jnp.float32)
+        dropped = (jnp.sum(counts) - n_live).astype(jnp.float32)
     with scope("moe_dispatch"):
-        rows = _held_rows(x, pair_of_row // n_held, row_of_pair, pair_kept)
+        rows = _held_rows(x, pair_of_row // n_held, n_live)
     with scope("moe_experts"):
         y = _grouped_experts(params, rows, group_sizes, activation, row_used)
     with scope("moe_dispatch"):
-        if d >= _ROW_SPACE_WIDTH:
-            out = _combine_pairs(y, weights, row_of_pair, pair_kept,
-                                 pair_of_row, row_used)
-        else:
-            y = _rows_to_pairs(y, row_of_pair, pair_kept, pair_of_row,
-                               row_used)
-            out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
+        out = _combine_rows(y, weights, row_of_pair, pair_kept, pair_of_row,
+                            n_live)
     return out, dropped
 
 
@@ -549,7 +565,11 @@ def dropless_moe(params: GatedMoEParams, x: jax.Array, top_k: int,
     held experts' part alone, and their rows go through a static buffer of
     ``buffer_factor`` x the mean (:func:`held_row_buffer`); a row beyond it
     is dropped and counted in ``RouterStats.dropped``.  ``counts`` stays
-    what the router chose, over all its outputs, held or not.
+    what the router chose, over all its outputs, held or not.  The buffer's
+    rows are weighed and added back into their tokens, and their cotangents
+    into the tokens', over the buffer's live rows (``_token_sums``; the
+    trace-time counter ``hvd_moe_token_sums_built_total{site}`` says it
+    engaged), in fp32.
     """
     t, d = x.shape
     e = params.gate.shape[1]

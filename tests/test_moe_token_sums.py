@@ -1,0 +1,431 @@
+"""The held experts' rows are summed into their tokens over the buffer's live
+rows (``parallel/moe.py`` ``_token_sums``), not over every (token, held
+expert) pair: values and both gradients of the held path against the plain
+pair-space formulas written out here, the cases a row-space walk can get
+wrong (no row, a full run, live rows over several trips of the loop, a
+buffer that is no multiple of the chunk, an overflowing buffer, an empty one), the three families' models with the helper swapped for the
+pair-space formula, and the shape of the traced step: no value with a row of
+width d for every (token, held expert) pair, one sum a site a held layer."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from horovod_tpu.metrics.registry import registry
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.parallel import moe
+from horovod_tpu.parallel.mesh import create_mesh
+
+PAR = tfm.ParallelConfig()
+
+
+# -- the plain formulas ----------------------------------------------------------
+
+def pair_space_routing(chosen, row_buffer):
+    """Which pairs have a row, with no sort: pair (t, e) is the
+    (chosen pairs of the experts before e + chosen tokens before t of e)-th
+    row, and it is kept while that is inside the buffer."""
+    counts = jnp.sum(chosen, axis=0)
+    before = jnp.cumsum(counts) - counts
+    rank = before[None, :] + jnp.cumsum(chosen, axis=0) - chosen
+    kept = chosen & (rank < row_buffer)
+    return kept, jnp.where(kept, rank, 0), int(chosen.sum() - kept.sum())
+
+
+def plain_held_experts(params, x, weights, kept, activation):
+    """Every held expert on every token, the kept pairs weighed and summed:
+    nothing of the buffer."""
+    def up(w):
+        return jnp.einsum("td,edf->tef", x, w)
+    hidden = activation(up(params.w_up)) if params.w_gate is None else (
+        activation(up(params.w_gate)) * up(params.w_up))
+    y = jnp.einsum("tef,efd->ted", hidden, params.w_down)
+    return jnp.sum(jnp.where(kept[..., None], y, 0) * weights[..., None],
+                   axis=1)
+
+
+def pair_space_sums(held):
+    """``_token_sums`` as the parent took it: one gathered row for every
+    (token, held expert) pair, masked, weighed and summed over the ``held``
+    experts.  The pairs are found again from the rows: sorted by token
+    (stable: expert order inside a token), a token's rows are a run, and a
+    run is no longer than the experts held."""
+    def sums(z, scale, token_of_row, n_live, tokens, site):
+        moe._token_sums_built(site)
+        rows = z.shape[0]
+        key = jnp.where(jnp.arange(rows) < n_live, token_of_row, tokens)
+        order = jnp.argsort(key, stable=True)
+        key = key[order]
+        start = jnp.searchsorted(key, jnp.arange(tokens))
+        q = start[:, None] + jnp.arange(held)[None, :]          # (T, held)
+        at = jnp.minimum(q, rows - 1)
+        live = (q < rows) & (key[at] == jnp.arange(tokens)[:, None])
+        picked = jnp.where(live[..., None], z[order[at]], 0)
+        picked = picked.astype(jnp.float32)
+        if scale is not None:
+            picked = picked * jnp.where(live, scale[order[at]], 0)[..., None]
+        return jnp.sum(picked, axis=1)
+    return sums
+
+
+# -- the held path, values and gradients ----------------------------------------
+
+def held_case(t, d, n_held, top_k, n_experts, key=0, gated=True, d_ff=8):
+    ks = jax.random.split(jax.random.PRNGKey(key), 6)
+    params = moe.GatedMoEParams(
+        gate=None,
+        w_gate=(jax.random.normal(ks[0], (n_held, d, d_ff)) / np.sqrt(d)
+                if gated else None),
+        w_up=jax.random.normal(ks[1], (n_held, d, d_ff)) / np.sqrt(d),
+        w_down=jax.random.normal(ks[2], (n_held, d_ff, d)) / np.sqrt(d_ff))
+    x = jax.random.normal(ks[3], (t, d))
+    weights = jax.random.uniform(ks[4], (t, n_held), minval=0.1)
+    chosen = jax.random.uniform(ks[5], (t, n_held)) < top_k / n_experts
+    return params, x, weights, chosen
+
+
+def check_held_path(params, x, weights, chosen, row_buffer, top_k,
+                    activation=jax.nn.silu):
+    """Values, the dropped count and the gradients in x, the weights and the
+    experts against the plain formula; returns the path's output."""
+    kept, _, dropped = pair_space_routing(chosen, row_buffer)
+
+    def ours(x, weights, w_up, w_down):
+        out, lost = moe._held_experts(
+            params._replace(w_up=w_up, w_down=w_down), x, weights, chosen,
+            activation, row_buffer)
+        return jnp.sum(jnp.sin(out)), (out, lost)
+
+    def plain(x, weights, w_up, w_down):
+        out = plain_held_experts(params._replace(w_up=w_up, w_down=w_down),
+                                 x, weights, kept, activation)
+        return jnp.sum(jnp.sin(out)), out
+
+    args = (x, weights, params.w_up, params.w_down)
+    with jax.default_matmul_precision("highest"):
+        (_, (out, lost)), got = jax.jit(jax.value_and_grad(
+            ours, (0, 1, 2, 3), has_aux=True))(*args)
+        (_, want_out), want = jax.jit(jax.value_and_grad(
+            plain, (0, 1, 2, 3), has_aux=True))(*args)
+    assert float(lost) == dropped
+    np.testing.assert_allclose(out, want_out, atol=2e-5, rtol=1e-5)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5 * max(
+            1.0, float(jnp.abs(w).max())), rtol=1e-4)
+    return out
+
+
+@pytest.mark.parametrize("n_held", [4, 8, 16])
+@pytest.mark.parametrize("d", [8, 1024, 2048, 3072])
+def test_the_held_path_is_the_pair_space_formula(d, n_held):
+    """Rows narrower than, as wide as and wider than the 2048 at which the
+    parent changed forms, 4, 8 and 16 experts held: one path."""
+    top_k, n_experts = 8, 8 * n_held
+    params, x, weights, chosen = held_case(24, d, n_held, top_k, n_experts,
+                                           key=d + n_held)
+    check_held_path(params, x, weights, chosen, moe.held_row_buffer(
+        24, top_k, n_held, n_experts, 4.0), top_k)
+
+
+def test_a_token_with_no_row_and_a_token_with_every_row_it_can_have():
+    t, d, n_held, top_k = 16, 8, 8, 3
+    params, x, weights, chosen = held_case(t, d, n_held, top_k, 32, key=1)
+    chosen = chosen.at[0].set(False).at[1].set(False)
+    chosen = chosen.at[1, jnp.array([1, 4, 7])].set(True)   # min(top_k, held)
+    chosen = jnp.where(jnp.sum(chosen, axis=1, keepdims=True) > top_k,
+                       False, chosen)
+    out = check_held_path(params, x, weights, chosen, 64, top_k)
+    assert not np.asarray(out[0]).any()
+    assert np.asarray(out[1]).any()
+
+
+@pytest.mark.parametrize("gated", [True, False])
+@pytest.mark.parametrize("rows", [128, 100])
+def test_the_live_rows_span_several_trips_of_the_loop(monkeypatch, gated,
+                                                      rows):
+    """Chunks of 8 rows under 40-odd live ones: a token's rows (they lie an
+    expert apart in the buffer) are added in different trips, the last live
+    chunk is part padding, and a buffer of 100 rows is no multiple of the
+    chunk (its last trip would start early, were it ever reached)."""
+    monkeypatch.setattr(moe, "_SUM_CHUNK", 8)
+    t, d, n_held, top_k = 32, 8, 4, 4
+    params, x, weights, chosen = held_case(t, d, n_held, top_k, 12, key=2,
+                                           gated=gated)
+    per_token = np.asarray(chosen.sum(1))
+    assert per_token.max() == 4 and per_token.sum() > 16
+    assert per_token.sum() % 8
+    check_held_path(params, x, weights, chosen, rows, top_k)
+
+
+def test_a_full_buffer_that_is_no_multiple_of_the_chunk(monkeypatch):
+    """Every row live and the last trip starting early: the rows it shares
+    with the trip before are added once."""
+    monkeypatch.setattr(moe, "_SUM_CHUNK", 16)
+    t, d, n_held, top_k = 32, 8, 4, 4
+    params, x, weights, chosen = held_case(t, d, n_held, top_k, 8, key=6)
+    assert int(chosen.sum()) > 40
+    check_held_path(params, x, weights, chosen, 40, top_k)
+
+
+def test_a_buffer_that_overflows_drops_the_rows_the_parent_dropped():
+    """The buffer's last rows go first: the pairs past it in expert order
+    (the last experts' last tokens) are dropped, counted, and the kept ones
+    summed as before."""
+    t, d, n_held, top_k = 32, 8, 4, 4
+    params, x, weights, chosen = held_case(t, d, n_held, top_k, 8, key=3)
+    rows = 24
+    kept, _, dropped = pair_space_routing(chosen, rows)
+    assert dropped > 8 and int(kept.sum()) == rows
+    assert bool(kept[:, 0].sum() == chosen[:, 0].sum())     # expert 0 whole
+    assert int(kept[:, -1].sum()) == 0                      # the last lost
+    check_held_path(params, x, weights, chosen, rows, top_k)
+
+
+def test_a_buffer_with_no_live_row_gives_zeros():
+    t, d, n_held, top_k = 16, 8, 4, 4
+    params, x, weights, chosen = held_case(t, d, n_held, top_k, 8, key=4)
+    out = check_held_path(params, x, weights, jnp.zeros_like(chosen), 32,
+                          top_k)
+    assert not np.asarray(out).any()
+
+
+# -- the helper alone -------------------------------------------------------------
+
+def routing_of(chosen, row_buffer):
+    """The routing integers as ``_held_experts`` makes them."""
+    t, n_held = chosen.shape
+    key = jnp.where(chosen, jnp.arange(n_held), n_held)
+    pair_of_row = jnp.argsort(key.reshape(-1), stable=True)
+    row_of_pair = jnp.argsort(pair_of_row).reshape(t, n_held)
+    pair_of_row = pair_of_row[:row_buffer]
+    n_live = jnp.minimum(jnp.sum(chosen), row_buffer).astype(jnp.int32)
+    row_used = jnp.arange(row_buffer) < n_live
+    kept = chosen & (row_of_pair < row_buffer)
+    row_of_pair = jnp.minimum(row_of_pair, row_buffer - 1)
+    return n_live, row_of_pair, kept, pair_of_row, row_used
+
+
+@pytest.mark.parametrize("scaled", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_token_sums_is_the_gathered_sum(monkeypatch, scaled, dtype):
+    """Against ``sum_e where(kept, z[row_of_pair]) * w`` with ``jnp``
+    gathers: fp32 sums of the same terms; a token's rows arrive an expert at
+    a time, so they are added in ascending expert order wherever they lie in
+    different trips (a last bit may differ where two lie in one)."""
+    monkeypatch.setattr(moe, "_SUM_CHUNK", 16)
+    t, n_held, d, rows = 48, 8, 16, 96
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    chosen = jax.random.uniform(ks[0], (t, n_held)) < 0.2
+    n_live, row_of_pair, kept, pair_of_row, row_used = routing_of(chosen,
+                                                                  rows)
+    z = jax.random.normal(ks[1], (rows, d)).astype(dtype)
+    w = jax.random.uniform(ks[2], (t, n_held))
+    picked = jnp.where(kept[..., None], z[row_of_pair], 0).astype(jnp.float32)
+    if scaled:
+        picked = picked * w[..., None]
+    want = jnp.sum(picked, axis=1)
+    scale = w.reshape(-1)[pair_of_row] if scaled else None
+    got = jax.jit(lambda z: moe._token_sums(
+        z, scale, pair_of_row // n_held, n_live, t, "combine"))(z)
+    assert got.dtype == jnp.float32 and got.shape == (t, d)
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
+
+
+def test_the_combines_backward_is_ads():
+    """The combine's hand-written backward (row space) against AD of the
+    same sum written with a plain gather.  (Was
+    ``test_sdar_layers.py::test_the_held_paths_gradients_are_ads``.)"""
+    t, held, d, rows = 24, 4, 8, 40
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    chosen = jax.random.uniform(keys[0], (t, held)) < 0.4
+    n_live, row_of_pair, kept, pair_of_row, row_used = routing_of(chosen,
+                                                                  rows)
+    assert int(chosen.sum()) < rows
+    y = jax.random.normal(keys[1], (rows, d))
+    w = jax.random.uniform(keys[2], (t, held))
+    g = jax.random.normal(keys[3], (t, d))
+
+    def plain(y, w):
+        picked = jnp.where(kept[..., None], y[row_of_pair], 0)
+        return jnp.sum(jnp.sum(picked * w[..., None], axis=1) * g)
+
+    def ours(y, w):
+        return jnp.sum(moe._combine_rows(y, w, row_of_pair, kept,
+                                         pair_of_row, n_live) * g)
+
+    np.testing.assert_allclose(ours(y, w), plain(y, w), rtol=1e-6)
+    for a, b in zip(jax.grad(ours, (0, 1))(y, w),
+                    jax.grad(plain, (0, 1))(y, w)):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5)
+
+
+def test_the_dispatchs_backward_is_ads():
+    t, held, d, rows = 24, 4, 8, 40
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    chosen = jax.random.uniform(keys[0], (t, held)) < 0.4
+    n_live, _, _, pair_of_row, row_used = routing_of(chosen, rows)
+    x = jax.random.normal(keys[1], (t, d))
+    # The padding's cotangent is whatever the grouped matmuls' transposes
+    # leave there: it goes nowhere.
+    g = jax.random.normal(keys[2], (rows, d))
+    token_of_row = pair_of_row // held
+
+    def ours(x):
+        return jnp.sum(moe._held_rows(x, token_of_row, n_live) * g)
+
+    def plain(x):
+        return jnp.sum(x[token_of_row] * jnp.where(row_used[:, None], g, 0))
+
+    np.testing.assert_allclose(jax.grad(ours)(x), jax.grad(plain)(x),
+                               atol=1e-6, rtol=1e-5)
+
+
+# -- the three families' models ----------------------------------------------------
+
+SDAR = tfm.TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=4, d_ff=12, n_layers=4, seq_len=16,
+    n_experts=64, top_k=4, dtype=jnp.float32, dropless=True,
+    tied_head=False, gated_experts=True, layer_pattern="*E",
+    learned_positions=False, n_kv_heads=2, attn_head_dim=8, rope_theta=1e6,
+    router_renormalise=True, head_qk_norm=True, diffusion_block=4,
+    expert_buffer_factor=4.0, n_experts_held=4)
+LAGUNA = tfm.TransformerConfig(
+    vocab_size=128, d_model=32, n_heads=2, d_ff=24, n_layers=4, seq_len=48,
+    n_experts=16, top_k=3, dtype=jnp.float32, dropless=True,
+    tied_head=False, gated_experts=True, layer_pattern="WE",
+    leading_pattern="*D", learned_positions=False, n_kv_heads=1,
+    attn_head_dim=8, rope_theta=500000.0, rope_fraction=0.5,
+    rope_yarn=(128.0, 16, 32.0, 1.0, 1.4852), attn_window=8, window_heads=3,
+    window_rope_theta=10000.0, attn_gate=True, dense_ff=40,
+    router_renormalise=True, router_scale=2.5, shared_expert_ff=24,
+    expert_buffer_factor=4.0, n_experts_held=4)
+NEMOTRON = tfm.TransformerConfig(
+    vocab_size=128, d_model=32, n_heads=2, d_ff=24, n_layers=3, seq_len=48,
+    n_experts=128, top_k=10, dtype=jnp.float32, remat=True, norm_eps=1e-5,
+    dropless=True, tied_head=False, layer_pattern="EM*",
+    learned_positions=False, n_kv_heads=1, attn_head_dim=8, ssm_heads=2,
+    ssm_head_dim=4, ssm_groups=1, ssm_state=8, ssm_chunk=16,
+    router_scoring="sigmoid", router_renormalise=True, router_scale=2.5,
+    moe_latent=16, shared_expert_ff=40, expert_activation="relu2",
+    expert_buffer_factor=4.0, n_experts_held=8)
+OLMOE = tfm.TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=4, d_ff=12, n_layers=1, seq_len=16,
+    n_experts=8, top_k=2, dtype=jnp.float32, dropless=True, tied_head=False,
+    gated_experts=True, learned_positions=False, rope_theta=10000.0)
+FAMILIES = {"sdar": SDAR, "laguna": LAGUNA, "nemotron": NEMOTRON}
+
+
+def one_device_mesh():
+    return create_mesh({"dp": 1, "pp": 1, "mp": 1}, devices=jax.devices()[:1])
+
+
+def loss_and_grads(cfg):
+    params = tfm.init_params(jax.random.PRNGKey(5), cfg, PAR)
+    batch = tfm.synthetic_batch(jax.random.PRNGKey(11), cfg, 2)
+    # traced anew a call: the helper is looked up at trace time
+    return jax.jit(jax.value_and_grad(tfm.make_loss_fn(
+        cfg, PAR, one_device_mesh())))(params, *batch)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_familys_loss_and_gradients_are_the_pair_space_forms(monkeypatch,
+                                                               family):
+    """The whole model with ``_token_sums`` as shipped and with the parent's
+    gather of every (token, held expert) pair in its place.  (Was
+    ``test_sdar_layers.py::
+    test_both_forms_of_the_held_paths_combine_give_one_answer``, for the one
+    family and the combine's two backward forms.)"""
+    row_space = loss_and_grads(FAMILIES[family])
+    monkeypatch.setattr(moe, "_token_sums", pair_space_sums(
+        FAMILIES[family].n_experts_held))
+    pair_space = loss_and_grads(FAMILIES[family])
+    assert np.isfinite(float(row_space[0]))
+    for a, b in zip(jax.tree_util.tree_leaves(pair_space),
+                    jax.tree_util.tree_leaves(row_space)):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5)
+
+
+# -- the shape of the traced step ---------------------------------------------------
+
+def every_value(jaxpr):
+    """Every variable of a jaxpr, of its sub-jaxprs and of the custom-VJP
+    rules it carries (a ``custom_vjp_call``'s forward and backward are
+    functions until they are traced: traced here by differentiating)."""
+    for eqn in jaxpr.eqns:
+        yield from eqn.outvars
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from every_value(sub)
+    yield from jaxpr.invars
+
+
+def widest_rows(jaxpr, width):
+    """Elements of the largest value made of rows ``width`` wide (the
+    router's one-hot of (T, k, E) is larger than any and is no row)."""
+    return max(int(np.prod(v.aval.shape)) for v in every_value(jaxpr.jaxpr)
+               if getattr(v.aval, "shape", ())[-1:] == (width,))
+
+
+def built(site):
+    return registry().counter(
+        "hvd_moe_token_sums_built_total",
+        "held experts' sums of buffer rows into their tokens traced, by site",
+        site=site).value
+
+
+def expert_layer(cfg):
+    """value_and_grad of one "E" block (an MLP block for OLMoE's) in its
+    input and its parameters."""
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg, PAR)["layers"]
+    x = jax.random.normal(jax.random.PRNGKey(1),
+                          (2, cfg.seq_len, cfg.d_model))
+    if cfg.layer_pattern is None:
+        lp = {k: v[0, 0] for k, v in params.items()}
+        fn = tfm._mlp_block
+    else:
+        lp = {k: v[0, 0, 0] for k, v in params["moe"].items()}
+        fn = tfm._expert_mixer
+    return jax.make_jaxpr(jax.value_and_grad(
+        lambda lp, x: jnp.sum(jnp.sin(fn(cfg, lp, x)[0])), (0, 1)))(lp, x)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_no_value_has_a_row_for_every_token_and_held_expert(family):
+    """Walked through value_and_grad of one expert layer: forward, the
+    custom-VJP rules' backward and the loops' bodies.  The pair-space arrays
+    the parent had, (T, held, d) and its flat (T x held, d), would be the
+    largest values of the layer made of rows d wide; none reaches their size,
+    in any family (one path for every row width: no combine is exempt)."""
+    cfg = FAMILIES[family]
+    before = {site: built(site) for site in ("combine", "dispatch_bwd")}
+    jaxpr = expert_layer(cfg)
+    t = 2 * cfg.seq_len                       # the layer's own input
+    width = cfg.moe_latent or cfg.d_model
+    pair_space = t * cfg.n_experts_held * width
+    largest = widest_rows(jaxpr, width)
+    assert 0 < largest < pair_space, (largest, pair_space)
+    # The walk sees the loop: the rows are added inside a while's body.
+    assert "while" in str(jaxpr) and "scatter-add" in str(jaxpr)
+    assert {site: built(site) - n for site, n in before.items()} == {
+        "combine": 1, "dispatch_bwd": 1}
+
+
+def test_the_walk_would_see_the_parents_pair_space_gather(monkeypatch):
+    """The same walk over the same layer with the parent's form in the
+    helper's place finds a value of the pair-space size: the structural test
+    can fail."""
+    cfg = SDAR
+    monkeypatch.setattr(moe, "_token_sums",
+                        pair_space_sums(cfg.n_experts_held))
+    jaxpr = expert_layer(cfg)
+    pair_space = 2 * cfg.seq_len * cfg.n_experts_held * cfg.d_model
+    assert widest_rows(jaxpr, cfg.d_model) >= pair_space
+
+
+def test_a_layer_that_holds_every_expert_builds_no_token_sum():
+    before = {site: built(site) for site in ("combine", "dispatch_bwd")}
+    jaxpr = expert_layer(OLMOE)
+    assert "ragged_dot" in str(jaxpr)
+    assert {site: built(site) for site in before} == before

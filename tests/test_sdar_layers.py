@@ -221,60 +221,6 @@ def test_the_eight_expert_shares_add_up_to_the_whole_expert_layer():
     assert np.abs(y.reshape(-1, 32) - want).max() > 1e-3
 
 
-def test_the_held_paths_gradients_are_ads():
-    """The combine's hand-written backward (row space) against AD of the
-    same sum written with a plain gather."""
-    from horovod_tpu.parallel import moe
-    t, held, d, rows = 24, 4, 8, 40
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    chosen = jax.random.uniform(keys[0], (t, held)) < 0.4
-    order = jnp.argsort(jnp.where(chosen, jnp.arange(held), held).reshape(-1),
-                        stable=True)
-    row_of_pair = jnp.argsort(order).reshape(t, held)
-    pair_of_row = order[:rows]
-    n_rows = int(chosen.sum())
-    assert n_rows < rows
-    row_used = jnp.arange(rows) < n_rows
-    kept = chosen & (row_of_pair < rows)
-    row_of_pair = jnp.minimum(row_of_pair, rows - 1)
-    y = jax.random.normal(keys[1], (rows, d))
-    w = jax.random.uniform(keys[2], (t, held))
-    g = jax.random.normal(keys[3], (t, d))
-
-    def plain(y, w):
-        picked = jnp.where(kept[..., None], y[row_of_pair], 0)
-        return jnp.sum(jnp.sum(picked * w[..., None], axis=1) * g)
-
-    def ours(y, w):
-        return jnp.sum(moe._combine_pairs(y, w, row_of_pair, kept,
-                                          pair_of_row, row_used) * g)
-
-    for a, b in zip(jax.grad(ours, (0, 1))(y, w),
-                    jax.grad(plain, (0, 1))(y, w)):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5)
-
-
-def test_both_forms_of_the_held_paths_combine_give_one_answer(monkeypatch):
-    """Rows at least ``_ROW_SPACE_WIDTH`` wide (the cell's 2048) take the
-    row-space backward, narrower ones (every test model, Nemotron's latent
-    1024) the pair-space one: the same loss and gradients either way."""
-    from horovod_tpu.parallel import moe
-    assert moe._ROW_SPACE_WIDTH == 2048
-    params = seeded(SHARE, key=5)
-    batch = tfm.synthetic_batch(jax.random.PRNGKey(11), SHARE, 2)
-
-    def run():                 # traced anew: the width is read at trace time
-        return jax.jit(jax.value_and_grad(tfm.make_loss_fn(
-            SHARE, PAR, one_device_mesh())))(params, *batch)
-
-    pair_space = run()
-    monkeypatch.setattr(moe, "_ROW_SPACE_WIDTH", 0)
-    row_space = run()
-    for a, b in zip(jax.tree_util.tree_leaves(pair_space),
-                    jax.tree_util.tree_leaves(row_space)):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5)
-
-
 # -- (d) the published count --------------------------------------------------------------
 
 def test_parameter_count_is_exact():
