@@ -128,6 +128,17 @@ TIER_FAST=(
   test_moe_token_sums.py
   benchmark_tests/test_benchmark_sdar.py
   benchmark_tests/test_benchmark_compile_v5e_sdar.py
+  # LFM2's gated short convolution on the training path (ISSUE 41): the "C"
+  # block against loops written out and the reference, its causality bit
+  # for bit, the router's choice with and without the bias, the eight
+  # expert shares summing to the whole layer, the published parameter
+  # count, dQ's transposes in pieces at 32,768 queries, the touched paths
+  # against the parent's formulas jaxpr for jaxpr.  With it the cell's own
+  # benchmark tests (system against reference, the controls, the readers)
+  # and its step compiled for a described v5e.
+  test_lfm2_layers.py
+  benchmark_tests/test_benchmark_lfm2.py
+  benchmark_tests/test_benchmark_compile_v5e_lfm2.py
   test_timeline.py
   # Serving plane (ISSUE 15): admission-policy goldens, prefill/decode
   # parity vs the training-path logits, continuous-vs-static occupancy,

@@ -34,7 +34,12 @@ letters — ``W`` sliding-window attention with its own head count and rotary
 base, ``D`` a gated dense MLP — with ``leading_pattern`` (blocks that run
 once, in front of the scanned periods), rotary positions on a share of the
 head with YaRN-scaled frequencies and a per-head output gate give Laguna's
-mix of windowed and full attention (``laguna``).  ``diffusion_block``
+mix of windowed and full attention (``laguna``).  A sixth letter — ``C``
+a gated short convolution (``[B, C, u] = h W_in``, a causal depthwise
+convolution of ``conv_taps`` taps over ``B * u``, ``(C * conv) W_out``) —
+with the sigmoid router's renormalisation over ``sum + router_renorm_eps``
+gives LFM2's hybrid of convolution and grouped-query attention blocks
+(``lfm2_moe``).  ``diffusion_block``
 turns the step itself into block-diffusion training (BD3-LMs,
 arXiv:2503.09573; SDAR, arXiv:2510.06303): a sequence of L tokens goes
 through the stack as 2L positions, a noised copy and then the clean copy,
@@ -68,7 +73,8 @@ from ..parallel import moe as moe_lib
 from ..parallel import pipeline as pp_lib
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
-from ..utils.profiler import DENSE_MLP_SCOPE, TP_RING_SCOPES, scope
+from ..utils.profiler import (CONV_SCOPES, DENSE_MLP_SCOPE, TP_RING_SCOPES,
+                              scope)
 
 GATHER_RING, SCATTER_RING = TP_RING_SCOPES
 
@@ -149,6 +155,11 @@ class TransformerConfig(NamedTuple):
     # ``seq_len``; see ``forward_loss``.
     diffusion_block: Optional[int] = None
     head_qk_norm: bool = False    # "*" / "W": RMSNorm over each head of q, k
+    # "C" blocks (LFM2's gated short convolution): ``[B, C, u] = h W_in``,
+    # a causal depthwise convolution of ``conv_taps`` taps over ``B * u``
+    # (no bias), ``(C * conv) W_out``.
+    conv_taps: int = 0
+    router_renorm_eps: float = 0.0  # renormalised weights: over sum + eps
 
     @property
     def head_dim(self) -> int:
@@ -186,7 +197,8 @@ def _has_pos_table(cfg: TransformerConfig) -> bool:
 # A pattern's letters, the key of each kind's parameters under ``layers``
 # and the step scope its blocks run under.
 BLOCK_KINDS = {"M": ("ssm", "ssm"), "E": ("moe", "mlp"), "*": ("attn", "attn"),
-               "W": ("swa", "attn"), "D": ("dense", "mlp")}
+               "W": ("swa", "attn"), "D": ("dense", "mlp"),
+               "C": ("conv", CONV_SCOPES[0])}
 _ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
                 "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
@@ -226,6 +238,9 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
         if "D" in letters and not cfg.dense_ff:
             raise ValueError('a "D" block is a gated dense MLP: dense_ff '
                              "goes with it")
+        if "C" in letters and cfg.conv_taps < 1:
+            raise ValueError('a "C" block is a gated short convolution: '
+                             "conv_taps goes with it")
         hkv = cfg.n_kv_heads or cfg.n_heads
         if cfg.n_heads % hkv or (cfg.window_heads or hkv) % hkv:
             raise ValueError(
@@ -240,12 +255,13 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
                 raise ValueError(
                     f"seq_len {cfg.seq_len} is not whole blocks of "
                     f"diffusion_block {cfg.diffusion_block}")
-            if set(letters) & set("WM"):
+            if set(letters) & set("WMC"):
                 raise NotImplementedError(
                     "diffusion_block goes with \"*\" attention blocks: a "
-                    "sliding window (\"W\", attn_window) or a state-space "
-                    "scan (\"M\") over the doubled sequence would cross "
-                    "from the noised copy into the clean one")
+                    "sliding window (\"W\", attn_window), a state-space "
+                    "scan (\"M\") or a convolution (\"C\") over the "
+                    "doubled sequence would cross from the noised copy into "
+                    "the clean one")
             if _has_pos_table(cfg):
                 raise NotImplementedError(
                     "diffusion_block wraps rotary positions (rope_theta) at "
@@ -261,17 +277,18 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
             raise NotImplementedError(
                 "a model with a layer_pattern runs on dp alone: its mixers "
                 "are neither sharded over mp nor staged over pp (ROADMAP "
-                "M7); nor is a diffusion_block's doubled sequence")
+                "M0); nor is a diffusion_block's doubled sequence")
     elif cfg.n_kv_heads not in (None, cfg.n_heads) or cfg.moe_latent \
             or cfg.shared_expert_ff or cfg.leading_pattern \
             or cfg.attn_window or cfg.attn_gate or cfg.dense_ff \
             or cfg.rope_yarn or cfg.rope_fraction != 1.0 \
-            or cfg.diffusion_block is not None or cfg.head_qk_norm:
+            or cfg.diffusion_block is not None or cfg.head_qk_norm \
+            or cfg.conv_taps:
         raise ValueError(
             "n_kv_heads, moe_latent, shared_expert_ff, leading_pattern, "
             "attn_window, attn_gate, dense_ff, rope_yarn, rope_fraction, "
-            "diffusion_block and head_qk_norm are a patterned model's: set "
-            "layer_pattern")
+            "diffusion_block, head_qk_norm and conv_taps are a patterned "
+            "model's: set layer_pattern")
     if _holds_a_share(cfg) and (par.mp > 1 or par.pp > 1):
         raise NotImplementedError(
             f"a layer that holds {_experts_held(cfg)} of {cfg.n_experts} "
@@ -375,7 +392,8 @@ def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
     state-space scan's behaviour hangs on, by Mamba-2's published scheme:
     ``dt_bias`` the inverse softplus of a log-uniform draw in
     ``ssm_dt_range``, ``a_log = log U(1, 16)``, ``d_skip`` 1, and the
-    depthwise conv U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it."""
+    depthwise conv U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it (a "C"
+    block's too)."""
     d, std = cfg.d_model, 0.02
     out_scale = std / math.sqrt(2 * cfg.n_layers)
 
@@ -437,6 +455,12 @@ def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
             return {"ln": ones(d), "w_gate": rand(d, cfg.dense_ff),
                     "w_up": rand(d, cfg.dense_ff),
                     "w_down": rand(cfg.dense_ff, d, scale=out_scale)}
+        if kind == "conv":
+            bound = 1.0 / math.sqrt(cfg.conv_taps)
+            # w_in's columns [B | C | u], d_model each.
+            return {"ln": ones(d), "w_in": rand(d, 3 * d),
+                    "conv_w": uniform(d, cfg.conv_taps, lo=-bound, hi=bound),
+                    "w_out": rand(d, d, scale=out_scale)}
         e, held, ff = cfg.n_experts, _experts_held(cfg), cfg.d_ff
         width = cfg.moe_latent or d
         blk = {"ln": ones(d), "gate": rand(d, e)}
@@ -673,7 +697,7 @@ def _route_experts(cfg: TransformerConfig, lp: Dict[str, jax.Array],
             w_down=lp["w_down"], bias=lp.get("router_bias")),
         tok, cfg.top_k, activation=_expert_activation(cfg),
         router=moe_lib.Router(cfg.router_scoring, cfg.router_renormalise,
-                              cfg.router_scale),
+                              cfg.router_scale, cfg.router_renorm_eps),
         router_x=router_x, buffer_factor=cfg.expert_buffer_factor)
 
 
@@ -803,6 +827,25 @@ def _dense_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                           lp["w_down"].astype(x.dtype))
 
 
+def _conv_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
+                x: jax.Array) -> jax.Array:
+    """A "C" block, LFM2's gated short convolution, on the normed stream:
+    ``[B, C, u] = h W_in`` (three times ``d_model`` columns), ``c =
+    conv(B * u)`` — depthwise and causal over ``conv_taps`` positions
+    (ops/ssd.py ``gated_causal_conv1d``), position t reading t - taps + 1 .. t,
+    no activation — and ``(C * c) W_out``.  Both gates and the convolution
+    are evaluated in fp32 between the two matmuls and rounded once, under
+    ``hvd_conv_gate``; ``B * u`` is taken tap by tap from the shifted
+    factors and never written out.  x: (mb, S, d)."""
+    hnorm = _rmsnorm(x, lp["ln"], cfg.norm_eps)
+    proj = jnp.einsum("bsd,de->bse", hnorm, lp["w_in"].astype(x.dtype))
+    with scope(CONV_SCOPES[1]):
+        b, c, u = jnp.split(proj, 3, axis=-1)
+        conv = ssd.gated_causal_conv1d(b, u, lp["conv_w"])
+        gated = (c.astype(jnp.float32) * conv).astype(x.dtype)
+    return jnp.einsum("bse,ed->bsd", gated, lp["w_out"].astype(x.dtype))
+
+
 def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                   x: jax.Array):
     """(An "E" block's output, its ``moe.RouterStats``): the routed experts
@@ -843,7 +886,7 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
     blocks a period, ...)."""
     mixers = {"ssm": _ssm_mixer, "attn": _gqa_mixer, "moe": _expert_mixer,
               "swa": functools.partial(_gqa_mixer, kind="swa"),
-              "dense": _dense_mixer}
+              "dense": _dense_mixer, "conv": _conv_mixer}
     with_stats = "E" in cfg.layer_pattern
 
     def block(kind, scope_name):
@@ -1504,7 +1547,7 @@ def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
     """Forward matmul-FLOPs a token of one patterned block, as this device
     computes it: the heads and experts it holds, causal scores halved, a
     window's over its band, the scan as the chunked algorithm's four
-    products."""
+    products, a gated convolution's two projections and its taps."""
     d, s = cfg.d_model, cfg.seq_len
     if letter == "M":
         h, p, g, n, q = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
@@ -1528,6 +1571,8 @@ def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
                 + 4.0 * pairs * hq * hd)
     if letter == "D":
         return 6.0 * d * cfg.dense_ff
+    if letter == "C":
+        return 8.0 * d * d + 2.0 * cfg.conv_taps * d
     width = cfg.moe_latent or d
     mats = 3.0 if cfg.gated_experts else 2.0
     routed = (cfg.top_k * _experts_held(cfg) / cfg.n_experts

@@ -637,6 +637,18 @@ def _bwd_dq_kernel(dqt_ref, dq_ref):
 _DQ_BLOCK_BYTES = 1 << 20
 
 
+def _dq_pieces(nq: int, block_q: int, d: int, itemsize: int) -> int:
+    """Into how many pieces along the sequence the dQ kernel cuts one head:
+    the fewest whole numbers of query tiles whose blocks — dQᵀ's piece in,
+    dQ's out with its rows laid 128 lanes wide, each buffered twice — stay
+    inside half of Mosaic's default scoped VMEM.  1 for every length up to
+    8192 at a head of 128 (8 MiB: what that call always held); 4 for 32,768
+    queries of a head of 64, which whole would ask for 24 MiB of the 16."""
+    step_bytes = 2 * nq * block_q * (d + max(d, 128)) * itemsize
+    return next(n for n in range(1, nq + 1)
+                if nq % n == 0 and step_bytes // n <= _SCOPED_VMEM_BYTES // 2)
+
+
 # Mosaic's default scoped VMEM on a v5e, inside which dK and dV's blocks,
 # accumulators and score temporaries fit at every tile ``_supported`` picks
 # (``_WIDE_BLOCK_ROW_BYTES``), and the most the pass is ever given: half of
@@ -730,19 +742,32 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
         name="hvd_flash_bwd_dkv" + _suffix(window, bd),
     )(offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
 
-    # dQ: no dot, no exp; a read and a write of dQ, ``hb`` heads a step.
-    head_bytes = sq * d * jnp.dtype(q_bhsd.dtype).itemsize
+    # dQ: no dot, no exp; a read and a write of dQ, ``hb`` heads a step, or
+    # one head in ``pieces`` along the sequence where a whole one would not
+    # fit (``_dq_pieces``: 32,768 queries of a head of 64).
+    itemsize = jnp.dtype(q_bhsd.dtype).itemsize
+    head_bytes = sq * d * itemsize
     hb = max(n for n in range(1, h + 1)
              if h % n == 0 and (n == 1 or n * head_bytes <= _DQ_BLOCK_BYTES))
+    pieces = _dq_pieces(nq, block_q, d, itemsize) if hb == 1 else 1
+    if pieces == 1:
+        grid, semantics = (b, h // hb), ("parallel", "parallel")
+        in_spec = pl.BlockSpec((1, hb, nq, d, block_q),
+                               lambda b, h: (b, h, 0, 0, 0))
+        out_spec = pl.BlockSpec((1, hb, sq, d), lambda b, h: (b, h, 0, 0))
+    else:
+        grid, semantics = (b, h, pieces), ("parallel",) * 3
+        in_spec = pl.BlockSpec((1, 1, nq // pieces, d, block_q),
+                               lambda b, h, p: (b, h, p, 0, 0))
+        out_spec = pl.BlockSpec((1, 1, sq // pieces, d),
+                                lambda b, h, p: (b, h, p, 0))
     dq = pl.pallas_call(
         _bwd_dq_kernel,
-        grid=(b, h // hb),
-        in_specs=[pl.BlockSpec((1, hb, nq, d, block_q),
-                               lambda b, h: (b, h, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, hb, sq, d), lambda b, h: (b, h, 0, 0)),
+        grid=grid,
+        in_specs=[in_spec],
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q_bhsd.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name="hvd_flash_bwd_dq" + _suffix(window, bd),
     )(dqt)

@@ -1,7 +1,8 @@
 """The state-space mixer's three pieces (Mamba-2, Dao & Gu 2024,
-arXiv:2405.21060): a causal depthwise convolution, the selective
-state-space recurrence by the chunked (state-space dual) algorithm, and
-the gated group RMSNorm.  Plain ``jax.numpy`` that XLA compiles; the
+arXiv:2405.21060): a causal depthwise convolution (and the same over a
+product of two factors, which a gated short-convolution block takes), the
+selective state-space recurrence by the chunked (state-space dual)
+algorithm, and the gated group RMSNorm.  Plain ``jax.numpy`` that XLA compiles; the
 backward pass is JAX's differentiation of this program.  A Pallas kernel
 of the scan is ROADMAP M7's other half.
 
@@ -42,6 +43,32 @@ def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     for j in range(k):
         out = out + xp[:, j:j + s] * w[:, j].astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+def gated_causal_conv1d(x: jax.Array, gate: jax.Array,
+                        w: jax.Array) -> jax.Array:
+    """:func:`causal_conv1d` of ``x * gate`` (both (batch, S, channels)),
+    without a bias, in fp32 for the caller to round:
+    ``out[t, c] = sum_j w[c, j] (x * gate)[t - (K - 1) + j, c]``.
+    Each tap's product is taken from the two factors shifted in their own
+    type, so that neither the product nor a widened copy of a factor is an
+    array of its own: one pass reads ``x`` and ``gate`` once (compiled for a
+    v5e, PERF.md section 6, PR 41: a product made first, in fp32, was a
+    write and K reads of 4 bytes a value beside factors of 2)."""
+    k = w.shape[1]
+    s = x.shape[1]
+
+    def shifted(t, by):          # position t reads t - by, 0 before the first
+        if by == 0:
+            return t.astype(jnp.float32)
+        return jnp.pad(t, ((0, 0), (by, 0), (0, 0)))[:, :s].astype(jnp.float32)
+
+    out = 0.0
+    for j in range(k):
+        by = k - 1 - j
+        tap = shifted(x, by) * shifted(gate, by) * w[:, j].astype(jnp.float32)
+        out = tap if j == 0 else out + tap
+    return out
 
 
 def gated_group_rmsnorm(y: jax.Array, z: jax.Array, scale: jax.Array,

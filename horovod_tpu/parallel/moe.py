@@ -277,6 +277,7 @@ class Router(NamedTuple):
     scoring: str = "softmax"     # "softmax" | "sigmoid" (choice by s + bias)
     renormalise: bool = False    # chosen weights over their sum
     scale: float = 1.0           # x the weights (``routed_scaling_factor``)
+    renorm_eps: float = 0.0      # added to that sum (LFM2's 1e-6)
 
 
 class RouterStats(NamedTuple):
@@ -357,9 +358,13 @@ def _scores(params: GatedMoEParams, x: jax.Array, top_k: int,
 
 def _weigh(p, router: Router):
     """The chosen experts' scores (0 elsewhere) as the weights of their
-    outputs: as they are, or over their sum, and scaled."""
+    outputs: as they are, or over their sum (plus ``renorm_eps`` where a
+    model adds one), and scaled."""
     if router.renormalise:
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
+        total = jnp.sum(p, axis=-1, keepdims=True)
+        if router.renorm_eps:
+            total = total + router.renorm_eps
+        p = p / total
     return p if router.scale == 1.0 else p * router.scale
 
 

@@ -18,7 +18,7 @@ on the host timeline of a captured trace alongside the device steps.
 * ``scope(name)`` — names a block of the *compiled* training step
   (``STEP_SCOPES``, ``MOE_SCOPES``, ``SSM_SCOPES``,
   ``LATENT_MOE_SCOPES``, ``ATTN_PART_SCOPES``, ``DENSE_MLP_SCOPE``,
-  ``TP_RING_SCOPES``): a
+  ``CONV_SCOPES``, ``TP_RING_SCOPES``): a
   ``jax.named_scope``, so the name
   lands in every HLO operation's ``op_name`` and from there in a device
   profile.
@@ -88,6 +88,11 @@ ATTN_PART_SCOPES = ("attn_rope", "attn_gate", "attn_qknorm")
 # A patterned model's gated dense MLP block, inside ``mlp`` (what is left of
 # ``mlp`` is then the expert blocks').
 DENSE_MLP_SCOPE = "mlp_dense"
+# A patterned model's gated short-convolution block
+# (``models/transformer._conv_mixer``), a block of the step as ``ssm`` is,
+# and inside it everything between its two matmuls: both gates and the
+# depthwise convolution.
+CONV_SCOPES = ("conv", "conv_gate")
 # Inside ``attn`` and ``mlp`` over more than one ``mp`` member: the two forms
 # of ``parallel/tensor_parallel.py``'s ring, whose collective-permutes a
 # profile then shows by block, phase and form.

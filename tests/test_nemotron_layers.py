@@ -452,7 +452,7 @@ def test_balancer_moves_the_bias_until_every_expert_is_chosen_alike():
 
 
 @pytest.mark.parametrize("par, item", [
-    (tfm.ParallelConfig(mp=2), "M7"), (tfm.ParallelConfig(pp=2), "M7")])
+    (tfm.ParallelConfig(mp=2), "M0"), (tfm.ParallelConfig(pp=2), "M0")])
 def test_a_patterned_model_refuses_mp_and_pp(par, item):
     with pytest.raises(NotImplementedError, match=f"ROADMAP {item}"):
         tfm.init_params(jax.random.PRNGKey(0), CFG, par)
