@@ -40,10 +40,10 @@ trap cleanup_orphans EXIT INT TERM
 TIER_FAST=(
   test_basics.py test_bert.py
   # The flash kernels under the block-diffusion mask (ISSUE 39): forward and
-  # the three gradients against the dense mask, the walk of the live tiles
+  # the three gradients against the dense mask, the list of the live tiles
   # (exactly the tiles with an unmasked pair, n in 1, 2, 4), the tile rule,
   # the counter, the refusals, and the flagship's, BERT's and Laguna's
-  # attention calls traced to the parent's text.
+  # attention calls' pinned traces.
   test_block_diffusion_attention.py
   test_checkpoint_engine.py test_chips.py
   test_ci_tiers.py
@@ -51,14 +51,19 @@ TIER_FAST=(
   test_dispatch.py
   test_flash_attention.py
   # The flash kernels with a sliding window (ISSUE 33): forward and the
-  # three gradients against the reference's mask, grids that walk the band
-  # only, the tile rule, the callers' window arguments.
+  # three gradients against the reference's mask, grids that walk the list
+  # of the band's tiles only, the tile rule, the callers' window arguments.
   test_flash_attention_window.py
   # The flash backward as one pass over the score tiles (ISSUE 34): dQ, dK,
   # dV against the two kernels it replaced (bit for bit in fp32) and the
   # reference, the scratch's zeroing, the VMEM the call states, the length
   # it refuses, and the backward compiled for a described v5e.
   test_flash_backward_pass.py
+  # The list the flash grids walk (ISSUE 43): the live tile pairs of the
+  # three masks against the dense mask (counts, order, offsets, unequal
+  # tiles), the grids and the counter of the cells' calls, and results bit
+  # for bit those of a walk over every tile and of traced offsets.
+  test_flash_walk.py
   test_fleet.py
   # The pause sentinel (ISSUE 36): garbage collections and the heartbeat in
   # the registry, the flight recorder, the log and a trace; arming under

@@ -44,14 +44,17 @@ and BERT's 512 take 512).  The kernels pay per grid step and per re-read of
 the operand they stream past the accumulator they keep resident (queries for
 the forward, keys for the backward pass), not per FLOP: a wider resident side
 halves the steps and the re-reads, a wider streamed side halves the steps
-again, and on a causal grid fewer steps are dead (at 512 x 512 a (batch,
-head) of 8192 walks 256 steps for 136 live tiles, at 1024 x 1024 64 for 36;
-a dead step is not free, 0.5-0.6 us with 512 keys a block: its K / V blocks
-are fetched before ``pl.when`` skips it).  Bare on a v5e, (5, 8192, 16, 64) bf16
-causal, ms a call (forward, dQ, dK/dV: PERF.md section 6, PR 29; the pass
-that took the two backward kernels' place and the dQ kernel that is left:
-PR 34, where the two old kernels read 18.83 and 23.93 at 1024 x 1024 and
-40.39 as one call):
+again.  A causal call's grid takes a step for a tile that holds an unmasked
+pair and for no other (the list, below): at 1024 x 1024 a (batch, head) of
+8192 has 36 such tiles of 64, one of 32,768 has 528 of 1,024.  Until PR 43
+the grid was the whole rectangle, and a dead step was not free, 1.20 us at
+1024 keys a block, 27 % of a live one: its K / V blocks were fetched before
+``pl.when`` skipped it.  Bare on a v5e, (5, 8192, 16, 64) bf16
+causal, ms a call (forward, dQ, dK/dV on the rectangle: PERF.md section 6,
+PR 29; the pass that took the two backward kernels' place and the dQ kernel
+that is left: PR 34, where the two old kernels read 18.83 and 23.93 at 1024 x
+1024 and 40.39 as one call; the last two rows the list's own timing, PRs
+42 and 43, forward and the whole backward, 15.43 and 25.05 on the rectangle):
 
     tile (bq x bk)   512x512  1024x512  512x1024  1024x1024  2048x1024
     forward            20.33     16.45     18.13      15.33      14.71
@@ -59,6 +62,8 @@ PR 34, where the two old kernels read 18.83 and 23.93 at 1024 x 1024 and
     dK/dV (until 34)   27.00     25.60     24.18      22.97      24.13 *
     backward pass          -     28.92     27.78      26.20          -
     + dQ's transposes      -      0.72      0.68       0.63          -
+    forward, list          -         -         -      13.64          -
+    backward, list         -         -         -      23.10          -
 
 (* needs ``vmem_limit_bytes`` of 32 MiB.)  The pass costs what dK/dV cost
 plus a tenth: its fifth dot rides on a tile whose time was never the dots.
@@ -70,37 +75,59 @@ of scoped VMEM — for heads up to 256 bytes a row (bf16 128, fp32 64); wider
 heads keep 512 (``_WIDE_BLOCK_ROW_BYTES``).  The pass asks for that much and
 for its resident dQ besides (``_bwd_vmem_limit``).
 
-A call with a ``window`` (each query sees the ``window`` keys up to and
-including its own) runs the same bodies over a grid that walks the band
-only: the streamed axis has ``_band_extent`` steps a resident tile whatever
-the sequence length (two for a window and tiles of 512), its index map
-offset from the resident tile's index and clamped at the sequence's ends.
-Its kernels are named ``hvd_flash_fwd_win``, ``hvd_flash_bwd_dq_win`` and
-``hvd_flash_bwd_dkv_win``; a call without a window traces to what it always
-did.
+Both grids that see score tiles — the forward's and the backward pass's —
+walk a list.  Where the mask's layout is known as the call is traced (a
+causal call whose offsets are Python ints: every trainer's), the (resident
+tile, streamed tile) pairs that hold an unmasked pair are laid out in numpy
+(``_live_tiles``, ``_live_pairs``), resident tile ascending and streamed tile
+ascending inside it, and go in as scalar prefetch: the grid is (batch, heads,
+pairs), the index maps read step t's pair, the bodies read "first / last
+step of this resident tile" from the list, and no step is dead, so nothing
+of a dead tile is fetched and no body skips.  One rule a mask, one list for
+all: the triangle of a causal call, the band of a ``window`` (each query sees
+the ``window`` keys up to and including its own; two tiles a resident tile
+at a window and tiles of 512, whatever the sequence), the live tiles of a
+``diffusion_block`` call (below).  A trace-time counter,
+``hvd_flash_tiles_built_total{kernel, state}``, holds what each such grid
+was built with: the steps it takes (``live``), those a body skips
+(``skipped``: none) and the tiles of the rectangle it never visits
+(``unvisited``).  A call with nothing to lay out keeps the rectangle, a step
+a tile: one without a mask (BERT's: every tile is live, and with one tile a
+(batch, head) the bodies' "first" and "last" fold away, which a list's flags
+do not: 8.27 ms a forward call against 8.83 as a list of one) and a causal
+call whose offsets are traced values (ring attention's shards), whose bodies
+skip a dead tile themselves.  Bare on a v5e, bf16, ms a call forward /
+backward (PERF.md section 6, PRs 42 and 43: the rectangle, dead steps fetched
+and skipped; a map that clamps a dead step to the block its neighbour read,
+which is how the band and the block-diffusion mask were walked until then;
+the list):
+
+    call                           rectangle      clamped map    list
+    (5, 8192, 16, 64) causal     15.43 / 25.05  14.62 / 23.61  13.64 / 23.10
+    (1, 32768, 32, 64) causal    82.31 / 134.3  74.48 / 126.4  71.90 / 123.4
+    (3, 8192, 24, 128) causal    15.87 / 24.87  15.12 / 23.51  14.24 / 23.09
+    (4, 4096, 16, 128) causal     4.39 / 6.78    4.30 / 6.36    4.10 / 6.25
+    (3, 8192, 36, 128) window 512      -         5.62 / 9.81    5.79 / 9.94
+    (1, 8192, 32, 128) block 4         -         4.94 / 8.62    4.61 / 7.87
+
+A step of a list costs some 0.05 us more than a step of a map (the band's
+31 steps against 32 are 3 % slower forward, 1.4 % backward) and a dead step
+that a map still takes about 0.5 us: the list wins wherever a map would have
+dead steps to clamp.  A windowed call's kernels are named
+``hvd_flash_fwd_win``, ``hvd_flash_bwd_dq_win`` and ``hvd_flash_bwd_dkv_win``.
 
 A call with a ``diffusion_block`` (block-diffusion training: the sequence is
 a noised copy and then the clean copy of the same L tokens, in blocks; a
 noised query sees its own noised block and the clean blocks strictly before
 it, a clean query the clean blocks up to its own, nobody else a noised key)
 runs the same bodies again, ``_scores_t`` masking a tile by the rule from
-the tile's global starts, over grids that follow the mask: the tile is
-picked from the *half* (one width for both sides: 4096 takes 1024 at a bf16
-head of 128), and with n = L / tile tiles a half each grid walks the n^2 + 2n
-tiles of the 4 n^2 that hold an unmasked pair (24 of 64 at n = 4; a causal
-call of the same 2L positions has 36 live).  The step-to-tile map is
-``_bd_streamed``: the forward n + 1 steps a resident query tile, the
-backward pass 2n a resident key tile, a step past a resident tile's live run
-naming the block its neighbour read (nothing fetched) and skipped by the
-body, as the band's clamped steps are: n^2 skipped of the forward's 2 n^2 +
-2n steps, 3 n^2 - 2n of the backward pass's 4 n^2.  The kernels are named
-``hvd_flash_fwd_bd``, ``hvd_flash_bwd_dq_bd`` and ``hvd_flash_bwd_dkv_bd``,
-dQ's resident scratch covers all 2L queries (24 MiB of VMEM at 8192 x 128),
-and a trace-time counter, ``hvd_flash_tiles_built_total{kernel, state}``,
-holds the live and skipped steps each grid was built with.  Bare on a v5e,
-(1, 8192, 32, 128) bf16, block 4, ms a call (PERF.md section 6, PR 39): the
-forward 5.57 and the backward 9.02, where the causal call of the same
-length takes 7.65 and 11.52 for half as many live pairs again.
+the tile's global starts: the tile is picked from the *half* (one width for
+both sides: 4096 takes 1024 at a bf16 head of 128), and with n = L / tile
+tiles a half the list holds the n^2 + 2n tiles of the 4 n^2 that hold an
+unmasked pair (24 of 64 at n = 4; a causal call of the same 2L positions has
+36 live).  The kernels are named ``hvd_flash_fwd_bd``,
+``hvd_flash_bwd_dq_bd`` and ``hvd_flash_bwd_dkv_bd``, and dQ's resident
+scratch covers all 2L queries (24 MiB of VMEM at 8192 x 128).
 
 Three kernels, two of which see scores:
 
@@ -263,83 +290,141 @@ def _scores_t(q, k, *, causal: bool, scale: float, q_start, k_start,
 
 
 # ---------------------------------------------------------------------------
-# The band of a windowed call
+# The walk: a list of the live tile pairs
 # ---------------------------------------------------------------------------
 #
-# With a window the streamed axis of each grid walks only the tiles the band
-# ``q_pos - window < k_pos <= q_pos`` touches: ``_band_extent`` steps a
-# resident tile, a number that depends on the two tile widths and the window
-# and not on the sequence.  Step j of resident tile i is streamed tile
-# ``_band_first(i) + j``; where that falls off either end of the sequence the
-# index map clamps it to the end's tile (the block it names is then the one
-# the neighbouring step reads, so nothing is fetched twice) and the body
-# skips the step.
+# Where the mask's layout is known as the call is traced (a causal call whose
+# offsets are Python ints: every trainer's, every windowed and every
+# block-diffusion call), the grid of the forward and of the backward pass is
+# (batch, heads, steps) and its last axis a walk over a list: the (resident
+# tile, streamed tile) pairs whose score tile holds an unmasked pair, laid
+# out in numpy and handed to the kernel as scalar prefetch.  The index maps
+# read step t's pair from it, the bodies read "first / last step of this
+# resident tile" from it, and a tile that holds no pair is never a step:
+# nothing of it is fetched, nothing skipped.  One list serves every mask,
+# since a mask is only a rule for ``_live_tiles``: the triangle of a causal
+# call, the band of a ``window``, the n^2 + 2n tiles of a block-diffusion
+# call.  A call with nothing to lay out keeps the rectangle, (batch, heads,
+# resident tiles, streamed tiles), the step its own pair: a call without a
+# mask, where every tile is live, and a causal call whose offsets are traced
+# values (ring attention's shards under ``shard_map``), whose bodies skip a
+# dead tile themselves (``_step_live``).
 
-def _floordiv(a, b: int):
-    """a // b for a >= 0: a Python int, or a traced int32 of a grid."""
-    return a // b if isinstance(a, int) else lax.div(a, jnp.int32(b))
-
-
-def _band_first(i, block_res: int, block_str: int, window: int,
-                keys_streamed: bool):
-    """First streamed tile the band touches for resident tile ``i`` (may be
-    negative).  Keys streamed past resident queries (forward, dQ): the tile
-    of key ``i * bq - (window - 1)``.  Queries streamed past resident keys
-    (dK/dV): the tile of query ``i * bk``."""
-    if not keys_streamed:
-        return _floordiv(i * block_res, block_str)
-    back = -(-(window - 1) // block_str)        # tiles a window reaches back
-    return _floordiv(i * block_res + back * block_str - (window - 1),
-                     block_str) - back
-
-
-def _band_extent(n_res: int, block_res: int, block_str: int, window: int,
-                 keys_streamed: bool) -> int:
-    """Streamed tiles the band touches for one resident tile, at most."""
-    reach = block_res - 1 + (0 if keys_streamed else window - 1)
-    return max((i * block_res + reach) // block_str
-               - _band_first(i, block_res, block_str, window, keys_streamed)
-               + 1 for i in range(n_res))
-
-
-def _streamed_tile(i, j, n_str: int, block_res: int, block_str: int,
-                   window: Optional[int], keys_streamed: bool, bd=None):
-    """(streamed tile of grid step (i, j), whether the step is one of the
-    resident tile's own): the step-to-tile map the mask gives.  Without a
-    mask of its own the grid walks every tile, j itself; a window's walks
-    the band, a block-diffusion call's the live tiles."""
+def _live_tiles(sq: int, sk: int, block_q: int, block_k: int,
+                window: Optional[int], bd, offsets) -> np.ndarray:
+    """(query tiles, key tiles) bool: the score tiles of a causal call that
+    hold an unmasked pair, ``offsets`` = (q_offset, kv_offset).  Unless
+    every (q, k) has q_pos < k_pos; with a window also unless every k lies
+    at or before q_pos - window.  Block diffusion: unless the quadrant's
+    rule (``_bd_quadrant``) leaves the tile's blocks no pair (a block as
+    wide as the tile hides the clean copy of a noised tile's own
+    positions)."""
+    qi = np.arange(sq // block_q)[:, None]
+    ki = np.arange(sk // block_k)[None, :]
     if bd is not None:
-        return _bd_streamed(i, j, n_str // 2, keys_streamed)
-    if window is None:
-        return j, True
-    t = _band_first(i, block_res, block_str, window, keys_streamed) + j
-    return t, jnp.logical_and(t >= 0, t < n_str)
-
-
-def _tile_live(q_start, k_start, block_q: int, block_k: int, causal: bool,
-               window: Optional[int], inside, bd=None):
-    """Whether a score tile has an unmasked pair.  Causal: unless every
-    (q, k) has q_pos < k_pos; with a window also unless every k lies at or
-    before q_pos - window; a step clamped at the sequence's end is none.
-    Block diffusion: a step of the resident tile's live run, unless the
-    quadrant's rule leaves the tile's blocks no pair (a block as wide as the
-    tile hides the clean copy of a noised tile's own positions)."""
-    if bd is not None:
-        q_lo, k_lo, ahead, behind, shift = _bd_quadrant(q_start, k_start, bd)
-        return jnp.logical_and(inside, jnp.logical_and(
-            (k_lo >> shift) <= ((q_lo + block_q - 1) >> shift) - ahead,
-            ((k_lo + block_k - 1) >> shift) >= (q_lo >> shift) - behind))
-    if not causal:
-        return True
+        block, half = bd
+        n, shift = half // block_q, block.bit_length() - 1
+        q_noised, k_noised = qi < n, ki < n
+        q_lo, k_lo = (qi % n) * block_q, (ki % n) * block_k
+        qb, qb_last = q_lo >> shift, (q_lo + block_q - 1) >> shift
+        kb, kb_last = k_lo >> shift, (k_lo + block_k - 1) >> shift
+        return np.where(k_noised, q_noised & (kb <= qb_last) & (kb_last >= qb),
+                        kb <= qb_last - q_noised)
+    q_start = offsets[0] + qi * block_q
+    k_start = offsets[1] + ki * block_k
     live = q_start + block_q - 1 >= k_start
     if window is not None:
-        live = jnp.logical_and(
-            jnp.logical_and(live, k_start + block_k - 1 > q_start - window),
-            inside)
+        live &= k_start + block_k - 1 > q_start - window
     return live
 
+
+def _live_pairs(sq: int, sk: int, block_q: int, block_k: int,
+                window: Optional[int], bd, offsets,
+                keys_streamed: bool) -> np.ndarray:
+    """The walk of one grid, (steps, 2) int32 rows of (resident tile,
+    streamed tile): the live tiles, resident tile ascending and streamed
+    tile ascending inside it, so that the forward's online softmax and dQ's
+    sum over key tiles run in the order they always had.  Keys streamed past
+    resident queries: the forward.  Queries streamed past resident keys: the
+    backward pass.  Two particulars.  A block-diffusion forward reads a
+    noised query tile's clean key tiles before its own noised one, as it did
+    when its walk was a map.  And a resident tile with no live tile at all
+    (an earlier chunk's queries against a later chunk's keys) keeps one
+    step, which the mask empties: its outputs are that step's zeros."""
+    live = _live_tiles(sq, sk, block_q, block_k, window, bd, offsets)
+    if not keys_streamed:
+        live = live.T
+    live[~live.any(axis=1), 0] = True
+    res, streamed = np.nonzero(live)
+    if bd is not None and keys_streamed:
+        order = np.lexsort((streamed, streamed < live.shape[1] // 2, res))
+        res, streamed = res[order], streamed[order]
+    return np.stack([res, streamed], axis=1).astype(np.int32)
+
+
+def _walk(kernel: str, b: int, h: int, sq: int, sk: int, block_q: int,
+          block_k: int, causal: bool, window: Optional[int], bd, offsets,
+          keys_streamed: bool):
+    """How a grid steps over the score tiles: (the grid, its scalar-prefetch
+    operands, ``spec(block, index)``: the BlockSpec of a block whose index
+    after (batch, head) is ``index(resident tile, streamed tile)`` of the
+    step).  The list of a laid-out call goes in as (4, steps) int32: the
+    resident tile of step t, the streamed tile, whether t is the first step
+    of its resident tile, whether the last.  It is counted as the call is
+    traced, over the (batch, head)s: the steps the grid takes, the steps it
+    takes and the body skips (none: a step is live by its list), the tiles
+    of the rectangle it never visits."""
+    nq, nk = sq // block_q, sk // block_k
+    if not causal or offsets is None:
+        grid = (b, h, nq, nk) if keys_streamed else (b, h, nk, nq)
+        operands = ()
+        tiles = lambda i, j: (i, j)
+    else:
+        pairs = _live_pairs(sq, sk, block_q, block_k, window, bd, offsets,
+                            keys_streamed)
+        for state, steps in (("live", len(pairs)), ("skipped", 0),
+                             ("unvisited", nq * nk - len(pairs))):
+            registry().counter(
+                "hvd_flash_tiles_built_total",
+                "score tiles of the flash grids that walk a list, as traced, "
+                "by kernel and state", kernel=kernel, state=state).inc(
+                    b * h * steps)
+        turn = pairs[1:, 0] != pairs[:-1, 0]
+        table = np.stack([pairs[:, 0], pairs[:, 1], np.r_[True, turn],
+                          np.r_[turn, True]]).astype(np.int32)
+        grid, operands = (b, h, len(pairs)), (jnp.asarray(table),)
+        tiles = lambda t, table: (table[0, t], table[1, t])
+
+    def spec(block, index):
+        return pl.BlockSpec(
+            block, lambda b, h, *step: (b, h, *index(*tiles(*step))))
+    return grid, operands, spec
+
+
+def _step(walk_ref):
+    """This grid step's (resident tile, streamed tile, whether it is the
+    first step of its resident tile, whether the last): read from the list,
+    or the rectangle's own two indices."""
+    if walk_ref is None:
+        i, j = pl.program_id(2), pl.program_id(3)
+        return i, j, j == 0, j == pl.num_programs(3) - 1
+    t = pl.program_id(2)
+    return (walk_ref[0, t], walk_ref[1, t], walk_ref[2, t] == 1,
+            walk_ref[3, t] == 1)
+
+
+def _step_live(walk_ref, causal: bool, q_start, k_start, block_q: int):
+    """Whether this step's score tile has an unmasked pair, at the tile's
+    global starts.  A step of a list has, by its layout; on the rectangle a
+    causal tile (traced offsets) unless every (q, k) has q_pos < k_pos: the
+    bodies' own test, and then they skip the step."""
+    if walk_ref is not None or not causal:
+        return True
+    return q_start + block_q - 1 >= k_start
+
+
 # ---------------------------------------------------------------------------
-# The mask and the walk of a block-diffusion call
+# The mask of a block-diffusion call
 # ---------------------------------------------------------------------------
 #
 # The sequence is two halves of L positions: a noised copy (positions 0 ..
@@ -352,15 +437,10 @@ def _tile_live(q_start, k_start, block_q: int, block_k: int, causal: bool,
 # for two scalars of the quadrant (``_bd_quadrant``).
 #
 # With n = L / tile the square has 4 n^2 tiles of which n^2 + 2n hold an
-# unmasked pair, and each grid walks those alone (``_bd_streamed``): the
-# forward n + 1 steps a resident query tile (a noised tile r reads clean key
-# tiles n .. n + r, then its own noised tile; a clean tile n + r reads n ..
-# n + r), the backward pass 2n steps a resident key tile (a noised tile c
-# reads query tile c alone; a clean tile n + c reads noised query tiles c ..
-# n - 1, then clean ones n + c .. 2n - 1).  A step past a resident tile's
-# live run names the block the run's last step read, so nothing is fetched,
-# and the body skips it: n^2 of the forward's 2 n^2 + 2n steps and 3 n^2 -
-# 2n of the backward pass's 4 n^2.
+# unmasked pair, and each walk is those alone: a noised query tile r reads
+# clean key tiles n .. n + r and its own noised tile; a clean one n + r reads
+# n .. n + r; a noised key tile c is read by query tile c alone, a clean one
+# n + c by noised query tiles c .. n - 1 and clean ones n + c .. 2n - 1.
 
 _FAR = 1 << 24      # more blocks than any sequence has
 
@@ -381,56 +461,14 @@ def _bd_quadrant(q_start, k_start, bd):
             block.bit_length() - 1)
 
 
-def _bd_streamed(i, j, n: int, keys_streamed: bool):
-    """(streamed tile of step j of resident tile i, whether the step is in
-    the tile's live run) with n tiles a half; Python ints or a grid's traced
-    int32s.  Keys streamed past resident queries: the forward.  Queries
-    streamed past resident keys: the backward pass."""
-    if isinstance(i, int):
-        where, least = (lambda c, a, b: a if c else b), min
-    else:
-        where, least = jnp.where, jnp.minimum
-    noised = i < n
-    r = where(noised, i, i - n)
-    if keys_streamed:
-        last = where(noised, r + 1, r)
-        step = least(j, last)
-        tile = where(step > r, r, n + step)     # past the clean run: its own
-    else:
-        last = where(noised, 0, 2 * (n - r) - 1)
-        step = least(j, last)
-        tile = where(noised, r, where(step < n - r, r + step, step + 2 * r))
-    return tile, j <= last
-
-
-def _bd_steps(n: int, keys_streamed: bool) -> int:
-    return n + 1 if keys_streamed else 2 * n
-
-
-def _bd_built(kernel: str, n: int, keys_streamed: bool, calls: int) -> None:
-    """Trace-time count of the tile steps a block-diffusion call's grid was
-    built with, live and skipped, over its ``calls`` (batch, head)s."""
-    live = n * n + 2 * n
-    for state, steps in (("live", live),
-                         ("skipped",
-                          2 * n * _bd_steps(n, keys_streamed) - live)):
-        registry().counter(
-            "hvd_flash_tiles_built_total",
-            "tile steps of the block-diffusion flash grids traced, by "
-            "kernel and state", kernel=kernel, state=state).inc(calls * steps)
-
-
-def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+def _fwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
                 block_q: int, block_k: int, window: Optional[int] = None,
-                n_str: int = 0, bd=None):
-    i = pl.program_id(2)          # q tile
-    j = pl.program_id(3)          # k step (innermost: scratch carries over j)
-    nk = pl.num_programs(3)
-    kt, inside = _streamed_tile(i, j, n_str, block_q, block_k, window, True,
-                                bd)
+                bd=None):
+    # i: q tile; kt: k tile (innermost: scratch carries over a q tile's steps)
+    i, kt, first, last = _step(walk_ref)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -440,10 +478,8 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     kv_off = off_ref[0, 1]
     q_start = q_off + i * block_q
     k_start = kv_off + kt * block_k
-    live = _tile_live(q_start, k_start, block_q, block_k, causal, window,
-                      inside, bd)
 
-    @pl.when(live)
+    @pl.when(_step_live(walk_ref, causal, q_start, k_start, block_q))
     def _compute():
         q = q_ref[0, 0]                # (bq, D), the caller's type
         k = k_ref[0, 0]                # (bk, D)
@@ -465,7 +501,7 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _finalize():
         m = m_scr[:1, :]
         l = l_scr[:1, :]
@@ -475,76 +511,51 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
-def _streamed_index(n_res: int, n_str: int, block_res: int, block_str: int,
-                    window: Optional[int], keys_streamed: bool, bd=None):
-    """(steps of the streamed grid axis, grid indices (i, j) -> the streamed
-    tile's block index): every tile without a mask of its own, with a
-    window the band's tiles clamped into the sequence, with ``bd`` the
-    resident tile's live tiles and past them the last of those."""
-    if bd is not None:
-        return (_bd_steps(n_str // 2, keys_streamed),
-                lambda i, j: _bd_streamed(i, j, n_str // 2, keys_streamed)[0])
-    if window is None:
-        return n_str, lambda i, j: j
-    steps = _band_extent(n_res, block_res, block_str, window, keys_streamed)
-
-    def tile(i, j):
-        return jnp.clip(_band_first(i, block_res, block_str, window,
-                                    keys_streamed) + j, 0, n_str - 1)
-    return steps, tile
-
-
 def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
-              block_q, block_k, interpret, window=None, bd=None):
+              block_q, block_k, interpret, window=None, bd=None,
+              static_offsets=None):
     b, h, sq, d = q_bhsd.shape
     sk = k_bhsd.shape[2]
-    nq, nk = sq // block_q, sk // block_k
-    steps, kt = _streamed_index(nq, nk, block_q, block_k, window, True, bd)
-    grid = (b, h, nq, steps)
-    kern = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                             block_q=block_q, block_k=block_k)
-    if window is not None:
-        kern = functools.partial(kern, window=window, n_str=nk)
-    if bd is not None:
-        kern = functools.partial(kern, n_str=nk, bd=bd)
-        _bd_built("hvd_flash_fwd_bd", nk // 2, True, b * h)
+    name = "hvd_flash_fwd" + _suffix(window, bd)
+    grid, walk, spec = _walk(name, b, h, sq, sk, block_q, block_k, causal,
+                             window, bd, static_offsets, True)
+    q_spec = spec((1, 1, block_q, d), lambda i, kt: (i, 0))
+    k_spec = spec((1, 1, block_k, d), lambda i, kt: (kt, 0))
     out, lse = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda b, h, i, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h, kt(i, j), 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b, h, i, j: (b, h, kt(i, j), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            # lse rows replicated over 8 sublanes so the (…, 8, block_q)
-            # tile meets Mosaic's (8, 128)-alignment; squeezed by callers.
-            pl.BlockSpec((1, 1, 8, block_q),
-                         lambda b, h, i, j: (b, h, 0, i)),
-        ],
+        functools.partial(_fwd_kernel, *((None,) if not walk else ()),
+                          causal=causal, scale=scale, block_q=block_q,
+                          block_k=block_k, window=window, bd=bd),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 2), lambda *_: (0, 0),
+                             memory_space=pltpu.SMEM),
+                q_spec, k_spec, k_spec,
+            ],
+            out_specs=[
+                q_spec,
+                # lse rows replicated over 8 sublanes so the (…, 8, block_q)
+                # tile meets Mosaic's (8, 128)-alignment; squeezed by callers.
+                spec((1, 1, 8, block_q), lambda i, kt: (0, i)),
+            ],
+            # m, l as lane-dense rows (8 sublanes for the (8, 128) tiling,
+            # like lse); the output accumulator transposed, as the body works.
+            scratch_shapes=[
+                pltpu.VMEM((8, block_q), jnp.float32),
+                pltpu.VMEM((8, block_q), jnp.float32),
+                pltpu.VMEM((d, block_q), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q_bhsd.dtype),
             jax.ShapeDtypeStruct((b, h, 8, sq), jnp.float32),
         ],
-        # m, l as lane-dense rows (8 sublanes for the (8, 128) tiling, like
-        # lse); the output accumulator transposed, as the body works.
-        scratch_shapes=[
-            pltpu.VMEM((8, block_q), jnp.float32),
-            pltpu.VMEM((8, block_q), jnp.float32),
-            pltpu.VMEM((d, block_q), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",)),
         interpret=interpret,
-        name="hvd_flash_fwd" + _suffix(window, bd),
-    )(offsets, q_bhsd, k_bhsd, v_bhsd)
+        name=name,
+    )(*walk, offsets, q_bhsd, k_bhsd, v_bhsd)
     return out, lse[:, :, 0, :]
 
 
@@ -552,27 +563,23 @@ def _fwd_call(q_bhsd, k_bhsd, v_bhsd, offsets, *, causal, scale,
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dqt_ref, dk_scr, dv_scr, dq_scr, *,
+def _bwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, dk_ref, dv_ref, dqt_ref, dk_scr, dv_scr, dq_scr, *,
                 causal: bool, scale: float, block_q: int, block_k: int,
-                window: Optional[int] = None, n_str: int = 0, bd=None):
+                nk: int, window: Optional[int] = None, bd=None):
     """The one pass over the score tiles: a key tile resident, query tiles
-    streamed past it.  dK and dV of the resident tile are summed over the
-    inner axis; dQᵀ of every query tile of the (batch, head) lives in
-    ``dq_scr`` and is summed over the outer one, in ascending key tile."""
-    i = pl.program_id(2)          # k tile (scratch dq carries over i)
-    j = pl.program_id(3)          # q step (innermost)
-    nk = pl.num_programs(2)
-    nq = pl.num_programs(3)
-    qt, inside = _streamed_tile(i, j, n_str, block_k, block_q, window, False,
-                                bd)
+    streamed past it.  dK and dV of the resident tile are summed over its
+    steps; dQᵀ of every query tile of the (batch, head) lives in ``dq_scr``
+    and is summed over the resident tiles, in ascending key tile."""
+    # i: k tile (scratch dq carries over i); qt: q tile (innermost)
+    i, qt, first, last = _step(walk_ref)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(jnp.logical_and(i == 0, j == 0))
+    @pl.when(jnp.logical_and(i == 0, first))
     def _init_dq():               # a (batch, head) starts from nothing
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -580,10 +587,8 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     kv_off = off_ref[0, 1]
     q_start = q_off + qt * block_q
     k_start = kv_off + i * block_k
-    live = _tile_live(q_start, k_start, block_q, block_k, causal, window,
-                      inside, bd)
 
-    @pl.when(live)
+    @pl.when(_step_live(walk_ref, causal, q_start, k_start, block_q))
     def _compute():
         q = q_ref[0, 0]                                        # (bq, D)
         k = k_ref[0, 0]                                        # (bk, D)
@@ -611,12 +616,12 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k, dst, dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                # (D, bq)
 
-    @pl.when(j == nq - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
-    @pl.when(jnp.logical_and(i == nk - 1, j == nq - 1))
+    @pl.when(jnp.logical_and(i == nk - 1, last))
     def _finalize_dq():           # every key tile is in: the one rounding
         dqt_ref[0, 0] = dq_scr[:].astype(dqt_ref.dtype)
 
@@ -667,10 +672,10 @@ def _bwd_vmem_limit(sq: int, d: int, dtype) -> int:
 
 def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
               causal, scale, block_q, block_k, interpret, window=None,
-              bd=None):
+              bd=None, static_offsets=None):
     b, h, sq, d = q_bhsd.shape
     sk = k_bhsd.shape[2]
-    nq, nk = sq // block_q, sk // block_k
+    nq = sq // block_q
     vmem_limit = _bwd_vmem_limit(sq, d, q_bhsd.dtype)
     if vmem_limit > _VMEM_CEILING_BYTES:
         raise ValueError(
@@ -682,65 +687,50 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
     lse = jnp.broadcast_to(lse[:, :, None, :], (b, h, 8, sq))
     delta = jnp.broadcast_to(delta[:, :, None, :], (b, h, 8, sq))
 
-    def q_spec(ix):
-        return pl.BlockSpec((1, 1, block_q, d), ix)
-
-    def k_spec(ix):
-        return pl.BlockSpec((1, 1, block_k, d), ix)
-
-    def row_spec(ix):
-        return pl.BlockSpec((1, 1, 8, block_q), ix)
-
-    kern = functools.partial(_bwd_kernel, causal=causal, scale=scale,
-                             block_q=block_q, block_k=block_k)
-    if window is not None:
-        kern = functools.partial(kern, window=window, n_str=nq)
-    if bd is not None:
-        kern = functools.partial(kern, n_str=nq, bd=bd)
-        _bd_built("hvd_flash_bwd_dkv_bd", nq // 2, False, b * h)
-
-    # The pass: grid over (k tiles, q steps), q innermost.  dQᵀ's block is
-    # the whole (batch, head)'s, resident from its first step to its last.
-    q_steps, qt = _streamed_index(nk, nq, block_k, block_q, window, False,
-                                  bd)
+    # The pass: a key tile resident, its query tiles in a row.  dQᵀ's block
+    # is the whole (batch, head)'s, resident from its first step to its last.
+    name = "hvd_flash_bwd_dkv" + _suffix(window, bd)
+    grid, walk, spec = _walk(name, b, h, sq, sk, block_q, block_k, causal,
+                             window, bd, static_offsets, False)
+    q_spec = spec((1, 1, block_q, d), lambda i, qt: (qt, 0))
+    k_spec = spec((1, 1, block_k, d), lambda i, qt: (i, 0))
+    row_spec = spec((1, 1, 8, block_q), lambda i, qt: (0, qt))
     dk, dv, dqt = pl.pallas_call(
-        kern,
-        grid=(b, h, nk, q_steps),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda b, h, i, j: (0, 0),
-                         memory_space=pltpu.SMEM),
-            q_spec(lambda b, h, i, j: (b, h, qt(i, j), 0)),
-            k_spec(lambda b, h, i, j: (b, h, i, 0)),
-            k_spec(lambda b, h, i, j: (b, h, i, 0)),
-            q_spec(lambda b, h, i, j: (b, h, qt(i, j), 0)),
-            row_spec(lambda b, h, i, j: (b, h, 0, qt(i, j))),
-            row_spec(lambda b, h, i, j: (b, h, 0, qt(i, j))),
-        ],
-        out_specs=[
-            k_spec(lambda b, h, i, j: (b, h, i, 0)),
-            k_spec(lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, nq, d, block_q),
-                         lambda b, h, i, j: (b, h, 0, 0, 0)),
-        ],
+        functools.partial(_bwd_kernel, *((None,) if not walk else ()),
+                          causal=causal, scale=scale, block_q=block_q,
+                          block_k=block_k, nk=sk // block_k, window=window,
+                          bd=bd),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 2), lambda *_: (0, 0),
+                             memory_space=pltpu.SMEM),
+                q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
+            ],
+            out_specs=[
+                k_spec, k_spec,
+                spec((1, 1, nq, d, block_q), lambda i, qt: (0, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((nq, d, block_q), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sk, d), k_bhsd.dtype),
             jax.ShapeDtypeStruct((b, h, sk, d), v_bhsd.dtype),
             jax.ShapeDtypeStruct((b, h, nq, d, block_q), q_bhsd.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((nq, d, block_q), jnp.float32),
-        ],
-        # dQ's scratch carries across key tiles: that axis is no longer
-        # parallel (a v5e has one TensorCore; nothing is lost).
+        # dQ's scratch carries across key tiles: that axis is not parallel
+        # (a v5e has one TensorCore; nothing is lost).
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"),
+            dimension_semantics=("parallel", "parallel")
+            + ("arbitrary",) * (len(grid) - 2),
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
-        name="hvd_flash_bwd_dkv" + _suffix(window, bd),
-    )(offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
+        name=name,
+    )(*walk, offsets, q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta)
 
     # dQ: no dot, no exp; a read and a write of dQ, ``hb`` heads a step, or
     # one head in ``pieces`` along the sequence where a whole one would not
@@ -778,29 +768,31 @@ def _bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets, *,
 # Differentiable entry points (custom VJP on (B, S, H, D) layout)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
-           window=None, bd=None):
+           window=None, bd=None, static_offsets=None):
     out, _ = _flash_impl(q, k, v, offsets, causal, scale, block_q, block_k,
-                         interpret, window, bd)
+                         interpret, window, bd, static_offsets)
     return out
 
 
 def _flash_impl(q, k, v, offsets, causal, scale, block_q, block_k,
-                interpret, window=None, bd=None):
+                interpret, window=None, bd=None, static_offsets=None):
     qt = q.transpose(0, 2, 1, 3)      # (B, H, S, D)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out, lse = _fwd_call(qt, kt, vt, offsets, causal=causal, scale=scale,
                          block_q=block_q, block_k=block_k,
-                         interpret=interpret, window=window, bd=bd)
+                         interpret=interpret, window=window, bd=bd,
+                         static_offsets=static_offsets)
     return out.transpose(0, 2, 1, 3), lse
 
 
 def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
-               window=None, bd=None):
+               window=None, bd=None, static_offsets=None):
     out, lse = _flash_impl(q, k, v, offsets, causal, scale, block_q,
-                           block_k, interpret, window, bd)
+                           block_k, interpret, window, bd, static_offsets)
     # Named for ``checkpoint_keeping_attention``.  The primal output and the
     # residual are both the named value reshaped back: were either the
     # kernel's own output beside a named copy, the recompute would need the
@@ -812,8 +804,8 @@ def _flash_fwd(q, k, v, offsets, causal, scale, block_q, block_k, interpret,
     return out, (q, k, v, offsets, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, window, bd, res,
-               g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, bd,
+               static_offsets, res, g):
     q, k, v, offsets, out, lse = res
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -828,7 +820,8 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, bd, res,
     dq, dk, dv = _bwd_call(qt, kt, vt, dot, lse, delta, offsets,
                            causal=causal, scale=scale, block_q=block_q,
                            block_k=block_k, interpret=interpret,
-                           window=window, bd=bd)
+                           window=window, bd=bd,
+                           static_offsets=static_offsets)
     d_off = np.zeros(offsets.shape, dtype=jax.dtypes.float0)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3), d_off)
@@ -932,6 +925,16 @@ def _checked_diffusion(diffusion_block, causal, window, q, k, q_offset,
     return block, q.shape[1] // 2
 
 
+def _offsets(q_offset, kv_offset):
+    """(the kernels' (1, 2) int32 operand, the two offsets as a tuple where
+    both are Python ints — the walks are then laid out from them — else
+    None)."""
+    static = isinstance(q_offset, int) and isinstance(kv_offset, int)
+    return (jnp.stack([jnp.asarray(q_offset, jnp.int32),
+                       jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2),
+            (q_offset, kv_offset) if static else None)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, scale: Optional[float] = None,
                     q_offset=0, kv_offset=0,
@@ -976,11 +979,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             f"a block-diffusion call has one tile width, which divides the "
             f"half ({bd[1]}) and which the block ({bd[0]}) divides; got "
             f"block_q={bq} block_k={bk}")
-    offsets = jnp.stack(
-        [jnp.asarray(q_offset, jnp.int32),
-         jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)
+    offsets, static_offsets = _offsets(q_offset, kv_offset)
     return _flash(q, k, v, offsets, causal, float(scale), bq, bk,
-                  bool(interpret), window, bd)
+                  bool(interpret), window, bd, static_offsets)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,
@@ -1002,11 +1003,9 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     if blocks is None:
         return _xla_attention_with_lse(q, k, v, causal, scale, q_offset,
                                        kv_offset, None, diffusion_block)
-    offsets = jnp.stack(
-        [jnp.asarray(q_offset, jnp.int32),
-         jnp.asarray(kv_offset, jnp.int32)]).reshape(1, 2)
+    offsets, static_offsets = _offsets(q_offset, kv_offset)
     return _flash_impl(q, k, v, offsets, causal, float(scale), blocks[0],
-                       blocks[1], bool(interpret), None, bd)
+                       blocks[1], bool(interpret), None, bd, static_offsets)
 
 
 def diffusion_mask(length: int, block: int) -> jax.Array:

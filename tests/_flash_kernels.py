@@ -1,5 +1,6 @@
-"""How often a program calls each flash kernel, read from its jaxpr, and the
-two-kernel backward the one pass replaced, kept as its oracle.
+"""How often a program calls each flash kernel and on what grids, read from
+its jaxpr, what the tile counter holds, and the two-kernel backward the one
+pass replaced, kept as its oracle.
 
 Shared via ``import _flash_kernels`` (as ``_loadprobe`` is) by the tests of
 what the layer checkpoint keeps: tests/test_flash_attention.py,
@@ -28,11 +29,38 @@ def kernel_calls(jaxpr) -> dict:
     return {k: text.count(f"name={k}") for k in KERNELS}
 
 
+def pallas_grids(fn, *args) -> dict:
+    """{kernel name: (grid, number of scalar-prefetch operands)} of every
+    pallas_call in ``fn``'s jaxpr (nothing runs)."""
+    found = {}
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                found[eqn.params["name"]] = (tuple(gm.grid),
+                                             gm.num_index_operands)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def tiles_built(*kernels) -> dict:
+    """{(kernel, state): ``hvd_flash_tiles_built_total``} as it stands."""
+    from horovod_tpu.metrics import registry
+    return {(kernel, state): registry().counter(
+        "hvd_flash_tiles_built_total", kernel=kernel, state=state).value
+        for kernel in kernels for state in ("live", "skipped", "unvisited")}
+
+
 def bwd_operands(sq, sk, d, dtype, causal, q_offset=0, kv_offset=0,
                  window=None, b=1, h=2, seed=0):
     """(``fa._bwd_call``'s positional arguments, (B, H, S, D), with ``lse``
     and ``delta`` of the XLA path's forward on the same seeded inputs; the
-    ``causal`` and ``scale`` keywords)."""
+    ``causal`` and ``scale`` keywords, and the offsets as the Python ints the
+    pass lays its walk out from, as the public entry points hand them on)."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     q, g = (jax.random.normal(key, (b, sq, h, d), dtype)
             for key in keys[::3])
@@ -45,7 +73,9 @@ def bwd_operands(sq, sk, d, dtype, causal, q_offset=0, kv_offset=0,
                     axis=-1).transpose(0, 2, 1)
     offsets = jnp.asarray([[q_offset, kv_offset]], jnp.int32)
     return ([x.transpose(0, 2, 1, 3) for x in (q, k, v, g)]
-            + [lse, delta, offsets]), dict(causal=causal, scale=scale)
+            + [lse, delta, offsets]), dict(
+                causal=causal, scale=scale,
+                static_offsets=(q_offset, kv_offset))
 
 
 # ---------------------------------------------------------------------------
@@ -59,14 +89,15 @@ def bwd_operands(sq, sk, d, dtype, causal, q_offset=0, kv_offset=0,
 
 def _two_kernel_body(which, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                      delta_ref, *refs, causal, scale, block_q, block_k,
-                     window=None, n_str=0):
+                     window=None):
+    # Every tile of the rectangle is a step, the streamed tile the step's own
+    # index, and the body skips the dead ones: the walk the kernels had
+    # before their grids followed the mask.
     i, j = pl.program_id(2), pl.program_id(3)       # resident tile, step
     last = pl.num_programs(3) - 1
     dq = which == "dq"
     out_refs, scratch = (refs[:1], refs[1:]) if dq else (refs[:2], refs[2:])
-    t, inside = fa._streamed_tile(i, j, n_str, block_q if dq else block_k,
-                                  block_k if dq else block_q, window, dq)
-    qt, kt = (i, t) if dq else (t, i)
+    qt, kt = (i, j) if dq else (j, i)
 
     @pl.when(j == 0)
     def _init():
@@ -76,8 +107,13 @@ def _two_kernel_body(which, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     q_start = off_ref[0, 0] + qt * block_q
     k_start = off_ref[0, 1] + kt * block_k
 
-    @pl.when(fa._tile_live(q_start, k_start, block_q, block_k, causal,
-                           window, inside))
+    live = True
+    if causal:
+        live = q_start + block_q - 1 >= k_start
+    if window is not None:
+        live = jnp.logical_and(live, k_start + block_k - 1 > q_start - window)
+
+    @pl.when(live)
     def _compute():
         q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         lse = lse_ref[0, 0][:1, :]
@@ -119,8 +155,9 @@ def _two_kernel_body(which, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def two_kernel_bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets,
                         *, causal, scale, block_q, block_k, interpret,
-                        window=None):
-    """``fa._bwd_call``'s arguments and results, by the two old kernels."""
+                        window=None, static_offsets=None):
+    """``fa._bwd_call``'s arguments and results, by the two old kernels
+    (``static_offsets`` lays out the pass's list; this walk has none)."""
     b, h, sq, d = q_bhsd.shape
     sk = k_bhsd.shape[2]
     nq, nk = sq // block_q, sk // block_k
@@ -137,24 +174,19 @@ def two_kernel_bwd_call(q_bhsd, k_bhsd, v_bhsd, do_bhsd, lse, delta, offsets,
                             lambda b, h, i, j: (b, h, tile(i, j), 0))
 
     def call(which, n_res, n_str, keys_streamed, out_lens, scratch):
-        block_res, block_str = ((block_q, block_k) if keys_streamed
-                                else (block_k, block_q))
-        steps, streamed = fa._streamed_index(n_res, n_str, block_res,
-                                             block_str, window, keys_streamed)
-        resident = lambda i, j: i
+        resident, streamed = (lambda i, j: i), (lambda i, j: j)
         qi, ki = ((resident, streamed) if keys_streamed
                   else (streamed, resident))
         kern = functools.partial(_two_kernel_body, which, causal=causal,
                                  scale=scale, block_q=block_q,
-                                 block_k=block_k)
-        if window is not None:
-            kern = functools.partial(kern, window=window, n_str=n_str)
+                                 block_k=block_k, window=window)
         return pl.pallas_call(
-            kern, grid=(b, h, n_res, steps),
+            kern, grid=(b, h, n_res, n_str),
             in_specs=[off, spec(block_q, qi), spec(block_k, ki),
                       spec(block_k, ki), spec(block_q, qi),
                       spec("row", qi), spec("row", qi)],
-            out_specs=[spec(block_res, resident) for _ in out_lens],
+            out_specs=[spec(block_q if keys_streamed else block_k, resident)
+                       for _ in out_lens],
             out_shape=[jax.ShapeDtypeStruct((b, h, n, d), q_bhsd.dtype)
                        for n in out_lens],
             scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],

@@ -3,9 +3,9 @@ copy and then the clean copy of the same tokens, in blocks; a noised query
 sees its own noised block and the clean blocks strictly before it, a clean
 query the clean blocks up to its own, nobody else a noised key.  Forward and
 the three gradients against the dense mask in the Pallas interpreter; the
-grids that walk the live tiles only; the tile rule; the callers' arguments;
-and a call without the mask, which traces to what it traced to before the
-mask existed.  (``tests/test_flash_attention.py`` and
+lists of the live tiles that the grids walk; the tile rule; the callers'
+arguments; and the calls without the mask, whose traces are pinned.
+(``tests/test_flash_attention.py`` and
 ``tests/test_flash_attention_window.py`` have the other calls.)"""
 
 from __future__ import annotations
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.metrics import registry
+from _flash_kernels import pallas_grids, tiles_built
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel import ring_attention as ra
 from horovod_tpu.parallel import ulysses
@@ -132,94 +132,77 @@ def test_the_walk_visits_exactly_the_live_tiles(n, keys_streamed):
     tile, block = 8, 2
     want = live_tiles(n, tile, block)
     assert len(want) == n * n + 2 * n
-    steps = fa._bd_steps(n, keys_streamed)
-    assert steps == (n + 1 if keys_streamed else 2 * n)
-    visited, skipped = [], 0
-    for i in range(2 * n):
-        run = [fa._bd_streamed(i, j, n, keys_streamed) for j in range(steps)]
-        live = [t for t, inside in run if inside]
-        # A step past the live run names the block the run's last step
-        # read: nothing new is fetched.
-        assert all(t == live[-1] for t, inside in run if not inside)
-        assert [inside for _, inside in run] == sorted(
-            (inside for _, inside in run), reverse=True)
-        skipped += steps - len(live)
-        visited += [(t, i) if not keys_streamed else (i, t) for t in live]
+    pairs = fa._live_pairs(2 * n * tile, 2 * n * tile, tile, tile, None,
+                           (block, n * tile), (0, 0), keys_streamed)
+    assert pairs.dtype == np.int32 and pairs.shape == (n * n + 2 * n, 2)
+    visited = [(i, t) if keys_streamed else (t, i) for i, t in pairs.tolist()]
     assert len(visited) == len(set(visited))           # no tile twice
-    assert set(visited) == want
-    assert skipped == (n * n if keys_streamed else 3 * n * n - 2 * n)
-    # Traced, as the grid's index maps call it: the same tiles.
-    traced = jax.jit(jax.vmap(jax.vmap(
-        lambda i, j: fa._bd_streamed(i, j, n, keys_streamed),
-        (None, 0)), (0, None)))(jnp.arange(2 * n), jnp.arange(steps))
+    assert set(visited) == want                        # and no dead one
+    # Resident tiles ascending, every one of them there; a resident tile's
+    # steps in a row.
+    assert sorted(set(pairs[:, 0])) == list(range(2 * n))
+    assert (np.diff(pairs[:, 0]) >= 0).all()
     for i in range(2 * n):
-        for j in range(steps):
-            assert (int(traced[0][i, j]), bool(traced[1][i, j])) == \
-                fa._bd_streamed(i, j, n, keys_streamed)
+        run = pairs[pairs[:, 0] == i, 1].tolist()
+        r = i % n
+        if keys_streamed:
+            # A noised query tile reads the clean key tiles n .. n + r and
+            # then its own noised one; a clean one n .. n + r.
+            assert run == list(range(n, n + r + 1)) + ([r] if i < n else [])
+        else:
+            # A noised key tile is read by its own query tile alone; a clean
+            # one n + c by noised query tiles c .. n - 1, then clean ones.
+            assert run == ([r] if i < n else
+                           list(range(r, n)) + list(range(n + r, 2 * n)))
 
 
 @pytest.mark.parametrize("tile, block", [(8, 2), (8, 8), (8, 4)])
 def test_a_tile_is_live_where_the_dense_mask_has_a_pair(tile, block):
-    """``_tile_live`` at every tile of the square: with a block as wide as
-    the tile the clean copy of a noised tile's own positions is dead, though
-    the walk names it."""
+    """``_live_tiles`` at every tile of the square: with a block as wide as
+    the tile the clean copy of a noised tile's own positions is dead, and no
+    step of either list."""
     n = 2
     want = live_tiles(n, tile, block)
-    for i in range(2 * n):
-        for j in range(2 * n):
-            live = bool(fa._tile_live(jnp.int32(i * tile),
-                                      jnp.int32(j * tile), tile, tile, True,
-                                      None, True, (block, n * tile)))
-            assert live == ((i, j) in want), (i, j)
+    live = fa._live_tiles(2 * n * tile, 2 * n * tile, tile, tile, None,
+                          (block, n * tile), (0, 0))
+    assert live.shape == (2 * n, 2 * n)
+    assert {(i, j) for i, j in zip(*np.nonzero(live))} == want
+    for keys_streamed in (True, False):
+        pairs = fa._live_pairs(2 * n * tile, 2 * n * tile, tile, tile, None,
+                               (block, n * tile), (0, 0), keys_streamed)
+        assert {(i, t) if keys_streamed else (t, i)
+                for i, t in pairs.tolist()} == want
 
 
-def pallas_calls(fn, *args):
-    """{kernel name: grid} of every pallas_call in ``fn``'s jaxpr."""
-    found = {}
-
-    def walk(jp):
-        for eqn in jp.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] = tuple(
-                    eqn.params["grid_mapping"].grid)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
-
-
-def tiles_built() -> dict:
-    return {(kernel, state): registry().counter(
-        "hvd_flash_tiles_built_total", kernel=kernel, state=state).value
-        for kernel in ("hvd_flash_fwd_bd", "hvd_flash_bwd_dkv_bd")
-        for state in ("live", "skipped")}
+BD_KERNELS = ("hvd_flash_fwd_bd", "hvd_flash_bwd_dkv_bd")
 
 
 def test_the_grids_the_names_and_the_counter():
     """The cell's call, traced: 8192 positions of 32 heads of 128 in bf16
-    take tiles of 1024, n = 4; the forward's streamed axis has n + 1 steps,
-    the backward pass's 2n; the kernels carry the ``_bd`` names; and the
-    trace-time counter holds n^2 + 2n live steps a (batch, head) for each
-    grid, n^2 skipped for the forward and 3 n^2 - 2n for the backward."""
+    take tiles of 1024, n = 4; both grids walk the list of the n^2 + 2n = 24
+    live tiles of the 64; the kernels carry the ``_bd`` names; and the
+    trace-time counter holds 24 live steps a (batch, head) for each grid,
+    none skipped, 40 tiles unvisited."""
     x = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16)
     assert fa._supported(x, x, diffusion_block=4) == (1024, 1024)
-    before = tiles_built()
-    grids = pallas_calls(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+    before = tiles_built(*BD_KERNELS)
+    grids = pallas_grids(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
         q, k, v, diffusion_block=4).astype(jnp.float32)), (0, 1, 2)), x, x, x)
-    assert grids == {"hvd_flash_fwd_bd": (2, 32, 8, 5),
-                     "hvd_flash_bwd_dkv_bd": (2, 32, 8, 8),
-                     "hvd_flash_bwd_dq_bd": (2, 32)}
-    after = tiles_built()
+    assert grids == {"hvd_flash_fwd_bd": ((2, 32, 24), 1),
+                     "hvd_flash_bwd_dkv_bd": ((2, 32, 24), 1),
+                     "hvd_flash_bwd_dq_bd": ((2, 32), 0)}
+    after = tiles_built(*BD_KERNELS)
     built = {key: after[key] - before[key] for key in after}
     heads = 2 * 32
     assert built == {("hvd_flash_fwd_bd", "live"): 24 * heads,
-                     ("hvd_flash_fwd_bd", "skipped"): 16 * heads,
+                     ("hvd_flash_fwd_bd", "skipped"): 0,
+                     ("hvd_flash_fwd_bd", "unvisited"): 40 * heads,
                      ("hvd_flash_bwd_dkv_bd", "live"): 24 * heads,
-                     ("hvd_flash_bwd_dkv_bd", "skipped"): 40 * heads}
-    # A causal call builds none.
-    pallas_calls(lambda q, k, v: fa.flash_attention(q, k, v), x, x, x)
-    assert tiles_built() == after
+                     ("hvd_flash_bwd_dkv_bd", "skipped"): 0,
+                     ("hvd_flash_bwd_dkv_bd", "unvisited"): 40 * heads}
+    # A causal call counts under its own kernels' names.
+    pallas_grids(lambda q, k, v: fa.flash_attention(q, k, v), x, x, x)
+    assert tiles_built(*BD_KERNELS) == after
 
 
 # -- the tile rule, the callers' arguments ---------------------------------------------
@@ -296,16 +279,19 @@ def test_full_attention_dispatches_the_mask(monkeypatch):
 
 # Every equation's primitive and result types, every kernel's name, grid,
 # compiler parameters, block shapes and index maps, of the forward and the
-# three gradients: hashed on the commit before the mask existed (PR 37's
-# tree) by this very walk.
-PARENT = {
+# three gradients, by this very walk.  Hashed on PR 43's tree, where the
+# causal and the windowed calls took the list of their live tiles for a grid
+# (until then: on the commit before the block-diffusion mask existed, PR
+# 37's).  BERT's call keeps the rectangle, and its text differs from that
+# commit's in where six scalar comparisons of the grid indices stand.
+PINNED = {
     "flagship": ((1, 8192, 16, 64), dict(causal=True),
-                 261, "c4f49bfd999533f5"),
-    "bert": ((2, 512, 12, 64), dict(causal=False), 245, "c8b404604cb6f10f"),
+                 283, "fdac602a6bcf12d0"),
+    "bert": ((2, 512, 12, 64), dict(causal=False), 243, "1a62923671b7d7f4"),
     "laguna_full": ((1, 8192, 24, 128), dict(causal=True),
-                    261, "6c035cc326d3ec9a"),
+                    283, "88dd3cd486d3a78e"),
     "laguna_window": ((1, 8192, 36, 128), dict(causal=True, window=512),
-                      372, "d4827552e44fd1fc"),
+                      313, "5919f4702e2ce7cc"),
 }
 
 
@@ -333,9 +319,9 @@ def trace_of(shape, **kw) -> list:
     return seen
 
 
-@pytest.mark.parametrize("call", sorted(PARENT))
-def test_a_call_without_the_mask_traces_to_the_parents_text(call):
-    shape, kw, n, digest = PARENT[call]
+@pytest.mark.parametrize("call", sorted(PINNED))
+def test_a_call_without_the_mask_traces_to_its_pinned_text(call):
+    shape, kw, n, digest = PINNED[call]
     seen = trace_of(shape, **kw)
     assert len(seen) == n
     assert hashlib.sha256("\n".join(seen).encode()).hexdigest()[:16] == digest

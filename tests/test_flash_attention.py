@@ -480,12 +480,14 @@ def _fwd_and_bwd(**kwargs):
     return f
 
 
-def _expected_calls(b, h, sq, sk, d, bq, bk, hb=1):
+def _expected_calls(b, h, sq, sk, d, bq, bk, hb=1, steps=None):
     """The three calls' grids, blocks and scratch on one (bq, bk) pair: the
     forward's, the backward pass's with a (batch, head)'s dQ' resident as
     (q tiles, D, bq), and the dQ kernel's, ``hb`` heads a step (as many as
-    make a block of at most 1 MiB)."""
-    grid = (b, h, sq // bq, sk // bk)
+    make a block of at most 1 MiB).  The two grids that walk score tiles are
+    the rectangle, or with ``steps`` a list that long (a causal call's live
+    tiles; the list itself is scalar prefetch and has no block)."""
+    grid = (b, h, steps) if steps else (b, h, sq // bq, sk // bk)
     off, row = (1, 2), (1, 1, 8, bq)
     qt, kt = (1, 1, bq, d), (1, 1, bk, d)
     dqt = (1, 1, sq // bq, d, bq)
@@ -493,7 +495,8 @@ def _expected_calls(b, h, sq, sk, d, bq, bk, hb=1):
     return {
         "hvd_flash_fwd": (grid, ins + [qt, row],
                           [(8, bq), (8, bq), (d, bq)]),
-        "hvd_flash_bwd_dkv": ((b, h, sk // bk, sq // bq),
+        "hvd_flash_bwd_dkv": (grid if steps else
+                              (b, h, sk // bk, sq // bq),
                               ins + [qt, row, row, kt, kt, dqt],
                               [(bk, d), (bk, d), dqt[2:]]),
         "hvd_flash_bwd_dq": ((b, h // hb), [(1, hb) + dqt[2:],
@@ -512,14 +515,15 @@ def test_at_512_the_program_is_the_one_it_was(causal):
     assert (str(jax.make_jaxpr(auto)(x, x, x))
             == str(jax.make_jaxpr(explicit)(x, x, x)))
     assert _pallas_calls(auto, x, x, x) == _expected_calls(
-        2, 12, 512, 512, 64, 512, 512, hb=12)
+        2, 12, 512, 512, 64, 512, 512, hb=12, steps=1 if causal else None)
 
 
 @pytest.mark.parametrize("s,h,d", [(8192, 16, 64), (4096, 16, 128)])
 def test_at_the_long_cells_shapes_every_call_is_on_1024_tiles(s, h, d):
     x = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16)
+    n = s // 1024
     assert _pallas_calls(_fwd_and_bwd(causal=True), x, x, x) == (
-        _expected_calls(1, h, s, s, d, 1024, 1024))
+        _expected_calls(1, h, s, s, d, 1024, 1024, steps=n * (n + 1) // 2))
 
 
 def _xla_out_lse_grads(q, k, v, g, causal, q_offset=0, kv_offset=0):

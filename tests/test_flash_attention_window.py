@@ -1,8 +1,9 @@
 """The flash kernels with a sliding window: each query sees the ``window``
 keys up to and including its own.  Forward and the three gradients against
 ``reference_attention`` with the same window, in the Pallas interpreter; the
-grid that walks the band only; the tile rule; the callers' ``window``
-arguments.  (``tests/test_flash_attention.py`` has the calls without one.)"""
+grid that walks the list of the band's tiles only; the tile rule; the
+callers' ``window`` arguments.  (``tests/test_flash_attention.py`` has the
+calls without one.)"""
 
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _flash_kernels import bwd_operands, two_kernel_bwd_call
+from _flash_kernels import bwd_operands, pallas_grids, two_kernel_bwd_call
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel import ring_attention as ra
 from horovod_tpu.parallel import ulysses
@@ -119,32 +120,33 @@ def test_a_window_that_reaches_the_first_key_is_the_causal_call(window):
 def grids(s, window, d=16):
     """{kernel name: grid} of the differentiated call's pallas_calls."""
     q, k, v, _ = qkvg(s, d=d)
-    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
-        fa.flash_attention(q, k, v, causal=True, window=window,
-                           interpret=True)), (0, 1, 2)))(q, k, v)
-    found = {}
+    return {name: grid for name, (grid, _) in pallas_grids(
+        jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, window=window, interpret=True)),
+            (0, 1, 2)), q, k, v).items()}
 
-    def walk(jp):
-        for eqn in jp.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] = tuple(
-                    eqn.params["grid_mapping"].grid)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
 
-    walk(jaxpr.jaxpr)
-    return found
+def band(n_res, block_res, block_str, window, keys_streamed):
+    """Steps a resident tile of the band's list, the most of any: (n_res
+    resident tiles of block_res, streamed in tiles of block_str)."""
+    bq, bk = ((block_res, block_str) if keys_streamed
+              else (block_str, block_res))
+    s = n_res * block_res
+    pairs = fa._live_pairs(s, s, bq, bk, window, None, (0, 0), keys_streamed)
+    return int(np.bincount(pairs[:, 0]).max())
 
 
 def test_the_windowed_grids_walk_the_band_only():
-    """The streamed axis is as long as the band is wide in tiles, whatever
-    the sequence: two tiles for a window and tiles of 128, where the causal
-    call walks every tile."""
+    """The list holds the band's tiles alone: for a window and tiles of 128
+    two steps a resident tile whatever the sequence (one for the first,
+    whose band is cut by the sequence's start and is no step at all), where
+    the causal call's list is the triangle."""
     names = {"hvd_flash_fwd_win", "hvd_flash_bwd_dq_win",
              "hvd_flash_bwd_dkv_win"}
     def walked(g):
         """The two grids that walk score tiles: the forward's and the
-        backward pass's.  The dQ kernel's is a step a (batch, head)."""
+        backward pass's, (batch, heads, steps).  The dQ kernel's is a step
+        a (batch, head)."""
         assert all(len(grid) == 2 for name, grid in g.items()
                    if "bwd_dq" in name)
         return [grid for name, grid in g.items() if "bwd_dq" not in name]
@@ -152,21 +154,24 @@ def test_the_windowed_grids_walk_the_band_only():
     for s in (512, 1024, 4096):
         g = grids(s, 128)
         assert set(g) == names
-        assert {grid[3] for grid in walked(g)} == {2}, (s, g)
-        assert {grid[2] for grid in walked(g)} == {s // 128}
+        assert {grid[2] for grid in walked(g)} == {2 * (s // 128) - 1}, (s, g)
+        assert all(len(grid) == 3 for grid in walked(g))
     # 100 keys under tiles of 128 still touch two tiles; 129 touch two too
     # (one key into the tile before), 130 three steps of one resident tile.
-    assert {grid[3] for grid in walked(grids(1024, 100))} == {2}
-    assert {grid[3] for grid in walked(grids(1024, 129))} == {2}
-    assert {grid[3] for grid in walked(grids(1024, 130))} == {3}
+    n = 1024 // 128
+    assert {grid[2] for grid in walked(grids(1024, 100))} == {2 * n - 1}
+    assert {grid[2] for grid in walked(grids(1024, 129))} == {2 * n - 1}
+    assert {grid[2] for grid in walked(grids(1024, 130))} == {3 * n - 3}
     causal = grids(1024, None)
     assert set(causal) == {"hvd_flash_fwd", "hvd_flash_bwd_dq",
                            "hvd_flash_bwd_dkv"}
-    assert {grid[3] for grid in walked(causal)} == {1}    # 1024-wide tiles
-    assert fa._band_extent(64, 512, 512, 512, True) == 2
-    assert fa._band_extent(64, 512, 512, 512, False) == 2
-    assert fa._band_extent(8, 1024, 512, 512, True) == 3
-    assert fa._band_extent(8, 1024, 1024, 512, True) == 2
+    assert {grid[2] for grid in walked(causal)} == {1}    # 1024-wide tiles
+    causal = grids(4096, None)
+    assert {grid[2] for grid in walked(causal)} == {10}   # 4 x 4: the triangle
+    assert band(64, 512, 512, 512, True) == 2
+    assert band(64, 512, 512, 512, False) == 2
+    assert band(8, 1024, 512, 512, True) == 3
+    assert band(8, 1024, 1024, 512, True) == 2
 
 
 def test_tiles_of_a_windowed_call_are_no_wider_than_the_window():

@@ -185,7 +185,7 @@ def _compile_bwd_for_v5e(topo, b, h, s, d, causal, window):
     off = jax.ShapeDtypeStruct((1, 2), jnp.int32, sharding=one)
     return jax.jit(lambda *a: fa._bwd_call(
         *a, causal=causal, scale=d ** -0.5, block_q=bq, block_k=bk,
-        interpret=False, window=window)).lower(
+        interpret=False, window=window, static_offsets=(0, 0))).lower(
             t, t, t, t, row, row, off).compile()
 
 
