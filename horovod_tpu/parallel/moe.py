@@ -30,8 +30,9 @@ router has outputs, the layer computes the part of the result its own
 experts give, through a static buffer of rows, and what the absent experts
 would have added is left out.  Nothing stands in for them.  The buffer is
 sized for the worst case and mostly padding, so what moves rows of width d
-walks its rows, and where it can its live rows only (``_token_sums``), never
-the (token, held expert) pairs, of which one in sixteen or fewer is chosen.
+walks its live rows only (``_token_sums``, ``_live_prefix``,
+``_combine_rows_bwd``), never its padding and never the (token, held expert)
+pairs, of which one in sixteen or fewer is chosen.
 
 The reference's only layout-shuffling primitive is alltoall with uneven
 splits (operations.cc:1136-1198, SURVEY.md §2.3 "the only primitive that
@@ -416,16 +417,57 @@ def _token_sums_built(site: str) -> None:
         site=site).inc()
 
 
+# Shares of the buffer ``_live_prefix`` may gather: the smallest that holds
+# the live rows.  A buffer of 4 x the mean is a quarter live, a few percent
+# either way.
+_LIVE_PREFIXES = (5 / 16, 1 / 2, 1)
+
+
+def _live_prefix(x, token_of_row, n_live):
+    """(R, d): ``x[token_of_row]`` in the buffer's live prefix, rows 0 ..
+    ``n_live`` - 1, zeros in its padding.  One plain gather of the shortest
+    of a few static prefixes that holds ``n_live`` rows (a device scalar:
+    ``lax.switch``), padded to the buffer, so a buffer that is a quarter
+    live costs 5/16 of its rows and a full one all of them.  Not a loop that
+    carries the buffer: that one held 0.4 GiB more where Laguna's step
+    peaks (PERF.md section 6, PR 44)."""
+    _live_gathers_built("rows")
+    rows = token_of_row.shape[0]
+
+    def prefix(n):
+        def gather():
+            live = (jnp.arange(n) < n_live)[:, None]
+            return jnp.pad(jnp.where(live, x[token_of_row[:n]], 0),
+                           ((0, rows - n), (0, 0)))
+        return gather
+
+    sizes = sorted({min(rows, -(-int(rows * share) // 8) * 8)
+                    for share in _LIVE_PREFIXES})
+    which = sum((n_live > n).astype(jnp.int32) for n in sizes[:-1])
+    return lax.switch(which, [prefix(n) for n in sizes])
+
+
+def _live_gathers_built(site: str) -> None:
+    """Trace-time count of the row gathers built over the live prefix, by
+    site: none for a layer that holds every expert."""
+    registry().counter(
+        "hvd_moe_live_gathers_built_total",
+        "held experts' row gathers over the buffer's live prefix traced, "
+        "by site", site=site).inc()
+
+
 @jax.custom_vjp
 def _held_rows(x, token_of_row, n_live):
-    """``x[token_of_row]``: the rows of the buffer, in expert order, of
-    which the first ``n_live`` are live.  Backward adds a token's live rows
-    of ``g`` (:func:`_token_sums`)."""
-    return x[token_of_row]
+    """The rows of the buffer, in expert order: ``x[token_of_row]`` for the
+    first ``n_live``, which are live, and zeros for the padding
+    (:func:`_live_prefix`).  Backward adds a token's live rows of ``g``
+    (:func:`_token_sums`)."""
+    return _live_prefix(x, token_of_row, n_live)
 
 
 def _held_rows_fwd(x, token_of_row, n_live):
-    return x[token_of_row], (token_of_row, n_live, x.shape[0])
+    return (_live_prefix(x, token_of_row, n_live),
+            (token_of_row, n_live, x.shape[0]))
 
 
 def _held_rows_bwd(res, g):
@@ -438,37 +480,67 @@ _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine_rows(y, weights, row_of_pair, pair_kept, pair_of_row, n_live):
-    """(T, d) fp32: ``sum_e weights[t, e] * y[row_of_pair[t, e]]`` over the
+def _combine_rows(y, weights, pair_of_row, n_live):
+    """(T, d) fp32: ``sum_e weights[t, e] * y[row of (t, e)]`` over the
     pairs that have a row, forward and backward in row space (row r came
     from pair ``pair_of_row[r]``; the first ``n_live`` rows are live).
-    Forward: :func:`_token_sums` of the rows times their weights.  Backward:
-    ``dy[r] = g[token of r] * weight of r`` and ``dweights`` from one dot a
-    row, 0 for the buffer's unused rows.  No array has a row for every
-    (token, held expert) pair: AD's transpose of the pair-space sum broadcast
-    ``g`` to (T, held, d) in fp32, 3 GiB at 24,576 positions, 16 held and
-    2048 features."""
+    Forward: :func:`_token_sums` of the rows times their weights.  Backward
+    (:func:`_combine_rows_bwd`): ``dy[r] = g[token of r] * weight of r``
+    and one dot a row for ``dweights``, over the live prefix.  No array has
+    a row for every (token, held expert) pair: AD's transpose of the
+    pair-space sum broadcast ``g`` to (T, held, d) in fp32, 3 GiB at 24,576
+    positions, 16 held and 2048 features."""
     t, n_held = weights.shape
     return _token_sums(y, weights.reshape(-1)[pair_of_row],
                        pair_of_row // n_held, n_live, t, "combine")
 
 
-def _combine_rows_fwd(y, weights, row_of_pair, pair_kept, pair_of_row,
-                      n_live):
-    return (_combine_rows(y, weights, row_of_pair, pair_kept, pair_of_row,
-                          n_live),
-            (y, weights, row_of_pair, pair_kept, pair_of_row, n_live))
+def _combine_rows_fwd(y, weights, pair_of_row, n_live):
+    return (_combine_rows(y, weights, pair_of_row, n_live),
+            (y, weights, pair_of_row, n_live))
+
+
+# Rows of the buffer's live prefix a trip of ``_combine_rows_bwd``'s loop
+# writes.
+_GATHER_CHUNK = 512
 
 
 def _combine_rows_bwd(res, g):
-    y, weights, row_of_pair, pair_kept, pair_of_row, n_live = res
-    g_rows = g[pair_of_row // weights.shape[1]]                  # (R, d) fp32
-    w_rows = jnp.where(jnp.arange(y.shape[0]) < n_live,
-                       weights.reshape(-1)[pair_of_row], 0)
-    dy = (g_rows * w_rows[:, None]).astype(y.dtype)
-    dots = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)      # (R,)
-    dw = jnp.where(pair_kept, dots[row_of_pair], 0).astype(weights.dtype)
-    return dy, dw, None, None, None, None
+    """``dy`` (R, d) and ``dweights`` (T, held) of the combine, over the
+    buffer's live prefix: a trip gathers its ``_GATHER_CHUNK`` rows' tokens'
+    rows of ``g`` in fp32, writes them times the rows' weights into the
+    ``dy`` the loop carries, and sets each row's dot with ``y`` at its pair
+    in ``dweights`` (a live row is one kept pair's, so every kept pair is
+    set once and the others stay 0).  Trip count read from ``n_live``; the
+    padding's ``dy`` is zeros, and no (R, d) fp32 array is made."""
+    y, weights, pair_of_row, n_live = res
+    _live_gathers_built("combine_bwd")
+    rows, d = y.shape
+    pairs = weights.size
+    n_held = weights.shape[1]
+    flat = weights.reshape(-1)
+    chunk = min(_GATHER_CHUNK, rows)
+
+    def trip(c, carry):
+        dy, dw = carry
+        # The last trip of a buffer that is no multiple of the chunk starts
+        # early and writes the rows it shares with the one before again.
+        at = jnp.minimum(c * chunk, rows - chunk)
+        live = at + jnp.arange(chunk) < n_live
+        pair = lax.dynamic_slice(pair_of_row, (at,), (chunk,))
+        g_rows = g[pair // n_held]                        # (chunk, d) fp32
+        w_rows = jnp.where(live, flat[pair], 0)
+        dy = lax.dynamic_update_slice(
+            dy, (g_rows * w_rows[:, None]).astype(y.dtype), (at, 0))
+        ys = lax.dynamic_slice(y, (at, 0), (chunk, d)).astype(jnp.float32)
+        dw = dw.at[jnp.where(live, pair, pairs)].set(
+            jnp.sum(g_rows * ys, axis=-1), mode="drop", unique_indices=True)
+        return dy, dw
+
+    dy, dw = lax.fori_loop(
+        0, (n_live + chunk - 1) // chunk, trip,
+        (jnp.zeros_like(y), jnp.zeros((pairs,), jnp.float32)))
+    return dy, dw.reshape(weights.shape).astype(weights.dtype), None, None
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
@@ -513,34 +585,32 @@ def _held_experts(params: GatedMoEParams, x, weights, chosen, activation,
     the first ``row_buffer`` rows are computed, the rest dropped and
     counted.  The buffer's rows are in expert order with the unchosen pairs
     behind all chosen ones, so rows 0 .. ``n_live`` - 1 are live and the rest
-    is padding.  Rows go into the buffer by one gather of its rows and
-    come back to their tokens (the combine; the dispatch's backward) by
-    :func:`_token_sums` over the live prefix: nothing in this path has a
-    row of width d for every (token, held expert) pair.  Returns (out (T, d)
-    fp32, pairs dropped ())."""
+    is padding.  Rows go into the buffer by one gather of its live prefix
+    (:func:`_live_prefix`) and come back to their tokens (the combine; the
+    dispatch's backward) by :func:`_token_sums` over the live prefix, as the
+    combine's backward goes (:func:`_combine_rows_bwd`): nothing in this
+    path has a row of width d for every (token, held expert) pair, and
+    nothing moves the padding.  Returns (out (T, d) fp32, pairs dropped
+    ())."""
     t, d = x.shape
     n_held = params.w_up.shape[0]
     with scope("moe_route"):
         counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)        # (n_held,)
         # Pair (t, e) sorts under its expert, an unchosen one after all.
         key = jnp.where(chosen, jnp.arange(n_held, dtype=jnp.int32), n_held)
-        pair_of_row = jnp.argsort(key.reshape(t * n_held), stable=True)
-        row_of_pair = jnp.argsort(pair_of_row).reshape(t, n_held)
-        pair_of_row = pair_of_row[:row_buffer]
+        pair_of_row = jnp.argsort(key.reshape(t * n_held),
+                                  stable=True)[:row_buffer]
         ends = jnp.minimum(jnp.cumsum(counts), row_buffer)
         group_sizes = jnp.diff(ends, prepend=0)
         n_live = ends[-1]
         row_used = jnp.arange(row_buffer) < n_live
-        pair_kept = chosen & (row_of_pair < row_buffer)
-        row_of_pair = jnp.minimum(row_of_pair, row_buffer - 1)
         dropped = (jnp.sum(counts) - n_live).astype(jnp.float32)
     with scope("moe_dispatch"):
         rows = _held_rows(x, pair_of_row // n_held, n_live)
     with scope("moe_experts"):
         y = _grouped_experts(params, rows, group_sizes, activation, row_used)
     with scope("moe_dispatch"):
-        out = _combine_rows(y, weights, row_of_pair, pair_kept, pair_of_row,
-                            n_live)
+        out = _combine_rows(y, weights, pair_of_row, n_live)
     return out, dropped
 
 
@@ -571,10 +641,12 @@ def dropless_moe(params: GatedMoEParams, x: jax.Array, top_k: int,
     ``buffer_factor`` x the mean (:func:`held_row_buffer`); a row beyond it
     is dropped and counted in ``RouterStats.dropped``.  ``counts`` stays
     what the router chose, over all its outputs, held or not.  The buffer's
-    rows are weighed and added back into their tokens, and their cotangents
-    into the tokens', over the buffer's live rows (``_token_sums``; the
-    trace-time counter ``hvd_moe_token_sums_built_total{site}`` says it
-    engaged), in fp32.
+    rows are gathered, weighed and added back into their tokens, and their
+    cotangents made and added into the tokens', over the buffer's live rows
+    (``_live_prefix``, ``_token_sums``, ``_combine_rows_bwd``; the
+    trace-time counters ``hvd_moe_live_gathers_built_total{site}`` and
+    ``hvd_moe_token_sums_built_total{site}`` say they engaged), the sums in
+    fp32.
     """
     t, d = x.shape
     e = params.gate.shape[1]

@@ -1,11 +1,15 @@
 """The held experts' rows are summed into their tokens over the buffer's live
 rows (``parallel/moe.py`` ``_token_sums``), not over every (token, held
-expert) pair: values and both gradients of the held path against the plain
-pair-space formulas written out here, the cases a row-space walk can get
-wrong (no row, a full run, live rows over several trips of the loop, a
-buffer that is no multiple of the chunk, an overflowing buffer, an empty one), the three families' models with the helper swapped for the
-pair-space formula, and the shape of the traced step: no value with a row of
-width d for every (token, held expert) pair, one sum a site a held layer."""
+expert) pair, and gathered over them too (``_live_prefix``,
+``_combine_rows_bwd``), not over the buffer's padding: values and both
+gradients of the held path against the plain pair-space formulas written out
+here, the cases a row-space walk can get wrong (no row, a full run, live rows
+over several trips of the loop, a buffer that is no multiple of the chunk, an
+overflowing buffer, an empty one), the four families' models with the helpers
+swapped for the parent's forms, and the shape of the traced step: no value
+with a row of width d for every (token, held expert) pair, no gather of the
+whole buffer and no fp32 array of its size, one sum and one gather a site a
+held layer."""
 
 from __future__ import annotations
 
@@ -234,32 +238,104 @@ def test_token_sums_is_the_gathered_sum(monkeypatch, scaled, dtype):
     np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
 
 
-def test_the_combines_backward_is_ads():
-    """The combine's hand-written backward (row space) against AD of the
-    same sum written with a plain gather.  (Was
-    ``test_sdar_layers.py::test_the_held_paths_gradients_are_ads``.)"""
-    t, held, d, rows = 24, 4, 8, 40
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    chosen = jax.random.uniform(keys[0], (t, held)) < 0.4
+# rows of the buffer, P(pair chosen), rows a trip: live rows in one trip; over
+# several, the last part padding; a full buffer that is no multiple of the
+# chunk; a buffer that overflows; a buffer with no live row; a buffer a
+# quarter live, as the cells' are (its shortest prefix holds them)
+WALKS = {"one_trip": (48, 0.3, 512), "several_trips": (100, 0.3, 8),
+         "full_no_multiple": (40, 0.9, 16), "overflow": (24, 0.5, 8),
+         "empty": (40, 0.0, 8), "quarter_live": (128, 0.25, 4)}
+
+
+def walk_case(name, t=32, held=4):
+    rows, p, chunk = WALKS[name]
+    chosen = jax.random.uniform(jax.random.PRNGKey(9), (t, held)) < p
+    live = min(int(chosen.sum()), rows)
+    assert {"one_trip": 0 < live < rows, "several_trips": 3 * chunk < live
+            < rows and live % chunk, "full_no_multiple": live == rows
+            and rows % chunk, "overflow": int(chosen.sum()) > rows,
+            "empty": live == 0, "quarter_live": 4 * chunk < live
+            <= rows * 5 // 16}[name]
+    return chosen, rows, chunk
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_the_combines_backward_is_ads(monkeypatch, walk):
+    """The combine's hand-written backward (a loop over the live prefix that
+    writes ``dy`` and sets ``dweights``) against AD of the same sum written
+    in pair space with a plain gather, on ``dy`` and ``dw``; the buffer's
+    padding gets a ``dy`` of zeros.  (Was ``test_sdar_layers.py::
+    test_the_held_paths_gradients_are_ads``.)"""
+    t, held, d = 32, 4, 8
+    chosen, rows, chunk = walk_case(walk, t, held)
+    monkeypatch.setattr(moe, "_GATHER_CHUNK", chunk)
+    monkeypatch.setattr(moe, "_SUM_CHUNK", chunk)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
     n_live, row_of_pair, kept, pair_of_row, row_used = routing_of(chosen,
                                                                   rows)
-    assert int(chosen.sum()) < rows
-    y = jax.random.normal(keys[1], (rows, d))
-    w = jax.random.uniform(keys[2], (t, held))
-    g = jax.random.normal(keys[3], (t, d))
+    y = jax.random.normal(keys[0], (rows, d))
+    w = jax.random.uniform(keys[1], (t, held))
+    g = jax.random.normal(keys[2], (t, d))
 
     def plain(y, w):
         picked = jnp.where(kept[..., None], y[row_of_pair], 0)
         return jnp.sum(jnp.sum(picked * w[..., None], axis=1) * g)
 
     def ours(y, w):
-        return jnp.sum(moe._combine_rows(y, w, row_of_pair, kept,
-                                         pair_of_row, n_live) * g)
+        return jnp.sum(moe._combine_rows(y, w, pair_of_row, n_live) * g)
 
     np.testing.assert_allclose(ours(y, w), plain(y, w), rtol=1e-6)
-    for a, b in zip(jax.grad(ours, (0, 1))(y, w),
-                    jax.grad(plain, (0, 1))(y, w)):
+    got, want = jax.grad(ours, (0, 1))(y, w), jax.grad(plain, (0, 1))(y, w)
+    for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5)
+    assert not np.asarray(got[0])[int(n_live):].any()
+    assert not np.asarray(got[1])[~np.asarray(kept)].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_held_rows_is_the_gather_in_the_live_prefix_and_zeros_past_it(walk,
+                                                                       dtype):
+    """``x[token_of_row]`` to the bit in rows 0 .. ``n_live`` - 1, zeros in
+    the padding (the parent had other tokens' rows there, which ``row_used``
+    masked): whichever of the static prefixes holds the live rows."""
+    t, held, d = 32, 4, 8
+    chosen, rows, _ = walk_case(walk, t, held)
+    n_live, _, _, pair_of_row, row_used = routing_of(chosen, rows)
+    x = jax.random.normal(jax.random.PRNGKey(3), (t, d)).astype(dtype)
+    token_of_row = pair_of_row // held
+    got = jax.jit(moe._held_rows)(x, token_of_row, n_live)
+    assert got.shape == (rows, d) and got.dtype == dtype
+    np.testing.assert_array_equal(
+        got, jnp.where(row_used[:, None], x[token_of_row], 0))
+
+
+@pytest.mark.parametrize("share, branch", [
+    (0.0, 0), (0.25, 0), (0.375, 0), (0.39, 1), (0.5, 1), (0.52, 2),
+    (1.0, 2)])
+def test_the_gather_takes_the_shortest_prefix_that_holds_the_live_rows(
+        monkeypatch, share, branch):
+    """A buffer of 64 rows has the prefixes 24 (5/16 of it, up to a multiple
+    of 8), 32 and 64: one gather of that many rows a branch, and the branch
+    taken is the shortest that holds ``n_live``, at and either side of each
+    boundary."""
+    rows, t, d = 64, 16, 8
+    x = jnp.arange(1, t * d + 1, dtype=jnp.float32).reshape(t, d)
+    token_of_row = jnp.arange(rows) % t
+    n_live = jnp.int32(round(share * rows))
+    jaxpr = jax.make_jaxpr(moe._live_prefix)(x, token_of_row, n_live)
+    cond, = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert [[v.aval.shape for e in b.eqns for v in e.outvars
+             if e.primitive.name == "gather"]
+            for b in cond.params["branches"]] == [[(24, d)], [(32, d)],
+                                                  [(64, d)]]
+    taken, switch = [], jax.lax.switch
+    monkeypatch.setattr(jax.lax, "switch", lambda i, branches, *a: (
+        taken.append(int(i)), switch(i, branches, *a))[1])
+    got = moe._live_prefix(x, token_of_row, n_live)         # eager: concrete
+    assert taken == [branch]
+    np.testing.assert_array_equal(got, jnp.where(
+        (jnp.arange(rows) < n_live)[:, None], x[token_of_row], 0))
 
 
 def test_the_dispatchs_backward_is_ads():
@@ -315,7 +391,17 @@ OLMOE = tfm.TransformerConfig(
     vocab_size=64, d_model=32, n_heads=4, d_ff=12, n_layers=1, seq_len=16,
     n_experts=8, top_k=2, dtype=jnp.float32, dropless=True, tied_head=False,
     gated_experts=True, learned_positions=False, rope_theta=10000.0)
-FAMILIES = {"sdar": SDAR, "laguna": LAGUNA, "nemotron": NEMOTRON}
+LFM2 = tfm.TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=4, d_ff=12, n_layers=10, seq_len=16,
+    n_experts=24, top_k=4, dtype=jnp.float32, dropless=True, norm_eps=1e-5,
+    tied_head=True, gated_experts=True, leading_pattern="CD",
+    layer_pattern="*ECECECE", learned_positions=False, n_kv_heads=2,
+    attn_head_dim=8, rope_theta=1e6, head_qk_norm=True,
+    router_scoring="sigmoid", router_renormalise=True,
+    router_renorm_eps=1e-6, dense_ff=40, conv_taps=3,
+    expert_buffer_factor=4.0, n_experts_held=4)
+FAMILIES = {"sdar": SDAR, "laguna": LAGUNA, "nemotron": NEMOTRON,
+            "lfm2": LFM2}
 
 
 def one_device_mesh():
@@ -330,17 +416,70 @@ def loss_and_grads(cfg):
         cfg, PAR, one_device_mesh())))(params, *batch)
 
 
+def whole_buffer_gather(x, token_of_row, n_live):
+    """``_live_prefix`` as the parent had it: every row of the buffer
+    gathered, the padding's left to ``row_used``."""
+    return x[token_of_row]
+
+
+def pair_space_combine(y, weights, pair_of_row, n_live):
+    """``_combine_rows`` as the plain sum over a token's kept pairs, one
+    gathered row a (token, held expert) pair, for AD to differentiate: no
+    hand-written backward."""
+    t, held = weights.shape
+    rows = y.shape[0]
+    at = jnp.where(jnp.arange(rows) < n_live, pair_of_row, t * held)
+    row_of_pair = jnp.zeros((t * held,), jnp.int32).at[at].set(
+        jnp.arange(rows), mode="drop").reshape(t, held)
+    kept = jnp.zeros((t * held,), bool).at[at].set(
+        True, mode="drop").reshape(t, held)
+    picked = jnp.where(kept[..., None], y[row_of_pair], 0)
+    return jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1)
+
+
+@jax.custom_vjp
+def parents_combine_rows(y, weights, pair_of_row, n_live):
+    return pair_space_combine(y, weights, pair_of_row, n_live)
+
+
+def parents_combine_rows_fwd(y, weights, pair_of_row, n_live):
+    return (pair_space_combine(y, weights, pair_of_row, n_live),
+            (y, weights, pair_of_row, n_live))
+
+
+def parents_combine_rows_bwd(res, g):
+    """The parent's backward: ``g`` gathered for every row of the buffer
+    into an (R, d) fp32 array, then the products and the dots over all R."""
+    y, weights, pair_of_row, n_live = res
+    g_rows = g[pair_of_row // weights.shape[1]]                  # (R, d) fp32
+    live = jnp.arange(y.shape[0]) < n_live
+    w_rows = jnp.where(live, weights.reshape(-1)[pair_of_row], 0)
+    dots = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)
+    dw = jnp.zeros((weights.size,), jnp.float32).at[
+        jnp.where(live, pair_of_row, weights.size)].set(dots, mode="drop")
+    return ((g_rows * w_rows[:, None]).astype(y.dtype),
+            dw.reshape(weights.shape), None, None)
+
+
+parents_combine_rows.defvjp(parents_combine_rows_fwd,
+                            parents_combine_rows_bwd)
+
+
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_a_familys_loss_and_gradients_are_the_pair_space_forms(monkeypatch,
                                                                family):
-    """The whole model with ``_token_sums`` as shipped and with the parent's
-    gather of every (token, held expert) pair in its place.  (Was
-    ``test_sdar_layers.py::
+    """The whole model with the held path as shipped, and with the parents'
+    forms in its three helpers' places: ``_token_sums`` as a gather of every
+    (token, held expert) pair, ``_live_prefix`` as a gather of every row of
+    the buffer, and the combine as the pair-space sum that AD differentiates.
+    (Was ``test_sdar_layers.py::
     test_both_forms_of_the_held_paths_combine_give_one_answer``, for the one
     family and the combine's two backward forms.)"""
     row_space = loss_and_grads(FAMILIES[family])
     monkeypatch.setattr(moe, "_token_sums", pair_space_sums(
         FAMILIES[family].n_experts_held))
+    monkeypatch.setattr(moe, "_live_prefix", whole_buffer_gather)
+    monkeypatch.setattr(moe, "_combine_rows", pair_space_combine)
     pair_space = loss_and_grads(FAMILIES[family])
     assert np.isfinite(float(row_space[0]))
     for a, b in zip(jax.tree_util.tree_leaves(pair_space),
@@ -368,11 +507,20 @@ def widest_rows(jaxpr, width):
                if getattr(v.aval, "shape", ())[-1:] == (width,))
 
 
-def built(site):
-    return registry().counter(
-        "hvd_moe_token_sums_built_total",
+SUMS = ("hvd_moe_token_sums_built_total",
         "held experts' sums of buffer rows into their tokens traced, by site",
-        site=site).value
+        ("combine", "dispatch_bwd"))
+GATHERS = ("hvd_moe_live_gathers_built_total",
+           "held experts' row gathers over the buffer's live prefix traced, "
+           "by site", ("rows", "combine_bwd"))
+
+
+def built(site, counter=SUMS):
+    return registry().counter(counter[0], counter[1], site=site).value
+
+
+def built_by_site(counter):
+    return {site: built(site, counter) for site in counter[2]}
 
 
 def expert_layer(cfg):
@@ -380,7 +528,7 @@ def expert_layer(cfg):
     input and its parameters."""
     params = tfm.init_params(jax.random.PRNGKey(0), cfg, PAR)["layers"]
     x = jax.random.normal(jax.random.PRNGKey(1),
-                          (2, cfg.seq_len, cfg.d_model))
+                          (2, cfg.seq_len, cfg.d_model)).astype(cfg.dtype)
     if cfg.layer_pattern is None:
         lp = {k: v[0, 0] for k, v in params.items()}
         fn = tfm._mlp_block
@@ -399,7 +547,7 @@ def test_no_value_has_a_row_for_every_token_and_held_expert(family):
     largest values of the layer made of rows d wide; none reaches their size,
     in any family (one path for every row width: no combine is exempt)."""
     cfg = FAMILIES[family]
-    before = {site: built(site) for site in ("combine", "dispatch_bwd")}
+    before = built_by_site(SUMS)
     jaxpr = expert_layer(cfg)
     t = 2 * cfg.seq_len                       # the layer's own input
     width = cfg.moe_latent or cfg.d_model
@@ -424,8 +572,99 @@ def test_the_walk_would_see_the_parents_pair_space_gather(monkeypatch):
     assert widest_rows(jaxpr, cfg.d_model) >= pair_space
 
 
-def test_a_layer_that_holds_every_expert_builds_no_token_sum():
-    before = {site: built(site) for site in ("combine", "dispatch_bwd")}
+@pytest.mark.parametrize("counter", [SUMS, GATHERS], ids=["sums", "gathers"])
+def test_a_layer_that_holds_every_expert_builds_no_token_sum(counter):
+    """No buffer, no live prefix: 0 / 0 of either counter."""
+    before = built_by_site(counter)
     jaxpr = expert_layer(OLMOE)
     assert "ragged_dot" in str(jaxpr)
-    assert {site: built(site) for site in before} == before
+    assert built_by_site(counter) == before
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_held_layer_builds_one_live_gather_a_site(family):
+    """``hvd_moe_live_gathers_built_total``: one ``rows`` for the traced
+    forward of a held layer and one ``combine_bwd`` for its traced
+    backward."""
+    before = built_by_site(GATHERS)
+    expert_layer(FAMILIES[family])
+    assert {site: n - before[site] for site, n in built_by_site(
+        GATHERS).items()} == {"rows": 1, "combine_bwd": 1}
+
+
+# -- no gather of the whole buffer, no fp32 array of its size ----------------------
+
+def eqns_and_where(jaxpr, inside=()):
+    """Every equation of a jaxpr and of its sub-jaxprs, with the ``while``s
+    and ``cond``s it lies inside."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from eqns_and_where(sub, inside + (
+                (eqn.primitive.name,) if eqn.primitive.name in (
+                    "while", "cond") else ()))
+
+
+def whole_buffer_values(cfg):
+    """Walked through value_and_grad of one expert layer in bf16, 8 rows a
+    trip of the loops: (the gathers that yield the buffer's (R, width) in
+    no loop's body and no branch of a ``cond`` — the switch's last branch,
+    for a buffer over half full, is such a gather — and the fp32 values of
+    R x width elements, wherever they lie)."""
+    t = 2 * cfg.seq_len
+    width = cfg.moe_latent or cfg.d_model
+    rows = moe.held_row_buffer(t, cfg.top_k, cfg.n_experts_held,
+                               cfg.n_experts, cfg.expert_buffer_factor)
+    # or an array of the tokens', of the pairs', of the router's or of the
+    # experts' hidden width would pass for the buffer's
+    assert rows not in (t, t * cfg.n_experts_held, cfg.n_experts)
+    assert width != cfg.d_ff
+    jaxpr = expert_layer(cfg._replace(dtype=jnp.bfloat16))
+    gathers, fp32 = [], []
+    for eqn, inside in eqns_and_where(jaxpr.jaxpr):
+        for v in eqn.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if (eqn.primitive.name == "gather" and not inside
+                    and shape == (rows, width)):
+                gathers.append(eqn)
+            if (int(np.prod(shape)) == rows * width and shape[-1:] == (width,)
+                    and v.aval.dtype == jnp.float32):
+                fp32.append(eqn)
+    return gathers, fp32
+
+
+# SDAR's small preset holds 4 of 64 at top-4: its buffer would have as many
+# rows as the layer has tokens (and at 8 held as the router has experts).
+WALKED = dict(FAMILIES, sdar=SDAR._replace(n_experts_held=12))
+
+
+@pytest.fixture
+def eight_rows_a_trip(monkeypatch):
+    monkeypatch.setattr(moe, "_SUM_CHUNK", 8)
+    monkeypatch.setattr(moe, "_GATHER_CHUNK", 8)
+
+
+@pytest.mark.parametrize("family", sorted(WALKED))
+def test_no_gather_yields_the_whole_buffer_and_no_fp32_array_has_its_size(
+        eight_rows_a_trip, family):
+    """The three gathers walk the live prefix: the forward's (and the
+    recompute's) is of a static prefix inside a ``cond``'s branch, the
+    combine's backward's of a chunk inside a loop's body, in fp32 a chunk at
+    a time."""
+    gathers, fp32 = whole_buffer_values(WALKED[family])
+    assert not gathers, gathers
+    assert not fp32, fp32
+
+
+@pytest.mark.parametrize("helper, parents, makes_fp32", [
+    ("_live_prefix", whole_buffer_gather, False),
+    ("_combine_rows", parents_combine_rows, True)])
+def test_the_walk_would_see_the_parents_whole_buffer_gathers(
+        monkeypatch, eight_rows_a_trip, helper, parents, makes_fp32):
+    """The same walk with the parent's form put back finds its gather of
+    every row of the buffer, and for the combine's backward the (R, d) fp32
+    array it made: the structural test can fail."""
+    monkeypatch.setattr(moe, helper, parents)
+    gathers, fp32 = whole_buffer_values(WALKED["lfm2"])
+    assert gathers
+    assert bool(fp32) == makes_fp32
