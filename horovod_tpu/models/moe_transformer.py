@@ -317,6 +317,14 @@ def serial_forward_loss(cfg: MoEConfig, params: Dict[str, Any],
     return -jnp.mean(ll)
 
 
+def _flagship_config(cfg: MoEConfig, **mlp) -> tfm.TransformerConfig:
+    """``cfg``'s geometry as the flagship's config, MLPs of ``mlp``."""
+    return tfm.TransformerConfig(
+        vocab_size=cfg.vocab_size, d_model=cfg.d_model,
+        n_heads=cfg.n_heads, n_layers=cfg.n_layers, seq_len=cfg.seq_len,
+        dtype=cfg.dtype, remat=cfg.remat, **mlp)
+
+
 def flops_matched_dense_config(cfg: MoEConfig) -> tfm.TransformerConfig:
     """The dense baseline with identical per-token matmul FLOPs.
 
@@ -326,35 +334,16 @@ def flops_matched_dense_config(cfg: MoEConfig) -> tfm.TransformerConfig:
     Loss-parity-at-equal-FLOPs experiments train both from the same
     seed and compare trajectories.
     """
-    return tfm.TransformerConfig(
-        vocab_size=cfg.vocab_size, d_model=cfg.d_model,
-        n_heads=cfg.n_heads, d_ff=cfg.top_k * cfg.d_ff,
-        n_layers=cfg.n_layers, seq_len=cfg.seq_len, n_experts=0,
-        dtype=cfg.dtype, remat=cfg.remat)
+    return _flagship_config(cfg, d_ff=cfg.top_k * cfg.d_ff, n_experts=0)
 
 
 def train_flops_per_seq(cfg: MoEConfig) -> float:
     """Audited matmul-FLOPs for one training sequence (3x forward);
-    counts the routed top_k experts + gate per token — the duck-typed
-    MoE branch of the flagship accounting."""
-    return tfm.train_flops_per_seq(cfg)
-
-
-def dispatch_wire_ratio(cfg: MoEConfig, par: MoEParallelConfig,
-                        n_local_tokens: int) -> float:
-    """fp32-over-quantized bytes on the dispatch all_to_all wire for one
-    layer crossing (1.0 when dispatch_bits == 0)."""
-    spec = cfg.quant_spec()
-    cap = moe_lib.expert_capacity(
-        n_local_tokens, cfg.n_experts, cfg.capacity_factor, cfg.top_k)
-    fp32 = moe_lib.dispatch_wire_bytes(
-        par.ep, cfg.n_experts // par.ep, cap, cfg.d_model, None)
-    if spec is None:
-        return 1.0
-    quant = moe_lib.dispatch_wire_bytes(
-        par.ep, cfg.n_experts // par.ep, cap, cfg.d_model, spec)
-    return fp32 / quant
+    counts the routed top_k experts + gate per token, as the flagship
+    accounts its own ungated experts."""
+    return tfm.train_flops_per_seq(_flagship_config(
+        cfg, d_ff=cfg.d_ff, n_experts=cfg.n_experts, top_k=cfg.top_k))
 
 
 def synthetic_batch(key, cfg: MoEConfig, batch: int):
-    return tfm.synthetic_batch(key, cfg, batch)
+    return tfm.synthetic_batch(key, _flagship_config(cfg), batch)

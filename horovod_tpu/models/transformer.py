@@ -25,29 +25,23 @@ same training path into a published one: ``rope_theta``, ``qk_norm``,
 path, experts replicated over the mesh), ``tied_head=False`` and the
 router's two auxiliary losses give OLMoE-1B-7B (arXiv:2409.02060) for
 training.  ``layer_pattern`` turns the one block into a period of blocks of
-several kinds, one mixer each — ``M`` a Mamba-2 state-space mixer
-(ops/ssd.py), ``E`` an expert MLP, ``*`` grouped-query attention — with
-``n_experts_held`` (a share of the routed experts), a sigmoid router,
-experts in a latent space and a shared expert: Nemotron 3's hybrid
-(``nemotron_h``) as one rank of its deployment, on ``dp`` alone.  Two more
-letters — ``W`` sliding-window attention with its own head count and rotary
-base, ``D`` a gated dense MLP — with ``leading_pattern`` (blocks that run
-once, in front of the scanned periods), rotary positions on a share of the
-head with YaRN-scaled frequencies and a per-head output gate give Laguna's
-mix of windowed and full attention (``laguna``).  A sixth letter — ``C``
-a gated short convolution (``[B, C, u] = h W_in``, a causal depthwise
-convolution of ``conv_taps`` taps over ``B * u``, ``(C * conv) W_out``) —
-with the sigmoid router's renormalisation over ``sum + router_renorm_eps``
-gives LFM2's hybrid of convolution and grouped-query attention blocks
-(``lfm2_moe``).  ``diffusion_block``
-turns the step itself into block-diffusion training (BD3-LMs,
-arXiv:2503.09573; SDAR, arXiv:2510.06303): a sequence of L tokens goes
-through the stack as 2L positions, a noised copy and then the clean copy,
-positions 0 .. L-1 twice, under a mask in which a noised block sees itself
-and the clean blocks before it; logits are taken from the noised half and
-the cross-entropy is weighted by a third batch array (the noise is data:
-:func:`noised_batch`).  ``head_qk_norm`` is an RMSNorm over each head of q
-and of k.  The serving entry points below cover learned positions only.
+several kinds, ``x + mixer(RMSNorm(x))`` each and each kind one row of
+``BLOCKS``: ``M`` a Mamba-2 state-space mixer (ops/ssd.py), ``E`` an expert
+MLP, ``*`` grouped-query attention, ``W`` sliding-window attention with its
+own head count and rotary base, ``D`` a gated dense MLP, ``C`` a gated short
+convolution.  With ``n_experts_held`` (a share of the routed experts), a
+sigmoid router, experts in a latent space and a shared expert they give
+Nemotron 3's hybrid (``nemotron_h``) as one rank of its deployment, on
+``dp`` alone; with ``leading_pattern`` (blocks that run once, in front of
+the scanned periods), rotary positions on a share of the head with
+YaRN-scaled frequencies and a per-head output gate, Laguna's mix of windowed
+and full attention (``laguna``); with the router's renormalisation over
+``sum + router_renorm_eps``, LFM2's hybrid of convolution and attention
+blocks (``lfm2_moe``).  ``diffusion_block`` turns the step itself into
+block-diffusion training (BD3-LMs, arXiv:2503.09573; SDAR,
+arXiv:2510.06303; see ``forward_loss``), the noise being data
+(:func:`noised_batch`).  The serving entry points below cover learned
+positions only.
 
 Compute dtype defaults to bfloat16 (MXU-native); normalization, softmax and
 loss accumulate in fp32.
@@ -57,8 +51,10 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import math
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from types import SimpleNamespace
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,18 +98,16 @@ class TransformerConfig(NamedTuple):
     tied_head: bool = True        # False → ``lm_head``, apart from ``embed``
     aux_loss_coef: float = 0.0    # x router load-balancing loss (dropless)
     z_loss_coef: float = 0.0      # x router z-loss (dropless)
-    # Blocks of several kinds.  One letter a block of one period, each block
-    # ``x + mixer(RMSNorm(x))``: "M" state-space mixer, "E" expert MLP
-    # (dropless), "*" attention.  ``n_layers`` is a multiple of its length.
-    # None → the block above (attention then MLP) ``n_layers`` times.
+    # Blocks of several kinds: one letter of ``BLOCKS`` a block of one
+    # period, ``n_layers`` a multiple of its length.  None → the block above
+    # (attention then MLP) ``n_layers`` times.
     layer_pattern: Optional[str] = None
     learned_positions: bool = True  # False (and no rope_theta) → none at all
     n_kv_heads: Optional[int] = None    # "*" blocks; None → n_heads
     attn_head_dim: Optional[int] = None  # None → d_model // n_heads
     # "M" blocks (Mamba-2): heads of ``ssm_head_dim``, ``ssm_groups`` groups
     # of B / C of ``ssm_state``, a causal conv of ``ssm_conv`` taps, the scan
-    # in chunks of ``ssm_chunk``.  dt_bias is drawn so that softplus(dt_bias)
-    # is log-uniform in [min, max], floored.
+    # in chunks of ``ssm_chunk``, dt log-uniform in [min, max], floored.
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_groups: int = 1
@@ -131,13 +125,11 @@ class TransformerConfig(NamedTuple):
     shared_expert_ff: int = 0     # > 0: an expert every token takes
     expert_activation: Optional[str] = None  # None → silu gated, else gelu
     # Blocks that run once, in front of the scanned periods (letters as
-    # ``layer_pattern``'s, but for "E"): a model's leading dense layers.
-    # They count in ``n_layers``.
+    # ``layer_pattern``'s, but for "E"); they count in ``n_layers``.
     leading_pattern: str = ""
     # "W" blocks: "*" with ``attn_window`` keys a query (its own included),
-    # ``window_heads`` query heads (None → n_heads) over the same
-    # ``n_kv_heads``, and rotary positions at ``window_rope_theta`` on the
-    # whole head (None → none).
+    # ``window_heads`` query heads (None → n_heads) over the same kv heads,
+    # and the whole head rotated at ``window_rope_theta`` (None → not).
     attn_window: Optional[int] = None
     window_heads: Optional[int] = None
     window_rope_theta: Optional[float] = None
@@ -149,16 +141,11 @@ class TransformerConfig(NamedTuple):
     rope_yarn: Optional[Tuple[float, int, float, float, float]] = None
     attn_gate: bool = False       # "*" / "W": head i's output x sigmoid(h Wg)_i
     dense_ff: int = 0             # "D" blocks: (silu(h W1) * h W3) W2
-    # Block-diffusion training of a patterned model: ``tokens`` are 2 x
-    # ``seq_len`` positions (the noised copy, then the clean one) in blocks
-    # of ``diffusion_block``, ``labels`` and a third batch array ``weights``
-    # ``seq_len``; see ``forward_loss``.
+    # Block-diffusion training of a patterned model, in blocks of
+    # ``diffusion_block`` positions; see ``forward_loss``.
     diffusion_block: Optional[int] = None
     head_qk_norm: bool = False    # "*" / "W": RMSNorm over each head of q, k
-    # "C" blocks (LFM2's gated short convolution): ``[B, C, u] = h W_in``,
-    # a causal depthwise convolution of ``conv_taps`` taps over ``B * u``
-    # (no bias), ``(C * conv) W_out``.
-    conv_taps: int = 0
+    conv_taps: int = 0            # "C" blocks: the convolution's taps
     router_renorm_eps: float = 0.0  # renormalised weights: over sum + eps
 
     @property
@@ -194,74 +181,47 @@ def _has_pos_table(cfg: TransformerConfig) -> bool:
     return cfg.rope_theta is None and cfg.learned_positions
 
 
-# A pattern's letters, the key of each kind's parameters under ``layers``
-# and the step scope its blocks run under.
-BLOCK_KINDS = {"M": ("ssm", "ssm"), "E": ("moe", "mlp"), "*": ("attn", "attn"),
-               "W": ("swa", "attn"), "D": ("dense", "mlp"),
-               "C": ("conv", CONV_SCOPES[0])}
 _ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
                 "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
-    """What a patterned or share-holding model runs on: ``dp`` alone."""
+    """What a patterned or share-holding model runs on, ``dp`` alone, and
+    what the kinds of block its pattern names (``BLOCKS``) ask of it."""
     if cfg.layer_pattern is not None:
-        letters = cfg.leading_pattern + cfg.layer_pattern
-        bad = set(letters) - set(BLOCK_KINDS)
-        if bad or not cfg.layer_pattern:
+        lead, period = cfg.leading_pattern, cfg.layer_pattern
+        present = set(lead + period)
+        if present - set(BLOCKS) or not period:
             raise ValueError(
-                f"layer_pattern {cfg.layer_pattern!r} after leading_pattern "
-                f"{cfg.leading_pattern!r}: letters are {sorted(BLOCK_KINDS)}")
-        if (cfg.n_layers - len(cfg.leading_pattern)) % len(cfg.layer_pattern):
+                f"layer_pattern {period!r} after leading_pattern {lead!r}: "
+                f"letters are {sorted(BLOCKS)}")
+        if (cfg.n_layers - len(lead)) % len(period):
             raise ValueError(
-                f"n_layers {cfg.n_layers} is not {len(cfg.leading_pattern)} "
-                f"leading blocks and a multiple of the pattern's "
-                f"{len(cfg.layer_pattern)}")
-        if "E" in cfg.leading_pattern:
-            raise NotImplementedError(
-                'an "E" block cannot lead: the router statistics are stacked '
-                "by period")
-        if ("E" in cfg.layer_pattern) != _routes_dropless(cfg):
-            raise ValueError('an "E" block is a dropless expert MLP: '
-                             "n_experts and dropless go with it")
-        if "M" in letters and (
-                cfg.ssm_heads < 1 or cfg.ssm_heads % cfg.ssm_groups):
-            raise ValueError(f"ssm_heads {cfg.ssm_heads} do not divide into "
-                             f"ssm_groups {cfg.ssm_groups}")
+                f"n_layers {cfg.n_layers} is not {len(lead)} leading blocks "
+                f"and a multiple of the pattern's {len(period)}")
         if cfg.qk_norm:
             raise NotImplementedError(
                 "a patterned model's attention blocks take rotary positions "
                 "(rope_theta, window_rope_theta) and no QK-norm yet")
-        if "W" in letters and not cfg.attn_window:
-            raise ValueError('a "W" block is sliding-window attention: '
-                             "attn_window goes with it")
-        if "D" in letters and not cfg.dense_ff:
-            raise ValueError('a "D" block is a gated dense MLP: dense_ff '
-                             "goes with it")
-        if "C" in letters and cfg.conv_taps < 1:
-            raise ValueError('a "C" block is a gated short convolution: '
-                             "conv_taps goes with it")
-        hkv = cfg.n_kv_heads or cfg.n_heads
-        if cfg.n_heads % hkv or (cfg.window_heads or hkv) % hkv:
-            raise ValueError(
-                f"n_heads {cfg.n_heads} and window_heads {cfg.window_heads} "
-                f"are not multiples of n_kv_heads {cfg.n_kv_heads}")
-        if not 0.0 < cfg.rope_fraction <= 1.0 or \
-                cfg.head_dim * cfg.rope_fraction % 2:
-            raise ValueError(f"rope_fraction {cfg.rope_fraction} of a head "
-                             f"of {cfg.head_dim} is not a whole even share")
+        for c, row in BLOCKS.items():
+            if row.routes and lead.count(c):
+                raise NotImplementedError(
+                    f'an "{c}" block cannot lead: the router statistics are '
+                    "stacked by period")
+            refusal = row.asks(cfg, c in present)
+            if refusal:
+                raise ValueError(refusal)
         if cfg.diffusion_block is not None:
             if cfg.diffusion_block < 1 or cfg.seq_len % cfg.diffusion_block:
                 raise ValueError(
                     f"seq_len {cfg.seq_len} is not whole blocks of "
                     f"diffusion_block {cfg.diffusion_block}")
-            if set(letters) & set("WMC"):
+            if any(BLOCKS[c].crosses for c in present):
+                *some, last = [r.crosses for r in BLOCKS.values() if r.crosses]
                 raise NotImplementedError(
-                    "diffusion_block goes with \"*\" attention blocks: a "
-                    "sliding window (\"W\", attn_window), a state-space "
-                    "scan (\"M\") or a convolution (\"C\") over the "
-                    "doubled sequence would cross from the noised copy into "
-                    "the clean one")
+                    "diffusion_block goes with \"*\" attention blocks: "
+                    f"{', '.join(some)} or {last} over the doubled sequence "
+                    "would cross from the noised copy into the clean one")
             if _has_pos_table(cfg):
                 raise NotImplementedError(
                     "diffusion_block wraps rotary positions (rope_theta) at "
@@ -278,26 +238,21 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
                 "a model with a layer_pattern runs on dp alone: its mixers "
                 "are neither sharded over mp nor staged over pp (ROADMAP "
                 "M0); nor is a diffusion_block's doubled sequence")
-    elif cfg.n_kv_heads not in (None, cfg.n_heads) or cfg.moe_latent \
-            or cfg.shared_expert_ff or cfg.leading_pattern \
-            or cfg.attn_window or cfg.attn_gate or cfg.dense_ff \
-            or cfg.rope_yarn or cfg.rope_fraction != 1.0 \
-            or cfg.diffusion_block is not None or cfg.head_qk_norm \
-            or cfg.conv_taps:
-        raise ValueError(
-            "n_kv_heads, moe_latent, shared_expert_ff, leading_pattern, "
-            "attn_window, attn_gate, dense_ff, rope_yarn, rope_fraction, "
-            "diffusion_block, head_qk_norm and conv_taps are a patterned "
-            "model's: set layer_pattern")
+    else:
+        # The pattern's own fields and every kind's stay at their defaults.
+        own = {"leading_pattern", "diffusion_block"}.union(
+            *(row.fields for row in BLOCKS.values()))
+        *some, last = [f for f in cfg._fields if f in own]
+        if cfg.n_kv_heads == cfg.n_heads:      # what its default means
+            own.remove("n_kv_heads")
+        if any(getattr(cfg, f) != cfg._field_defaults[f] for f in own):
+            raise ValueError(f"{', '.join(some)} and {last} are a patterned "
+                             "model's: set layer_pattern")
     if _holds_a_share(cfg) and (par.mp > 1 or par.pp > 1):
         raise NotImplementedError(
             f"a layer that holds {_experts_held(cfg)} of {cfg.n_experts} "
             "experts runs on dp alone: the exchange that brings the other "
             "ranks' tokens is not written (ROADMAP M2)")
-
-
-def _split(key, n):
-    return jax.random.split(key, n)
 
 
 def init_params(key, cfg: TransformerConfig,
@@ -311,7 +266,7 @@ def init_params(key, cfg: TransformerConfig,
     if cfg.n_layers % n_pp != 0:
         raise ValueError(f"n_layers {cfg.n_layers} not divisible by pp {n_pp}")
     lps = cfg.n_layers // n_pp  # layers per stage
-    k = iter(_split(key, 16))
+    k = iter(jax.random.split(key, 16))
     std = 0.02
 
     def norm_init(*shape):
@@ -370,13 +325,18 @@ def init_params(key, cfg: TransformerConfig,
     return params
 
 
+def _row(kind: str) -> "BlockKind":
+    return next(row for row in BLOCKS.values() if row.key == kind)
+
+
 def pattern_counts(cfg: TransformerConfig, leading: bool = False
                    ) -> Dict[str, int]:
-    """{kind: its blocks in one period} for the kinds the pattern has;
-    ``leading``: {kind: its blocks} of ``leading_pattern``."""
+    """{kind: its blocks in one period} for the kinds the pattern has, in
+    the order of ``BLOCKS``; ``leading``: {kind: its blocks} of
+    ``leading_pattern``."""
     pattern = cfg.leading_pattern if leading else cfg.layer_pattern
-    return {BLOCK_KINDS[c][0]: pattern.count(c)
-            for c in BLOCK_KINDS if c in pattern}
+    return {row.key: pattern.count(c) for c, row in BLOCKS.items()
+            if c in pattern}
 
 
 def _n_periods(cfg: TransformerConfig) -> int:
@@ -385,26 +345,18 @@ def _n_periods(cfg: TransformerConfig) -> int:
 
 def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
     """A patterned model's blocks, stacked by kind: every leaf is (1 stage,
-    periods, blocks of the kind in a period, ...); under ``leading`` the
+    periods, the kind's blocks a period, ...); under ``leading`` the
     blocks of ``leading_pattern`` by kind, (1 stage, blocks of the kind,
-    ...).  Weights as the block above's (normal 0.02, the projections that
-    write the residual scaled by 1 / sqrt(2 n_layers)) except what a
-    state-space scan's behaviour hangs on, by Mamba-2's published scheme:
-    ``dt_bias`` the inverse softplus of a log-uniform draw in
-    ``ssm_dt_range``, ``a_log = log U(1, 16)``, ``d_skip`` 1, and the
-    depthwise conv U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it (a "C"
-    block's too)."""
-    d, std = cfg.d_model, 0.02
-    out_scale = std / math.sqrt(2 * cfg.n_layers)
+    ...).  A kind's leaves are its row's to make (``BlockKind.init``), each
+    ``lead + shape`` in fp32, the drawn ones off one key stream in the order
+    they are asked for; weights as the block above's, normal ``std``, the
+    projections that write the residual ``out_scale``."""
+    std = 0.02
 
-    def key_stream():
-        # 24 at a time, the first 24 as they always were drawn.
-        n = 0
-        while True:
-            yield from _split(jax.random.fold_in(key, n) if n else key, 24)
-            n += 1
-
-    keys = key_stream()
+    # 24 keys at a time, the first 24 as they always were drawn.
+    keys = itertools.chain.from_iterable(
+        jax.random.split(jax.random.fold_in(key, n) if n else key, 24)
+        for n in itertools.count())
 
     def init_kind(kind, lead):
         def ones(*shape):
@@ -418,71 +370,9 @@ def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
             return jax.random.uniform(next(keys), lead + shape,
                                       jnp.float32, lo, hi)
 
-        if kind == "ssm":
-            h, hp = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
-            conv = hp + 2 * cfg.ssm_groups * cfg.ssm_state
-            dt_min, dt_max, dt_floor = cfg.ssm_dt_range
-            dt = jnp.maximum(jnp.exp(uniform(h, lo=math.log(dt_min),
-                                             hi=math.log(dt_max))), dt_floor)
-            bound = 1.0 / math.sqrt(cfg.ssm_conv)
-            return {
-                "ln": ones(d),
-                # columns [z | x | B | C | dt]: z and x head-major (H, P),
-                # B and C group-major (G, N), dt a head.
-                "w_in": rand(d, hp + conv + h),
-                "conv_w": uniform(conv, cfg.ssm_conv, lo=-bound, hi=bound),
-                "conv_b": uniform(conv, lo=-bound, hi=bound),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
-                "a_log": jnp.log(uniform(h, lo=1.0, hi=16.0)),
-                "d_skip": ones(h),
-                "norm": ones(hp),
-                "w_out": rand(hp, d, scale=out_scale),
-            }
-        if kind in ("attn", "swa"):
-            hq, hkv, hd = _attn_heads(cfg, kind), \
-                cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
-            blk = {
-                "ln": ones(d), "wq": rand(d, hq * hd),
-                "wk": rand(d, hkv * hd), "wv": rand(d, hkv * hd),
-                "wo": rand(hq * hd, d, scale=out_scale),
-            }
-            if cfg.attn_gate:
-                blk["w_head_gate"] = rand(d, hq)
-            if cfg.head_qk_norm:
-                blk["q_norm"], blk["k_norm"] = ones(hd), ones(hd)
-            return blk
-        if kind == "dense":
-            return {"ln": ones(d), "w_gate": rand(d, cfg.dense_ff),
-                    "w_up": rand(d, cfg.dense_ff),
-                    "w_down": rand(cfg.dense_ff, d, scale=out_scale)}
-        if kind == "conv":
-            bound = 1.0 / math.sqrt(cfg.conv_taps)
-            # w_in's columns [B | C | u], d_model each.
-            return {"ln": ones(d), "w_in": rand(d, 3 * d),
-                    "conv_w": uniform(d, cfg.conv_taps, lo=-bound, hi=bound),
-                    "w_out": rand(d, d, scale=out_scale)}
-        e, held, ff = cfg.n_experts, _experts_held(cfg), cfg.d_ff
-        width = cfg.moe_latent or d
-        blk = {"ln": ones(d), "gate": rand(d, e)}
-        if cfg.router_scoring == "sigmoid":
-            # The choice's correction bias: a buffer, zero until a
-            # trainer's balancing rule moves it; outside the gradient.
-            blk["router_bias"] = 0.0 * ones(e)
-        if cfg.moe_latent:
-            blk["w_latent_in"] = rand(d, width)
-            blk["w_latent_out"] = rand(width, d, scale=out_scale)
-        if cfg.gated_experts:
-            blk["w_gate"] = rand(held, width, ff)
-        blk["w_up"] = rand(held, width, ff)
-        blk["w_down"] = rand(held, ff, width,
-                             scale=std if cfg.moe_latent else out_scale)
-        if cfg.shared_expert_ff:
-            if cfg.gated_experts:
-                blk["shared_gate"] = rand(d, cfg.shared_expert_ff)
-            blk["shared_up"] = rand(d, cfg.shared_expert_ff)
-            blk["shared_down"] = rand(cfg.shared_expert_ff, d,
-                                      scale=out_scale)
-        return blk
+        return _row(kind).init(cfg, SimpleNamespace(
+            ones=ones, rand=rand, uniform=uniform, std=std,
+            out_scale=std / math.sqrt(2 * cfg.n_layers)))
 
     layers = {kind: init_kind(kind, (1, _n_periods(cfg), n))
               for kind, n in pattern_counts(cfg).items()}
@@ -493,25 +383,20 @@ def _init_pattern_layers(key, cfg: TransformerConfig) -> Dict[str, Any]:
     return layers
 
 
-def _attn_heads(cfg: TransformerConfig, kind: str) -> int:
-    """Query heads of an attention block of ``kind`` ("attn" | "swa")."""
-    return (cfg.window_heads or cfg.n_heads) if kind == "swa" else cfg.n_heads
-
-
 def param_specs(cfg: TransformerConfig, par: ParallelConfig) -> Dict[str, Any]:
     """PartitionSpec pytree matching ``init_params`` (mesh axes dp/pp/mp)."""
     _check_layout(cfg, par)
+    specs = {"embed": P(), "final_norm": P()}
+    if _has_pos_table(cfg):
+        specs["pos"] = P()
+    if not cfg.tied_head:
+        specs["lm_head"] = P()
     if cfg.layer_pattern is not None:
         # On dp alone: every leaf replicated, its gradient reduced by AD.
         shapes = jax.eval_shape(lambda: _init_pattern_layers(
             jax.random.PRNGKey(0), cfg))
-        specs = {"embed": P(), "final_norm": P(),
-                 "layers": jax.tree_util.tree_map(lambda _: P("pp"), shapes)}
-        if _has_pos_table(cfg):
-            specs["pos"] = P()
-        if not cfg.tied_head:
-            specs["lm_head"] = P()
-        return specs
+        return {**specs, "layers": jax.tree_util.tree_map(
+            lambda _: P("pp"), shapes)}
     megatron = cfg.attn_mode == "megatron"
     layers: Dict[str, Any] = {
         "ln1": P("pp"),
@@ -541,12 +426,7 @@ def param_specs(cfg: TransformerConfig, par: ParallelConfig) -> Dict[str, Any]:
     else:
         layers["w1"] = P("pp", None, None, "mp")
         layers["w2"] = P("pp", None, "mp", None)
-    specs = {"embed": P(), "final_norm": P(), "layers": layers}
-    if _has_pos_table(cfg):
-        specs["pos"] = P()
-    if not cfg.tied_head:
-        specs["lm_head"] = P()
-    return specs
+    return {**specs, "layers": layers}
 
 
 def _rmsnorm(x, scale, eps: float = 1e-6):
@@ -729,6 +609,30 @@ def _mlp_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         return tp.row_parallel(u, lp["w2"], "mp", scatter_sequence=True), None
 
 
+def _init_ssm(cfg: TransformerConfig, new) -> Dict[str, jax.Array]:
+    """What a state-space scan's behaviour hangs on, by Mamba-2's published
+    scheme: ``dt_bias`` the inverse softplus of a log-uniform draw in
+    ``ssm_dt_range``, ``a_log = log U(1, 16)``, ``d_skip`` 1, the depthwise
+    conv U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it."""
+    d, h, hp = cfg.d_model, cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
+    conv = hp + 2 * cfg.ssm_groups * cfg.ssm_state
+    dt_min, dt_max, dt_floor = cfg.ssm_dt_range
+    dt = jnp.maximum(jnp.exp(new.uniform(h, lo=math.log(dt_min),
+                                         hi=math.log(dt_max))), dt_floor)
+    bound = 1.0 / math.sqrt(cfg.ssm_conv)
+    return {
+        "ln": new.ones(d), "d_skip": new.ones(h), "norm": new.ones(hp),
+        # columns [z | x | B | C | dt]: z and x head-major (H, P), B and C
+        # group-major (G, N), dt a head.
+        "w_in": new.rand(d, hp + conv + h),
+        "conv_w": new.uniform(conv, cfg.ssm_conv, lo=-bound, hi=bound),
+        "conv_b": new.uniform(conv, lo=-bound, hi=bound),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+        "a_log": jnp.log(new.uniform(h, lo=1.0, hi=16.0)),
+        "w_out": new.rand(hp, d, scale=new.out_scale),
+    }
+
+
 def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                x: jax.Array) -> jax.Array:
     """A Mamba-2 mixer (ops/ssd.py) on the normed stream.  x: (mb, S, d)."""
@@ -753,30 +657,60 @@ def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     return jnp.einsum("bse,ed->bsd", y, lp["w_out"].astype(x.dtype))
 
 
+def _ssm_flops(cfg: TransformerConfig) -> float:
+    """The projections, the conv, the scan as the chunked algorithm's four
+    products."""
+    d, h, p, g, n, q = (cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim,
+                        cfg.ssm_groups, cfg.ssm_state, cfg.ssm_chunk)
+    proj = 2.0 * d * (2 * h * p + 2 * g * n + h) + 2.0 * h * p * d
+    conv = 2.0 * cfg.ssm_conv * (h * p + 2 * g * n)
+    scan = 2.0 * q * n * g + 2.0 * q * p * h + 4.0 * p * n * h
+    return proj + conv + scan
+
+
+class _Attention(NamedTuple):
+    """What separates one kind of block over ``_gqa_mixer`` from another."""
+    heads: int                    # query heads, over the same n_kv_heads
+    window: Optional[int]         # keys a query sees, its own included
+    theta: Optional[float]        # rotary base; None: no position encoding
+    fraction: float = 1.0         # the share of each head that rotates
+    yarn: Optional[Tuple[float, int, float, float, float]] = None
+
+
+def _init_gqa(cfg: TransformerConfig, new, kind: str) -> Dict[str, jax.Array]:
+    d, hq = cfg.d_model, _row(kind).attention(cfg).heads
+    hkv, hd = cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+    blk = {
+        "ln": new.ones(d), "wq": new.rand(d, hq * hd),
+        "wk": new.rand(d, hkv * hd), "wv": new.rand(d, hkv * hd),
+        "wo": new.rand(hq * hd, d, scale=new.out_scale),
+    }
+    if cfg.attn_gate:
+        blk["w_head_gate"] = new.rand(d, hq)
+    if cfg.head_qk_norm:
+        blk["q_norm"], blk["k_norm"] = new.ones(hd), new.ones(hd)
+    return blk
+
+
 def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                x: jax.Array, kind: str = "attn") -> jax.Array:
     """Causal attention with ``n_kv_heads`` key / value heads, query head i
-    reading head i // (heads / n_kv_heads).  A "*" block (``kind`` "attn")
-    sees every earlier key and rotates q and k at ``rope_theta`` (None:
-    no position encoding) on ``rope_fraction`` of the head, ``rope_yarn``
-    scaling the frequencies; a "W" block ("swa") has ``window_heads`` query
-    heads, sees ``attn_window`` keys and rotates the whole head at
-    ``window_rope_theta``.  Positions are 0 .. S-1, or with
-    ``diffusion_block`` 0 .. S/2-1 twice (the noised copy and the clean
-    copy of the same tokens), and the mask is then the block-diffusion
-    one: a noised query sees its own noised block and the clean blocks
-    before it, a clean query the clean blocks up to its own.  With
-    ``head_qk_norm`` each head of q and of k is RMS-normalised (one scale
-    vector of ``head_dim`` for q, one for k, shared by the heads) before
-    the rotation.  With ``attn_gate`` head i's output is multiplied by
-    ``sigmoid(h Wg)_i``, a scalar a head and token from the block's normed
-    input.  Each K / V head is repeated across its query heads before the
-    kernels (their index maps taking several query heads a K / V block is
-    ROADMAP M4)."""
+    reading head i // (heads / n_kv_heads); the row of ``kind`` says how
+    many query heads, how many keys a query sees, and q and k's rotation
+    (``_Attention``; "attn" a "*" block, "swa" a "W" block).  Positions are
+    0 .. S-1, or with ``diffusion_block`` 0 .. S/2-1 twice (the noised copy
+    and the clean copy of the same tokens), and the mask is then the
+    block-diffusion one: a noised query sees its own noised block and the
+    clean blocks before it, a clean query the clean blocks up to its own.
+    ``head_qk_norm`` normalises each head of q and of k (one scale vector of
+    ``head_dim`` each, shared by the heads) before the rotation; ``attn_gate``
+    multiplies head i's output by ``sigmoid(h Wg)_i``, a scalar a head and
+    token from the block's normed input.  Each K / V head is repeated
+    across its query heads before the kernels (their index maps taking
+    several query heads a K / V block is ROADMAP M4)."""
     mb, s, _ = x.shape
-    windowed = kind == "swa"
-    hq, hkv, hd = (_attn_heads(cfg, kind), cfg.n_kv_heads or cfg.n_heads,
-                   cfg.head_dim)
+    a = _row(kind).attention(cfg)
+    hq, hkv, hd = a.heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
     hnorm = _rmsnorm(x, lp["ln"], cfg.norm_eps)
 
     def heads(w, n):
@@ -788,20 +722,16 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         with scope("attn_qknorm"):
             q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
             k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-    theta, fraction, yarn = (
-        (cfg.window_rope_theta, 1.0, None) if windowed
-        else (cfg.rope_theta, cfg.rope_fraction, cfg.rope_yarn))
-    if theta is not None:
+    if a.theta is not None:
         with scope("attn_rope"):
             def positions():          # one arange a tensor, as ever
                 at = jnp.arange(s)
                 return at if cfg.diffusion_block is None else at % (s // 2)
-            q, k = (_rope(t, positions(), theta, fraction, yarn)
+            q, k = (_rope(t, positions(), a.theta, a.fraction, a.yarn)
                     for t in (q, k))
     if hkv != hq:
         k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
-    o = ra.full_attention(q, k, v, causal=True,
-                          window=cfg.attn_window if windowed else None,
+    o = ra.full_attention(q, k, v, causal=True, window=a.window,
                           diffusion_block=cfg.diffusion_block)
     if cfg.attn_gate:
         with scope("attn_gate"):
@@ -811,6 +741,28 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
             o = (o.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
     return jnp.einsum("bse,ed->bsd", o.reshape(mb, s, hq * hd),
                       lp["wo"].astype(x.dtype))
+
+
+def _gqa_flops(cfg: TransformerConfig, kind: str) -> float:
+    """The projections, the gate and the scores, by the (query, key) pairs
+    a query: the causal half, or the band's window S - window (window - 1)
+    / 2 pairs a sequence, or under the block-diffusion mask S^2 + S block
+    pairs over 2 S positions."""
+    a, d, s = _row(kind).attention(cfg), cfg.d_model, cfg.seq_len
+    hq, hkv, hd = a.heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+    w = min(a.window, s) if a.window else 0
+    pairs = w - w * (w - 1) / (2.0 * s) if w else s / 2.0
+    if cfg.diffusion_block is not None:
+        pairs = (s + cfg.diffusion_block) / 2.0
+    gate = 2.0 * d * hq if cfg.attn_gate else 0.0
+    return 2.0 * d * hd * (2 * hq + 2 * hkv) + gate + 4.0 * pairs * hq * hd
+
+
+def _init_dense(cfg: TransformerConfig, new) -> Dict[str, jax.Array]:
+    d, ff = cfg.d_model, cfg.dense_ff
+    return {"ln": new.ones(d), "w_gate": new.rand(d, ff),
+            "w_up": new.rand(d, ff),
+            "w_down": new.rand(ff, d, scale=new.out_scale)}
 
 
 def _dense_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
@@ -825,6 +777,15 @@ def _dense_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         hidden = jax.nn.silu(up(lp["w_gate"])) * up(lp["w_up"])
         return jnp.einsum("bsf,fd->bsd", hidden.astype(x.dtype),
                           lp["w_down"].astype(x.dtype))
+
+
+def _init_conv(cfg: TransformerConfig, new) -> Dict[str, jax.Array]:
+    """``w_in``'s columns [B | C | u], d_model each; the depthwise conv
+    U(+-1 / sqrt(taps)) as ``nn.Conv1d`` draws it."""
+    d, bound = cfg.d_model, 1.0 / math.sqrt(cfg.conv_taps)
+    return {"ln": new.ones(d), "w_in": new.rand(d, 3 * d),
+            "conv_w": new.uniform(d, cfg.conv_taps, lo=-bound, hi=bound),
+            "w_out": new.rand(d, d, scale=new.out_scale)}
 
 
 def _conv_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
@@ -844,6 +805,31 @@ def _conv_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         conv = ssd.gated_causal_conv1d(b, u, lp["conv_w"])
         gated = (c.astype(jnp.float32) * conv).astype(x.dtype)
     return jnp.einsum("bse,ed->bsd", gated, lp["w_out"].astype(x.dtype))
+
+
+def _init_experts(cfg: TransformerConfig, new) -> Dict[str, jax.Array]:
+    d, e, held, ff = cfg.d_model, cfg.n_experts, _experts_held(cfg), cfg.d_ff
+    width = cfg.moe_latent or d
+    blk = {"ln": new.ones(d), "gate": new.rand(d, e)}
+    if cfg.router_scoring == "sigmoid":
+        # The choice's correction bias: a buffer, zero until a trainer's
+        # balancing rule moves it; outside the gradient.
+        blk["router_bias"] = 0.0 * new.ones(e)
+    if cfg.moe_latent:
+        blk["w_latent_in"] = new.rand(d, width)
+        blk["w_latent_out"] = new.rand(width, d, scale=new.out_scale)
+    if cfg.gated_experts:
+        blk["w_gate"] = new.rand(held, width, ff)
+    blk["w_up"] = new.rand(held, width, ff)
+    blk["w_down"] = new.rand(
+        held, ff, width, scale=new.std if cfg.moe_latent else new.out_scale)
+    if cfg.shared_expert_ff:
+        if cfg.gated_experts:
+            blk["shared_gate"] = new.rand(d, cfg.shared_expert_ff)
+        blk["shared_up"] = new.rand(d, cfg.shared_expert_ff)
+        blk["shared_down"] = new.rand(cfg.shared_expert_ff, d,
+                                      scale=new.out_scale)
+    return blk
 
 
 def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
@@ -876,62 +862,146 @@ def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     return y.reshape(mb, s, d), stats
 
 
+def _expert_flops(cfg: TransformerConfig) -> float:
+    """As this device computes it: the router, the latent projections, the
+    share of the routed experts it holds, the shared expert."""
+    d, width = cfg.d_model, cfg.moe_latent or cfg.d_model
+    mats = 3.0 if cfg.gated_experts else 2.0
+    routed = (cfg.top_k * _experts_held(cfg) / cfg.n_experts
+              * mats * 2.0 * width * cfg.d_ff)
+    latent = 4.0 * d * width if cfg.moe_latent else 0.0
+    return (2.0 * d * cfg.n_experts + latent + routed
+            + mats * 2.0 * d * cfg.shared_expert_ff)
+
+
+class BlockKind(NamedTuple):
+    """One kind of block a ``layer_pattern`` names, a row of ``BLOCKS``: a
+    block is ``x + mixer(cfg, its leaves, x)``, the mixer norming its input,
+    and all this file knows of a kind is its row and what the row names."""
+    key: str                  # its leaves' key under ``layers``
+    scope: str                # the step scope its blocks run under
+    fields: Tuple[str, ...]   # the TransformerConfig fields of its own
+    asks: Callable            # (cfg, has it such blocks) -> a refusal or None
+    init: Callable            # (cfg, new) -> leaves: ``_init_pattern_layers``
+    mixer: Callable           # (cfg, leaves, x) -> y
+    flops: Callable           # (cfg) -> forward matmul-FLOPs a token
+    routes: bool = False      # y is (y, moe.RouterStats): it cannot lead
+    crosses: str = ""         # what of it a ``diffusion_block`` refuses
+    attention: Optional[Callable] = None      # (cfg) -> _Attention
+
+
+def _attention_kind(key: str, fields: Tuple[str, ...], variant,
+                    needs=lambda cfg: None, crosses: str = "") -> BlockKind:
+    """A row over ``_gqa_mixer``: ``variant(cfg)`` the ``_Attention`` that
+    separates it from the others, ``fields`` and ``needs(cfg)`` its own."""
+    def asks(cfg, here):
+        a, hkv = variant(cfg), cfg.n_kv_heads or cfg.n_heads
+        if here and a.heads % hkv:
+            return (f"n_heads {cfg.n_heads} and window_heads "
+                    f"{cfg.window_heads} are not multiples of n_kv_heads "
+                    f"{cfg.n_kv_heads}")
+        if here and (not 0.0 < a.fraction <= 1.0
+                     or cfg.head_dim * a.fraction % 2):
+            return (f"rope_fraction {a.fraction} of a head of "
+                    f"{cfg.head_dim} is not a whole even share")
+        return here and needs(cfg)
+
+    return BlockKind(
+        key, "attn", fields + ("n_kv_heads", "attn_gate", "head_qk_norm"),
+        asks, *(functools.partial(f, kind=key)
+                for f in (_init_gqa, _gqa_mixer, _gqa_flops)),
+        crosses=crosses, attention=variant)
+
+
+# The kinds of block by their letter in a ``layer_pattern``.  A new kind is
+# its fields on ``TransformerConfig``, its mixer and a row here.
+BLOCKS: Dict[str, BlockKind] = {
+    "M": BlockKind(
+        "ssm", "ssm",
+        tuple(f for f in TransformerConfig._fields if f.startswith("ssm_")),
+        lambda cfg, here: here and (
+            cfg.ssm_heads < 1 or cfg.ssm_heads % cfg.ssm_groups) and
+        f"ssm_heads {cfg.ssm_heads} do not divide into ssm_groups "
+        f"{cfg.ssm_groups}",
+        _init_ssm, _ssm_mixer, _ssm_flops,
+        crosses='a state-space scan ("M")'),
+    "E": BlockKind(
+        "moe", "mlp", ("moe_latent", "shared_expert_ff"),
+        lambda cfg, here: here != _routes_dropless(cfg) and 'an "E" block '
+        "is a dropless expert MLP: n_experts and dropless go with it",
+        _init_experts, _expert_mixer, _expert_flops, routes=True),
+    "*": _attention_kind(
+        "attn", ("rope_fraction", "rope_yarn"),
+        lambda cfg: _Attention(cfg.n_heads, None, cfg.rope_theta,
+                               cfg.rope_fraction, cfg.rope_yarn)),
+    "W": _attention_kind(
+        "swa", ("attn_window", "window_heads", "window_rope_theta"),
+        lambda cfg: _Attention(cfg.window_heads or cfg.n_heads,
+                               cfg.attn_window, cfg.window_rope_theta),
+        lambda cfg: not cfg.attn_window and
+        'a "W" block is sliding-window attention: attn_window goes with it',
+        crosses='a sliding window ("W", attn_window)'),
+    "D": BlockKind(
+        "dense", "mlp", ("dense_ff",),
+        lambda cfg, here: here and not cfg.dense_ff and
+        'a "D" block is a gated dense MLP: dense_ff goes with it',
+        _init_dense, _dense_mixer,
+        lambda cfg: 6.0 * cfg.d_model * cfg.dense_ff),
+    "C": BlockKind(
+        "conv", CONV_SCOPES[0], ("conv_taps",),
+        lambda cfg, here: here and cfg.conv_taps < 1 and
+        'a "C" block is a gated short convolution: conv_taps goes with it',
+        _init_conv, _conv_mixer,
+        lambda cfg: (8.0 * cfg.d_model       # two projections, and the taps
+                     + 2.0 * cfg.conv_taps) * cfg.d_model,
+        crosses='a convolution ("C")'),
+}
+# Letter -> (the key of the kind's leaves, the scope of its blocks).
+BLOCK_KINDS = {c: (row.key, row.scope) for c, row in BLOCKS.items()}
+
+
 def _make_pattern_stage_fn(cfg: TransformerConfig):
     """stage_fn(stage_params, act) for a ``layer_pattern``: the blocks of
     ``leading_pattern`` once, then a scan over the periods, inside one
     period its blocks in the pattern's order, each under its step scope and
-    (``cfg.remat``) its own checkpoint, which keeps a "*" or "W" block's
+    (``cfg.remat``) its own checkpoint, which keeps an attention block's
     flash forward output and lse.  Returns the activation and, where the
-    pattern routes, the "E" blocks' ``moe.RouterStats`` stacked (periods,
-    blocks a period, ...)."""
-    mixers = {"ssm": _ssm_mixer, "attn": _gqa_mixer, "moe": _expert_mixer,
-              "swa": functools.partial(_gqa_mixer, kind="swa"),
-              "dense": _dense_mixer, "conv": _conv_mixer}
-    with_stats = "E" in cfg.layer_pattern
-
-    def block(kind, scope_name):
+    pattern has blocks that route, their ``moe.RouterStats`` stacked
+    (periods, blocks a period, ...)."""
+    def block(row):
         def run(act, lp):
-            with scope(scope_name):
-                out = mixers[kind](cfg, lp, act)
-                y, stats = out if kind == "moe" else (out, None)
+            with scope(row.scope):
+                out = row.mixer(cfg, lp, act)
+                y, stats = out if row.routes else (out, None)
                 return act + y, stats
         return ra.checkpoint_keeping_attention(run) if cfg.remat else run
 
-    blocks = {kind: block(kind, name) for kind, name in BLOCK_KINDS.values()}
+    blocks = {c: block(row) for c, row in BLOCKS.items()}
 
-    def in_order(pattern):
-        """(kind, which of the kind's blocks) for each letter."""
-        order, seen = [], {}
-        for letter in pattern:
-            kind = BLOCK_KINDS[letter][0]
-            order.append((kind, seen.get(kind, 0)))
-            seen[kind] = order[-1][1] + 1
-        return order
-
-    def run_blocks(order, act, params):
+    def run_blocks(pattern, act, params):
         stats = []
-        for kind, j in order:
-            lp = jax.tree_util.tree_map(lambda a: a[j], params[kind])
-            act, st = blocks[kind](act, lp)
+        for i, c in enumerate(pattern):
+            j = pattern[:i].count(c)          # which of its kind's blocks
+            lp = jax.tree_util.tree_map(lambda a: a[j],
+                                        params[BLOCKS[c].key])
+            act, st = blocks[c](act, lp)
             if st is not None:
                 stats.append(st)
         return act, stats
 
-    leading, period = in_order(cfg.leading_pattern), in_order(
-        cfg.layer_pattern)
-
     def period_fn(act, period_params):
-        act, stats = run_blocks(period, act, period_params)
+        act, stats = run_blocks(cfg.layer_pattern, act, period_params)
         if not stats:
             return act, None
         return act, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
 
     def stage_fn(stage_params, act):
-        if leading:
+        if cfg.leading_pattern:
             stage_params = dict(stage_params)
-            act, _ = run_blocks(leading, act, stage_params.pop("leading"))
+            act, _ = run_blocks(cfg.leading_pattern, act,
+                                stage_params.pop("leading"))
         out, stats = lax.scan(period_fn, act, stage_params)
-        return (out, stats) if with_stats else out
+        return out if stats is None else (out, stats)
 
     return stage_fn
 
@@ -1155,16 +1225,18 @@ def make_router_balancer(cfg: TransformerConfig, par: ParallelConfig, mesh,
     which busiest / mean is about 1.05 on the batch and 1.2 on fresh ones
     (chip runs, PERF.md PR 31).  The bias stays a buffer outside the
     gradient, and nothing updates it afterwards."""
-    if cfg.layer_pattern is None or "E" not in cfg.layer_pattern \
-            or cfg.router_scoring != "sigmoid":
+    routers = [k for k in (cfg.layer_pattern and pattern_counts(cfg) or ())
+               if _row(k).routes]
+    if not routers or cfg.router_scoring != "sigmoid":
         raise ValueError("only a patterned model's sigmoid router has a "
                          "correction bias to balance")
+    (routed,) = routers
     loss_of = make_loss_fn(cfg, par, mesh, with_routing=True)
     target = cfg.top_k / cfg.n_experts
 
     def with_bias(params, bias):
-        moe = {**params["layers"]["moe"], "router_bias": bias}
-        return {**params, "layers": {**params["layers"], "moe": moe}}
+        moe = {**params["layers"][routed], "router_bias": bias}
+        return {**params, "layers": {**params["layers"], routed: moe}}
 
     def balance(params, tokens, labels):
         def one_round(_, bias):
@@ -1174,7 +1246,7 @@ def make_router_balancer(cfg: TransformerConfig, par: ParallelConfig, mesh,
                 target / jnp.maximum(share, 0.1 * target))
 
         return with_bias(params, lax.fori_loop(
-            0, rounds, one_round, params["layers"]["moe"]["router_bias"]))
+            0, rounds, one_round, params["layers"][routed]["router_bias"]))
 
     return balance
 
@@ -1259,12 +1331,10 @@ def synthetic_batch(key, cfg: TransformerConfig, batch: int):
     ``diffusion_block`` configuration :func:`noised_batch`'s three arrays,
     the mask token being the vocabulary's last id and the data the others."""
     kt, kl = jax.random.split(key)
-    # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no such field.
-    block = getattr(cfg, "diffusion_block", None)
-    if block is not None:
+    if cfg.diffusion_block is not None:
         ids = jax.random.randint(kt, (batch, cfg.seq_len), 0,
                                  cfg.vocab_size - 1, dtype=jnp.int32)
-        return noised_batch(kl, ids, block, cfg.vocab_size - 1)
+        return noised_batch(kl, ids, cfg.diffusion_block, cfg.vocab_size - 1)
     tokens = jax.random.randint(kt, (batch, cfg.seq_len), 0, cfg.vocab_size,
                                 dtype=jnp.int32)
     labels = jnp.roll(tokens, -1, axis=1)
@@ -1529,77 +1599,28 @@ def draft_params_from(params: Dict[str, Any],
     return out
 
 
-def _mlp_flops_per_token(cfg: TransformerConfig) -> float:
-    """Per-token per-layer MLP matmul-FLOPs: dense 4*d*ff; MoE routes
-    top_k experts per token (top_k * 4*d*ff, 6*d*ff with the gate
-    projection of ``gated_experts``) plus the 2*d*E router."""
-    d, ff = cfg.d_model, cfg.d_ff
-    if cfg.n_experts > 0:
-        # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no such
-        # field and ungated experts.
-        gated = getattr(cfg, "gated_experts", False)
-        per_expert = (6.0 if gated else 4.0) * d * ff
-        return cfg.top_k * per_expert + 2.0 * d * cfg.n_experts
-    return 4.0 * d * ff
-
-
-def _block_flops_per_token(cfg: TransformerConfig, letter: str) -> float:
-    """Forward matmul-FLOPs a token of one patterned block, as this device
-    computes it: the heads and experts it holds, causal scores halved, a
-    window's over its band, the scan as the chunked algorithm's four
-    products, a gated convolution's two projections and its taps."""
-    d, s = cfg.d_model, cfg.seq_len
-    if letter == "M":
-        h, p, g, n, q = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
-                         cfg.ssm_state, cfg.ssm_chunk)
-        proj = 2.0 * d * (2 * h * p + 2 * g * n + h) + 2.0 * h * p * d
-        conv = 2.0 * cfg.ssm_conv * (h * p + 2 * g * n)
-        scan = 2.0 * q * n * g + 2.0 * q * p * h + 4.0 * p * n * h
-        return proj + conv + scan
-    if letter in "*W":
-        hq = _attn_heads(cfg, BLOCK_KINDS[letter][0])
-        hkv, hd = cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
-        # (query, key) pairs a query: the causal half, or the band's
-        # window S - window (window - 1) / 2 pairs a sequence, or under the
-        # block-diffusion mask S^2 + S block pairs over 2 S positions.
-        w = min(cfg.attn_window, s) if letter == "W" else 0
-        pairs = w - w * (w - 1) / (2.0 * s) if w else s / 2.0
-        if cfg.diffusion_block is not None:
-            pairs = (s + cfg.diffusion_block) / 2.0
-        gate = 2.0 * d * hq if cfg.attn_gate else 0.0
-        return (2.0 * d * hd * (2 * hq + 2 * hkv) + gate
-                + 4.0 * pairs * hq * hd)
-    if letter == "D":
-        return 6.0 * d * cfg.dense_ff
-    if letter == "C":
-        return 8.0 * d * d + 2.0 * cfg.conv_taps * d
-    width = cfg.moe_latent or d
-    mats = 3.0 if cfg.gated_experts else 2.0
-    routed = (cfg.top_k * _experts_held(cfg) / cfg.n_experts
-              * mats * 2.0 * width * cfg.d_ff)
-    latent = 4.0 * d * width if cfg.moe_latent else 0.0
-    return (2.0 * d * cfg.n_experts + latent + routed
-            + mats * 2.0 * d * cfg.shared_expert_ff)
-
-
 def train_flops_per_seq(cfg: TransformerConfig) -> float:
     """Matmul-FLOPs for one training sequence of ``seq_len`` data tokens
     (train = 3x fwd), importable so training loops can feed
     ``hvd.metrics.set_step_flops()``.  Dense per token 8d^2 (qkv+proj)
     + 4*d*ff (mlp) per layer + 2dV vocab head; causal attention
     2*S^2*d per layer per seq (half the bidirectional 4*S^2*d — the
-    mask zeroes the upper triangle).  MoE configs count the routed
-    top_k experts + gate per token (``_mlp_flops_per_token``)."""
+    mask zeroes the upper triangle).  MoE configs count the routed top_k
+    experts a token (4*d*ff each, 6*d*ff with the gate projection of
+    ``gated_experts``) and the 2*d*E router."""
     d, L, s, v = (cfg.d_model, cfg.n_layers, cfg.seq_len,
                   cfg.vocab_size)
-    # ``cfg`` may be ``moe_transformer.MoEConfig``, which has no pattern.
-    if getattr(cfg, "layer_pattern", None) is not None:
+    if cfg.layer_pattern is not None:
         blocks = cfg.leading_pattern + _n_periods(cfg) * cfg.layer_pattern
         # Block diffusion: a token is two positions in the blocks, one at
         # the head.
         through = 2.0 if cfg.diffusion_block is not None else 1.0
         return 3.0 * s * (2.0 * d * v + through * sum(
-            _block_flops_per_token(cfg, c) for c in blocks))
-    dense = s * (L * (8.0 * d * d + _mlp_flops_per_token(cfg)) + 2.0 * d * v)
+            BLOCKS[c].flops(cfg) for c in blocks))
+    mlp = 4.0 * d * cfg.d_ff
+    if cfg.n_experts > 0:
+        mlp = (cfg.top_k * ((6.0 if cfg.gated_experts else 4.0) * d * cfg.d_ff)
+               + 2.0 * d * cfg.n_experts)
+    dense = s * (L * (8.0 * d * d + mlp) + 2.0 * d * v)
     attn = L * 2.0 * s * s * d
     return 3.0 * (dense + attn)

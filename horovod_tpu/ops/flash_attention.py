@@ -190,7 +190,6 @@ callers' decision (parallel/ring_attention.py ``_flash_enabled``).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -224,27 +223,10 @@ _NARROW_BLOCK = 512
 _WIDE_BLOCK_ROW_BYTES = 256
 
 
-def _pick_block(size: int, widest: int, env: str = "") -> Optional[int]:
+def _pick_block(size: int, widest: int) -> Optional[int]:
     """Widest 128-aligned candidate up to ``widest`` that divides ``size``,
     else the whole dim (Mosaic's equal-to-array-dim exemption) when small
-    enough to fit VMEM tiles, else None: the kernels cannot tile ``size``.
-
-    ``env`` names an override variable (HVD_TPU_FLASH_BLOCK_Q/K) for
-    silicon block-size tuning: the override must divide the dimension,
-    else it is ignored and auto-selection applies."""
-    if env:
-        try:
-            forced = int(os.environ.get(env, "0"))
-        except ValueError:
-            forced = 0  # non-numeric override: ignore, auto-select
-        # A 128-aligned divisor no wider than the widest candidate (a sweep
-        # may try it at any head; auto-selection is stricter), or the whole
-        # (small) dim — anything else would fail Mosaic's lane alignment /
-        # VMEM fit on silicon.
-        if forced > 0 and size % forced == 0 and (
-                (forced % 128 == 0 and forced <= _BLOCK_CANDIDATES[0])
-                or (forced == size and size <= _NARROW_BLOCK)):
-            return forced
+    enough to fit VMEM tiles, else None: the kernels cannot tile ``size``."""
     for c in _BLOCK_CANDIDATES:
         if c <= widest and size % c == 0:
             return c
@@ -856,8 +838,7 @@ def _supported(q, k, window: Optional[int] = None,
                         if c <= widest and (sq // 2) % c == 0
                         and c % diffusion_block == 0), None)
     else:
-        bq = _pick_block(sq, widest, env="HVD_TPU_FLASH_BLOCK_Q")
-        bk = _pick_block(sk, widest, env="HVD_TPU_FLASH_BLOCK_K")
+        bq, bk = _pick_block(sq, widest), _pick_block(sk, widest)
     if bq is None or bk is None:
         return None
     if _bwd_vmem_limit(sq, d, q.dtype) > _VMEM_CEILING_BYTES:

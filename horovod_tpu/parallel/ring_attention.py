@@ -38,14 +38,14 @@ from jax import lax
 from ..compat import axis_size
 
 _NEG_INF = -1e30
+_FLASH_MIN_SEQ = 512
 
 
 def _flash_enabled(seq_k: Optional[int] = None) -> bool:
     """Dispatch policy for the fused kernel. ``HVD_TPU_FLASH=1/0`` forces;
     in auto mode, use it on TPU once the key sequence is long enough that
     the kernel's O(S) memory + tiling beat XLA's fused attention (measured
-    on v5e: +18% BERT-Base train throughput already at S=512; tune with
-    ``HVD_TPU_FLASH_MIN_SEQ``)."""
+    on v5e: +18% BERT-Base train throughput already at S=512)."""
     v = os.environ.get("HVD_TPU_FLASH", "auto")
     if v == "0":
         return False
@@ -53,11 +53,7 @@ def _flash_enabled(seq_k: Optional[int] = None) -> bool:
         return True
     if jax.default_backend() != "tpu":
         return False
-    try:
-        min_seq = int(os.environ.get("HVD_TPU_FLASH_MIN_SEQ", "512"))
-    except ValueError:
-        min_seq = 512
-    return seq_k is None or seq_k >= min_seq
+    return seq_k is None or seq_k >= _FLASH_MIN_SEQ
 
 
 def _block_attn(q, k, v, q_offset, kv_offset, causal, scale, m, l, o):

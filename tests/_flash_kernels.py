@@ -21,6 +21,16 @@ KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
 ONCE = dict.fromkeys(KERNELS, 1)
 
 
+def force_tile(monkeypatch, bq, bk):
+    """Every entry point's own tiling, forced: the chooser (``_supported``)
+    answers (bq, bk) wherever it answers at all.  ``flash_attention`` alone
+    takes ``block_q=`` / ``block_k=``; ``flash_attention_with_lse`` and the
+    ring path ask the chooser."""
+    real = fa._supported
+    monkeypatch.setattr(fa, "_supported",
+                        lambda *a, **kw: real(*a, **kw) and (bq, bk))
+
+
 def kernel_calls(jaxpr) -> dict:
     """``pallas_call`` equations by kernel ``name=`` in a jaxpr's text.  A
     scan's body is printed once, so a layer's calls count once whatever the

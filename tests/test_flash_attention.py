@@ -10,7 +10,7 @@ from jax import lax
 from horovod_tpu.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from _flash_kernels import ONCE, kernel_calls
+from _flash_kernels import ONCE, force_tile, kernel_calls
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.parallel import ring_attention as ra
 
@@ -122,35 +122,30 @@ def test_ring_flash_gradients_ride_the_ring(sp_mesh):
                                    atol=5e-2, rtol=1e-2)
 
 
-def test_block_size_env_override(monkeypatch):
-    """HVD_TPU_FLASH_BLOCK_Q/K force the kernel block sizes (silicon
-    tuning knob) through the auto-selection path — no explicit kwargs,
-    so the env plumbing itself is what is exercised; illegal overrides
-    (non-divisor, non-128-aligned, oversized whole-dim) are ignored."""
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "128")
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", "128")
+def test_block_size_env_override():
+    """The chooser's tile (no environment name forces one any more), and
+    ``block_q=`` / ``block_k=``, which force one for a sweep through the
+    public entry point and must divide their side."""
     q, k, v = _qkv(s=256)
-    assert fa._supported(q, k) == (128, 128)
+    # The widest candidate that divides the side, not the narrowest.
+    assert fa._supported(q, k) == (256, 256)
     ref = ra.reference_attention(q, k, v, causal=True)
-    out = fa.flash_attention(q, k, v, causal=True, interpret=True)
+    out = fa.flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
+                             interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-2, rtol=1e-3)
-    # Illegal overrides fall back to auto-selection: non-divisor,
-    # non-128-aligned divisor, and non-divisor larger than the dim.
-    for bad in ("96", "64", "1024"):
-        monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", bad)
-        assert fa._supported(q, k)[0] == 256, bad
-    # A 128-aligned divisor up to the widest candidate is legal, also at a
-    # head auto-selection keeps to 512 for (fp32, 128: a sweep may try it).
+    # A forced tile that does not divide its side is refused by name.
+    for side, bad in (("block_q", 96), ("block_k", 96), ("block_q", 1024)):
+        with pytest.raises(ValueError, match=f"{side}={bad} must divide"):
+            fa.flash_attention(q, k, v, causal=True, interpret=True,
+                               **{side: bad})
+    # A head of 512 bytes a row (fp32, 128) keeps to 512 where 1024 would
+    # divide; 128 keys are one tile.
     q2, k2, _ = _qkv(s=1024, d=128)
-    monkeypatch.delenv("HVD_TPU_FLASH_BLOCK_Q")
-    assert fa._supported(q2, k2) == (512, 128)
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "1024")
-    assert fa._supported(q2, k2) == (1024, 128)
-    # One above the widest candidate is rejected: s=2048 forced to 2048
-    # falls back to the auto-selected 1024.
+    assert fa._supported(q2, k2) == (512, 512)
+    assert fa._supported(q2, k2[:, :128]) == (512, 128)
+    # 2048 is tiled by the widest candidate, 1024, not taken whole.
     q3, k3, _ = _qkv(s=2048)
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "2048")
     assert fa._supported(q3, k3)[0] == 1024
 
 
@@ -281,8 +276,7 @@ def test_bf16_lse_is_the_fp32_kernels(causal, monkeypatch):
     differ by the rounding: that the cast is there at all."""
     q, k, v = _qkv(b=1, s=512, h=2, d=64, dtype=jnp.bfloat16, seed=5)
     # Four 128-wide key tiles through the public entry point's own tiling.
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", "128")
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", "128")
+    force_tile(monkeypatch, 128, 128)
     out16, lse16 = fa.flash_attention_with_lse(
         q, k, v, causal=causal, interpret=True)
     out32, lse32 = fa.flash_attention_with_lse(
@@ -541,11 +535,6 @@ def _xla_out_lse_grads(q, k, v, g, causal, q_offset=0, kv_offset=0):
 TILES = [(1024, 1024), (1024, 512), (512, 1024)]
 
 
-def _force_tile(monkeypatch, tile):
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(tile[0]))
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(tile[1]))
-
-
 def _assert_fp32_matches_xla(q, k, v, causal, q_offset=0, kv_offset=0):
     """Forward, ``lse``, dQ, dK, dV of the kernels against the XLA path, at
     the fp32 tests' tolerances."""
@@ -572,7 +561,7 @@ def _assert_fp32_matches_xla(q, k, v, causal, q_offset=0, kv_offset=0):
 def test_fp32_on_wide_tiles_matches_xla(causal, tile, monkeypatch):
     q, k, v = _qkv(b=1, s=2048, h=1, d=64, seed=4)
     assert fa._supported(q, k) == TILES[0]
-    _force_tile(monkeypatch, tile)
+    force_tile(monkeypatch, *tile)
     assert fa._supported(q, k) == tile
     _assert_fp32_matches_xla(q, k, v, causal)
 
@@ -603,7 +592,7 @@ def test_ring_shard_offsets_on_wide_tiles(q_offset, kv_offset, tile,
                                           monkeypatch):
     """The ``live`` test and the mask at global positions."""
     q, k, v = _qkv(b=1, s=2048, h=1, d=64, seed=6)
-    _force_tile(monkeypatch, tile)
+    force_tile(monkeypatch, *tile)
     assert fa._supported(q, k) == tile
     _assert_fp32_matches_xla(q, k, v, True, q_offset, kv_offset)
 
@@ -628,7 +617,7 @@ def test_ring_flash_on_wide_tiles(dtype, tile, monkeypatch):
     mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
     q, k, v = _qkv(b=1, s=4096, h=1, d=64, dtype=dtype, seed=12)
     g = jax.random.normal(jax.random.PRNGKey(13), q.shape, dtype)
-    _force_tile(monkeypatch, tile)
+    force_tile(monkeypatch, *tile)
     assert fa._supported(q[:, :2048], k[:, :2048]) == tile
     f = shard_map(
         lambda q, k, v: ra.ring_attention(q, k, v, "sp", causal=True,

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _flash_kernels import pallas_grids, tiles_built
+from _flash_kernels import force_tile, pallas_grids, tiles_built
 from horovod_tpu.ops import flash_attention as fa
 
 
@@ -214,12 +214,6 @@ def out_lse_grads(s, seed=0, **kw):
     return [np.asarray(x) for x in (out,) + vjp(g) + (lse,)]
 
 
-def tiles_of(monkeypatch, bq, bk):
-    """Both entry points' own tiling, forced (HVD_TPU_FLASH_BLOCK_Q/K)."""
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_Q", str(bq))
-    monkeypatch.setenv("HVD_TPU_FLASH_BLOCK_K", str(bk))
-
-
 # mask -> (the call's arguments, its (bq, bk))
 MASKS = {
     "causal": (dict(causal=True), (128, 128)),
@@ -245,7 +239,7 @@ def test_the_list_sums_what_the_rectangle_walk_sums_bit_for_bit(
     and the three gradients are the live tiles' list's to the bit: no live
     tile is left out, and the order of every sum is the rectangle's."""
     kw, tile = MASKS[mask]
-    tiles_of(monkeypatch, *tile)
+    force_tile(monkeypatch, *tile)
     got = out_lse_grads(512, **kw)
     steps = []
     real = fa._live_tiles
@@ -268,7 +262,7 @@ def test_traced_offsets_give_what_the_list_gives_bit_for_bit(
     rectangle, the bodies skipping the dead tiles.  Python ints lay the list
     out.  Same results, to the bit, the wholly dead chunk's zeros and
     ``lse`` of -1e30 among them."""
-    tiles_of(monkeypatch, 128, 128)
+    force_tile(monkeypatch, 128, 128)
     def run(q_offset, kv_offset):
         keys = jax.random.split(jax.random.PRNGKey(0), 4)
         q, k, v, g = (jax.random.normal(key, (1, 512, 2, 16), jnp.float32)

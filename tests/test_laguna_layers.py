@@ -162,7 +162,7 @@ def test_the_two_head_shares_add_up_to_the_whole_attention_block():
         lp = block(WHOLE, kind, leading=kind == "attn")
         lp = {**lp, "wq": lp["wq"] * 8.0, "wk": lp["wk"] * 8.0}
         x = stream(WHOLE)
-        hq = tfm._attn_heads(WHOLE, kind)
+        hq = tfm._row(kind).attention(WHOLE).heads
         hd, total = WHOLE.head_dim, 0.0
         for r in range(2):
             q = slice(r * hq // 2 * hd, (r + 1) * hq // 2 * hd)
@@ -283,10 +283,10 @@ def test_flops_count_the_new_letters():
     swa = 2 * d * hd * (2 * 6 + 2 * 2) + 2 * d * 6 + 4 * (pairs / s) * 6 * hd
     dense = 6 * d * 40
     moe = 2 * d * 16 + 6 * d * 24 + 3 * 4 / 16 * 6 * d * 24
-    assert tfm._block_flops_per_token(cfg, "*") == pytest.approx(attn)
-    assert tfm._block_flops_per_token(cfg, "W") == pytest.approx(swa)
-    assert tfm._block_flops_per_token(cfg, "D") == dense
-    assert tfm._block_flops_per_token(cfg, "E") == pytest.approx(moe)
+    assert tfm.BLOCKS["*"].flops(cfg) == pytest.approx(attn)
+    assert tfm.BLOCKS["W"].flops(cfg) == pytest.approx(swa)
+    assert tfm.BLOCKS["D"].flops(cfg) == dense
+    assert tfm.BLOCKS["E"].flops(cfg) == pytest.approx(moe)
     assert tfm.train_flops_per_seq(cfg) == pytest.approx(
         3 * s * (attn + dense + 2 * (swa + moe) + 2 * d * 128))
 

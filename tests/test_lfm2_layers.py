@@ -347,11 +347,11 @@ def test_the_step_trains_routes_and_names_its_parts():
 
 def test_flops_count_the_two_projections_and_the_taps():
     d = WHOLE.d_model
-    assert tfm._block_flops_per_token(WHOLE, "C") == 8 * d * d + 2 * 3 * d
+    assert tfm.BLOCKS["C"].flops(WHOLE) == 8 * d * d + 2 * 3 * d
     blocks = "CD" + "*ECECECE"
     assert tfm.train_flops_per_seq(WHOLE) == 3.0 * WHOLE.seq_len * (
         2.0 * d * WHOLE.vocab_size
-        + sum(tfm._block_flops_per_token(WHOLE, c) for c in blocks))
+        + sum(tfm.BLOCKS[c].flops(WHOLE) for c in blocks))
 
 
 # -- (g) dQ's transposes in pieces ---------------------------------------------------------

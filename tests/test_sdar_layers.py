@@ -355,8 +355,8 @@ def test_flops_count_two_positions_a_token_and_the_live_pairs():
     pairs = (s * s + s * bk) / (2 * s)            # a query, of 2 s queries
     attn = 2 * d * hd * (2 * 4 + 2 * 2) + 4 * pairs * 4 * hd
     moe = 2 * d * 16 + 4 * 2 / 16 * 6 * d * 12
-    assert tfm._block_flops_per_token(SHARE, "*") == pytest.approx(attn)
-    assert tfm._block_flops_per_token(SHARE, "E") == pytest.approx(moe)
+    assert tfm.BLOCKS["*"].flops(SHARE) == pytest.approx(attn)
+    assert tfm.BLOCKS["E"].flops(SHARE) == pytest.approx(moe)
     assert tfm.train_flops_per_seq(SHARE) == pytest.approx(
         3 * s * (2 * 2 * (attn + moe) + 2 * d * 64))
     # The benchmark's yardstick counts the same, from its own arithmetic.

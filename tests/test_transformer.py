@@ -185,3 +185,96 @@ def test_keeping_the_forward_leaves_the_models_gradients_alone(
         for other in (b, c):
             np.testing.assert_allclose(np.asarray(a), np.asarray(other),
                                        rtol=1e-4, atol=1e-6)
+
+
+# -- a block kind is one row of ``tfm.BLOCKS`` -------------------------------------------------
+
+PATTERNED = tfm.TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=4, d_ff=64, n_layers=3, seq_len=16,
+    dtype=jnp.float32, layer_pattern="*ZD", learned_positions=False,
+    rope_theta=1e4, dense_ff=48, tied_head=False)
+ONE = tfm.ParallelConfig()
+
+
+def _toy_kind():
+    """A seventh kind, written as a new one is: its mixer (a learned scale
+    of the normed stream), its leaves, its FLOPs, and what it asks — here
+    of ``dense_ff``, a field it borrows, there being no way to give
+    ``TransformerConfig`` a field from a test."""
+    def mixer(cfg, lp, x):
+        return tfm._rmsnorm(x, lp["ln"], cfg.norm_eps) * lp["scale"].astype(
+            x.dtype)
+
+    return tfm.BlockKind(
+        "toy", "mlp", (),
+        lambda cfg, here: here and cfg.dense_ff == 7 and
+        'a "Z" block and a dense_ff of 7 do not go together',
+        lambda cfg, new: {"ln": new.ones(cfg.d_model),
+                          "scale": new.rand(cfg.d_model)},
+        mixer, lambda cfg: 2.0 * cfg.d_model)
+
+
+def test_a_seventh_kind_is_its_mixer_and_one_row(monkeypatch):
+    """Registered by this test alone, the kind is refused as a letter until
+    its row is there; then it initialises, is specified, counts its FLOPs,
+    is refused where its row says, leads, and trains beside "*" and "D"."""
+    with pytest.raises(ValueError, match="letters are"):
+        tfm.init_params(jax.random.PRNGKey(0), PATTERNED, ONE)
+    monkeypatch.setitem(tfm.BLOCKS, "Z", _toy_kind())
+    params = tfm.init_params(jax.random.PRNGKey(0), PATTERNED, ONE)
+    assert tfm.pattern_counts(PATTERNED) == {"attn": 1, "dense": 1, "toy": 1}
+    toy = params["layers"]["toy"]
+    assert toy["ln"].shape == toy["scale"].shape == (1, 1, 1, 32)
+    assert float(jnp.abs(toy["scale"]).max()) > 0
+    specs = tfm.param_specs(PATTERNED, ONE)
+    assert jax.tree_util.tree_structure(
+        specs, is_leaf=lambda s: isinstance(s, tfm.P)
+    ) == jax.tree_util.tree_structure(params)
+    without = tfm.train_flops_per_seq(PATTERNED._replace(
+        layer_pattern="*D", n_layers=2))
+    assert tfm.train_flops_per_seq(PATTERNED) == without + 3 * 16 * 2.0 * 32
+    with pytest.raises(ValueError, match="dense_ff of 7 do not go together"):
+        tfm.param_specs(PATTERNED._replace(dense_ff=7), ONE)
+    # It returns no router statistics, so it may lead; nothing crosses
+    # between a diffusion_block's copies, so that admits it.
+    leads = PATTERNED._replace(leading_pattern="Z", n_layers=4)
+    assert tfm.pattern_counts(leads, leading=True) == {"toy": 1}
+    assert tfm.init_params(jax.random.PRNGKey(0), leads, ONE)["layers"][
+        "leading"]["toy"]["scale"].shape == (1, 1, 32)
+    tfm._check_layout(PATTERNED._replace(diffusion_block=4), ONE)
+
+    hvd.init()
+    mesh = create_mesh({"dp": 1, "pp": 1, "mp": 1}, devices=jax.devices()[:1])
+    opt = optax.adamw(1e-2)
+    step, shard = tfm.make_train_step(leads, ONE, mesh, opt)
+    p = shard(tfm.init_params(jax.random.PRNGKey(0), leads, ONE))
+    before = np.asarray(p["layers"]["toy"]["scale"])
+    state, batch = opt.init(p), tfm.synthetic_batch(
+        jax.random.PRNGKey(1), leads, 2)
+    losses = []
+    for _ in range(4):
+        p, state, loss = step(p, state, *batch)
+        losses.append(float(loss))
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert np.abs(np.asarray(p["layers"]["toy"]["scale"]) - before).max() > 0
+    assert np.abs(np.asarray(p["layers"]["leading"]["toy"]["scale"])).max() > 0
+
+
+@pytest.mark.parametrize("letter", list(tfm.BLOCK_KINDS))
+def test_a_kinds_own_fields_are_a_patterned_models(letter):
+    """The fields a model without a ``layer_pattern`` is refused for are
+    the rows' own, computed: every field of the row is named in the
+    refusal, and setting any one of them draws it."""
+    row = tfm.BLOCKS[letter]
+    assert tfm.BLOCK_KINDS[letter] == (row.key, row.scope)
+    assert row.fields and set(row.fields) <= set(tfm.TransformerConfig._fields)
+    off_default = {bool: True, int: 3, float: 0.5, str: "x"}
+    for field in row.fields:
+        default = tfm.TransformerConfig._field_defaults[field]
+        value = ((1, 2, 3) if default is None or isinstance(default, tuple)
+                 else off_default[type(default)])
+        with pytest.raises(ValueError, match="are a patterned model's: set "
+                                             "layer_pattern") as refusal:
+            tfm._check_layout(CFG._replace(**{field: value}), ONE)
+        assert all(f in str(refusal.value) for f in row.fields)
+    tfm._check_layout(CFG._replace(n_kv_heads=CFG.n_heads), ONE)
