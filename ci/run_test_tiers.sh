@@ -144,6 +144,16 @@ TIER_FAST=(
   test_lfm2_layers.py
   benchmark_tests/test_benchmark_lfm2.py
   benchmark_tests/test_benchmark_compile_v5e_lfm2.py
+  # SmallThinker's layer on the training path (ISSUE 46): an expert block
+  # whose router reads the stream as the block before it received it, ahead
+  # of the attention, against equations written out and the reference; full
+  # blocks without positions beside windowed ones that rotate; ReLU-gated
+  # experts; the controls; the four expert shares summing to the whole
+  # layer; the published parameter count; the refusals.  With it the cell's
+  # own benchmark tests and its step compiled for a described v5e.
+  test_smallthinker_layers.py
+  benchmark_tests/test_benchmark_smallthinker.py
+  benchmark_tests/test_benchmark_compile_v5e_smallthinker.py
   test_timeline.py
   # Serving plane (ISSUE 15): admission-policy goldens, prefill/decode
   # parity vs the training-path logits, continuous-vs-static occupancy,

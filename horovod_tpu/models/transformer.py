@@ -37,8 +37,11 @@ the scanned periods), rotary positions on a share of the head with
 YaRN-scaled frequencies and a per-head output gate, Laguna's mix of windowed
 and full attention (``laguna``); with the router's renormalisation over
 ``sum + router_renorm_eps``, LFM2's hybrid of convolution and attention
-blocks (``lfm2_moe``).  ``diffusion_block`` turns the step itself into
-block-diffusion training (BD3-LMs, arXiv:2503.09573; SDAR,
+blocks (``lfm2_moe``); with ``router_before_attention`` (an ``E`` block's
+router reading the stream as the block before it received it), ReLU-gated
+experts and full layers without positions beside rotating windowed ones,
+SmallThinker's layer (``smallthinker``).  ``diffusion_block`` turns the
+step itself into block-diffusion training (BD3-LMs, arXiv:2503.09573; SDAR,
 arXiv:2510.06303; see ``forward_loss``), the noise being data
 (:func:`noised_batch`).  The serving entry points below cover learned
 positions only.
@@ -119,6 +122,10 @@ class TransformerConfig(NamedTuple):
     router_scoring: str = "softmax"   # | "sigmoid": choice by score + bias
     router_renormalise: bool = False  # chosen weights over their sum
     router_scale: float = 1.0
+    # "E" blocks: the router reads the stream as the block before received
+    # it, unnormed (a layer's input, ahead of its attention); the experts
+    # still read this block's normed input.
+    router_before_attention: bool = False
     n_experts_held: Optional[int] = None  # experts 0..held-1 live here
     expert_buffer_factor: float = 4.0  # held experts' rows: x the mean
     moe_latent: int = 0           # > 0: experts work in a latent space
@@ -182,6 +189,7 @@ def _has_pos_table(cfg: TransformerConfig) -> bool:
 
 
 _ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
+                "relu": jax.nn.relu,
                 "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
@@ -211,6 +219,12 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
             refusal = row.asks(cfg, c in present)
             if refusal:
                 raise ValueError(refusal)
+            if row.before(cfg) and c in (lead[:1], period[0]):
+                raise ValueError(
+                    f'an "{c}" block that reads the stream as the block '
+                    "before it received it cannot open the model or the "
+                    f"period {period!r}: no block of its scan step lies "
+                    "before it")
         if cfg.diffusion_block is not None:
             if cfg.diffusion_block < 1 or cfg.seq_len % cfg.diffusion_block:
                 raise ValueError(
@@ -833,22 +847,26 @@ def _init_experts(cfg: TransformerConfig, new) -> Dict[str, jax.Array]:
 
 
 def _expert_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
-                  x: jax.Array):
+                  x: jax.Array, before: Optional[jax.Array] = None):
     """(An "E" block's output, its ``moe.RouterStats``): the routed experts
     on the normed stream, or on its projection into ``moe_latent`` features
     with the router still reading the stream (LatentMoE), plus the shared
     expert on the stream: ``act(h V1) V2``, or with ``gated_experts``
-    ``(act(h Vg) * h V1) V2`` as the routed experts are."""
+    ``(act(h Vg) * h V1) V2`` as the routed experts are.  ``before``
+    (``router_before_attention``): the stream as the block before this one
+    received it, which the router then reads as it is, unnormed."""
     mb, s, d = x.shape
     tok = _rmsnorm(x, lp["ln"], cfg.norm_eps).reshape(mb * s, d)
+    router_x = (before.reshape(mb * s, d) if before is not None
+                else tok if cfg.moe_latent else None)
     if cfg.moe_latent:
         with scope("moe_latent"):
             latent = jnp.dot(tok, lp["w_latent_in"].astype(x.dtype))
-        y, stats = _route_experts(cfg, lp, latent, router_x=tok)
+        y, stats = _route_experts(cfg, lp, latent, router_x=router_x)
         with scope("moe_latent"):
             y = jnp.dot(y, lp["w_latent_out"].astype(x.dtype))
     else:
-        y, stats = _route_experts(cfg, lp, tok)
+        y, stats = _route_experts(cfg, lp, tok, router_x=router_x)
     if cfg.shared_expert_ff:
         with scope("moe_shared"):
             def up(w):
@@ -876,8 +894,10 @@ def _expert_flops(cfg: TransformerConfig) -> float:
 
 class BlockKind(NamedTuple):
     """One kind of block a ``layer_pattern`` names, a row of ``BLOCKS``: a
-    block is ``x + mixer(cfg, its leaves, x)``, the mixer norming its input,
-    and all this file knows of a kind is its row and what the row names."""
+    block is ``x + mixer(cfg, its leaves, x)``, the mixer norming its input
+    (where ``before(cfg)``: ``mixer(cfg, its leaves, x, x as the block before
+    it received it)``), and all this file knows of a kind is its row and
+    what the row names."""
     key: str                  # its leaves' key under ``layers``
     scope: str                # the step scope its blocks run under
     fields: Tuple[str, ...]   # the TransformerConfig fields of its own
@@ -888,6 +908,8 @@ class BlockKind(NamedTuple):
     routes: bool = False      # y is (y, moe.RouterStats): it cannot lead
     crosses: str = ""         # what of it a ``diffusion_block`` refuses
     attention: Optional[Callable] = None      # (cfg) -> _Attention
+    # (cfg) -> whether the mixer also takes the block before's input
+    before: Callable = lambda cfg: False
 
 
 def _attention_kind(key: str, fields: Tuple[str, ...], variant,
@@ -926,10 +948,12 @@ BLOCKS: Dict[str, BlockKind] = {
         _init_ssm, _ssm_mixer, _ssm_flops,
         crosses='a state-space scan ("M")'),
     "E": BlockKind(
-        "moe", "mlp", ("moe_latent", "shared_expert_ff"),
+        "moe", "mlp",
+        ("moe_latent", "shared_expert_ff", "router_before_attention"),
         lambda cfg, here: here != _routes_dropless(cfg) and 'an "E" block '
         "is a dropless expert MLP: n_experts and dropless go with it",
-        _init_experts, _expert_mixer, _expert_flops, routes=True),
+        _init_experts, _expert_mixer, _expert_flops, routes=True,
+        before=lambda cfg: cfg.router_before_attention),
     "*": _attention_kind(
         "attn", ("rope_fraction", "rope_yarn"),
         lambda cfg: _Attention(cfg.n_heads, None, cfg.rope_theta,
@@ -965,13 +989,16 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
     ``leading_pattern`` once, then a scan over the periods, inside one
     period its blocks in the pattern's order, each under its step scope and
     (``cfg.remat``) its own checkpoint, which keeps an attention block's
-    flash forward output and lse.  Returns the activation and, where the
-    pattern has blocks that route, their ``moe.RouterStats`` stacked
-    (periods, blocks a period, ...)."""
+    flash forward output and lse.  A block whose row says ``before`` is
+    also handed the input of the block before it: that block's checkpoint
+    input already, so nothing more is saved, and its cotangent joins the
+    stream's there.  Returns the activation and, where the pattern has
+    blocks that route, their ``moe.RouterStats`` stacked (periods, blocks a
+    period, ...)."""
     def block(row):
-        def run(act, lp):
+        def run(act, lp, *before):
             with scope(row.scope):
-                out = row.mixer(cfg, lp, act)
+                out = row.mixer(cfg, lp, act, *before)
                 y, stats = out if row.routes else (out, None)
                 return act + y, stats
         return ra.checkpoint_keeping_attention(run) if cfg.remat else run
@@ -979,12 +1006,14 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
     blocks = {c: block(row) for c, row in BLOCKS.items()}
 
     def run_blocks(pattern, act, params):
-        stats = []
+        stats, before = [], None
         for i, c in enumerate(pattern):
             j = pattern[:i].count(c)          # which of its kind's blocks
             lp = jax.tree_util.tree_map(lambda a: a[j],
                                         params[BLOCKS[c].key])
-            act, st = blocks[c](act, lp)
+            carried = (before,) if BLOCKS[c].before(cfg) else ()
+            before = act
+            act, st = blocks[c](act, lp, *carried)
             if st is not None:
                 stats.append(st)
         return act, stats
