@@ -131,6 +131,9 @@ TIER_FAST=(
   # rows (ISSUE 40): the held path against the pair-space formulas, the
   # loop's edge cases, the three families' models, the traced step's shape.
   test_moe_token_sums.py
+  # The Pallas kernel that adds those rows in VMEM (ISSUE 47), alone in the
+  # interpreter: tile and chunk edges, a shared walk, exact weights.
+  test_token_sum_kernel.py
   benchmark_tests/test_benchmark_sdar.py
   benchmark_tests/test_benchmark_compile_v5e_sdar.py
   # LFM2's gated short convolution on the training path (ISSUE 41): the "C"

@@ -32,7 +32,12 @@ would have added is left out.  Nothing stands in for them.  The buffer is
 sized for the worst case and mostly padding, so what moves rows of width d
 walks its live rows only (``_token_sums``, ``_live_prefix``,
 ``_combine_rows_bwd``), never its padding and never the (token, held expert)
-pairs, of which one in sixteen or fewer is chosen.
+pairs, of which one in sixteen or fewer is chosen.  The two sums that bring
+rows back to their tokens (the combine; the dispatch's backward) scatter
+nothing into HBM: the live rows are listed by token (``_token_order``, one
+sort of integers a forward), gathered in that order, and added in VMEM by
+the Pallas kernel ``hvd_moe_token_sum`` (``ops/token_sum.py``), which writes
+each tile of the (tokens, d) result once.
 
 The reference's only layout-shuffling primitive is alltoall with uneven
 splits (operations.cc:1136-1198, SURVEY.md §2.3 "the only primitive that
@@ -369,43 +374,96 @@ def _weigh(p, router: Router):
     return p if router.scale == 1.0 else p * router.scale
 
 
-# Rows of the buffer's live prefix a trip of ``_token_sums``' loop adds.
-_SUM_CHUNK = 512
+# Shares of the buffer ``_live_prefix`` may gather: the smallest that holds
+# the live rows.  A buffer of 4 x the mean is a quarter live, a few percent
+# either way.  ``_token_sums`` takes the first or the whole list: each share
+# is a kernel to trace, lower and load, which is set-up time.
+_LIVE_PREFIXES = (5 / 16, 1 / 2, 1)
+_SUM_PREFIXES = (5 / 16, 1)
 
 
-def _token_sums(z, scale, token_of_row, n_live, tokens: int, site: str):
+class _TokenOrder(NamedTuple):
+    """The buffer's live rows listed by token (a token's by expert), int32,
+    the list padded to whole chunks of the kernel ``hvd_moe_token_sum``;
+    what is past the live rows is the padding, in no order.  With the
+    kernel's walk over the list (``ops/token_sum.walk``), which both sums
+    and each of their prefixes share."""
+    row: jax.Array     # (L,) the buffer row in this place of the list
+    token: jax.Array   # (L,) its token; ``tokens`` for the padding
+    pair: jax.Array    # (L,) its (token, held expert) pair; T x held there
+    steps: tuple       # ((2, steps) tile and chunk of each step, (1,) live)
+
+
+def _token_order(pair_of_row, n_live, n_held: int, tokens: int) -> _TokenOrder:
+    """One sort of the buffer's R integers: a live row's pair is token x held
+    + expert, so the pairs ascending are the rows by token; the padding's key
+    is past the last pair.  Made once a block's forward, for both sums."""
+    from ..ops import token_sum          # Pallas: not at the package's import
+    rows = pair_of_row.shape[0]
+    tile, chunk = token_sum.tiling(tokens, rows)
+    place = jnp.arange(rows, dtype=jnp.int32)
+    pair, row = lax.sort(
+        (jnp.where(place < n_live, pair_of_row.astype(jnp.int32),
+                   tokens * n_held), place), num_keys=1)
+    pad = (0, -rows % chunk)
+    pair = jnp.pad(pair, pad, constant_values=tokens * n_held)
+    token = pair // n_held
+    return _TokenOrder(row=jnp.pad(row, pad), token=token, pair=pair,
+                       steps=token_sum.walk(token, tokens, tile, chunk))
+
+
+def _token_sums(z, weights, order: _TokenOrder, n_live, tokens: int,
+                site: str):
     """(tokens, d) fp32: token t's sum of the buffer's live rows that are its
-    own, ``z[r] * scale[r]`` in fp32 (``scale`` None: ``z[r]``); 0 for a
-    token with no row.  Both sums of the held path are this one: the
-    combine's forward (``scale`` the row's weight) and the dispatch's
-    backward.
+    own, ``z[r] * weights[pair of r]`` in fp32 (``weights`` None: ``z[r]``);
+    0 for a token with no row.  Both sums of the held path are this one: the
+    combine's forward (``weights`` the flat (T x held,) routing weights) and
+    the dispatch's backward.
 
-    Taken over the buffer's live prefix, rows 0 .. ``n_live`` - 1, not over
-    every (token, held expert) pair: ``_SUM_CHUNK`` rows a trip, as they lie
-    in the buffer, scatter-added into the (tokens, d) result the loop carries;
-    the trip count is read from ``n_live``, so the buffer's padding (three
-    quarters of it at a factor of 4) costs nothing, and nothing but the result
-    is as large as a token's row for every token.  The loop is inside
-    custom-VJP rules, which AD never sees."""
+    No row is scattered into HBM.  The live rows are gathered into token
+    order (``order``: one plain gather of a static prefix of the list, 5/16
+    of it where that holds ``n_live`` rows and all of it where not, as
+    :func:`_live_prefix` takes the buffer's, masked past ``n_live``; their
+    weights with them, a scalar a listed row), where a token's rows are
+    neighbours, and the kernel ``hvd_moe_token_sum`` (``ops/token_sum.py``)
+    adds them into a token tile that stays in VMEM until it is whole and is
+    written once.  Nothing but the result is as large as a token's row for
+    every token, no (R, d) fp32 array is made, and the padding is neither
+    read nor added.  Compiled by Mosaic where the program is lowered for a
+    TPU, run by the Pallas interpreter elsewhere: on a TPU host the program
+    is lowered for the TPU it is traced on; off one
+    ``lax.platform_dependent`` decides as the program is lowered, so the CPU
+    tests run the kernel's own code and a step compiled for a described TPU
+    holds the kernel.  (Both branches traced on the TPU host cost set-up time
+    for nothing: PERF.md section 6, PR 47.)  Inside custom-VJP rules, which
+    AD never sees."""
+    from ..ops import token_sum
     _token_sums_built(site)
-    rows, d = z.shape
-    chunk = min(_SUM_CHUNK, rows)
+    _token_sum_kernels_built(site)
+    rows, listed = z.shape[0], order.row.shape[0]
+    tile, chunk = token_sum.tiling(tokens, rows)
 
-    def trip(c, out):
-        # The last trip of a buffer that is no multiple of the chunk starts
-        # early; the rows it met before go nowhere, as the padding does.
-        at = jnp.minimum(c * chunk, rows - chunk)
-        zs = lax.dynamic_slice(z, (at, 0), (chunk, d)).astype(jnp.float32)
-        if scale is not None:
-            zs = zs * lax.dynamic_slice(scale, (at,), (chunk,))[:, None]
-        row = at + jnp.arange(chunk)
-        token = jnp.where((row >= c * chunk) & (row < n_live),
-                          lax.dynamic_slice(token_of_row, (at,), (chunk,)),
-                          tokens)
-        return out.at[token].add(zs, mode="drop")
+    def prefix(n):
+        def sums():
+            live = jnp.arange(n) < n_live
+            picked = jnp.where(live[:, None], z[order.row[:n]], 0)
+            scale = None if weights is None else jnp.where(
+                live, weights[jnp.minimum(order.pair[:n], weights.size - 1)],
+                0)
+            kernel = partial(token_sum.token_sum, tokens=tokens, tile=tile,
+                             chunk=chunk)
+            operands = picked, order.token[:n], scale, order.steps
+            if jax.default_backend() == "tpu":
+                return kernel(*operands)
+            return lax.platform_dependent(
+                *operands, tpu=kernel, default=partial(kernel, interpret=True))
+        return sums
 
-    return lax.fori_loop(0, (n_live + chunk - 1) // chunk, trip,
-                         jnp.zeros((tokens, d), jnp.float32))
+    # Whole chunks, so that the kernel pads nothing.
+    sizes = sorted({min(listed, -(-int(rows * share) // chunk) * chunk)
+                    for share in _SUM_PREFIXES})
+    which = sum((n_live > n).astype(jnp.int32) for n in sizes[:-1])
+    return lax.switch(which, [prefix(n) for n in sizes])
 
 
 def _token_sums_built(site: str) -> None:
@@ -417,10 +475,13 @@ def _token_sums_built(site: str) -> None:
         site=site).inc()
 
 
-# Shares of the buffer ``_live_prefix`` may gather: the smallest that holds
-# the live rows.  A buffer of 4 x the mean is a quarter live, a few percent
-# either way.
-_LIVE_PREFIXES = (5 / 16, 1 / 2, 1)
+def _token_sum_kernels_built(site: str) -> None:
+    """Trace-time count of the sums that are the kernel ``hvd_moe_token_sum``,
+    by site: none for a layer that holds every expert."""
+    registry().counter(
+        "hvd_moe_token_sum_kernels_built_total",
+        "held experts' token sums traced as the Pallas kernel, by site",
+        site=site).inc()
 
 
 def _live_prefix(x, token_of_row, n_live):
@@ -457,46 +518,48 @@ def _live_gathers_built(site: str) -> None:
 
 
 @jax.custom_vjp
-def _held_rows(x, token_of_row, n_live):
+def _held_rows(x, token_of_row, n_live, order: _TokenOrder):
     """The rows of the buffer, in expert order: ``x[token_of_row]`` for the
     first ``n_live``, which are live, and zeros for the padding
-    (:func:`_live_prefix`).  Backward adds a token's live rows of ``g``
-    (:func:`_token_sums`)."""
+    (:func:`_live_prefix`).  Backward adds a token's live rows of ``g``:
+    :func:`_token_sums`, the rows taken in ``order`` and added in VMEM by
+    the kernel ``hvd_moe_token_sum``."""
     return _live_prefix(x, token_of_row, n_live)
 
 
-def _held_rows_fwd(x, token_of_row, n_live):
+def _held_rows_fwd(x, token_of_row, n_live, order):
     return (_live_prefix(x, token_of_row, n_live),
-            (token_of_row, n_live, x.shape[0]))
+            (order, n_live, x.shape[0]))
 
 
 def _held_rows_bwd(res, g):
-    token_of_row, n_live, tokens = res
-    dx = _token_sums(g, None, token_of_row, n_live, tokens, "dispatch_bwd")
-    return dx.astype(g.dtype), None, None
+    order, n_live, tokens = res
+    dx = _token_sums(g, None, order, n_live, tokens, "dispatch_bwd")
+    return dx.astype(g.dtype), None, None, None
 
 
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
 @jax.custom_vjp
-def _combine_rows(y, weights, pair_of_row, n_live):
+def _combine_rows(y, weights, pair_of_row, n_live, order: _TokenOrder):
     """(T, d) fp32: ``sum_e weights[t, e] * y[row of (t, e)]`` over the
     pairs that have a row, forward and backward in row space (row r came
     from pair ``pair_of_row[r]``; the first ``n_live`` rows are live).
-    Forward: :func:`_token_sums` of the rows times their weights.  Backward
+    Forward: :func:`_token_sums` of the rows times their weights — the live
+    rows taken in ``order``, by token, and added in VMEM by the kernel
+    ``hvd_moe_token_sum``; nothing is scattered.  Backward
     (:func:`_combine_rows_bwd`): ``dy[r] = g[token of r] * weight of r``
     and one dot a row for ``dweights``, over the live prefix.  No array has
     a row for every (token, held expert) pair: AD's transpose of the
     pair-space sum broadcast ``g`` to (T, held, d) in fp32, 3 GiB at 24,576
     positions, 16 held and 2048 features."""
-    t, n_held = weights.shape
-    return _token_sums(y, weights.reshape(-1)[pair_of_row],
-                       pair_of_row // n_held, n_live, t, "combine")
+    return _token_sums(y, weights.reshape(-1), order, n_live,
+                       weights.shape[0], "combine")
 
 
-def _combine_rows_fwd(y, weights, pair_of_row, n_live):
-    return (_combine_rows(y, weights, pair_of_row, n_live),
+def _combine_rows_fwd(y, weights, pair_of_row, n_live, order):
+    return (_combine_rows(y, weights, pair_of_row, n_live, order),
             (y, weights, pair_of_row, n_live))
 
 
@@ -540,7 +603,8 @@ def _combine_rows_bwd(res, g):
     dy, dw = lax.fori_loop(
         0, (n_live + chunk - 1) // chunk, trip,
         (jnp.zeros_like(y), jnp.zeros((pairs,), jnp.float32)))
-    return dy, dw.reshape(weights.shape).astype(weights.dtype), None, None
+    return (dy, dw.reshape(weights.shape).astype(weights.dtype), None, None,
+            None)
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
@@ -587,11 +651,14 @@ def _held_experts(params: GatedMoEParams, x, weights, chosen, activation,
     behind all chosen ones, so rows 0 .. ``n_live`` - 1 are live and the rest
     is padding.  Rows go into the buffer by one gather of its live prefix
     (:func:`_live_prefix`) and come back to their tokens (the combine; the
-    dispatch's backward) by :func:`_token_sums` over the live prefix, as the
-    combine's backward goes (:func:`_combine_rows_bwd`): nothing in this
-    path has a row of width d for every (token, held expert) pair, and
-    nothing moves the padding.  Returns (out (T, d) fp32, pairs dropped
-    ())."""
+    dispatch's backward) by :func:`_token_sums`: the live rows listed by
+    token (:func:`_token_order`, made here once for both), gathered in that
+    order and added in VMEM by the kernel ``hvd_moe_token_sum``, no row
+    scattered into HBM.  The combine's backward walks the live prefix
+    (:func:`_combine_rows_bwd`).  Nothing in this path has a row of width d
+    for every (token, held expert) pair or for every (token, choice) pair
+    beyond the buffer itself, and nothing moves the padding.  Returns (out
+    (T, d) fp32, pairs dropped ())."""
     t, d = x.shape
     n_held = params.w_up.shape[0]
     with scope("moe_route"):
@@ -606,11 +673,12 @@ def _held_experts(params: GatedMoEParams, x, weights, chosen, activation,
         row_used = jnp.arange(row_buffer) < n_live
         dropped = (jnp.sum(counts) - n_live).astype(jnp.float32)
     with scope("moe_dispatch"):
-        rows = _held_rows(x, pair_of_row // n_held, n_live)
+        order = _token_order(pair_of_row, n_live, n_held, t)
+        rows = _held_rows(x, pair_of_row // n_held, n_live, order)
     with scope("moe_experts"):
         y = _grouped_experts(params, rows, group_sizes, activation, row_used)
     with scope("moe_dispatch"):
-        out = _combine_rows(y, weights, pair_of_row, n_live)
+        out = _combine_rows(y, weights, pair_of_row, n_live, order)
     return out, dropped
 
 
@@ -646,7 +714,10 @@ def dropless_moe(params: GatedMoEParams, x: jax.Array, top_k: int,
     (``_live_prefix``, ``_token_sums``, ``_combine_rows_bwd``; the
     trace-time counters ``hvd_moe_live_gathers_built_total{site}`` and
     ``hvd_moe_token_sums_built_total{site}`` say they engaged), the sums in
-    fp32.
+    fp32 and in VMEM: the rows are taken in token order and added by the
+    Pallas kernel ``hvd_moe_token_sum``, one call a sum
+    (``hvd_moe_token_sum_kernels_built_total{site}``), so no row is
+    read-modified-written in HBM.
     """
     t, d = x.shape
     e = params.gate.shape[1]

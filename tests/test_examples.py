@@ -143,6 +143,17 @@ def test_chip_smoke_refuses_without_a_tpu():
 
 
 @pytest.mark.timeout(300)
+def test_token_sum_timing_refuses_without_a_tpu():
+    """A timing comes from a TPU: off one the example says what it found,
+    times nothing and prints no line."""
+    r = _run([os.path.join(EXAMPLES, "token_sum_timing.py"), "sdar"],
+             timeout=100)
+    assert r.returncode != 0
+    assert "this is cpu" in r.stderr
+    assert r.stdout.strip() == ""
+
+
+@pytest.mark.timeout(300)
 def test_block_diffusion_lm_trains_on_the_virtual_mesh():
     """A tiny block-diffusion configuration (``diffusion_block``: the
     doubled sequence, the third batch array from ``synthetic_batch`` /

@@ -1,15 +1,20 @@
 """The held experts' rows are summed into their tokens over the buffer's live
 rows (``parallel/moe.py`` ``_token_sums``), not over every (token, held
 expert) pair, and gathered over them too (``_live_prefix``,
-``_combine_rows_bwd``), not over the buffer's padding: values and both
-gradients of the held path against the plain pair-space formulas written out
-here, the cases a row-space walk can get wrong (no row, a full run, live rows
-over several trips of the loop, a buffer that is no multiple of the chunk, an
-overflowing buffer, an empty one), the four families' models with the helpers
-swapped for the parent's forms, and the shape of the traced step: no value
-with a row of width d for every (token, held expert) pair, no gather of the
-whole buffer and no fp32 array of its size, one sum and one gather a site a
-held layer."""
+``_combine_rows_bwd``), not over the buffer's padding; the sums scatter
+nothing: the live rows are taken in token order and added in VMEM by the
+kernel ``hvd_moe_token_sum`` (``ops/token_sum.py``), which runs here in the
+Pallas interpreter.  Values and both gradients of the held path against the
+plain pair-space formulas written out here, the cases a row-space walk can get
+wrong (no row, a full run, live rows over several tiles and chunks of the
+kernel's walk, a buffer that is no multiple of the chunk, an overflowing
+buffer, an empty one), the sums against the gathered sum at the cells' widths
+(the kernel alone: ``tests/test_token_sum_kernel.py``), the families' models
+with the helpers swapped for the parent's forms, and the shape of the traced
+step: no value with a row of width d for
+every (token, held expert) or (token, choice) pair, no scatter-add of such
+rows, no gather of the whole buffer and no fp32 array of its size, one sum, one
+kernel and one gather a site a held layer."""
 
 from __future__ import annotations
 
@@ -20,6 +25,7 @@ import pytest
 
 from horovod_tpu.metrics.registry import registry
 from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import token_sum
 from horovod_tpu.parallel import moe
 from horovod_tpu.parallel.mesh import create_mesh
 
@@ -52,27 +58,32 @@ def plain_held_experts(params, x, weights, kept, activation):
 
 
 def pair_space_sums(held):
-    """``_token_sums`` as the parent took it: one gathered row for every
+    """``_token_sums`` as PR 40's parent took it: one gathered row for every
     (token, held expert) pair, masked, weighed and summed over the ``held``
-    experts.  The pairs are found again from the rows: sorted by token
-    (stable: expert order inside a token), a token's rows are a run, and a
-    run is no longer than the experts held."""
-    def sums(z, scale, token_of_row, n_live, tokens, site):
+    experts.  The pairs are found again from the rows listed by token: a
+    token's rows are a run, and a run is no longer than the experts held."""
+    def sums(z, weights, order, n_live, tokens, site):
         moe._token_sums_built(site)
         rows = z.shape[0]
-        key = jnp.where(jnp.arange(rows) < n_live, token_of_row, tokens)
-        order = jnp.argsort(key, stable=True)
-        key = key[order]
-        start = jnp.searchsorted(key, jnp.arange(tokens))
+        start = jnp.searchsorted(order.token, jnp.arange(tokens))
         q = start[:, None] + jnp.arange(held)[None, :]          # (T, held)
         at = jnp.minimum(q, rows - 1)
-        live = (q < rows) & (key[at] == jnp.arange(tokens)[:, None])
-        picked = jnp.where(live[..., None], z[order[at]], 0)
+        live = (q < rows) & (order.token[at] == jnp.arange(tokens)[:, None])
+        picked = jnp.where(live[..., None], z[order.row[at]], 0)
         picked = picked.astype(jnp.float32)
-        if scale is not None:
-            picked = picked * jnp.where(live, scale[order[at]], 0)[..., None]
+        if weights is not None:
+            picked = picked * jnp.where(live, weights[jnp.minimum(
+                order.pair[at], weights.size - 1)], 0)[..., None]
         return jnp.sum(picked, axis=1)
     return sums
+
+
+def force_tiling(monkeypatch, tile, chunk):
+    """The kernel's walk at ``tile`` tokens a tile and ``chunk`` rows a chunk
+    at most (``ops/token_sum.py`` takes both from the shapes: 256 and 256
+    where the arrays are that large)."""
+    monkeypatch.setattr(token_sum, "_TILE", tile)
+    monkeypatch.setattr(token_sum, "_CHUNK", chunk)
 
 
 # -- the held path, values and gradients ----------------------------------------
@@ -148,13 +159,14 @@ def test_a_token_with_no_row_and_a_token_with_every_row_it_can_have():
 
 @pytest.mark.parametrize("gated", [True, False])
 @pytest.mark.parametrize("rows", [128, 100])
-def test_the_live_rows_span_several_trips_of_the_loop(monkeypatch, gated,
-                                                      rows):
-    """Chunks of 8 rows under 40-odd live ones: a token's rows (they lie an
-    expert apart in the buffer) are added in different trips, the last live
-    chunk is part padding, and a buffer of 100 rows is no multiple of the
-    chunk (its last trip would start early, were it ever reached)."""
-    monkeypatch.setattr(moe, "_SUM_CHUNK", 8)
+def test_the_live_rows_span_several_tiles_and_chunks(monkeypatch, gated,
+                                                     rows):
+    """Tiles of 8 tokens and chunks of 8 rows under 40-odd live ones: the
+    rows of a tile lie in two or three chunks and a chunk's rows in two
+    tiles, the live rows end inside a chunk and inside a tile's run, and a
+    buffer of 100 rows is no multiple of the chunk (the list is padded to
+    104).  (Was the loop's ``..._span_several_trips_of_the_loop``.)"""
+    force_tiling(monkeypatch, 8, 8)
     t, d, n_held, top_k = 32, 8, 4, 4
     params, x, weights, chosen = held_case(t, d, n_held, top_k, 12, key=2,
                                            gated=gated)
@@ -165,9 +177,9 @@ def test_the_live_rows_span_several_trips_of_the_loop(monkeypatch, gated,
 
 
 def test_a_full_buffer_that_is_no_multiple_of_the_chunk(monkeypatch):
-    """Every row live and the last trip starting early: the rows it shares
-    with the trip before are added once."""
-    monkeypatch.setattr(moe, "_SUM_CHUNK", 16)
+    """Every row live, the longest prefix taken, and the list's last chunk
+    half padding: each row is added once."""
+    force_tiling(monkeypatch, 8, 16)
     t, d, n_held, top_k = 32, 8, 4, 4
     params, x, weights, chosen = held_case(t, d, n_held, top_k, 8, key=6)
     assert int(chosen.sum()) > 40
@@ -212,15 +224,24 @@ def routing_of(chosen, row_buffer):
     return n_live, row_of_pair, kept, pair_of_row, row_used
 
 
+def order_of(pair_of_row, n_live, t, held):
+    """The live rows listed by token, as ``_held_experts`` lists them."""
+    return moe._token_order(pair_of_row, n_live, held, t)
+
+
 @pytest.mark.parametrize("scaled", [False, True])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_token_sums_is_the_gathered_sum(monkeypatch, scaled, dtype):
+@pytest.mark.parametrize("d", [8, 1024, 2048, 2560, 3072])
+def test_token_sums_is_the_gathered_sum(monkeypatch, d, scaled, dtype):
     """Against ``sum_e where(kept, z[row_of_pair]) * w`` with ``jnp``
-    gathers: fp32 sums of the same terms; a token's rows arrive an expert at
-    a time, so they are added in ascending expert order wherever they lie in
-    different trips (a last bit may differ where two lie in one)."""
-    monkeypatch.setattr(moe, "_SUM_CHUNK", 16)
-    t, n_held, d, rows = 48, 8, 16, 96
+    gathers: fp32 sums of the same terms, at a width that is no multiple of
+    128 lanes and at the five cells' widths; the kernel in the Pallas
+    interpreter, tiles of 16 tokens and chunks of 16 rows.  A token's rows
+    are neighbours in the list, an expert after another, and are added as one
+    fp32 product a chunk (a last bit may differ from a sum taken in
+    sequence)."""
+    force_tiling(monkeypatch, 16, 16)
+    t, n_held, rows = 48, 8, 96
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     chosen = jax.random.uniform(ks[0], (t, n_held)) < 0.2
     n_live, row_of_pair, kept, pair_of_row, row_used = routing_of(chosen,
@@ -231,9 +252,9 @@ def test_token_sums_is_the_gathered_sum(monkeypatch, scaled, dtype):
     if scaled:
         picked = picked * w[..., None]
     want = jnp.sum(picked, axis=1)
-    scale = w.reshape(-1)[pair_of_row] if scaled else None
     got = jax.jit(lambda z: moe._token_sums(
-        z, scale, pair_of_row // n_held, n_live, t, "combine"))(z)
+        z, w.reshape(-1) if scaled else None,
+        order_of(pair_of_row, n_live, t, n_held), n_live, t, "combine"))(z)
     assert got.dtype == jnp.float32 and got.shape == (t, d)
     np.testing.assert_allclose(got, want, atol=1e-6, rtol=0)
 
@@ -269,10 +290,11 @@ def test_the_combines_backward_is_ads(monkeypatch, walk):
     t, held, d = 32, 4, 8
     chosen, rows, chunk = walk_case(walk, t, held)
     monkeypatch.setattr(moe, "_GATHER_CHUNK", chunk)
-    monkeypatch.setattr(moe, "_SUM_CHUNK", chunk)
+    force_tiling(monkeypatch, 8, chunk)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     n_live, row_of_pair, kept, pair_of_row, row_used = routing_of(chosen,
                                                                   rows)
+    order = order_of(pair_of_row, n_live, t, held)
     y = jax.random.normal(keys[0], (rows, d))
     w = jax.random.uniform(keys[1], (t, held))
     g = jax.random.normal(keys[2], (t, d))
@@ -282,7 +304,8 @@ def test_the_combines_backward_is_ads(monkeypatch, walk):
         return jnp.sum(jnp.sum(picked * w[..., None], axis=1) * g)
 
     def ours(y, w):
-        return jnp.sum(moe._combine_rows(y, w, pair_of_row, n_live) * g)
+        return jnp.sum(moe._combine_rows(y, w, pair_of_row, n_live, order)
+                       * g)
 
     np.testing.assert_allclose(ours(y, w), plain(y, w), rtol=1e-6)
     got, want = jax.grad(ours, (0, 1))(y, w), jax.grad(plain, (0, 1))(y, w)
@@ -304,7 +327,8 @@ def test_held_rows_is_the_gather_in_the_live_prefix_and_zeros_past_it(walk,
     n_live, _, _, pair_of_row, row_used = routing_of(chosen, rows)
     x = jax.random.normal(jax.random.PRNGKey(3), (t, d)).astype(dtype)
     token_of_row = pair_of_row // held
-    got = jax.jit(moe._held_rows)(x, token_of_row, n_live)
+    got = jax.jit(moe._held_rows)(x, token_of_row, n_live, order_of(
+        pair_of_row, n_live, t, held))
     assert got.shape == (rows, d) and got.dtype == dtype
     np.testing.assert_array_equal(
         got, jnp.where(row_used[:, None], x[token_of_row], 0))
@@ -348,9 +372,10 @@ def test_the_dispatchs_backward_is_ads():
     # leave there: it goes nowhere.
     g = jax.random.normal(keys[2], (rows, d))
     token_of_row = pair_of_row // held
+    order = order_of(pair_of_row, n_live, t, held)
 
     def ours(x):
-        return jnp.sum(moe._held_rows(x, token_of_row, n_live) * g)
+        return jnp.sum(moe._held_rows(x, token_of_row, n_live, order) * g)
 
     def plain(x):
         return jnp.sum(x[token_of_row] * jnp.where(row_used[:, None], g, 0))
@@ -359,7 +384,7 @@ def test_the_dispatchs_backward_is_ads():
                                atol=1e-6, rtol=1e-5)
 
 
-# -- the three families' models ----------------------------------------------------
+# -- the five families' models -----------------------------------------------------
 
 SDAR = tfm.TransformerConfig(
     vocab_size=64, d_model=32, n_heads=4, d_ff=12, n_layers=4, seq_len=16,
@@ -400,8 +425,16 @@ LFM2 = tfm.TransformerConfig(
     router_scoring="sigmoid", router_renormalise=True,
     router_renorm_eps=1e-6, dense_ff=40, conv_taps=3,
     expert_buffer_factor=4.0, n_experts_held=4)
+SMALLTHINKER = tfm.TransformerConfig(
+    vocab_size=64, d_model=32, n_heads=4, d_ff=20, n_layers=8, seq_len=16,
+    n_experts=24, top_k=3, dtype=jnp.float32, dropless=True, norm_eps=1e-6,
+    tied_head=False, gated_experts=True, expert_activation="relu",
+    layer_pattern="*EWEWEWE", learned_positions=False, rope_theta=None,
+    n_kv_heads=2, attn_head_dim=8, attn_window=5, window_rope_theta=1.5e6,
+    router_renormalise=True, router_before_attention=True,
+    expert_buffer_factor=4.0, n_experts_held=4)
 FAMILIES = {"sdar": SDAR, "laguna": LAGUNA, "nemotron": NEMOTRON,
-            "lfm2": LFM2}
+            "lfm2": LFM2, "smallthinker": SMALLTHINKER}
 
 
 def one_device_mesh():
@@ -422,10 +455,10 @@ def whole_buffer_gather(x, token_of_row, n_live):
     return x[token_of_row]
 
 
-def pair_space_combine(y, weights, pair_of_row, n_live):
+def pair_space_combine(y, weights, pair_of_row, n_live, order=None):
     """``_combine_rows`` as the plain sum over a token's kept pairs, one
     gathered row a (token, held expert) pair, for AD to differentiate: no
-    hand-written backward."""
+    hand-written backward (and no use for the rows' order by token)."""
     t, held = weights.shape
     rows = y.shape[0]
     at = jnp.where(jnp.arange(rows) < n_live, pair_of_row, t * held)
@@ -438,11 +471,11 @@ def pair_space_combine(y, weights, pair_of_row, n_live):
 
 
 @jax.custom_vjp
-def parents_combine_rows(y, weights, pair_of_row, n_live):
+def parents_combine_rows(y, weights, pair_of_row, n_live, order):
     return pair_space_combine(y, weights, pair_of_row, n_live)
 
 
-def parents_combine_rows_fwd(y, weights, pair_of_row, n_live):
+def parents_combine_rows_fwd(y, weights, pair_of_row, n_live, order):
     return (pair_space_combine(y, weights, pair_of_row, n_live),
             (y, weights, pair_of_row, n_live))
 
@@ -458,14 +491,14 @@ def parents_combine_rows_bwd(res, g):
     dw = jnp.zeros((weights.size,), jnp.float32).at[
         jnp.where(live, pair_of_row, weights.size)].set(dots, mode="drop")
     return ((g_rows * w_rows[:, None]).astype(y.dtype),
-            dw.reshape(weights.shape), None, None)
+            dw.reshape(weights.shape), None, None, None)
 
 
 parents_combine_rows.defvjp(parents_combine_rows_fwd,
                             parents_combine_rows_bwd)
 
 
-@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("family", sorted(set(FAMILIES) - {"smallthinker"}))
 def test_a_familys_loss_and_gradients_are_the_pair_space_forms(monkeypatch,
                                                                family):
     """The whole model with the held path as shipped, and with the parents'
@@ -510,6 +543,9 @@ def widest_rows(jaxpr, width):
 SUMS = ("hvd_moe_token_sums_built_total",
         "held experts' sums of buffer rows into their tokens traced, by site",
         ("combine", "dispatch_bwd"))
+KERNELS = ("hvd_moe_token_sum_kernels_built_total",
+           "held experts' token sums traced as the Pallas kernel, by site",
+           ("combine", "dispatch_bwd"))
 GATHERS = ("hvd_moe_live_gathers_built_total",
            "held experts' row gathers over the buffer's live prefix traced, "
            "by site", ("rows", "combine_bwd"))
@@ -540,22 +576,36 @@ def expert_layer(cfg):
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_no_value_has_a_row_for_every_token_and_held_expert(family):
+def test_no_value_has_a_row_for_every_token_and_held_expert(monkeypatch,
+                                                            family):
     """Walked through value_and_grad of one expert layer: forward, the
-    custom-VJP rules' backward and the loops' bodies.  The pair-space arrays
-    the parent had, (T, held, d) and its flat (T x held, d), would be the
-    largest values of the layer made of rows d wide; none reaches their size,
-    in any family (one path for every row width: no combine is exempt)."""
+    custom-VJP rules' backward, the loops' and the kernels' bodies.  The
+    pair-space arrays PR 40's parent had, (T, held, d) and its flat (T x held,
+    d), would be the largest values of the layer made of rows d wide, and a
+    gather into (token, choice) space would make (T x top_k, d); none reaches
+    either size, in any family (one path for every row width: no combine is
+    exempt) — the second where the buffer itself is smaller than that
+    (Laguna's preset, as SmallThinker's cell, holds a quarter of the experts:
+    4 x the mean is then a row a (token, choice) pair).  The rows are added
+    by the kernel, in chunks of 8 here so that the list is the buffer's
+    length, and nothing scatter-adds a row d wide."""
+    force_tiling(monkeypatch, 8, 8)
     cfg = FAMILIES[family]
     before = built_by_site(SUMS)
     jaxpr = expert_layer(cfg)
     t = 2 * cfg.seq_len                       # the layer's own input
     width = cfg.moe_latent or cfg.d_model
-    pair_space = t * cfg.n_experts_held * width
+    rows = moe.held_row_buffer(t, cfg.top_k, cfg.n_experts_held,
+                               cfg.n_experts, cfg.expert_buffer_factor)
+    assert (rows < t * cfg.top_k) == (family != "laguna")
+    pair_space = t * width * min(
+        [cfg.n_experts_held] + [cfg.top_k] * (rows < t * cfg.top_k))
     largest = widest_rows(jaxpr, width)
     assert 0 < largest < pair_space, (largest, pair_space)
-    # The walk sees the loop: the rows are added inside a while's body.
-    assert "while" in str(jaxpr) and "scatter-add" in str(jaxpr)
+    assert str(jaxpr).count(f"name={token_sum.KERNEL}") >= 2
+    assert not [eqn for eqn, _ in eqns_and_where(jaxpr.jaxpr)
+                if eqn.primitive.name == "scatter-add"
+                and eqn.outvars[0].aval.shape[-1:] == (width,)]
     assert {site: built(site) - n for site, n in before.items()} == {
         "combine": 1, "dispatch_bwd": 1}
 
@@ -572,9 +622,10 @@ def test_the_walk_would_see_the_parents_pair_space_gather(monkeypatch):
     assert widest_rows(jaxpr, cfg.d_model) >= pair_space
 
 
-@pytest.mark.parametrize("counter", [SUMS, GATHERS], ids=["sums", "gathers"])
+@pytest.mark.parametrize("counter", [SUMS, KERNELS, GATHERS],
+                         ids=["sums", "kernels", "gathers"])
 def test_a_layer_that_holds_every_expert_builds_no_token_sum(counter):
-    """No buffer, no live prefix: 0 / 0 of either counter."""
+    """No buffer, no live prefix: 0 / 0 of each counter."""
     before = built_by_site(counter)
     jaxpr = expert_layer(OLMOE)
     assert "ragged_dot" in str(jaxpr)
@@ -590,6 +641,19 @@ def test_a_held_layer_builds_one_live_gather_a_site(family):
     expert_layer(FAMILIES[family])
     assert {site: n - before[site] for site, n in built_by_site(
         GATHERS).items()} == {"rows": 1, "combine_bwd": 1}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_held_layers_sums_are_the_kernel_once_a_site(family):
+    """``hvd_moe_token_sum_kernels_built_total``: one ``combine`` for the
+    traced forward of a held layer and one ``dispatch_bwd`` for its traced
+    backward, as ``hvd_moe_token_sums_built_total`` counts the sums: every
+    sum is the kernel."""
+    before = built_by_site(KERNELS), built_by_site(SUMS)
+    expert_layer(FAMILIES[family])
+    for counter, was in zip((KERNELS, SUMS), before):
+        assert {site: n - was[site] for site, n in built_by_site(
+            counter).items()} == {"combine": 1, "dispatch_bwd": 1}
 
 
 # -- no gather of the whole buffer, no fp32 array of its size ----------------------
@@ -640,7 +704,7 @@ WALKED = dict(FAMILIES, sdar=SDAR._replace(n_experts_held=12))
 
 @pytest.fixture
 def eight_rows_a_trip(monkeypatch):
-    monkeypatch.setattr(moe, "_SUM_CHUNK", 8)
+    force_tiling(monkeypatch, 8, 8)
     monkeypatch.setattr(moe, "_GATHER_CHUNK", 8)
 
 
