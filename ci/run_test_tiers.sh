@@ -166,6 +166,17 @@ TIER_FAST=(
   test_smallthinker_layers.py
   benchmark_tests/test_benchmark_smallthinker.py
   benchmark_tests/test_benchmark_compile_v5e_smallthinker.py
+  # Learned sparse attention on the training path (ISSUE 49): the exact
+  # choice of a query's keys against a sort, ties and all; the flash
+  # kernels of a selected call against reference_attention under the mask;
+  # rotary positions from three streams; the indexer's leaves learning from
+  # their loss alone; the refusals.  With it the Keye-VL cell's own
+  # benchmark tests (system against reference, the controls, the eight
+  # expert shares, the published parameter count, the traffic's positions,
+  # the readers) and its step compiled for a described v5e.
+  test_keye_vl_layers.py
+  benchmark_tests/test_benchmark_keye_vl.py
+  benchmark_tests/test_benchmark_compile_v5e_keye_vl.py
   test_timeline.py
   # Serving plane (ISSUE 15): admission-policy goldens, prefill/decode
   # parity vs the training-path logits, continuous-vs-static occupancy,

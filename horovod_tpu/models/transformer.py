@@ -40,7 +40,11 @@ and full attention (``laguna``); with the router's renormalisation over
 blocks (``lfm2_moe``); with ``router_before_attention`` (an ``E`` block's
 router reading the stream as the block before it received it), ReLU-gated
 experts and full layers without positions beside rotating windowed ones,
-SmallThinker's layer (``smallthinker``).  ``diffusion_block`` turns the
+SmallThinker's layer (``smallthinker``); with "S" blocks (attention over
+the ``index_topk`` keys a learned indexer ranks highest for each query,
+ops/sparse_index.py, the indexer's own loss beside the model's) and rotary
+positions read from three streams of a batch array (``rope_sections``),
+Keye-VL-2.0's language model (``keye_vl``).  ``diffusion_block`` turns the
 step itself into block-diffusion training (BD3-LMs, arXiv:2503.09573; SDAR,
 arXiv:2510.06303; see ``forward_loss``), the noise being data
 (:func:`noised_batch`).  The serving entry points below cover learned
@@ -67,13 +71,14 @@ from jax import lax
 from ..compat import axis_size
 from jax.sharding import PartitionSpec as P
 
+from ..ops import sparse_index as si
 from ..ops import ssd
 from ..parallel import moe as moe_lib
 from ..parallel import pipeline as pp_lib
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
-from ..utils.profiler import (CONV_SCOPES, DENSE_MLP_SCOPE, TP_RING_SCOPES,
-                              scope)
+from ..utils.profiler import (CONV_SCOPES, DENSE_MLP_SCOPE, INDEX_SCOPES,
+                              TP_RING_SCOPES, scope)
 
 GATHER_RING, SCATTER_RING = TP_RING_SCOPES
 
@@ -151,6 +156,18 @@ class TransformerConfig(NamedTuple):
     # Block-diffusion training of a patterned model, in blocks of
     # ``diffusion_block`` positions; see ``forward_loss``.
     diffusion_block: Optional[int] = None
+    # "S" blocks: "*" in which a query sees, of the keys at or before it, the
+    # ``index_topk`` that an indexer of ``index_heads`` heads of
+    # ``index_head_dim`` over one key head scores highest (ops/
+    # sparse_index.py), trained by ``index_loss_coef`` x its own loss; and
+    # q, k and the indexer's rotate by ``rope_sections`` (frequencies a
+    # stream, of head_dim / 2) of the three position streams a batch
+    # brings as a third array, (B, 3, S): ``forward_loss(positions=)``.
+    index_heads: int = 0
+    index_head_dim: int = 64
+    index_topk: int = 0
+    index_loss_coef: float = 1.0
+    rope_sections: Optional[Tuple[int, ...]] = None
     head_qk_norm: bool = False    # "*" / "W": RMSNorm over each head of q, k
     conv_taps: int = 0            # "C" blocks: the convolution's taps
     router_renorm_eps: float = 0.0  # renormalised weights: over sum + eps
@@ -188,6 +205,21 @@ def _has_pos_table(cfg: TransformerConfig) -> bool:
     return cfg.rope_theta is None and cfg.learned_positions
 
 
+def _selects(cfg: TransformerConfig) -> bool:
+    """Whether the pattern has blocks of a learned selection, whose batch
+    brings position streams and whose loss has the indexers' term."""
+    return cfg.layer_pattern is not None and any(
+        BLOCKS[c].selects for c in cfg.layer_pattern)
+
+
+def batch_extras(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The arrays a batch brings after ``tokens`` and ``labels``, by
+    ``forward_loss``'s names for them."""
+    if cfg.diffusion_block is not None:
+        return ("weights",)
+    return ("positions",) if _selects(cfg) else ()
+
+
 _ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
                 "relu": jax.nn.relu,
                 "relu2": lambda x: jnp.square(jax.nn.relu(x))}
@@ -212,10 +244,10 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
                 "a patterned model's attention blocks take rotary positions "
                 "(rope_theta, window_rope_theta) and no QK-norm yet")
         for c, row in BLOCKS.items():
-            if row.routes and lead.count(c):
+            if (row.routes or row.selects) and lead.count(c):
                 raise NotImplementedError(
-                    f'an "{c}" block cannot lead: the router statistics are '
-                    "stacked by period")
+                    f'an "{c}" block cannot lead: the router statistics '
+                    "and the indexers' losses are stacked by period")
             refusal = row.asks(cfg, c in present)
             if refusal:
                 raise ValueError(refusal)
@@ -225,6 +257,13 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
                     "before it received it cannot open the model or the "
                     f"period {period!r}: no block of its scan step lies "
                     "before it")
+        if cfg.attn_mode != "megatron" and any(
+                BLOCKS[c].selects for c in present):
+            raise NotImplementedError(
+                f"learned sparse attention with attn_mode {cfg.attn_mode!r}: "
+                "a query's chosen keys live on other shards of the sequence, "
+                "and the selection runs through selected_attention (attn_mode "
+                "'megatron', mp 1; ROADMAP M11)")
         if cfg.diffusion_block is not None:
             if cfg.diffusion_block < 1 or cfg.seq_len % cfg.diffusion_block:
                 raise ValueError(
@@ -251,7 +290,8 @@ def _check_layout(cfg: TransformerConfig, par: ParallelConfig) -> None:
             raise NotImplementedError(
                 "a model with a layer_pattern runs on dp alone: its mixers "
                 "are neither sharded over mp nor staged over pp (ROADMAP "
-                "M0); nor is a diffusion_block's doubled sequence")
+                "M0); nor is a diffusion_block's doubled sequence, nor a "
+                "learned selection's keys (ROADMAP M11)")
     else:
         # The pattern's own fields and every kind's stay at their defaults.
         own = {"leading_pattern", "diffusion_block"}.union(
@@ -515,6 +555,30 @@ def _rope(t, positions, theta: float, fraction: float = 1.0, yarn=None):
     return jnp.concatenate(parts, axis=-1).astype(t.dtype)
 
 
+def _rope_streams(t, positions, theta: float, sections):
+    """:func:`_rope` over the whole head with the angle of frequency i read
+    from one of several position streams (Qwen2-VL's sectioned layout):
+    ``positions`` (mb, streams, S) integers, ``sections`` the frequencies a
+    stream takes, in order, ``sum(sections)`` = half the head: frequency i
+    turns by ``positions[:, c(i)] * theta^(-2i/hd)``, c(i) the section i
+    falls in.  ``t``: (mb, S, heads, hd)."""
+    half = t.shape[-1] // 2
+    if sum(sections) != half or positions.shape[1] != len(sections):
+        raise ValueError(
+            f"rope sections {tuple(sections)} over {positions.shape[1]} "
+            f"position streams do not cover the {half} frequencies of a head "
+            f"of {t.shape[-1]}")
+    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    stream = np.repeat(np.arange(len(sections)), sections)       # (half,)
+    at = jnp.moveaxis(positions.astype(jnp.float32), 1, 2)   # (mb, S, streams)
+    angle = at[..., stream] * inv_freq                        # (mb, S, half)
+    cos, sin = jnp.cos(angle)[:, :, None, :], jnp.sin(angle)[:, :, None, :]
+    tf = t.astype(jnp.float32)
+    t1, t2 = tf[..., :half], tf[..., half:]
+    return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
+                           axis=-1).astype(t.dtype)
+
+
 def _position_qk(cfg: TransformerConfig, lp, q, k, positions, axis_name):
     """What the configuration does to q and k between the projection and
     the attention: nothing (learned positions were added to the stream),
@@ -689,6 +753,10 @@ class _Attention(NamedTuple):
     theta: Optional[float]        # rotary base; None: no position encoding
     fraction: float = 1.0         # the share of each head that rotates
     yarn: Optional[Tuple[float, int, float, float, float]] = None
+    # Frequencies a position stream (``_rope_streams``); None: one stream.
+    sections: Optional[Tuple[int, ...]] = None
+    # (heads, head width, keys a query) of a learned indexer; None: none.
+    index: Optional[Tuple[int, int, int]] = None
 
 
 def _init_gqa(cfg: TransformerConfig, new, kind: str) -> Dict[str, jax.Array]:
@@ -703,11 +771,51 @@ def _init_gqa(cfg: TransformerConfig, new, kind: str) -> Dict[str, jax.Array]:
         blk["w_head_gate"] = new.rand(d, hq)
     if cfg.head_qk_norm:
         blk["q_norm"], blk["k_norm"] = new.ones(hd), new.ones(hd)
+    index = _row(kind).attention(cfg).index
+    if index is not None:
+        j, di, _ = index
+        blk.update(
+            index_wq=new.rand(d, j * di), index_wk=new.rand(d, di),
+            index_ww=new.rand(d, j), index_k_norm=new.ones(di),
+            index_k_bias=0.0 * new.ones(di))
     return blk
 
 
+def _layernorm(x, scale, bias, eps: float):
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
+
+
+def _index(cfg: TransformerConfig, lp, hnorm, positions, a: "_Attention"):
+    """A learned indexer's operands from the block's normed input, held
+    constant (its leaves learn from their own loss alone): ``qi`` (mb, S,
+    J, Di), the one key head ``ki`` (mb, S, Di) under a LayerNorm, both
+    rotated as q and k are with the sections scaled to their head, and the
+    weight a query and head ``w`` (mb, S, J) fp32, the scale J^-1/2 Di^-1/2
+    folded in (DeepSeek-V3.2-Exp's lightning indexer)."""
+    j, di, _ = a.index
+    mb, s, _ = hnorm.shape
+    hnorm = lax.stop_gradient(hnorm)
+
+    def project(name):
+        return jnp.einsum("bsd,de->bse", hnorm, lp[name].astype(hnorm.dtype))
+
+    qi = project("index_wq").reshape(mb, s, j, di)
+    ki = _layernorm(project("index_wk"), lp["index_k_norm"],
+                    lp["index_k_bias"], cfg.norm_eps)
+    with scope("attn_rope"):
+        sections = [n * di // cfg.head_dim for n in a.sections]
+        qi = _rope_streams(qi, positions, a.theta, sections)
+        ki = _rope_streams(ki[:, :, None], positions, a.theta,
+                           sections)[:, :, 0]
+    w = project("index_ww").astype(jnp.float32) * (j * di) ** -0.5
+    return qi, ki, w
+
+
 def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
-               x: jax.Array, kind: str = "attn") -> jax.Array:
+               x: jax.Array, kind: str = "attn", positions=None):
     """Causal attention with ``n_kv_heads`` key / value heads, query head i
     reading head i // (heads / n_kv_heads); the row of ``kind`` says how
     many query heads, how many keys a query sees, and q and k's rotation
@@ -721,7 +829,16 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     multiplies head i's output by ``sigmoid(h Wg)_i``, a scalar a head and
     token from the block's normed input.  Each K / V head is repeated
     across its query heads before the kernels (their index maps taking
-    several query heads a K / V block is ROADMAP M4)."""
+    several query heads a K / V block is ROADMAP M4).
+
+    A variant with ``sections`` rotates by ``positions`` (mb, streams, S), a
+    batch array (``_rope_streams``); causality stays the index in the
+    sequence.  A variant with an ``index`` (an "S" block) returns ``(y, the
+    indexer's loss)``: the indexer ranks every query's causal keys
+    (``ops/sparse_index.select``, exact), the attention runs over the
+    chosen ones (``ring_attention.selected_attention``), and the loss is
+    ``ops/sparse_index.index_loss`` on the attention's own operands and
+    saved ``lse``, all held constant: no gradient passes between the two."""
     mb, s, _ = x.shape
     a = _row(kind).attention(cfg)
     hq, hkv, hd = a.heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
@@ -736,32 +853,54 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
         with scope("attn_qknorm"):
             q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
             k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
-    if a.theta is not None:
+    if a.sections is not None:
+        if positions is None:
+            raise ValueError(
+                f"rope_sections {a.sections}: the batch brings the position "
+                "streams as its third array, (B, streams, S)")
         with scope("attn_rope"):
-            def positions():          # one arange a tensor, as ever
+            q, k = (_rope_streams(t, positions, a.theta, a.sections)
+                    for t in (q, k))
+    elif a.theta is not None:
+        with scope("attn_rope"):
+            def index():              # one arange a tensor, as ever
                 at = jnp.arange(s)
                 return at if cfg.diffusion_block is None else at % (s // 2)
-            q, k = (_rope(t, positions(), a.theta, a.fraction, a.yarn)
+            q, k = (_rope(t, index(), a.theta, a.fraction, a.yarn)
                     for t in (q, k))
+    k_own = k
     if hkv != hq:
         k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
-    o = ra.full_attention(q, k, v, causal=True, window=a.window,
-                          diffusion_block=cfg.diffusion_block)
+    if a.index is None:
+        o = ra.full_attention(q, k, v, causal=True, window=a.window,
+                              diffusion_block=cfg.diffusion_block)
+    else:
+        with scope(INDEX_SCOPES[0]):
+            qi, ki, w = _index(cfg, lp, hnorm, positions, a)
+            visible_t = si.select(qi, ki, w, a.index[2])
+        o, lse = ra.selected_attention(q, k, v, visible_t)
+        index_loss = si.index_loss(
+            qi, ki, w, *(lax.stop_gradient(t) for t in (q, k_own, lse)),
+            visible_t, hd ** -0.5)
     if cfg.attn_gate:
         with scope("attn_gate"):
             gate = jax.nn.sigmoid(jnp.einsum(
                 "bsd,dh->bsh", hnorm, lp["w_head_gate"].astype(x.dtype)
             ).astype(jnp.float32))
             o = (o.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
-    return jnp.einsum("bse,ed->bsd", o.reshape(mb, s, hq * hd),
-                      lp["wo"].astype(x.dtype))
+    y = jnp.einsum("bse,ed->bsd", o.reshape(mb, s, hq * hd),
+                   lp["wo"].astype(x.dtype))
+    return y if a.index is None else (y, index_loss)
 
 
 def _gqa_flops(cfg: TransformerConfig, kind: str) -> float:
     """The projections, the gate and the scores, by the (query, key) pairs
     a query: the causal half, or the band's window S - window (window - 1)
     / 2 pairs a sequence, or under the block-diffusion mask S^2 + S block
-    pairs over 2 S positions."""
+    pairs over 2 S positions, or the chosen keys of a learned selection
+    (every causal key of a query's first ``topk``, then ``topk``), whose
+    indexer adds its projections and its scores over the causal half, once:
+    the pass its loss makes over the main scores is not model work."""
     a, d, s = _row(kind).attention(cfg), cfg.d_model, cfg.seq_len
     hq, hkv, hd = a.heads, cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
     w = min(a.window, s) if a.window else 0
@@ -769,7 +908,14 @@ def _gqa_flops(cfg: TransformerConfig, kind: str) -> float:
     if cfg.diffusion_block is not None:
         pairs = (s + cfg.diffusion_block) / 2.0
     gate = 2.0 * d * hq if cfg.attn_gate else 0.0
-    return 2.0 * d * hd * (2 * hq + 2 * hkv) + gate + 4.0 * pairs * hq * hd
+    index = 0.0
+    if a.index is not None:
+        j, di, topk = a.index
+        k = min(topk, s)
+        pairs = (k * (k + 1) / 2.0 + (s - k) * k) / s
+        index = 2.0 * d * (j * di + di + j) + 2.0 * j * di * (s + 1) / 2.0
+    return (2.0 * d * hd * (2 * hq + 2 * hkv) + gate + index
+            + 4.0 * pairs * hq * hd)
 
 
 def _init_dense(cfg: TransformerConfig, new) -> Dict[str, jax.Array]:
@@ -906,6 +1052,7 @@ class BlockKind(NamedTuple):
     mixer: Callable           # (cfg, leaves, x) -> y
     flops: Callable           # (cfg) -> forward matmul-FLOPs a token
     routes: bool = False      # y is (y, moe.RouterStats): it cannot lead
+    selects: bool = False     # y is (y, its indexer's loss): nor can it
     crosses: str = ""         # what of it a ``diffusion_block`` refuses
     attention: Optional[Callable] = None      # (cfg) -> _Attention
     # (cfg) -> whether the mixer also takes the block before's input
@@ -913,7 +1060,8 @@ class BlockKind(NamedTuple):
 
 
 def _attention_kind(key: str, fields: Tuple[str, ...], variant,
-                    needs=lambda cfg: None, crosses: str = "") -> BlockKind:
+                    needs=lambda cfg: None, crosses: str = "",
+                    selects: bool = False) -> BlockKind:
     """A row over ``_gqa_mixer``: ``variant(cfg)`` the ``_Attention`` that
     separates it from the others, ``fields`` and ``needs(cfg)`` its own."""
     def asks(cfg, here):
@@ -932,7 +1080,7 @@ def _attention_kind(key: str, fields: Tuple[str, ...], variant,
         key, "attn", fields + ("n_kv_heads", "attn_gate", "head_qk_norm"),
         asks, *(functools.partial(f, kind=key)
                 for f in (_init_gqa, _gqa_mixer, _gqa_flops)),
-        crosses=crosses, attention=variant)
+        crosses=crosses, attention=variant, selects=selects)
 
 
 # The kinds of block by their letter in a ``layer_pattern``.  A new kind is
@@ -965,6 +1113,22 @@ BLOCKS: Dict[str, BlockKind] = {
         lambda cfg: not cfg.attn_window and
         'a "W" block is sliding-window attention: attn_window goes with it',
         crosses='a sliding window ("W", attn_window)'),
+    "S": _attention_kind(
+        "sel", ("index_heads", "index_head_dim", "index_topk",
+                "index_loss_coef", "rope_sections"),
+        lambda cfg: _Attention(
+            cfg.n_heads, None, cfg.rope_theta, sections=cfg.rope_sections,
+            index=(cfg.index_heads, cfg.index_head_dim, cfg.index_topk)),
+        lambda cfg: (
+            min(cfg.index_heads, cfg.index_topk) < 1 or cfg.rope_theta is None
+            or sum(cfg.rope_sections or ()) * 2 != cfg.head_dim
+            or any(n * cfg.index_head_dim % cfg.head_dim
+                   for n in cfg.rope_sections)) and
+        'an "S" block is attention over the index_topk keys an indexer of '
+        "index_heads heads ranks highest, rotated at rope_theta by "
+        "rope_sections: frequencies a position stream, half of head_dim "
+        "together, that scale whole to index_head_dim",
+        crosses='a learned selection ("S", index_topk)', selects=True),
     "D": BlockKind(
         "dense", "mlp", ("dense_ff",),
         lambda cfg, here: here and not cfg.dense_ff and
@@ -984,7 +1148,7 @@ BLOCKS: Dict[str, BlockKind] = {
 BLOCK_KINDS = {c: (row.key, row.scope) for c, row in BLOCKS.items()}
 
 
-def _make_pattern_stage_fn(cfg: TransformerConfig):
+def _make_pattern_stage_fn(cfg: TransformerConfig, positions=None):
     """stage_fn(stage_params, act) for a ``layer_pattern``: the blocks of
     ``leading_pattern`` once, then a scan over the periods, inside one
     period its blocks in the pattern's order, each under its step scope and
@@ -994,19 +1158,26 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
     input already, so nothing more is saved, and its cotangent joins the
     stream's there.  Returns the activation and, where the pattern has
     blocks that route, their ``moe.RouterStats`` stacked (periods, blocks a
-    period, ...)."""
+    period, ...); where it has blocks that select, a dict of those under
+    ``"router"`` and the indexers' losses (periods, blocks a period) under
+    ``"index"``.
+    ``positions``: the batch's position streams (mb, streams, S), handed to
+    the attention kinds where the batch brings them."""
     def block(row):
+        more = ({"positions": positions}
+                if positions is not None and row.attention else {})
+
         def run(act, lp, *before):
             with scope(row.scope):
-                out = row.mixer(cfg, lp, act, *before)
-                y, stats = out if row.routes else (out, None)
+                out = row.mixer(cfg, lp, act, *before, **more)
+                y, stats = out if row.routes or row.selects else (out, None)
                 return act + y, stats
         return ra.checkpoint_keeping_attention(run) if cfg.remat else run
 
     blocks = {c: block(row) for c, row in BLOCKS.items()}
 
     def run_blocks(pattern, act, params):
-        stats, before = [], None
+        side, before = {"router": [], "index": []}, None
         for i, c in enumerate(pattern):
             j = pattern[:i].count(c)          # which of its kind's blocks
             lp = jax.tree_util.tree_map(lambda a: a[j],
@@ -1015,33 +1186,35 @@ def _make_pattern_stage_fn(cfg: TransformerConfig):
             before = act
             act, st = blocks[c](act, lp, *carried)
             if st is not None:
-                stats.append(st)
-        return act, stats
+                side["router" if BLOCKS[c].routes else "index"].append(st)
+        return act, side
 
     def period_fn(act, period_params):
-        act, stats = run_blocks(cfg.layer_pattern, act, period_params)
-        if not stats:
-            return act, None
-        return act, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats)
+        act, side = run_blocks(cfg.layer_pattern, act, period_params)
+        return act, {name: jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *found)
+            for name, found in side.items() if found}
 
     def stage_fn(stage_params, act):
         if cfg.leading_pattern:
             stage_params = dict(stage_params)
             act, _ = run_blocks(cfg.leading_pattern, act,
                                 stage_params.pop("leading"))
-        out, stats = lax.scan(period_fn, act, stage_params)
-        return out if stats is None else (out, stats)
+        out, side = lax.scan(period_fn, act, stage_params)
+        if set(side) == {"router"}:
+            side = side["router"]
+        return (out, side) if side else out
 
     return stage_fn
 
 
-def _make_stage_fn(cfg: TransformerConfig):
+def _make_stage_fn(cfg: TransformerConfig, positions=None):
     """stage_fn(stage_params, act) scanning this stage's layers, each
     (``cfg.remat``) under one checkpoint that keeps the flash forward's
     output and lse; with a dropless MoE it returns the activation and the
     layers' stacked ``moe.RouterStats``."""
     if cfg.layer_pattern is not None:
-        return _make_pattern_stage_fn(cfg)
+        return _make_pattern_stage_fn(cfg, positions)
     with_stats = _routes_dropless(cfg)
 
     def layer_fn(act, lp):
@@ -1065,7 +1238,8 @@ def _make_stage_fn(cfg: TransformerConfig):
 def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
                  params: Dict[str, Any], tokens: jax.Array,
                  labels: jax.Array, with_routing: bool = False,
-                 weights: Optional[jax.Array] = None):
+                 weights: Optional[jax.Array] = None,
+                 positions: Optional[jax.Array] = None):
     """Per-device loss body; call inside shard_map over mesh (dp, pp, mp).
 
     tokens/labels: (B_local, S) int32 shards (batch over dp).
@@ -1082,6 +1256,13 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     final norm and the head run on the first S (the noised copy) alone, and
     the loss is ``sum(weights * -log softmax(logits)[labels])`` over the
     global batch's B x S positions, divided by B x S.
+
+    With blocks of a learned selection ("S"): ``positions`` (B_local,
+    streams, S) integers, the rotary position streams of every token
+    (``rope_sections``), and the loss is the cross-entropy plus
+    ``index_loss_coef`` x the sum over those blocks of their indexer's
+    loss, each a mean over the global batch's tokens.  Causality and the
+    selection go by a token's index in the sequence, not by these values.
     """
     _check_layout(cfg, par)
     s_full = cfg.seq_len
@@ -1094,6 +1275,14 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
             "2 x seq_len positions and, after labels, weights of seq_len: "
             f"got tokens {tokens.shape}, weights "
             f"{None if weights is None else weights.shape}")
+    if _selects(cfg) != (positions is not None) or (
+            positions is not None and par.n_microbatches != 1):
+        raise ValueError(
+            "a configuration with \"S\" blocks, and no other, takes the "
+            "position streams (B, streams, seq_len) after labels, in one "
+            f"microbatch: got positions "
+            f"{None if positions is None else positions.shape}, "
+            f"n_microbatches {par.n_microbatches}")
     s_local = tokens.shape[1] // mp_size
     mp_idx = lax.axis_index("mp")
 
@@ -1109,7 +1298,7 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     # Pipeline over pp with GPipe microbatching.
     xs = pp_lib.stack_microbatches(x, par.n_microbatches)
     stage_params = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
-    stage_fn = _make_stage_fn(cfg)
+    stage_fn = _make_stage_fn(cfg, positions)
     if _routes_dropless(cfg) and (axis_size("pp") > 1
                                   or par.pp_schedule != "gpipe"):
         raise NotImplementedError(
@@ -1133,10 +1322,14 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     else:
         raise ValueError(
             f"unknown pp_schedule {par.pp_schedule!r} (gpipe | 1f1b)")
-    if _routes_dropless(cfg):
+    side = {}
+    if _routes_dropless(cfg) or _selects(cfg):
         # (n_micro, layers, ...) sums over each microbatch's tokens.
-        out, stats = out
-        stats = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), stats)
+        out, side = out
+        side = jax.tree_util.tree_map(lambda a: jnp.sum(a, axis=0), side)
+        if not _selects(cfg):
+            side = {"router": side}
+    stats = side.get("router")
     hidden = pp_lib.unstack_microbatches(out)            # (B_local, s_local, d)
 
     # Final norm + logits (tied to the embedding, or ``lm_head``) + CE on
@@ -1169,6 +1362,11 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
     else:
         loss = lax.pmean(lax.pmean(loss_local, "mp"), "dp")
     loss = lax.psum(loss * pp_lib.last_stage_mask("pp"), "pp")
+    if "index" in side:
+        # Each block's is a mean over this shard's tokens; dp's shards are
+        # equal.
+        loss = loss + cfg.index_loss_coef * lax.pmean(
+            jnp.sum(side["index"]), "dp")
     if not _routes_dropless(cfg):
         return loss
     # A product of two means depends on which tokens are averaged: sum the
@@ -1188,19 +1386,23 @@ def forward_loss(cfg: TransformerConfig, par: ParallelConfig,
 def make_loss_fn(cfg: TransformerConfig, par: ParallelConfig, mesh,
                  with_routing: bool = False):
     """Global-array loss: shard_map of ``forward_loss`` over (dp, pp, mp),
-    ``loss_of(params, tokens, labels)``; a ``diffusion_block``
-    configuration's takes its third batch array, ``weights``, last."""
+    ``loss_of(params, tokens, labels)``; a configuration whose batch brings
+    a third array (``batch_extras``: a ``diffusion_block``'s ``weights``, a
+    learned selection's ``positions``) takes it last."""
     from ..compat import shard_map
     specs = param_specs(cfg, par)
     data_spec = P("dp")
 
-    def loss_of(params, tokens, labels, *weights):
+    # ``forward_loss`` takes ``weights``, then ``positions``.
+    ahead = (None,) if batch_extras(cfg) == ("positions",) else ()
+
+    def loss_of(params, tokens, labels, *extras):
         fn = shard_map(
-            lambda p, t, l, *w: forward_loss(cfg, par, p, t, l, with_routing,
-                                             *w),
-            mesh=mesh, in_specs=(specs,) + (data_spec,) * (2 + len(weights)),
+            lambda p, t, l, *x: forward_loss(cfg, par, p, t, l, with_routing,
+                                             *ahead, *x),
+            mesh=mesh, in_specs=(specs,) + (data_spec,) * (2 + len(extras)),
             out_specs=P(), check_vma=False)
-        return fn(params, tokens, labels, *weights)
+        return fn(params, tokens, labels, *extras)
 
     return loss_of
 
@@ -1358,7 +1560,8 @@ def make_train_step(cfg: TransformerConfig, par: ParallelConfig, mesh,
 def synthetic_batch(key, cfg: TransformerConfig, batch: int):
     """A random batch for ``cfg``: (tokens, next-token labels), or for a
     ``diffusion_block`` configuration :func:`noised_batch`'s three arrays,
-    the mask token being the vocabulary's last id and the data the others."""
+    the mask token being the vocabulary's last id and the data the others,
+    or with blocks of a learned selection also the position streams."""
     kt, kl = jax.random.split(key)
     if cfg.diffusion_block is not None:
         ids = jax.random.randint(kt, (batch, cfg.seq_len), 0,
@@ -1367,6 +1570,11 @@ def synthetic_batch(key, cfg: TransformerConfig, batch: int):
     tokens = jax.random.randint(kt, (batch, cfg.seq_len), 0, cfg.vocab_size,
                                 dtype=jnp.int32)
     labels = jnp.roll(tokens, -1, axis=1)
+    if _selects(cfg):
+        # Text: the three streams all count the tokens.
+        return tokens, labels, jnp.broadcast_to(
+            jnp.arange(cfg.seq_len, dtype=jnp.int32),
+            (batch, len(cfg.rope_sections), cfg.seq_len))
     return tokens, labels
 
 

@@ -168,6 +168,19 @@ unmasked pair (24 of 64 at n = 4; a causal call of the same 2L positions has
 ``hvd_flash_bwd_dq_bd`` and ``hvd_flash_bwd_dkv_bd``, and dQ's resident
 scratch covers all 2L queries (24 MiB of VMEM at 8192 x 128).
 
+A selected call (``visible_t``: learned sparse attention, where a query sees
+of its causal keys those an indexer chose, ``ops/sparse_index.py``) has a
+mask that is data, made by the step itself and different for every query, so
+no list can be laid out from it: it walks the causal call's list and the
+bodies mask each tile by the matching (bk, bq) tile of the int8 visibility
+array, one more operand of the forward and of the backward pass, every
+head's alike.  The kernels are named ``hvd_flash_fwd_sel``,
+``hvd_flash_bwd_dq_sel`` and ``hvd_flash_bwd_dkv_sel``, and such a call also
+returns ``lse`` (the indexer's loss reads the heads' probabilities from it).
+Every causal tile is visited, whatever share of its pairs was chosen: at
+16,384 positions and 2,048 keys a query that is 4.3 x the chosen pairs'
+work (PERF.md section 5, PR 49; ROADMAP M11).
+
 Three kernels, two of which see scores:
 
 * ``_fwd_kernel`` (``hvd_flash_fwd``) — out + logsumexp, online softmax over
@@ -197,7 +210,8 @@ Three kernels, two of which see scores:
 Public API:
 
 * ``flash_attention(q, k, v, causal=…, window=…, diffusion_block=…)`` —
-  differentiable (custom VJP).
+  differentiable (custom VJP); with ``visible_t=`` a selected call
+  (``flash_attention_selected``), which returns ``(out, lse)``.
 * ``flash_attention_with_lse`` — also returns logsumexp rows, which is the
   composition hook ring attention (parallel/ring_attention.py) uses to
   merge per-ring-step partials into an exact global softmax.
@@ -277,12 +291,14 @@ def _pick_block(size: int, widest: int) -> Optional[int]:
     return size if size <= _NARROW_BLOCK else None
 
 
-def _suffix(window: Optional[int], bd=None) -> str:
-    """A windowed or block-diffusion call's kernels carry their own names:
-    the same prefixes, so a reader that matches kernels by prefix counts
-    them, and a suffix that tells them apart."""
+def _suffix(window: Optional[int], bd=None, sel=None) -> str:
+    """A windowed, block-diffusion or selected call's kernels carry their
+    own names: the same prefixes, so a reader that matches kernels by prefix
+    counts them, and a suffix that tells them apart."""
     if bd is not None:
         return "_bd"
+    if sel is not None:
+        return "_sel"
     return "" if window is None else "_win"
 
 
@@ -291,11 +307,13 @@ def _suffix(window: Optional[int], bd=None) -> str:
 # ---------------------------------------------------------------------------
 
 def _scores_t(q, k, *, causal: bool, scale: float, q_start, k_start,
-              window: Optional[int] = None, bd=None):
+              window: Optional[int] = None, bd=None, sel=None):
     """The transposed score tile Sᵀ = K·Qᵀ · scale, (bk, bq) fp32, from the
     caller's (bq, D) and (bk, D) tiles as they are; causally masked at the
     tile's global positions, with a ``window`` to the band ``q_pos -
-    window < k_pos <= q_pos``, with ``bd`` by the block-diffusion rule."""
+    window < k_pos <= q_pos``, with ``bd`` by the block-diffusion rule,
+    with ``sel`` (the tile of a selected call's visibility array, (bk, bq)
+    int32, non-zero where the query chose the key) to the chosen pairs."""
     st = jax.lax.dot_general(
         k, q, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -311,6 +329,8 @@ def _scores_t(q, k, *, causal: bool, scale: float, q_start, k_start,
         keep = q_pos >= k_pos
         if window is not None:
             keep = jnp.logical_and(keep, q_pos - k_pos < window)
+        if sel is not None:
+            keep = jnp.logical_and(keep, sel != 0)
         st = jnp.where(keep, st, _NEG_INF)
     return st
 
@@ -499,10 +519,25 @@ def _bd_quadrant(q_start, k_start, bd):
             block.bit_length() - 1)
 
 
-def _fwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
-                block_q: int, block_k: int, window: Optional[int] = None,
-                bd=None):
+def _selected(refs, selected: bool):
+    """(the visibility tile's ref or None, the other refs): a selected
+    call's kernels take it as their last input, ahead of their outputs."""
+    return (refs[0], refs[1:]) if selected else (None, refs)
+
+
+def _sel_tile(sel_ref):
+    """A selected call's (bk, bq) visibility tile as the mask compares it:
+    int8 in HBM and VMEM, widened once a step, for all the program's
+    heads."""
+    return None if sel_ref is None else sel_ref[0].astype(jnp.int32)
+
+
+def _fwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, *refs, causal: bool,
+                scale: float, block_q: int, block_k: int,
+                window: Optional[int] = None, bd=None,
+                selected: bool = False):
+    sel_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _selected(refs,
+                                                                 selected)
     # i: q tile; kt: k tile (innermost: scratch carries over a q tile's steps)
     i, kt, first, last = _step(walk_ref)
     g = m_scr.shape[0]                 # the program's heads
@@ -521,13 +556,15 @@ def _fwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(_step_live(walk_ref, causal, q_start, k_start, block_q))
     def _compute():
+        sel = _sel_tile(sel_ref)
         for j in range(g):            # head j's lanes of the (block, g·D) tile
             head = slice(j * d, (j + 1) * d)
             q = q_ref[0, :, head]     # (bq, D), the caller's type
             k = k_ref[0, :, head]     # (bk, D)
             v = v_ref[0, :, head]
             st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
-                           k_start=k_start, window=window, bd=bd)  # (bk, bq)
+                           k_start=k_start, window=window, bd=bd,
+                           sel=sel)                                # (bk, bq)
             m_prev = m_scr[j, :1, :]                               # (1, bq)
             l_prev = l_scr[j, :1, :]
             m_cur = jnp.max(st, axis=0, keepdims=True)
@@ -556,31 +593,52 @@ def _fwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0] = jnp.transpose(jnp.concatenate(rows)).astype(o_ref.dtype)
 
 
+def _sel_built(kernel: str) -> None:
+    registry().counter(
+        "hvd_sparse_attention_built_total",
+        "flash kernels built for a selected call (a data-dependent set of "
+        "visible keys a query), as traced, by kernel", kernel=kernel).inc()
+
+
+# What a selected call's visibility tile adds to a kernel's VMEM: the int8
+# block twice (1 MiB each at 1024 x 1024) and its int32 widening.
+_SEL_VMEM_BYTES = 8 << 20
+
+
 def _fwd_call(q, k, v, offsets, *, heads, group, causal, scale, block_q,
-              block_k, interpret, window=None, bd=None, static_offsets=None):
+              block_k, interpret, window=None, bd=None, static_offsets=None,
+              sel=None):
     """q (B, Sq, H·D), k and v (B, Sk, H·D), the callers' rows as they lie;
-    ``heads`` = H, ``group`` = the heads a program takes (``_supported``).
-    (out (B, Sq, H·D), lse (B, H, Sq) fp32)."""
+    ``heads`` = H, ``group`` = the heads a program takes (``_supported``);
+    ``sel`` (B, Sk, Sq) int8, a selected call's visibility, key-major as the
+    bodies' score tile is.  (out (B, Sq, H·D), lse (B, H, Sq) fp32)."""
     b, sq, hd = q.shape
     sk, d, g = k.shape[1], hd // heads, group
-    name = "hvd_flash_fwd" + _suffix(window, bd)
+    name = "hvd_flash_fwd" + _suffix(window, bd, sel)
     grid, walk, spec = _walk(name, b, heads, g, sq, sk, block_q, block_k,
                              causal, window, bd, static_offsets, True)
     # Head(s) hg's lanes of tile i of the rows: a (block, g·D) block of the
     # (B, S, H·D) array, 128 lanes wide or the whole last dimension.
     q_spec = spec((1, block_q, g * d), lambda b, hg, i, kt: (b, i, hg))
     k_spec = spec((1, block_k, g * d), lambda b, hg, i, kt: (b, kt, hg))
+    selected = ()
+    if sel is not None:
+        # The (bk, bq) tile of the visibility array, every head's alike.
+        _sel_built(name)
+        selected = ((spec((1, block_k, block_q),
+                          lambda b, hg, i, kt: (b, kt, i)), sel),)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, *((None,) if not walk else ()),
                           causal=causal, scale=scale, block_q=block_q,
-                          block_k=block_k, window=window, bd=bd),
+                          block_k=block_k, window=window, bd=bd,
+                          selected=bool(selected)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 2), lambda *_: (0, 0),
                              memory_space=pltpu.SMEM),
-                q_spec, k_spec, k_spec,
+                q_spec, k_spec, k_spec, *(s for s, _ in selected),
             ],
             out_specs=[
                 q_spec,
@@ -602,10 +660,12 @@ def _fwd_call(q, k, v, offsets, *, heads, group, causal, scale, block_q,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(grid) - 1)
-            + ("arbitrary",)),
+            + ("arbitrary",),
+            **(dict(vmem_limit_bytes=_SCOPED_VMEM_BYTES + _SEL_VMEM_BYTES)
+               if selected else {})),
         interpret=interpret,
         name=name,
-    )(*walk, offsets, q, k, v)
+    )(*walk, offsets, q, k, v, *(a for _, a in selected))
     return out, lse[:, :, 0, :]
 
 
@@ -614,14 +674,16 @@ def _fwd_call(q, k, v, offsets, *, heads, group, causal, scale, block_q,
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                delta_ref, dk_ref, dv_ref, dqt_ref, dk_scr, dv_scr, dq_scr, *,
-                causal: bool, scale: float, block_q: int, block_k: int,
-                nk: int, window: Optional[int] = None, bd=None):
+                delta_ref, *refs, causal: bool, scale: float, block_q: int,
+                block_k: int, nk: int, window: Optional[int] = None, bd=None,
+                selected: bool = False):
     """The one pass over the score tiles: a key tile resident, query tiles
     streamed past it.  dK and dV of the resident tile are summed over its
     steps; dQᵀ of every query tile of the program's heads lives in
     ``dq_scr`` and is summed over the resident tiles, in ascending key
     tile."""
+    sel_ref, (dk_ref, dv_ref, dqt_ref, dk_scr, dv_scr, dq_scr) = _selected(
+        refs, selected)
     # i: k tile (scratch dq carries over i); qt: q tile (innermost)
     i, qt, first, last = _step(walk_ref)
     g = lse_ref.shape[1]               # the program's heads
@@ -643,6 +705,7 @@ def _bwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(_step_live(walk_ref, causal, q_start, k_start, block_q))
     def _compute():
+        sel = _sel_tile(sel_ref)
         for j in range(g):            # head j's lanes of the (block, g·D) tile
             head = slice(j * d, (j + 1) * d)
             q = q_ref[0, :, head]                                 # (bq, D)
@@ -652,7 +715,8 @@ def _bwd_kernel(walk_ref, off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             lse = lse_ref[0, j][:1, :]                             # (1, bq)
             delta = delta_ref[0, j][:1, :]
             st = _scores_t(q, k, causal=causal, scale=scale, q_start=q_start,
-                           k_start=k_start, window=window, bd=bd)  # (bk, bq)
+                           k_start=k_start, window=window, bd=bd,
+                           sel=sel)                                # (bk, bq)
             pt = jnp.where(jnp.logical_or(st <= _NEG_INF / 2,
                                           lse <= _NEG_INF / 2),
                            0.0, jnp.exp(st - lse))                 # (bk, bq)
@@ -735,16 +799,17 @@ def _bwd_vmem_limit(sq: int, lanes: int, dtype) -> int:
 
 def _bwd_call(q, k, v, do, lse, delta, offsets, *, heads, group, causal,
               scale, block_q, block_k, interpret, window=None, bd=None,
-              static_offsets=None):
+              static_offsets=None, sel=None):
     """q, dO (B, Sq, H·D), k and v (B, Sk, H·D), the callers' rows as they
     lie; lse and delta (B, H, Sq) fp32; ``heads`` = H, ``group`` = the heads
-    a program takes (``_supported``).  (dQ, dK, dV) in the operands'
-    layout."""
+    a program takes (``_supported``); ``sel`` as ``_fwd_call``'s.  (dQ, dK,
+    dV) in the operands' layout."""
     b, sq, hd = q.shape
     sk, h, g = k.shape[1], heads, group
     d = hd // h
     nq = sq // block_q
-    vmem_limit = _bwd_vmem_limit(sq, g * d, q.dtype)
+    vmem_limit = _bwd_vmem_limit(sq, g * d, q.dtype) + (
+        _SEL_VMEM_BYTES if sel is not None else 0)
     if vmem_limit > _VMEM_CEILING_BYTES:
         raise ValueError(
             f"the flash backward keeps the dQ of a program's {g} head(s) "
@@ -759,17 +824,22 @@ def _bwd_call(q, k, v, do, lse, delta, offsets, *, heads, group, causal,
     # The pass: a key tile resident, its query tiles in a row.  dQᵀ's block
     # is the program's heads' whole, resident from its first step to its
     # last.
-    name = "hvd_flash_bwd_dkv" + _suffix(window, bd)
+    name = "hvd_flash_bwd_dkv" + _suffix(window, bd, sel)
     grid, walk, spec = _walk(name, b, h, g, sq, sk, block_q, block_k, causal,
                              window, bd, static_offsets, False)
     q_spec = spec((1, block_q, g * d), lambda b, hg, i, qt: (b, qt, hg))
     k_spec = spec((1, block_k, g * d), lambda b, hg, i, qt: (b, i, hg))
     row_spec = spec((1, g, 8, block_q), lambda b, hg, i, qt: (b, hg, 0, qt))
+    selected = ()
+    if sel is not None:
+        _sel_built(name)
+        selected = ((spec((1, block_k, block_q),
+                          lambda b, hg, i, qt: (b, i, qt)), sel),)
     dk, dv, dqt = pl.pallas_call(
         functools.partial(_bwd_kernel, *((None,) if not walk else ()),
                           causal=causal, scale=scale, block_q=block_q,
                           block_k=block_k, nk=sk // block_k, window=window,
-                          bd=bd),
+                          bd=bd, selected=bool(selected)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(walk),
             grid=grid,
@@ -777,6 +847,7 @@ def _bwd_call(q, k, v, do, lse, delta, offsets, *, heads, group, causal,
                 pl.BlockSpec((1, 2), lambda *_: (0, 0),
                              memory_space=pltpu.SMEM),
                 q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
+                *(s for s, _ in selected),
             ],
             out_specs=[
                 k_spec, k_spec,
@@ -802,7 +873,7 @@ def _bwd_call(q, k, v, do, lse, delta, offsets, *, heads, group, causal,
             vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name=name,
-    )(*walk, offsets, q, k, v, do, lse, delta)
+    )(*walk, offsets, q, k, v, do, lse, delta, *(a for _, a in selected))
 
     # dQ: no dot, no exp; a read of dQᵀ's tiles and a write of dQ's rows,
     # ``hb`` heads a step (whole programs' worth), or one program's heads in
@@ -813,8 +884,10 @@ def _bwd_call(q, k, v, do, lse, delta, offsets, *, heads, group, causal,
              if h % n == 0 and (n == g or n * sq * d * itemsize
                                 <= _DQ_BLOCK_BYTES))
     pieces = _dq_pieces(nq, block_q, g * d, itemsize) if hb == g else 1
-    name = "hvd_flash_bwd_dq" + _suffix(window, bd)
+    name = "hvd_flash_bwd_dq" + _suffix(window, bd, sel)
     _kernel_built(name, g)
+    if sel is not None:
+        _sel_built(name)
     dq = pl.pallas_call(
         _bwd_dq_kernel,
         grid=(b, h // hb, pieces),
@@ -897,6 +970,56 @@ def _flash_bwd(causal, scale, tiling, interpret, window, bd, static_offsets,
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+# A selected call: causal, one whole sequence, and of the causal keys a query
+# sees those its row of ``sel`` marks.  The set is made by the step itself
+# (``ops/sparse_index.py``), so it is an operand and not a rule: the walk is
+# the causal call's list, the bodies mask a tile by the operand's tile.  The
+# caller needs ``lse`` too (the indexer's loss takes the heads'
+# probabilities from it), so it is a second result; nothing is
+# differentiated through it or through ``sel``.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_sel(q, k, v, sel, scale, tiling, interpret):
+    out, lse = _flash_sel_impl(q, k, v, sel, scale, tiling, interpret)
+    return out.reshape(q.shape), lse
+
+
+def _flash_sel_impl(q, k, v, sel, scale, tiling, interpret):
+    offsets, static_offsets = _offsets(0, 0)
+    return _fwd_call(_rows(q), _rows(k), _rows(v), offsets,
+                     heads=q.shape[2], group=tiling.group, causal=True,
+                     scale=scale, block_q=tiling.block_q,
+                     block_k=tiling.block_k, interpret=interpret,
+                     static_offsets=static_offsets, sel=sel)
+
+
+def _flash_sel_fwd(q, k, v, sel, scale, tiling, interpret):
+    out, lse = _flash_sel_impl(q, k, v, sel, scale, tiling, interpret)
+    # The saved names of every call (``_flash_fwd``).
+    out = checkpoint_name(out, SAVED_OUT).reshape(q.shape)
+    lse = checkpoint_name(lse, SAVED_LSE)
+    return (out, lse), (q, k, v, sel, out, lse)
+
+
+def _flash_sel_bwd(scale, tiling, interpret, res, g):
+    q, k, v, sel, out, lse = res
+    g = g[0]                       # lse's cotangent: nothing reads it
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1)                # (B, H, Sq)
+    offsets, static_offsets = _offsets(0, 0)
+    dq, dk, dv = _bwd_call(_rows(q), _rows(k), _rows(v), _rows(g), lse,
+                           delta, offsets, heads=q.shape[2],
+                           group=tiling.group, causal=True, scale=scale,
+                           block_q=tiling.block_q, block_k=tiling.block_k,
+                           interpret=interpret,
+                           static_offsets=static_offsets, sel=sel)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            np.zeros(sel.shape, dtype=jax.dtypes.float0))
+
+
+_flash_sel.defvjp(_flash_sel_fwd, _flash_sel_bwd)
 
 
 def _heads_per_program(h: int, d: int) -> Optional[int]:
@@ -1027,7 +1150,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: Optional[int] = None,
                     interpret: bool = False,
                     window: Optional[int] = None,
-                    diffusion_block: Optional[int] = None) -> jax.Array:
+                    diffusion_block: Optional[int] = None,
+                    visible_t: Optional[jax.Array] = None):
     """Differentiable fused attention; (B, S, H, D) in and out.
 
     ``window`` (causal only) keeps for each query the ``window`` keys up to
@@ -1037,7 +1161,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     noised block and the clean blocks strictly before it, a clean query the
     clean blocks up to its own (:func:`diffusion_mask`).  A shape the
     kernels cannot tile (``_supported`` is None) takes the XLA path with the
-    same semantics."""
+    same semantics.  ``visible_t`` (causal only, no window, no
+    ``diffusion_block``, no offsets): a selected call,
+    :func:`flash_attention_selected`, whose result is ``(out, lse)``."""
+    if visible_t is not None:
+        if not causal or window is not None or diffusion_block is not None \
+                or (q_offset, kv_offset) != (0, 0):
+            raise ValueError("a selected call is a causal call of one whole "
+                             "sequence, exclusive with a window and a "
+                             "diffusion_block")
+        return flash_attention_selected(q, k, v, visible_t, scale, block_q,
+                                        block_k, interpret)
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     bd = _checked_diffusion(diffusion_block, causal, window, q, k, q_offset,
@@ -1068,6 +1202,42 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return _flash(q, k, v, offsets, causal, float(scale),
                   tiling._replace(block_q=bq, block_k=bk), bool(interpret),
                   window, bd, static_offsets)
+
+
+def flash_attention_selected(q: jax.Array, k: jax.Array, v: jax.Array,
+                             visible_t: jax.Array,
+                             scale: Optional[float] = None,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
+                             interpret: bool = False):
+    """Causal attention over a data-dependent set of keys a query: ``(out,
+    lse)``, differentiable in q, k, v; (B, S, H, D) in and out, ``lse`` (B,
+    H, S) fp32.  ``visible_t`` (B, S keys, S queries) int8, non-zero where
+    the query (last axis) chose the key: of the keys at or before it a
+    query sees those and no other.  One whole sequence, no offsets.  The
+    kernels are ``hvd_flash_fwd_sel``, ``hvd_flash_bwd_dkv_sel`` and
+    ``hvd_flash_bwd_dq_sel``: the causal call's bodies, tiling and walk,
+    with the visibility tile as one more operand.  A shape the kernels
+    cannot tile takes the XLA path with the same semantics."""
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    b, s = q.shape[:2]
+    if k.shape[1] != s or visible_t.shape != (b, s, s) or (
+            visible_t.dtype != jnp.int8):
+        raise ValueError(
+            f"a selected call takes one whole sequence and its (B, S, S) "
+            f"int8 visibility, keys by queries: got q {q.shape}, k "
+            f"{k.shape}, visible_t {visible_t.shape} {visible_t.dtype}")
+    tiling = _supported(q, k)
+    if tiling is None:
+        return _xla_attention_with_lse(q, k, v, True, scale, 0, 0,
+                                       visible_t=visible_t)
+    bq, bk = block_q or tiling.block_q, block_k or tiling.block_k
+    if s % bq or s % bk:
+        raise ValueError(f"block_q={bq} and block_k={bk} must divide {s}")
+    return _flash_sel(q, k, v, visible_t, float(scale),
+                      tiling._replace(block_q=bq, block_k=bk),
+                      bool(interpret))
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True,
@@ -1110,7 +1280,8 @@ def diffusion_mask(length: int, block: int) -> jax.Array:
 
 
 def _xla_attention_with_lse(q, k, v, causal, scale, q_offset, kv_offset,
-                            window=None, diffusion_block=None):
+                            window=None, diffusion_block=None,
+                            visible_t=None):
     """XLA fallback with identical (out, lse) semantics."""
     sq, sk = q.shape[1], k.shape[1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -1125,6 +1296,9 @@ def _xla_attention_with_lse(q, k, v, causal, scale, q_offset, kv_offset,
         if window is not None:
             mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
+        if visible_t is not None:
+            s = jnp.where(jnp.swapaxes(visible_t, 1, 2)[:, None] != 0, s,
+                          _NEG_INF)
     m = jnp.max(s, axis=-1)
     m_safe = jnp.maximum(m, _NEG_INF / 2)
     p = jnp.where(s <= _NEG_INF / 2, 0.0, jnp.exp(s - m_safe[..., None]))

@@ -262,13 +262,16 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def reference_attention(q, k, v, causal: bool = True,
                         scale: Optional[float] = None,
                         window: Optional[int] = None,
-                        diffusion_block: Optional[int] = None) -> jax.Array:
+                        diffusion_block: Optional[int] = None,
+                        visible: Optional[jax.Array] = None) -> jax.Array:
     """Pure-XLA unsharded attention — the numerics oracle for tests.
     ``window`` (causal only): each query sees the ``window`` keys up to and
     including its own, ``q_pos - window < k_pos <= q_pos``.
     ``diffusion_block`` (causal only, no window): the block-diffusion mask
     over a noised and a clean half, built densely
-    (``ops/flash_attention.diffusion_mask``)."""
+    (``ops/flash_attention.diffusion_mask``).  ``visible`` (B, Sq, Sk)
+    bool, an explicit mask laid over the others: a query sees the keys its
+    row marks and no other."""
     b, sq, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -288,6 +291,8 @@ def reference_attention(q, k, v, causal: bool = True,
         if window is not None:
             mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
+    if visible is not None:
+        s = jnp.where(visible[:, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
@@ -299,11 +304,16 @@ def checkpoint_keeping_attention(layer_fn):
     flash forward kernel, whose output and ``lse`` are saved by name
     (``ops/flash_attention.py`` ``_flash_fwd``) — one (B, S, H·D) activation
     and one fp32 row statistic a layer.  The XLA path sets no names and is
-    recomputed whole."""
+    recomputed whole.  A layer of learned sparse attention also keeps what
+    its selection made (``ops/sparse_index.py``: the int8 (B, S, S)
+    visibility and the (B, S) log-normaliser of the indexer's loss), so the
+    recompute ranks nothing again."""
     from ..ops import flash_attention as fa
+    from ..ops import sparse_index as si
     return jax.checkpoint(
         layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
-            fa.SAVED_OUT, fa.SAVED_LSE))
+            fa.SAVED_OUT, fa.SAVED_LSE, si.SAVED_VISIBLE,
+            si.SAVED_INDEX_LSE))
 
 
 def full_attention(q, k, v, causal: bool = True,
@@ -329,3 +339,26 @@ def full_attention(q, k, v, causal: bool = True,
     return reference_attention(q, k, v, causal=causal, scale=scale,
                                window=window,
                                diffusion_block=diffusion_block)
+
+
+def selected_attention(q, k, v, visible_t, scale: Optional[float] = None,
+                       use_flash: Optional[bool] = None):
+    """Causal attention in which a query sees, of the keys at or before it,
+    those a selection chose: ``(out, lse)``, (B, S, H, D) and (B, H, S)
+    fp32.  ``visible_t`` (B, S keys, S queries) int8 is
+    ``ops/sparse_index.select``'s result, data the step made and not a rule
+    on positions, so it is an operand of the kernels
+    (``ops/flash_attention.flash_attention(visible_t=)``) where the dispatch
+    takes them and of the XLA path elsewhere.  Unsharded: a query's keys
+    live anywhere in the sequence."""
+    from ..ops import flash_attention as fa
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    if use_flash is None:
+        use_flash = (_flash_enabled(k.shape[1])
+                     and fa._supported(q, k) is not None)
+    if use_flash:
+        return fa.flash_attention(q, k, v, causal=True, scale=scale,
+                                  visible_t=visible_t)
+    return fa._xla_attention_with_lse(q, k, v, True, scale, 0, 0,
+                                      visible_t=visible_t)

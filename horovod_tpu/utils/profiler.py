@@ -17,7 +17,8 @@ on the host timeline of a captured trace alongside the device steps.
 
 * ``scope(name)`` — names a block of the *compiled* training step
   (``STEP_SCOPES``, ``MOE_SCOPES``, ``SSM_SCOPES``,
-  ``LATENT_MOE_SCOPES``, ``ATTN_PART_SCOPES``, ``DENSE_MLP_SCOPE``,
+  ``LATENT_MOE_SCOPES``, ``ATTN_PART_SCOPES``, ``INDEX_SCOPES``,
+  ``DENSE_MLP_SCOPE``,
   ``CONV_SCOPES``, ``TP_RING_SCOPES``): a
   ``jax.named_scope``, so the name
   lands in every HLO operation's ``op_name`` and from there in a device
@@ -85,6 +86,10 @@ LATENT_MOE_SCOPES = ("moe_latent", "moe_shared")
 # and k, the per-head gate on the attention's output, and the per-head
 # RMSNorm of q and k (``head_qk_norm``).
 ATTN_PART_SCOPES = ("attn_rope", "attn_gate", "attn_qknorm")
+# Inside the ``attn`` block of learned sparse attention: the indexer (its
+# projections, its scores, the choice), inside that the ranking alone, and
+# the indexer's loss, forward and backward (``ops/sparse_index.py``).
+INDEX_SCOPES = ("attn_index", "attn_select", "attn_index_loss")
 # A patterned model's gated dense MLP block, inside ``mlp`` (what is left of
 # ``mlp`` is then the expert blocks').
 DENSE_MLP_SCOPE = "mlp_dense"
