@@ -123,6 +123,11 @@ TIER_FAST=(
   test_perf_observatory.py
   test_probe_rendezvous.py
   test_quantization.py
+  # The fused q/k/v projection as three products of the stored wqkv's
+  # [q | k | v] reordering (ISSUE 51): both models' layers against the
+  # head-by-head form of the stored weights, one member and two; no
+  # activation of the traced layer interleaves q, k and v.
+  test_qkv_slabs.py
   test_recovery.py
   # Flat-shard layout math goldens (ISSUE 14): 1-D + (dp, mp) nested
   # reshard arithmetic every durability tier leans on.

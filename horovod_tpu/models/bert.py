@@ -112,16 +112,14 @@ def _encoder_layer(cfg: BertConfig, lp, x, *, sharded: bool):
     hd = cfg.head_dim
     with scope("attn"):
         h = _layernorm(x, lp["ln1"])
-        if sharded:
-            qkv = tp.column_parallel(h, lp["wqkv"].astype(x.dtype))
-        else:
-            qkv = jnp.einsum("bsd,de->bse", h, lp["wqkv"].astype(x.dtype))
-        b, s = qkv.shape[:2]
-        local_heads = qkv.shape[-1] // (3 * hd)
-        qkv = qkv.reshape(b, s, local_heads, 3, hd)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        # The stored wqkv's columns are head-major, q, k, v inside a head
+        # (an mp shard is whole heads): three products with its slabs.
+        b, s = h.shape[:2]
+        q, k, v = (
+            tp.column_parallel(h, w).reshape(b, s, -1, hd)
+            for w in tp.qkv_slabs(lp["wqkv"].astype(x.dtype), hd))
         o = ra.full_attention(q, k, v, causal=False)
-        o = o.reshape(b, s, local_heads * hd)
+        o = o.reshape(b, s, -1)
         if sharded:
             attn = tp.row_parallel(o, lp["wo"].astype(x.dtype), "mp",
                                    scatter_sequence=False)

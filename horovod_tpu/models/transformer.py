@@ -605,28 +605,26 @@ def _attention_block(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     """x: (mb, s_local, d) sequence-sharded over mp. Returns residual add."""
     h_heads, hd = cfg.n_heads, cfg.head_dim
     hnorm = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-    # wqkv layout: (d, h*3*hd) with heads outermost in the fused dim, so an
+    # wqkv is stored (d, h*3*hd) with heads outermost in the fused dim, so an
     # mp shard of the fused dim is a whole-head slice (q,k,v interleaved
-    # per head), making column-parallel == head-parallel.
+    # per head), making column-parallel == head-parallel; the step multiplies
+    # by its [q | k | v] reordering, three (d, heads*hd) slabs.
+    slabs = tp.qkv_slabs(lp["wqkv"].astype(x.dtype), hd)
     if cfg.attn_mode == "megatron":
         # gather sequence → heads-sharded attention → scatter sequence back.
-        local_heads = lp["wqkv"].shape[-1] // (3 * hd)
-        with _ring_scope(GATHER_RING):            # (mb, S, heads/mp, 3, hd)
-            qkv = tp.gather_column_parallel(
-                hnorm, lp["wqkv"], "mp", features=(local_heads, 3, hd))
-        mb, s_full = qkv.shape[0], qkv.shape[1]
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        with _ring_scope(GATHER_RING):            # each (mb, S, heads/mp * hd)
+            qkv = tp.gather_column_parallel(hnorm, slabs, "mp")
+        q, k, v = (t.reshape(t.shape[:2] + (-1, hd)) for t in qkv)
         # The sequence was gathered: positions 0 .. S-1; heads over mp.
-        q, k = _position_qk(cfg, lp, q, k, jnp.arange(s_full), "mp")
+        q, k = _position_qk(cfg, lp, q, k, jnp.arange(q.shape[1]), "mp")
         o = ra.full_attention(q, k, v, causal=True)
-        with _ring_scope(SCATTER_RING):      # o: (mb, S, heads/mp, hd) as is
-            return tp.row_parallel(o, lp["wo"], "mp", scatter_sequence=True,
-                                   feature_dims=2)
+        o = o.reshape(o.shape[:2] + (-1,))           # (mb, S, heads/mp * hd)
+        with _ring_scope(SCATTER_RING):
+            return tp.row_parallel(o, lp["wo"], "mp", scatter_sequence=True)
     else:  # ring/ulysses: sequence stays sharded through attention
-        qkv = jnp.einsum("bsd,de->bse", hnorm, lp["wqkv"].astype(x.dtype))
-        mb, s_local = qkv.shape[0], qkv.shape[1]
-        qkv = qkv.reshape(mb, s_local, h_heads, 3, hd)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+        mb, s_local = hnorm.shape[:2]
+        q, k, v = (tp.column_parallel(hnorm, w).reshape(
+            mb, s_local, h_heads, hd) for w in slabs)
         # This member's chunk of the sequence, every head of it.
         q, k = _position_qk(
             cfg, lp, q, k,

@@ -70,12 +70,9 @@ class RingRows(NamedTuple):
 
 def row_parallel(x_shard: Union[jax.Array, RingRows], w_shard: jax.Array,
                  axis_name: str, b: Optional[jax.Array] = None,
-                 scatter_sequence: bool = False,
-                 feature_dims: int = 1) -> jax.Array:
-    """(..., d_in/P) @ (d_in/P, d_out) -> psum -> (..., d_out).  The features
-    of ``x_shard`` may come as its last ``feature_dims`` dimensions ((...,
-    heads/P, head_dim) from attention: 2) and are folded here, piece by
-    piece where there are pieces; ``w_shard`` is cast to their type.
+                 scatter_sequence: bool = False) -> jax.Array:
+    """(..., d_in/P) @ (d_in/P, d_out) -> psum -> (..., d_out); ``w_shard``
+    is cast to ``x_shard``'s type.
 
     With ``scatter_sequence=True`` the psum becomes a reduce_scatter over the
     sequence dimension (dim -2), returning a sequence-sharded activation —
@@ -90,27 +87,21 @@ def row_parallel(x_shard: Union[jax.Array, RingRows], w_shard: jax.Array,
                          "scatter_sequence=True")
     p = axis_size(axis_name)
     d_in, d_out = w_shard.shape
-
-    def fold(t):
-        return t.reshape(t.shape[:-feature_dims] + (d_in,))
-
     if scatter_sequence and p > 1:
         if ring:
             parts = x_shard.parts
         else:
-            seq = x_shard.ndim - feature_dims - 1
+            seq = x_shard.ndim - 2
             s_loc = x_shard.shape[seq] // p
             rows = math.prod(x_shard.shape[:seq]) * s_loc
             parts = _take(x_shard, axis_name, _pieces(
                 s_loc, rows * d_out * x_shard.dtype.itemsize,
                 2.0 * rows * d_in * d_out, fused_add=True), seq)
-        parts = [fold(part) for part in parts]
         y = jnp.concatenate(_ring_matmul_scatter(
             parts, w_shard.astype(parts[0].dtype), axis_name), axis=-2)
     else:
         if ring:
             (x_shard,) = x_shard.parts
-        x_shard = fold(x_shard)
         partial_sum = jnp.einsum("...i,io->...o", x_shard,
                                  w_shard.astype(x_shard.dtype))
         if scatter_sequence:
@@ -124,23 +115,41 @@ def row_parallel(x_shard: Union[jax.Array, RingRows], w_shard: jax.Array,
     return y
 
 
-def gather_column_parallel(x: jax.Array, w_shard: jax.Array, axis_name: str,
-                           b_shard: Optional[jax.Array] = None,
-                           features: Optional[Sequence[int]] = None
-                           ) -> jax.Array:
+def qkv_slabs(wqkv: jax.Array, head_dim: int) -> Tuple[jax.Array, ...]:
+    """The q, k and v weights of a fused projection, each (d_in,
+    heads * head_dim) with heads in order.  ``wqkv`` is stored (d_in, heads *
+    3 * head_dim), heads outermost and q, k, v inside a head, so that an
+    ``mp`` shard of its columns is a slice of whole heads; here (a member's
+    shard of) it is reordered to [q | k | v] columns, a transpose the size of
+    the weight, and cut in three.  A product with a slab is (..., S, heads *
+    head_dim) in rows, as the attention kernels read it: no activation holds
+    q, k and v interleaved head by head, which XLA would write with S minor
+    (a minor dimension of 64 fills half a 128-lane tile) and every consumer
+    pay a transposing copy for."""
+    d_in = wqkv.shape[0]
+    slabs = wqkv.reshape(d_in, -1, 3, head_dim).swapaxes(1, 2)
+    return tuple(slabs[:, i].reshape(d_in, -1) for i in range(3))
+
+
+def gather_column_parallel(x: jax.Array,
+                           w_shard: Union[jax.Array, Sequence[jax.Array]],
+                           axis_name: str,
+                           b_shard: Optional[jax.Array] = None):
     """``column_parallel(gather_sequence(x), w_shard)`` for a sequence-sharded
     ``x`` (sequence on dim -2): (..., S/P, d_in) -> (..., S, d_out/P), rows in
     sequence order; ``w_shard`` is cast to ``x``'s type (fp32 master weights
-    under bf16 compute), and with ``features`` the product's last dimension
-    is reshaped to them ((heads, 3, head_dim) in front of attention).  Over
-    more than one member the gather is the ring of the module docstring, the
-    products then put in sequence order."""
-    parts, bounds = _gather_matmul(x, w_shard, b_shard, axis_name, False)
-    if features is not None:
-        parts = [y.reshape(y.shape[:-1] + tuple(features)) for y in parts]
-    if len(parts) == 1:
-        return parts[0]
-    return _place(parts, axis_name, bounds, x.ndim - 2)
+    under bf16 compute).  Several weights of the one input (:func:`qkv_slabs`
+    in front of attention; no bias then) give a product each, a tuple.  Over
+    more than one member the gather is the ring of the module docstring, each
+    piece multiplied by every weight, the products then put in sequence
+    order."""
+    several = isinstance(w_shard, (tuple, list))
+    products, bounds = _gather_matmul(
+        x, w_shard if several else (w_shard,), b_shard, axis_name, False)
+    placed = tuple(parts[0] if len(parts) == 1 else
+                   _place(parts, axis_name, bounds, x.ndim - 2)
+                   for parts in products)
+    return placed if several else placed[0]
 
 
 def gather_column_parallel_ring(x: jax.Array, w_shard: jax.Array,
@@ -151,20 +160,21 @@ def gather_column_parallel_ring(x: jax.Array, w_shard: jax.Array,
     (an MLP's activation, then ``row_parallel(..., scatter_sequence=True)``):
     the rows stay in the order the ring brought them, so nothing is copied
     into sequence order and back."""
-    return RingRows(tuple(
-        _gather_matmul(x, w_shard, b_shard, axis_name, True)[0]))
+    (parts,), _ = _gather_matmul(x, (w_shard,), b_shard, axis_name, True)
+    return RingRows(tuple(parts))
 
 
-def _gather_matmul(x, w_shard, b_shard, axis_name: str,
+def _gather_matmul(x, weights, b_shard, axis_name: str,
                    scattered_behind: bool):
-    """The products of the gathered rows and their bounds: one member's
-    plain all-gather and matmul, whole, or the ring's, in ring order."""
+    """The products of the gathered rows with each of ``weights``, a list of
+    parts a weight, and the parts' bounds: one member's plain all-gather and
+    matmuls, whole, or the ring's, in ring order."""
     if axis_size(axis_name) == 1:
         gathered = gather_sequence(x, axis_name, dim=x.ndim - 2)
-        return [column_parallel(gathered, w_shard.astype(x.dtype),
-                                b_shard)], ((0, x.shape[-2]),)
-    return _ring_gather_matmul(x, w_shard.astype(x.dtype), b_shard,
-                               axis_name, scattered_behind)
+        return ([[column_parallel(gathered, w.astype(x.dtype), b_shard)]
+                 for w in weights], ((0, x.shape[-2]),))
+    return _ring_gather_matmul(x, [w.astype(x.dtype) for w in weights],
+                               b_shard, axis_name, scattered_behind)
 
 
 def _ring_built(form: str) -> None:
@@ -204,28 +214,32 @@ def _pieces(s_loc: int, hop_bytes: int, hop_flops: float,
     return tuple((s_loc * i // k, s_loc * (i + 1) // k) for i in range(k))
 
 
-def _ring_gather_matmul(x: jax.Array, w_shard: jax.Array,
+def _ring_gather_matmul(x: jax.Array, weights: Sequence[jax.Array],
                         b_shard: Optional[jax.Array], axis_name: str,
                         scattered_behind: bool):
-    """``column_parallel`` of every chunk of the gathered sequence, as the
-    ring brings them: the pieces held are multiplied while the next chunk's
-    arrive.  ``scattered_behind``: the products go on to a ring scatter as
-    they are, which wants a chunk in two pieces at the least.  (The products
-    in ring order, the bounds a chunk was cut at.)"""
+    """``column_parallel`` of every chunk of the gathered sequence with each
+    of ``weights``, as the ring brings them: the pieces held are multiplied
+    while the next chunk's arrive.  ``scattered_behind``: the products go on
+    to a ring scatter as they are, which wants a chunk in two pieces at the
+    least.  (The products in ring order, a list a weight; the bounds a chunk
+    was cut at, which hang on the weights' widths together.)"""
     p = axis_size(axis_name)
     seq = x.ndim - 2
     rows = math.prod(x.shape[:-1])
     bounds = _pieces(x.shape[seq], rows * x.shape[-1] * x.dtype.itemsize,
-                     2.0 * rows * x.shape[-1] * w_shard.shape[-1],
+                     2.0 * rows * x.shape[-1]
+                     * sum(w.shape[-1] for w in weights),
                      fused_add=scattered_behind)
     _ring_built("gather")
     held = [lax.slice_in_dim(x, lo, hi, axis=seq) for lo, hi in bounds]
-    out = []
+    out = [[] for _ in weights]
     for hop in range(p):
         if hop < p - 1:
             arriving = [lax.ppermute(c, axis_name, _ring_perm(p))
                         for c in held]
-        out += [column_parallel(c, w_shard, b_shard) for c in held]
+        for c in held:
+            for products, w in zip(out, weights):
+                products.append(column_parallel(c, w, b_shard))
         held = arriving
     return out, bounds
 

@@ -326,19 +326,19 @@ def test_ring_pieces_come_from_the_shapes():
 
 
 def _parent_attention_block(cfg, lp, x):
-    """``models/transformer._attention_block`` (megatron mode) as it stood
-    before the ring: gather -> einsum -> attention -> einsum -> scatter."""
+    """``models/transformer._attention_block`` (megatron mode) with the
+    plain collectives that stood before the ring: gather -> the q, k and v
+    einsums -> attention -> einsum -> scatter."""
     hd = cfg.head_dim
     hnorm = tfm._rmsnorm(x, lp["ln1"], cfg.norm_eps)
+    slabs = tp.qkv_slabs(lp["wqkv"].astype(x.dtype), hd)
     hg = tp.gather_sequence(hnorm, "mp", dim=1)
-    qkv = tp.column_parallel(hg, lp["wqkv"].astype(x.dtype))
-    mb, s_full = qkv.shape[0], qkv.shape[1]
-    local_heads = qkv.shape[-1] // (3 * hd)
-    qkv = qkv.reshape(mb, s_full, local_heads, 3, hd)
-    q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    mb, s_full = hg.shape[:2]
+    qkv = [tp.column_parallel(hg, w) for w in slabs]
+    q, k, v = (t.reshape(mb, s_full, -1, hd) for t in qkv)
     q, k = tfm._position_qk(cfg, lp, q, k, jnp.arange(s_full), "mp")
     o = ra.full_attention(q, k, v, causal=True)
-    o = o.reshape(mb, s_full, local_heads * hd)
+    o = o.reshape(mb, s_full, -1)
     partial = jnp.einsum("...i,io->...o", o, lp["wo"].astype(x.dtype))
     return jax.lax.psum_scatter(partial, "mp", scatter_dimension=1,
                                 tiled=True)
