@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
-from ..utils.profiler import scope
+from ..utils.profiler import LAYERS_SCOPE, scope
 
 IGNORE_INDEX = -100
 
@@ -161,7 +161,8 @@ def _encode(cfg: BertConfig, params, tokens, *, sharded: bool):
         fn = ra.checkpoint_keeping_attention(body)
     else:
         fn = body
-    x, _ = lax.scan(fn, x, params["layers"])
+    with scope(LAYERS_SCOPE):
+        x, _ = lax.scan(fn, x, params["layers"])
     return x
 
 

@@ -77,7 +77,8 @@ from ..parallel import moe as moe_lib
 from ..parallel import pipeline as pp_lib
 from ..parallel import ring_attention as ra
 from ..parallel import tensor_parallel as tp
-from ..utils.profiler import (CONV_SCOPES, DENSE_MLP_SCOPE, INDEX_SCOPES,
+from ..utils.profiler import (ATTN_OPERAND_SCOPES, CONV_SCOPES,
+                              DENSE_MLP_SCOPE, INDEX_SCOPES, LAYERS_SCOPE,
                               TP_RING_SCOPES, scope)
 
 GATHER_RING, SCATTER_RING = TP_RING_SCOPES
@@ -584,11 +585,13 @@ def _position_qk(cfg: TransformerConfig, lp, q, k, positions, axis_name):
     the attention: nothing (learned positions were added to the stream),
     or OLMoE's QK-norm and rotary positions."""
     if cfg.qk_norm:
-        q = _qk_norm(q, lp["q_norm"], cfg.norm_eps, axis_name)
-        k = _qk_norm(k, lp["k_norm"], cfg.norm_eps, axis_name)
+        with scope("attn_qknorm"):
+            q = _qk_norm(q, lp["q_norm"], cfg.norm_eps, axis_name)
+            k = _qk_norm(k, lp["k_norm"], cfg.norm_eps, axis_name)
     if cfg.rope_theta is not None:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        with scope("attn_rope"):
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
     return q, k
 
 
@@ -868,7 +871,8 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                     for t in (q, k))
     k_own = k
     if hkv != hq:
-        k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
+        with scope(ATTN_OPERAND_SCOPES[0]):
+            k, v = (jnp.repeat(t, hq // hkv, axis=2) for t in (k, v))
     if a.index is None:
         o = ra.full_attention(q, k, v, causal=True, window=a.window,
                               diffusion_block=cfg.diffusion_block)
@@ -1194,11 +1198,12 @@ def _make_pattern_stage_fn(cfg: TransformerConfig, positions=None):
             for name, found in side.items() if found}
 
     def stage_fn(stage_params, act):
-        if cfg.leading_pattern:
-            stage_params = dict(stage_params)
-            act, _ = run_blocks(cfg.leading_pattern, act,
-                                stage_params.pop("leading"))
-        out, side = lax.scan(period_fn, act, stage_params)
+        with scope(LAYERS_SCOPE):
+            if cfg.leading_pattern:
+                stage_params = dict(stage_params)
+                act, _ = run_blocks(cfg.leading_pattern, act,
+                                    stage_params.pop("leading"))
+            out, side = lax.scan(period_fn, act, stage_params)
         if set(side) == {"router"}:
             side = side["router"]
         return (out, side) if side else out
@@ -1227,7 +1232,8 @@ def _make_stage_fn(cfg: TransformerConfig, positions=None):
         body = layer_fn
         if cfg.remat:
             body = ra.checkpoint_keeping_attention(layer_fn)
-        out, stats = lax.scan(body, act, stage_params)
+        with scope(LAYERS_SCOPE):
+            out, stats = lax.scan(body, act, stage_params)
         return (out, stats) if with_stats else out
 
     return stage_fn

@@ -256,6 +256,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..metrics.registry import registry
+from ..utils.profiler import ATTN_OPERAND_SCOPES, scope
 
 _NEG_INF = -1e30
 
@@ -263,6 +264,8 @@ _NEG_INF = -1e30
 # made: the kernel's (B, S, H·D) output and the (B, H, S) fp32 ``lse``.
 SAVED_OUT = "flash_attention_out"
 SAVED_LSE = "flash_attention_lse"
+# What XLA does to make the backward kernel's ``delta`` operand.
+DELTA_SCOPE = ATTN_OPERAND_SCOPES[1]
 
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
 # A dimension no candidate divides is taken whole up to this length, and a
@@ -819,7 +822,8 @@ def _bwd_call(q, k, v, do, lse, delta, offsets, *, heads, group, causal,
 
     # Row statistics in the sublane-replicated (B, H, 8, S) kernel layout.
     lse = jnp.broadcast_to(lse[:, :, None, :], (b, h, 8, sq))
-    delta = jnp.broadcast_to(delta[:, :, None, :], (b, h, 8, sq))
+    with scope(DELTA_SCOPE):
+        delta = jnp.broadcast_to(delta[:, :, None, :], (b, h, 8, sq))
 
     # The pass: a key tile resident, its query tiles in a row.  dQᵀ's block
     # is the program's heads' whole, resident from its first step to its
@@ -940,6 +944,15 @@ def _flash_impl(q, k, v, offsets, causal, scale, tiling, interpret,
                      window=window, bd=bd, static_offsets=static_offsets)
 
 
+def _delta(g, out):
+    """``delta`` = sum over a head of dO * O, (B, H, Sq) fp32: the backward
+    kernel's row statistic, made by XLA from the cotangent and the saved
+    output, under its own name in a profile."""
+    with scope(DELTA_SCOPE):
+        return jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                       axis=-1).transpose(0, 2, 1)
+
+
 def _flash_fwd(q, k, v, offsets, causal, scale, tiling, interpret,
                window=None, bd=None, static_offsets=None):
     out, lse = _flash_impl(q, k, v, offsets, causal, scale, tiling,
@@ -956,8 +969,7 @@ def _flash_fwd(q, k, v, offsets, causal, scale, tiling, interpret,
 def _flash_bwd(causal, scale, tiling, interpret, window, bd, static_offsets,
                res, g):
     q, k, v, offsets, out, lse = res
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)                # (B, H, Sq)
+    delta = _delta(g, out)
     dq, dk, dv = _bwd_call(_rows(q), _rows(k), _rows(v), _rows(g), lse,
                            delta, offsets, heads=q.shape[2],
                            group=tiling.group, causal=causal, scale=scale,
@@ -1006,8 +1018,7 @@ def _flash_sel_fwd(q, k, v, sel, scale, tiling, interpret):
 def _flash_sel_bwd(scale, tiling, interpret, res, g):
     q, k, v, sel, out, lse = res
     g = g[0]                       # lse's cotangent: nothing reads it
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)                # (B, H, Sq)
+    delta = _delta(g, out)
     offsets, static_offsets = _offsets(0, 0)
     dq, dk, dv = _bwd_call(_rows(q), _rows(k), _rows(v), _rows(g), lse,
                            delta, offsets, heads=q.shape[2],

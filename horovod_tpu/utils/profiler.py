@@ -17,9 +17,9 @@ on the host timeline of a captured trace alongside the device steps.
 
 * ``scope(name)`` — names a block of the *compiled* training step
   (``STEP_SCOPES``, ``MOE_SCOPES``, ``SSM_SCOPES``,
-  ``LATENT_MOE_SCOPES``, ``ATTN_PART_SCOPES``, ``INDEX_SCOPES``,
-  ``DENSE_MLP_SCOPE``,
-  ``CONV_SCOPES``, ``TP_RING_SCOPES``): a
+  ``LATENT_MOE_SCOPES``, ``ATTN_PART_SCOPES``, ``ATTN_OPERAND_SCOPES``,
+  ``INDEX_SCOPES``, ``DENSE_MLP_SCOPE``, ``CONV_SCOPES``,
+  ``TP_RING_SCOPES``, ``LAYERS_SCOPE``): a
   ``jax.named_scope``, so the name
   lands in every HLO operation's ``op_name`` and from there in a device
   profile.
@@ -86,6 +86,11 @@ LATENT_MOE_SCOPES = ("moe_latent", "moe_shared")
 # and k, the per-head gate on the attention's output, and the per-head
 # RMSNorm of q and k (``head_qk_norm``).
 ATTN_PART_SCOPES = ("attn_rope", "attn_gate", "attn_qknorm")
+# Inside the ``attn`` block, what XLA does to make the flash kernels'
+# operands: each K / V head repeated across its query heads
+# (``models/transformer._gqa_mixer``), and ``delta`` = sum(dO * O) with its
+# broadcast to eight sublanes (``ops/flash_attention.py``, backward only).
+ATTN_OPERAND_SCOPES = ("attn_kv_repeat", "attn_delta")
 # Inside the ``attn`` block of learned sparse attention: the indexer (its
 # projections, its scores, the choice), inside that the ranking alone, and
 # the indexer's loss, forward and backward (``ops/sparse_index.py``).
@@ -98,6 +103,11 @@ DENSE_MLP_SCOPE = "mlp_dense"
 # and inside it everything between its two matmuls: both gates and the
 # depthwise convolution.
 CONV_SCOPES = ("conv", "conv_gate")
+# Around the ``lax.scan`` over the layer stack (and a pattern's leading
+# blocks): the blocks carry it beside their own names, and what carries it
+# alone is the scan's own work: the saved stacks written and read a layer at
+# a time, the per-layer weight slices, the gradient stacks.
+LAYERS_SCOPE = "layers"
 # Inside ``attn`` and ``mlp`` over more than one ``mp`` member: the two forms
 # of ``parallel/tensor_parallel.py``'s ring, whose collective-permutes a
 # profile then shows by block, phase and form.

@@ -112,10 +112,11 @@ def test_every_mp_permute_has_a_matmul_between_start_and_done(step):
     hlo, _mem = step
     comps = computations(hlo)
     rings = [p for lines in comps.values() for p in permutes(lines, comps)]
-    # Forward, rematerialised and backward bodies, gathers and scatters.
+    # Forward, rematerialised and backward bodies, gathers and scatters
+    # (the scan over the layers lies under ``hvd_layers``).
     phases = ("jvp()", "rematted_computation",
-              "transpose(jvp())/shard_map/while/body/closed_call/checkpoint/"
-              "hvd_")
+              "transpose(jvp())/shard_map/hvd_layers/while/body/closed_call/"
+              "checkpoint/hvd_")
     for phase in phases:
         for form in ("hvd_tp_ring_gather", "hvd_tp_ring_scatter"):
             assert any(phase in name and form in name
