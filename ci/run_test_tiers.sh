@@ -148,6 +148,12 @@ TIER_FAST=(
   # The Pallas kernel that adds those rows in VMEM (ISSUE 47), alone in the
   # interpreter: tile and chunk edges, a shared walk, exact weights.
   test_token_sum_kernel.py
+  # q and k's position prologue as one Pallas pass (ISSUE 53): the kernel
+  # alone in the interpreter against the jnp form, every form a
+  # configuration gives it; and the step lowered for a TPU holding it under
+  # hvd_attn_rope in all three passes, the jnp form elsewhere.
+  test_qk_position_kernel.py
+  test_qk_position_step.py
   benchmark_tests/test_benchmark_sdar.py
   benchmark_tests/test_benchmark_compile_v5e_sdar.py
   # LFM2's gated short convolution on the training path (ISSUE 41): the "C"

@@ -71,6 +71,8 @@ from jax import lax
 from ..compat import axis_size
 from jax.sharding import PartitionSpec as P
 
+from ..metrics.registry import registry
+from ..ops import qk_position
 from ..ops import sparse_index as si
 from ..ops import ssd
 from ..parallel import moe as moe_lib
@@ -528,70 +530,217 @@ def _yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
             ).astype(np.float32)
 
 
-def _rope(t, positions, theta: float, fraction: float = 1.0, yarn=None):
-    """Rotate-half rotary embedding (HF ``apply_rotary_pos_emb``) on the
-    first ``fraction`` of each head, the rest passing: with the rotary part
-    ``[t1, t2]`` split at its half, ``[t1 cos - t2 sin, t2 cos + t1 sin]``
-    at angle ``position * theta^(-2i/rot)``, in fp32; with ``yarn`` the
-    frequencies are :func:`_yarn_inv_freq`'s and cos and sin are multiplied
-    by its attention factor.  ``t``: (mb, S, heads, hd); ``positions``:
-    (S,) global token positions."""
-    rot = int(t.shape[-1] * fraction)
+def _rope_angles(positions, rot: int, theta: float, yarn=None, lanes=None):
+    """(cos, sin), (S, rot // 2) fp32 each: the angle ``position *
+    theta^(-2i/rot)`` of the frequencies that rotate the first ``rot``
+    features of a head; with ``yarn`` the frequencies are
+    :func:`_yarn_inv_freq`'s and cos and sin are multiplied by its attention
+    factor.  ``positions``: (S,) global token positions.  With ``lanes``
+    (static ints) the columns are those frequencies' instead, one a lane of
+    the kernel's tables (``ops/qk_position.lanes``)."""
     half = rot // 2
+    index = np.arange(half) if lanes is None else lanes
     if yarn is None:
-        inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
-                                    / half))
+        inv_freq = 1.0 / (theta ** (jnp.asarray(index, jnp.float32) / half))
     else:
-        inv_freq = jnp.asarray(_yarn_inv_freq(rot, theta, yarn))
+        inv_freq = jnp.asarray(_yarn_inv_freq(rot, theta, yarn)[index])
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.cos(angle)[None, :, None, :]
-    sin = jnp.sin(angle)[None, :, None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
     if yarn is not None:
         cos, sin = cos * yarn[4], sin * yarn[4]
-    tf = t.astype(jnp.float32)
-    t1, t2 = tf[..., :half], tf[..., half:rot]
-    parts = [t1 * cos - t2 * sin, t2 * cos + t1 * sin]
-    if rot < t.shape[-1]:
-        parts.append(tf[..., rot:])
-    return jnp.concatenate(parts, axis=-1).astype(t.dtype)
+    return cos, sin
 
 
-def _rope_streams(t, positions, theta: float, sections):
-    """:func:`_rope` over the whole head with the angle of frequency i read
-    from one of several position streams (Qwen2-VL's sectioned layout):
-    ``positions`` (mb, streams, S) integers, ``sections`` the frequencies a
-    stream takes, in order, ``sum(sections)`` = half the head: frequency i
-    turns by ``positions[:, c(i)] * theta^(-2i/hd)``, c(i) the section i
-    falls in.  ``t``: (mb, S, heads, hd)."""
-    half = t.shape[-1] // 2
+def _stream_angles(positions, head_dim: int, theta: float, sections,
+                   lanes=None):
+    """(cos, sin), (mb, S, head_dim // 2) fp32 each, with the angle of
+    frequency i read from one of several position streams (Qwen2-VL's
+    sectioned layout): ``positions`` (mb, streams, S) integers, ``sections``
+    the frequencies a stream takes, in order, ``sum(sections)`` = half the
+    head: frequency i turns by ``positions[:, c(i)] * theta^(-2i/hd)``, c(i)
+    the section i falls in.  ``lanes`` as :func:`_rope_angles` takes it."""
+    half = head_dim // 2
     if sum(sections) != half or positions.shape[1] != len(sections):
         raise ValueError(
             f"rope sections {tuple(sections)} over {positions.shape[1]} "
             f"position streams do not cover the {half} frequencies of a head "
-            f"of {t.shape[-1]}")
-    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    stream = np.repeat(np.arange(len(sections)), sections)       # (half,)
+            f"of {head_dim}")
+    index = np.arange(half) if lanes is None else lanes
+    inv_freq = 1.0 / (theta ** (jnp.asarray(index, jnp.float32) / half))
+    stream = np.repeat(np.arange(len(sections)), sections)[index]
     at = jnp.moveaxis(positions.astype(jnp.float32), 1, 2)   # (mb, S, streams)
-    angle = at[..., stream] * inv_freq                        # (mb, S, half)
-    cos, sin = jnp.cos(angle)[:, :, None, :], jnp.sin(angle)[:, :, None, :]
+    angle = at[..., stream] * inv_freq                   # (mb, S, frequencies)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rotate_half(t, cos, sin):
+    """Rotate-half rotary embedding (HF ``apply_rotary_pos_emb``) on the
+    first ``2 half`` features of each head, the rest passing: with the
+    rotary part ``[t1, t2]`` split at its half, ``[t1 cos - t2 sin, t2 cos +
+    t1 sin]``, in fp32.  ``t``: (mb, S, heads, hd); ``cos``, ``sin``: (S,
+    half), or (mb, S, half) where a sequence has its own angles."""
+    half = cos.shape[-1]
+    cos, sin = cos[..., None, :], sin[..., None, :]
     tf = t.astype(jnp.float32)
-    t1, t2 = tf[..., :half], tf[..., half:]
-    return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
-                           axis=-1).astype(t.dtype)
+    t1, t2 = tf[..., :half], tf[..., half:2 * half]
+    parts = [t1 * cos - t2 * sin, t2 * cos + t1 * sin]
+    if 2 * half < t.shape[-1]:
+        parts.append(tf[..., 2 * half:])
+    return jnp.concatenate(parts, axis=-1).astype(t.dtype)
+
+
+def _rope(t, positions, theta: float, fraction: float = 1.0, yarn=None):
+    """:func:`_rotate_half` of ``t`` (mb, S, heads, hd) on the first
+    ``fraction`` of each head at :func:`_rope_angles`: the rotation as the
+    tests and the references read it."""
+    return _rotate_half(t, *_rope_angles(
+        positions, int(t.shape[-1] * fraction), theta, yarn))
+
+
+def _rope_streams(t, positions, theta: float, sections):
+    """:func:`_rotate_half` over the whole head at :func:`_stream_angles`."""
+    return _rotate_half(t, *_stream_angles(
+        positions, t.shape[-1], theta, sections))
+
+
+def _position(q, k, cos, sin, scales=(), eps: float = 0.0):
+    """q and k's position prologue, the one statement of its mathematics:
+    each head normalised by ``scales`` — (q's, k's) of a ``head_qk_norm``,
+    or () — then rotated (:func:`_rotate_half`).  What the kernel
+    ``ops/qk_position.py`` is tested against, and what runs where it does
+    not: off a TPU, and at shapes it does not fit."""
+    if scales:
+        q, k = (_rmsnorm(t, scale, eps) for t, scale in zip((q, k), scales))
+    return _rotate_half(q, cos, sin), _rotate_half(k, cos, sin)
+
+
+def _position_pullback(q, k, cos, sin, scales, eps: float, gq, gk):
+    """:func:`_position`'s pullback of (gq, gk) in the same form: the
+    transpose of a rotation is the rotation by the opposite angle, and the
+    norm's is AD's.  (dq, dk, the scales' gradients or ())."""
+    gq, gk = _rotate_half(gq, cos, -sin), _rotate_half(gk, cos, -sin)
+    if not scales:
+        return gq, gk, ()
+    pulled = [jax.vjp(functools.partial(_rmsnorm, eps=eps), t, scale)[1](g)
+              for t, scale, g in zip((q, k), scales, (gq, gk))]
+    return pulled[0][0], pulled[1][0], (pulled[0][1], pulled[1][1])
+
+
+def _for_tpu_or(kernel, xla, *operands):
+    """``kernel(*operands)`` where the program is lowered for a TPU,
+    ``xla(*operands)`` elsewhere: on a TPU host the kernel is traced alone,
+    off one ``lax.platform_dependent`` decides as the program is lowered
+    (``parallel/moe.py`` ``_token_sums``), so the CPU suite runs no Pallas
+    interpreter and a step lowered for a described TPU holds the kernel."""
+    if jax.default_backend() == "tpu":
+        return kernel(*operands)
+    return lax.platform_dependent(*operands, tpu=kernel, default=xla)
+
+
+def _rows(t):
+    """(mb, S, heads, hd) as the (mb, S, heads * hd) rows it is; None as
+    None."""
+    return t if t is None else t.reshape(t.shape[:2] + (-1,))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _qk_position(q, k, cos, sin, scales, half: int, eps: float, block: int):
+    """:func:`_position` as the kernel ``hvd_qk_position``: q and k go as
+    the (mb, S, heads * hd) rows they are, one call for both, ``block``
+    positions a program (``ops/qk_position.block``); ``cos`` and ``sin``
+    come a lane (``ops/qk_position.lanes``), the first ``half`` of them the
+    frequencies' own.  The backward is one call too, and saves nothing the
+    layer's checkpoint would keep: q, k and the angles are recomputed with
+    the block."""
+    return _qk_position_fwd(q, k, cos, sin, scales, half, eps, block)[0]
+
+
+def _qk_position_fwd(q, k, cos, sin, scales, half, eps, block):
+    hd = q.shape[-1]
+
+    def kernel(q, k, cos, sin, scales):
+        out = qk_position.forward(
+            _rows(q), _rows(k), qk_position.tables(cos, sin, hd, half),
+            scales, head_dim=hd, half=half, eps=eps, block=block)
+        return tuple(o.reshape(t.shape) for o, t in zip(out, (q, k)))
+
+    def xla(q, k, cos, sin, scales):
+        return _position(q, k, cos[..., :half], sin[..., :half], scales, eps)
+
+    out = _for_tpu_or(kernel, xla, q, k, cos, sin, scales)
+    # The rotation's transpose reads neither q nor k.
+    return out, ((q, k) if scales else (None, None), cos, sin, scales)
+
+
+def _qk_position_bwd(half, eps, block, saved, g):
+    (q, k), cos, sin, scales = saved
+    hd = g[0].shape[-1]
+
+    def kernel(q, k, cos, sin, scales, gq, gk):
+        dq, dk, sums = qk_position.backward(
+            _rows(q), _rows(k), qk_position.tables(cos, sin, hd, half),
+            scales, _rows(gq), _rows(gk), head_dim=hd, half=half, eps=eps,
+            block=block)
+        with scope("attn_qknorm"):      # the programs' partial sums
+            sums = tuple(
+                t.reshape(-1, hd).sum(axis=0).astype(scale.dtype)
+                for t, scale in zip(sums, scales))
+        return dq.reshape(gq.shape), dk.reshape(gk.shape), sums
+
+    def xla(q, k, cos, sin, scales, gq, gk):
+        return _position_pullback(q, k, cos[..., :half], sin[..., :half],
+                                  scales, eps, gq, gk)
+
+    dq, dk, dscales = _for_tpu_or(kernel, xla, q, k, cos, sin, scales, *g)
+    return dq, dk, None, None, dscales
+
+
+_qk_position.defvjp(_qk_position_fwd, _qk_position_bwd)
+
+
+def _position_heads(site: str, q, k, angles, half: int, scales=(),
+                    eps: float = 0.0):
+    """:func:`_position` of q (mb, S, heads, hd) and k (mb, S, kv heads, hd)
+    as the shapes allow: the kernel, the norm inside it, where they fit it
+    (``ops/qk_position.block``: heads that tile 128 lanes, positions a
+    multiple of a block); for anything else the jnp form, the norm under
+    its own scope.  ``angles(lanes=None)``: (cos, sin) of the
+    ``half`` frequencies, or of those ``lanes`` names
+    (:func:`_rope_angles`, :func:`_stream_angles`).  ``site`` labels the
+    trace-time counter ``hvd_qk_position_built_total{site, form}``."""
+    hd = q.shape[-1]
+    block = qk_position.block(q.shape[1], q.shape[2] * hd, k.shape[2] * hd,
+                              hd, q.dtype.itemsize)
+    if scales and block is None:
+        with scope("attn_qknorm"):
+            q, k = (_rmsnorm(t, scale, eps)
+                    for t, scale in zip((q, k), scales))
+        scales = ()
+    registry().counter(
+        "hvd_qk_position_built_total",
+        "position prologues of q and k traced, by site and by form: the "
+        "kernel hvd_qk_position, or XLA's slices and concatenate",
+        site=site, form="xla" if block is None else "kernel").inc()
+    with scope("attn_rope"):
+        if block is None:
+            return _position(q, k, *angles())
+        return _qk_position(q, k, *angles(qk_position.lanes(hd, half)),
+                            tuple(scales), half, eps, block)
 
 
 def _position_qk(cfg: TransformerConfig, lp, q, k, positions, axis_name):
     """What the configuration does to q and k between the projection and
     the attention: nothing (learned positions were added to the stream),
-    or OLMoE's QK-norm and rotary positions."""
+    or OLMoE's QK-norm — over every feature of a row, XLA's — and rotary
+    positions (:func:`_position_heads`)."""
     if cfg.qk_norm:
         with scope("attn_qknorm"):
             q = _qk_norm(q, lp["q_norm"], cfg.norm_eps, axis_name)
             k = _qk_norm(k, lp["k_norm"], cfg.norm_eps, axis_name)
     if cfg.rope_theta is not None:
-        with scope("attn_rope"):
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+        hd = q.shape[-1]
+        q, k = _position_heads("layer", q, k, functools.partial(
+            _rope_angles, positions, hd, cfg.rope_theta, None), hd // 2)
     return q, k
 
 
@@ -754,7 +903,7 @@ class _Attention(NamedTuple):
     theta: Optional[float]        # rotary base; None: no position encoding
     fraction: float = 1.0         # the share of each head that rotates
     yarn: Optional[Tuple[float, int, float, float, float]] = None
-    # Frequencies a position stream (``_rope_streams``); None: one stream.
+    # Frequencies a position stream (``_stream_angles``); None: one stream.
     sections: Optional[Tuple[int, ...]] = None
     # (heads, head width, keys a query) of a learned indexer; None: none.
     index: Optional[Tuple[int, int, int]] = None
@@ -806,11 +955,11 @@ def _index(cfg: TransformerConfig, lp, hnorm, positions, a: "_Attention"):
     qi = project("index_wq").reshape(mb, s, j, di)
     ki = _layernorm(project("index_wk"), lp["index_k_norm"],
                     lp["index_k_bias"], cfg.norm_eps)
-    with scope("attn_rope"):
-        sections = [n * di // cfg.head_dim for n in a.sections]
-        qi = _rope_streams(qi, positions, a.theta, sections)
-        ki = _rope_streams(ki[:, :, None], positions, a.theta,
-                           sections)[:, :, 0]
+    sections = [n * di // cfg.head_dim for n in a.sections]
+    qi, ki = _position_heads(
+        "index", qi, ki[:, :, None], functools.partial(
+            _stream_angles, positions, di, a.theta, sections), di // 2)
+    ki = ki[:, :, 0]
     w = project("index_ww").astype(jnp.float32) * (j * di) ** -0.5
     return qi, ki, w
 
@@ -826,14 +975,18 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
     block-diffusion one: a noised query sees its own noised block and the
     clean blocks before it, a clean query the clean blocks up to its own.
     ``head_qk_norm`` normalises each head of q and of k (one scale vector of
-    ``head_dim`` each, shared by the heads) before the rotation; ``attn_gate``
-    multiplies head i's output by ``sigmoid(h Wg)_i``, a scalar a head and
-    token from the block's normed input.  Each K / V head is repeated
-    across its query heads before the kernels (their index maps taking
-    several query heads a K / V block is ROADMAP M4).
+    ``head_dim`` each, shared by the heads) before the rotation; the two are
+    q and k's position prologue, :func:`_position_heads`: one pass of the
+    kernel ``hvd_qk_position`` over the rows the projections wrote, which the
+    flash kernels (q) and the repeat (k) read as they are, where the shapes
+    fit it and the step is lowered for a TPU, the jnp form elsewhere.
+    ``attn_gate`` multiplies head i's output by ``sigmoid(h Wg)_i``, a scalar
+    a head and token from the block's normed input.  Each K / V head is
+    repeated across its query heads before the kernels (their index maps
+    taking several query heads a K / V block is ROADMAP M4).
 
     A variant with ``sections`` rotates by ``positions`` (mb, streams, S), a
-    batch array (``_rope_streams``); causality stays the index in the
+    batch array (:func:`_stream_angles`); causality stays the index in the
     sequence.  A variant with an ``index`` (an "S" block) returns ``(y, the
     indexer's loss)``: the indexer ranks every query's causal keys
     (``ops/sparse_index.select``, exact), the attention runs over the
@@ -850,25 +1003,30 @@ def _gqa_mixer(cfg: TransformerConfig, lp: Dict[str, jax.Array],
                           w.astype(x.dtype)).reshape(mb, s, n, hd)
 
     q, k, v = heads(lp["wq"], hq), heads(lp["wk"], hkv), heads(lp["wv"], hkv)
-    if cfg.head_qk_norm:
-        with scope("attn_qknorm"):
-            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+    scales = (lp["q_norm"], lp["k_norm"]) if cfg.head_qk_norm else ()
     if a.sections is not None:
         if positions is None:
             raise ValueError(
                 f"rope_sections {a.sections}: the batch brings the position "
                 "streams as its third array, (B, streams, S)")
-        with scope("attn_rope"):
-            q, k = (_rope_streams(t, positions, a.theta, a.sections)
-                    for t in (q, k))
+        rot, angles = hd, functools.partial(
+            _stream_angles, positions, hd, a.theta, a.sections)
     elif a.theta is not None:
-        with scope("attn_rope"):
-            def index():              # one arange a tensor, as ever
-                at = jnp.arange(s)
-                return at if cfg.diffusion_block is None else at % (s // 2)
-            q, k = (_rope(t, index(), a.theta, a.fraction, a.yarn)
-                    for t in (q, k))
+        at = jnp.arange(s)
+        rot = int(hd * a.fraction)
+        angles = functools.partial(
+            _rope_angles,
+            at if cfg.diffusion_block is None else at % (s // 2),
+            rot, a.theta, a.yarn)
+    else:
+        angles = None
+    if angles is not None:
+        q, k = _position_heads("gqa", q, k, angles, rot // 2, scales,
+                               cfg.norm_eps)
+    elif scales:
+        with scope("attn_qknorm"):
+            q, k = (_rmsnorm(t, scale, cfg.norm_eps)
+                    for t, scale in zip((q, k), scales))
     k_own = k
     if hkv != hq:
         with scope(ATTN_OPERAND_SCOPES[0]):
